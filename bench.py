@@ -20,6 +20,13 @@ headline metric (strategy vs DP).  Pass a strategy file as argv[1] to bench
 it; with no strategy the benched config IS pure DP, so vs_baseline = 1.0 by
 definition (no second run is made).  BENCH_MODEL=alexnet switches to the
 AlexNet sanity config (batch 1024; single-chip saturation knee).
+
+The number is a device measurement: on any platform but ``tpu`` the run
+refuses (non-zero exit, no metric line), and the line names the platform,
+``device_kind`` and device count it measured on.  ``--cpu-rehearsal`` (an
+argument, never a default or an environment variable) runs the same code
+pinned to the CPU for the schema check of ``make bench-smoke``: the
+metric is then named ``cpu_rehearsal_*`` and carries no utilization.
 """
 
 import json
@@ -29,24 +36,19 @@ import time
 
 
 def run(model="inception", batch_size=None, iters=10, warmup=3,
-        dtype="bfloat16", strategy_file=None, compile_cache=False,
-        windows=5, param_dtype="float32", placed_overlap="on"):
+        dtype="bfloat16", strategy_file=None, windows=5,
+        param_dtype="float32", placed_overlap="on", rehearsal=False):
     """Returns (per_chip, tput, elapsed, mfu, spread, extras) — ``extras``
-    carries the execution-performance gauges the round-6 prongs add:
-    ``input_stall_s`` (prefetch residual over the timed windows) and the
-    regrid plan accounting."""
+    carries the device the run measured on, the execution-performance
+    gauges the round-6 prongs add (``input_stall_s``: prefetch residual
+    over the timed windows; the regrid plan accounting) and the
+    compiled-program account.  Refuses a platform other than ``tpu``
+    unless ``rehearsal``."""
     import jax
 
-    if compile_cache:
-        # persistent XLA compile cache: first-ever run pays ~3 min of
-        # Inception compilation, subsequent runs (e.g. the driver's) start
-        # in seconds.  Opt-in because it mutates process-global jax config;
-        # the CLI below enables it, library callers are unaffected.
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
+    from flexflow_tpu.utils.chip import max_memory_stat, require_tpu
+
+    device = require_tpu("bench.py", rehearsal)
 
     from flexflow_tpu.config import FFConfig
     from flexflow_tpu.data import synthetic_batches
@@ -83,7 +85,8 @@ def run(model="inception", batch_size=None, iters=10, warmup=3,
         img, lbl = next(data)
         params, state, opt_state, loss = step(params, state, opt_state,
                                               img, lbl)
-    float(loss)  # full sync (the steps form one dependency chain)
+    # sync-ok: full sync (the steps form one dependency chain)
+    jax.block_until_ready(loss)
     # Variance protocol (round 5, VERDICT r4 #2): a single timed window
     # made every per-round delta unfalsifiable.  Time ``windows``
     # independent windows of ``iters`` steps (each closed by a full
@@ -98,9 +101,10 @@ def run(model="inception", batch_size=None, iters=10, warmup=3,
             img, lbl = next(data)
             params, state, opt_state, loss = step(params, state, opt_state,
                                                   img, lbl)
-        float(loss)
+        jax.block_until_ready(loss)  # sync-ok: closes the timed window
         samples.append(time.perf_counter() - t0)
-    extras = {"input_stall_s": round(data.stall_s - stall0, 6)}
+    extras = {"device": device,
+              "input_stall_s": round(data.stall_s - stall0, 6)}
     # top-level budget shares (MFU-waterfall round): how much of the
     # timed windows went to input stall (measured), and the simulator's
     # collective share for the benched assignment (the paper's per-op
@@ -109,24 +113,18 @@ def run(model="inception", batch_size=None, iters=10, warmup=3,
     extras["stall_frac"] = round(extras["input_stall_s"] / total_timed, 6) \
         if total_timed > 0 else 0.0
     extras["comm_frac"] = 0.0
-    try:
-        from flexflow_tpu.sim.search import StrategySearch
+    from flexflow_tpu.sim.search import StrategySearch
 
-        ss = StrategySearch(ff, machine=machine)
-        asn = ss.assignment_for(cfg.strategies) if cfg.strategies \
-            else ss.dp_assignment()
-        sim_total = ss.simulate(asn)
-        if sim_total > 0:
-            extras["comm_frac"] = round(
-                sum(r["collective_s"]
-                    for r in ss.cost_breakdown(asn)) / sim_total, 6)
-    except Exception as e:
-        print(f"comm_frac unavailable: {e}", file=sys.stderr)
+    ss = StrategySearch(ff, machine=machine)
+    asn = ss.assignment_for(cfg.strategies) if cfg.strategies \
+        else ss.dp_assignment()
+    sim_total = ss.simulate(asn)
+    if sim_total > 0:
+        extras["comm_frac"] = round(
+            sum(r["collective_s"]
+                for r in ss.cost_breakdown(asn)) / sim_total, 6)
     data.close()
-    try:
-        rsum = ff.regrid_plan_summary()
-    except Exception:
-        rsum = None
+    rsum = ff.regrid_plan_summary()
     if rsum:
         extras["regrid_hops"] = rsum["hops_after"]
         extras["regrid"] = rsum
@@ -147,70 +145,56 @@ def run(model="inception", batch_size=None, iters=10, warmup=3,
 
     # MFU: FLOPs of the COMPILED step (post-fusion XLA cost analysis) over
     # elapsed time and whole-machine peak FLOPs — the pressure gauge
-    # VERDICT r1 asked for (weak #7).  Lowering hits jit's cache.
+    # VERDICT r1 asked for (weak #7).  Lowering hits jit's cache.  The
+    # peaks are those of the device_kind the run measured on (an unknown
+    # kind raises); a rehearsal has no chip to be a fraction of.
+    from flexflow_tpu.sim.cost_model import chip_perf
     from flexflow_tpu.utils.profiling import compiled_roofline
 
-    mfu = None
-    try:
-        compiled = step.lower(params, state, opt_state, img, lbl).compile()
-        rl = compiled_roofline(compiled, elapsed / iters,
-                               n_devices=machine.num_devices)
-        mfu = rl.get("mxu_utilization")
-        # the roofline ceiling (the honest MFU upper bound of THIS
-        # compiled program) and the step's HBM footprint — runtime peak
-        # when the backend reports it, else the compiled memory analysis
-        # (arguments + outputs - aliased + temporaries)
-        from flexflow_tpu.sim.cost_model import TpuChipPerf
-
-        perf = TpuChipPerf()
+    perf = None if rehearsal else chip_perf(device["kind"])
+    compiled = step.lower(params, state, opt_state, img, lbl).compile()
+    rl = compiled_roofline(compiled, elapsed / iters, perf,
+                           n_devices=machine.num_devices)
+    mfu = rl.get("mxu_utilization")
+    flops, bytes_ = rl["flops"], rl["bytes_accessed"]
+    if perf is not None and flops > 0:
+        # the roofline ceiling: the honest MFU upper bound of THIS
+        # compiled program on this chip
         peak = perf.peak_flops * machine.num_devices
         hbm_bw = perf.hbm_bandwidth * machine.num_devices
-        flops, bytes_ = rl["flops"], rl["bytes_accessed"]
-        floor = max(flops / peak, bytes_ / hbm_bw)
-        if flops > 0 and floor > 0:
-            extras["mfu_ceiling"] = round(flops / floor / peak, 4)
-            if mfu is not None:
-                # of_ceiling (VERDICT item 6): fraction of THIS
-                # program's honest roofline achieved — separates "the
-                # program is memory-bound" from "we left time on the
-                # table" in a way raw MFU can't
-                extras["of_ceiling"] = round(
-                    mfu / (flops / floor / peak), 4)
-        # compiled-program identity: line count + content hash of the
-        # optimized HLO, so two metric lines are comparable at a glance
-        # (same fingerprint = same program; an MFU move with a changed
-        # fingerprint is a different compilation, not a runtime win)
-        import hashlib
+        ceiling = flops / max(flops / peak, bytes_ / hbm_bw) / peak
+        extras["mfu_ceiling"] = round(ceiling, 4)
+        # of_ceiling (VERDICT item 6): fraction of THIS program's honest
+        # roofline achieved — separates "the program is memory-bound"
+        # from "we left time on the table" in a way raw MFU can't
+        extras["of_ceiling"] = round(mfu / ceiling, 4)
+    # compiled-program identity: line count + content hash of the
+    # optimized HLO, so two metric lines are comparable at a glance
+    # (same fingerprint = same program; an MFU move with a changed
+    # fingerprint is a different compilation, not a runtime win)
+    import hashlib
 
-        hlo_text = compiled.as_text()
-        extras["hlo_fingerprint"] = (
-            f"{len(hlo_text.splitlines())}:"
-            f"{hashlib.sha256(hlo_text.encode()).hexdigest()[:12]}")
-        # donation account (round 13): bytes the step aliases in place,
-        # straight from the executable's input_output_alias header — the
-        # same ground truth the enforcing lint reads.  A donated_bytes
-        # collapse between two metric lines means a buffer fell off the
-        # donation path (and the lint will name it).
-        from flexflow_tpu.verify.donation_lint import donation_summary
+    hlo_text = compiled.as_text()
+    extras["hlo_fingerprint"] = (
+        f"{len(hlo_text.splitlines())}:"
+        f"{hashlib.sha256(hlo_text.encode()).hexdigest()[:12]}")
+    # donation account (round 13): bytes the step aliases in place,
+    # straight from the executable's input_output_alias header — the
+    # same ground truth the enforcing lint reads.  A donated_bytes
+    # collapse between two metric lines means a buffer fell off the
+    # donation path (and the lint will name it).
+    from flexflow_tpu.verify.donation_lint import donation_summary
 
-        extras["donated_bytes"] = donation_summary(hlo_text)[
-            "donated_bytes"]
-        hbm_peak = None
-        try:
-            stats = machine.devices[0].memory_stats() or {}
-            hbm_peak = stats.get("peak_bytes_in_use")
-        except Exception:
-            pass
-        if hbm_peak is None:
-            mem = compiled.memory_analysis()
-            hbm_peak = (getattr(mem, "argument_size_in_bytes", 0)
-                        + getattr(mem, "output_size_in_bytes", 0)
-                        - getattr(mem, "alias_size_in_bytes", 0)
-                        + getattr(mem, "temp_size_in_bytes", 0))
-        if hbm_peak:
-            extras["hbm_peak_gb"] = round(hbm_peak / 1e9, 4)
-    except Exception:
-        pass  # cost analysis unavailable on some backends: omit MFU
+    extras["donated_bytes"] = donation_summary(hlo_text)["donated_bytes"]
+    # the step's HBM footprint: the fullest device's runtime peak where
+    # the backend reports one, else the compiled memory analysis
+    # (arguments + outputs - aliased + temporaries)
+    hbm_peak = max_memory_stat(machine.devices, "peak_bytes_in_use")
+    if hbm_peak is None:
+        mem = compiled.memory_analysis()
+        hbm_peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                    - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    extras["hbm_peak_gb"] = round(hbm_peak / 1e9, 4)
     return per_chip, tput, elapsed, mfu, spread, extras
 
 
@@ -228,11 +212,15 @@ def main():
 
 
 def _bench_record():
+    from flexflow_tpu.utils.chip import REHEARSAL_FLAG
+
     model = os.environ.get("BENCH_MODEL", "inception")
-    strategy_file = sys.argv[1] if len(sys.argv) > 1 else None
+    args = [a for a in sys.argv[1:] if a != REHEARSAL_FLAG]
+    rehearsal = len(args) != len(sys.argv) - 1
+    strategy_file = args[0] if args else None
     # smoke knobs (make bench-smoke): shrink the config so the metric
     # line's SCHEMA — incl. the round-6 regrid_hops / input_stall_s
-    # fields — is assertable on a laptop-class CPU run; unset = the
+    # fields — is assertable on a laptop-class CPU rehearsal; unset = the
     # real protocol
     knobs = {}
     for env, key, cast in (("BENCH_BATCH", "batch_size", int),
@@ -245,18 +233,21 @@ def _bench_record():
         if os.environ.get(env):
             knobs[key] = cast(os.environ[env])
     per_chip, tput, elapsed, mfu, spread, extras = run(
-        model=model, strategy_file=strategy_file, compile_cache=True,
+        model=model, strategy_file=strategy_file, rehearsal=rehearsal,
         **knobs)
     if strategy_file:
-        dp_per_chip, _, _, _, _, _ = run(model=model, compile_cache=True,
+        dp_per_chip, _, _, _, _, _ = run(model=model, rehearsal=rehearsal,
                                          **knobs)
         vs_baseline = round(per_chip / dp_per_chip, 4)
     else:
         vs_baseline = 1.0  # benched config is itself the pure-DP baseline
+    metric = (f"{model}_v3_train_throughput_per_chip"
+              if model == "inception" else
+              f"{model}_train_throughput_per_chip")
     out = {
-        "metric": f"{model}_v3_train_throughput_per_chip"
-                  if model == "inception" else
-                  f"{model}_train_throughput_per_chip",
+        # a rehearsal's number is not a device measurement and must not
+        # be found under the device metric's name
+        "metric": f"cpu_rehearsal_{metric}" if rehearsal else metric,
         "value": round(per_chip, 2),
         "unit": "images/s/chip",
         "vs_baseline": vs_baseline,
@@ -265,41 +256,24 @@ def _bench_record():
     out.update(extras)
     # mixed-precision round: which precision/overlap policy this record
     # measured rides the metric line (runs are only comparable within a
-    # policy), plus the MFU delta against the committed round-5 flagship
-    # record — the waterfall's "did the levers move the headline" gauge
+    # policy)
     out["param_dtype"] = knobs.get("param_dtype", "float32")
     out["placed_overlap"] = knobs.get("placed_overlap", "on")
     if mfu is not None:
         out["mfu"] = round(mfu, 4)
-    out["mfu_delta_vs_r05"] = None
-    try:
-        with open(os.path.join(
-                os.path.dirname(os.path.abspath(__file__)),
-                "BENCH_r05.json")) as f:
-            r05_mfu = json.load(f)["parsed"]["mfu"]
-        if mfu is not None:
-            out["mfu_delta_vs_r05"] = round(mfu - r05_mfu, 4)
-    except Exception as e:
-        print(f"mfu_delta_vs_r05 unavailable: {e}", file=sys.stderr)
     # round 13: share of the compute residual held by the fusion
     # auditor's top-3 rows, from the committed roofline profile for the
-    # benched model (None when no fixture exists — the same
-    # key-always-present pattern as mfu_delta_vs_r05).  A shrinking
-    # top-3 share with a flat residual means the big levers were spent
-    # and the tail is next.
-    out["residual_top_frac"] = None
-    try:
-        with open(os.path.join(
-                os.path.dirname(os.path.abspath(__file__)), "examples",
-                "profiles",
-                ("inception_v3" if model == "inception" else model)
-                + "_roofline.json")) as f:
-            profile = json.load(f)
-        from flexflow_tpu.obs.fusions import residual_top_frac
+    # benched model.  A shrinking top-3 share with a flat residual means
+    # the big levers were spent and the tail is next.
+    repo = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(
+            repo, "examples", "profiles",
+            ("inception_v3" if model == "inception" else model)
+            + "_roofline.json")) as f:
+        profile = json.load(f)
+    from flexflow_tpu.obs.fusions import residual_top_frac
 
-        out["residual_top_frac"] = round(residual_top_frac(profile), 4)
-    except Exception as e:
-        print(f"residual_top_frac unavailable: {e}", file=sys.stderr)
+    out["residual_top_frac"] = round(residual_top_frac(profile), 4)
     # the benched strategy's simulated timeline, when the search exported
     # one next to the artifact (apps/search.py -trace writes
     # <stem>.trace.json): its path rides the metric line so the harness
@@ -313,38 +287,34 @@ def _bench_record():
     # Side report (VERDICT r1 #5): the searched strategy this bench would
     # exercise on a multi-chip machine, with its simulated speedup from the
     # committed search artifacts (examples/strategies/summary.json).
-    try:
-        sdir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "examples", "strategies")
-        with open(os.path.join(sdir, "summary.json")) as f:
-            summary = json.load(f)
-        key = f"bench_{model}_8dev.json"
-        if key in summary:
-            out["searched_strategy"] = key
-            out["simulated_speedup_vs_dp"] = summary[key]["speedup_vs_dp"]
-    except Exception:
-        pass
+    with open(os.path.join(repo, "examples", "strategies",
+                           "summary.json")) as f:
+        summary = json.load(f)
+    key = f"bench_{model}_8dev.json"
+    if key in summary:
+        out["searched_strategy"] = key
+        out["simulated_speedup_vs_dp"] = summary[key]["speedup_vs_dp"]
     # bench surface of the obs subsystem: the full record also lands in
     # the run-telemetry JSONL, and its identity rides in the metric line
-    try:
-        from flexflow_tpu import obs as _obs
+    from flexflow_tpu import obs as _obs
 
-        obs_dir = os.environ.get(
-            "BENCH_OBS_DIR",
-            os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         ".obs"))
-        run_id = _obs.new_run_id()
-        with _obs.RunLog(os.path.join(obs_dir, f"{run_id}.jsonl"),
-                         run_id=run_id, surface="bench",
-                         meta={"app": "bench", "model": model,
-                               "strategy_file": strategy_file or ""}) as ol:
-            ol.event("bench", **out)
-            out["run_id"] = run_id
-            out["obs_path"] = ol.path
-    except Exception as e:
-        print(f"obs record unavailable: {e}", file=sys.stderr)
+    obs_dir = os.environ.get("BENCH_OBS_DIR", os.path.join(repo, ".obs"))
+    run_id = _obs.new_run_id()
+    with _obs.RunLog(os.path.join(obs_dir, f"{run_id}.jsonl"),
+                     run_id=run_id, surface="bench",
+                     meta={"app": "bench", "model": model,
+                           "strategy_file": strategy_file or ""}) as ol:
+        ol.event("bench", **out)
+        out["run_id"] = run_id
+        out["obs_path"] = ol.path
     return out
 
 
 if __name__ == "__main__":
+    # first-ever run pays minutes of Inception compilation; later runs
+    # that find the same cache directory start in seconds.  Set by the
+    # CLI only — callers of main()/run() keep their jax config.
+    from flexflow_tpu.utils.chip import enable_compile_cache
+
+    enable_compile_cache()
     main()

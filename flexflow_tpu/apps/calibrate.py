@@ -52,7 +52,7 @@ def _real_cnn_step(model: str, batch: int, dtype: str):
     import bench  # repo-root bench.py — the timed-loop protocol lives there
 
     per_chip, tput, elapsed, _, _, _ = bench.run(
-        model=model, batch_size=batch, dtype=dtype, compile_cache=True,
+        model=model, batch_size=batch, dtype=dtype,
         windows=3)  # calibration wants a stable point, not the full spread
     return batch / tput  # seconds per step (tput is machine-wide)
 
@@ -71,14 +71,16 @@ def _real_nmt_step(dtype: str):
     opt = model.init_opt_state(params)
     step = model.make_train_step()
     batch = next(data)
+    import jax
+
     for _ in range(3):
         params, state, opt, loss = step(params, state, opt, *batch)
-    float(loss)
+    jax.block_until_ready(loss)  # warm-up fence
     iters = 10
     t0 = time.perf_counter()
     for _ in range(iters):
         params, state, opt, loss = step(params, state, opt, *batch)
-    float(loss)
+    jax.block_until_ready(loss)  # closes the timed window
     return (time.perf_counter() - t0) / iters, model
 
 
@@ -289,4 +291,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from flexflow_tpu.utils.chip import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     main()

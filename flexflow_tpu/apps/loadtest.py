@@ -7,7 +7,7 @@ Drives the seeded load generator's composable arrival patterns
 (``diurnal``/``bursty``/``heavy_tail``, '+'-composed; serve/loadgen.py)
 through the continuous-batching engine at a sweep of device counts and
 pins the resulting p50/p99/TTFT/TPOT/QPS/goodput-under-SLO curve the
-way ``bench.py`` / ``BENCH_r0*.json`` pin training throughput.
+way ``bench.py`` pins training throughput.
 
 The sweep holds the virtual per-step service time constant and scales
 the decode rectangle with the mesh (``--slots-per-device`` slots per

@@ -174,4 +174,7 @@ def main(argv=None, log=print) -> dict:
 
 
 if __name__ == "__main__":
+    from flexflow_tpu.utils.chip import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     main()

@@ -26,6 +26,7 @@ def profile_model(model: str = "inception", batch_size: int = 256,
     from flexflow_tpu.config import FFConfig
     from flexflow_tpu.data import synthetic_batches
     from flexflow_tpu.machine import MachineModel
+    from flexflow_tpu.sim.cost_model import chip_perf
     from flexflow_tpu.utils.hlo_profile import (classify_ops,
                                                 device_op_times,
                                                 roofline_report)
@@ -40,6 +41,9 @@ def profile_model(model: str = "inception", batch_size: int = 256,
         raise SystemExit(f"unknown model {model!r}")
 
     machine = MachineModel()
+    # the roofline is a fraction of THIS device's peaks: an unknown
+    # device_kind raises here, before any step is timed
+    perf = chip_perf(machine.devices[0].device_kind)
     cfg = FFConfig(batch_size=batch_size, input_height=size,
                    input_width=size, num_iterations=iters, print_freq=0,
                    compute_dtype=dtype)
@@ -52,12 +56,12 @@ def profile_model(model: str = "inception", batch_size: int = 256,
     for _ in range(3):
         params, state, opt_state, loss = step(params, state, opt_state,
                                               img, lbl)
-    float(loss)
+    jax.block_until_ready(loss)  # sync-ok: warm-up fence
     t0 = time.perf_counter()
     for _ in range(iters):
         params, state, opt_state, loss = step(params, state, opt_state,
                                               img, lbl)
-    float(loss)
+    jax.block_until_ready(loss)  # sync-ok: closes the timed window
     sec = (time.perf_counter() - t0) / iters
 
     trace_steps = 2
@@ -66,13 +70,15 @@ def profile_model(model: str = "inception", batch_size: int = 256,
         for _ in range(trace_steps):
             params, state, opt_state, loss = step(params, state, opt_state,
                                                   img, lbl)
-        float(loss)
+        jax.block_until_ready(loss)  # sync-ok: inside the traced window
 
     compiled = step.lower(params, state, opt_state, img, lbl).compile()
     times = device_op_times(logdir, steps=trace_steps)
     rows, totals = classify_ops(compiled.as_text(), times)
-    report = roofline_report(compiled, sec, totals,
+    report = roofline_report(compiled, sec, perf, totals,
                              n_devices=machine.num_devices)
+    report["device_kind"] = machine.devices[0].device_kind
+    report["devices"] = machine.num_devices
     report["model"] = model
     report["batch_size"] = batch_size
     report["dtype"] = dtype
@@ -108,4 +114,7 @@ def main(argv=None, log=print):
 
 
 if __name__ == "__main__":
+    from flexflow_tpu.utils.chip import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     main()

@@ -406,6 +406,9 @@ def serve_run(opts, log=_err) -> dict:
         summary = engine.run(requests, drain=drain) if decode \
             else engine.run_forward(requests, drain=drain)
     summary["_olog"] = olog
+    # the served requests with their replies, for callers that check
+    # WHAT was answered (chip_smoke.py); never printed
+    summary["_requests"] = requests
     olog.close()
     return summary
 
@@ -849,4 +852,7 @@ def main(argv=None, log=_err) -> int:
 
 
 if __name__ == "__main__":
+    from flexflow_tpu.utils.chip import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     sys.exit(main())

@@ -145,7 +145,10 @@ def mfu_waterfall(events: Iterable[Dict], perf=None) -> Optional[Dict]:
     removable down to the roofline floor; its excess is listed as
     ``compute_overhead``.  Returns None when the stream has no
     ``step_budget`` record; MFU fields are None (seconds-only waterfall)
-    when the compile record carries no cost analysis."""
+    when the compile record carries no cost analysis or the stream was
+    not recorded on a TPU.  The peaks are those of the ``device_kind``
+    the stream's ``run_start`` record names (an unknown TPU kind raises,
+    ``sim.cost_model.chip_perf``) unless ``perf`` is passed."""
     events = list(events)
     budget = _latest(events, "step_budget")
     if budget is None:
@@ -159,21 +162,21 @@ def mfu_waterfall(events: Iterable[Dict], perf=None) -> Optional[Dict]:
     for e in events:
         if e.get("kind") == "run_start" and e.get("devices"):
             devices = int(e["devices"])
-    if perf is None:
-        from flexflow_tpu.sim.cost_model import TpuChipPerf
+            if perf is None and e.get("platform") == "tpu":
+                from flexflow_tpu.sim.cost_model import chip_perf
 
-        perf = TpuChipPerf()
-    peak = perf.peak_flops * max(devices, 1)
-    hbm = perf.hbm_bandwidth * max(devices, 1)
+                perf = chip_perf(e.get("device_kind"))
 
     floor_s = None
     mfu_ceiling = None
-    if flops > 0:
+    if perf is not None and flops > 0:
+        peak = perf.peak_flops * max(devices, 1)
+        hbm = perf.hbm_bandwidth * max(devices, 1)
         floor_s = max(flops / peak, bytes_ / hbm)
         mfu_ceiling = flops / floor_s / peak if floor_s > 0 else None
 
     def mfu_at(seconds: float) -> Optional[float]:
-        if flops <= 0 or seconds <= 0:
+        if floor_s is None or seconds <= 0:
             return None
         v = flops / seconds / peak
         # the floor is the honest limit; measurement jitter must not
@@ -236,8 +239,9 @@ def render_waterfall(wf: Dict) -> List[str]:
                      f"(ceiling {_pct(wf['mfu_ceiling'])} at the "
                      f"{_fmt_s(wf['floor_s'])} roofline floor)")
     else:
-        lines.append("  (no compiled cost analysis in the stream: "
-                     "seconds-only waterfall, MFU columns omitted)")
+        lines.append("  (no compiled cost analysis in the stream, or not "
+                     "recorded on a TPU: seconds-only waterfall, MFU "
+                     "columns omitted)")
     lines.append(f"  {'remove bucket':<18s} {'seconds':>12s} "
                  f"{'of step':>8s} {'MFU after':>10s}")
     for r in wf["rows"]:

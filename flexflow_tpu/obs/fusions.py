@@ -162,9 +162,12 @@ def fusion_account(profile: dict, perf=None, top_n: int = 10) -> dict:
     clamped to the remaining residual (clamped rows listed), and
     ``rows[*].excess_ms + unattributed_ms == residual_ms`` exactly."""
     if perf is None:
-        from flexflow_tpu.sim.cost_model import TpuChipPerf
+        from flexflow_tpu.sim.cost_model import TpuChipPerf, chip_perf
 
-        perf = TpuChipPerf()
+        # apps/profile.py records the chip it traced; the committed
+        # profiles that predate the field were taken on a v5e
+        kind = profile.get("device_kind")
+        perf = chip_perf(kind) if kind else TpuChipPerf()
     wall_ms = float(profile["seconds_per_step"]) * 1e3
     floor_ms = float(profile["step_floor_seconds"]) * 1e3
     residual_ms = max(0.0, wall_ms - floor_ms)

@@ -69,19 +69,6 @@ def flash_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def tpu_compiler_params():
-    """The pallas-TPU compiler-params class under whichever name this
-    jax release exports it (``TPUCompilerParams`` was renamed
-    ``CompilerParams``); None when neither exists.  The capability gate
-    for kernels that must raise the scoped-VMEM cap (maxpool) and for
-    the tests that exercise them — a None here means "skip with a
-    reason", not an AttributeError mid-kernel."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    return getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-
-
 def maxpool_enabled() -> bool:
     """Candidacy gate for the Pallas max-pool backward.  Per-op it beats
     XLA's select_and_scatter ~2x (2.9 vs 5.0 ms on Inception's two big
@@ -136,4 +123,4 @@ def bnrelu_enabled() -> bool:
 
 __all__ = ["avgpool_enabled", "bnrelu_enabled", "flash_attention",
            "flash_enabled", "get_policy", "maxpool_cost_gated",
-           "maxpool_enabled", "set_policy", "tpu_compiler_params"]
+           "maxpool_enabled", "set_policy"]

@@ -109,6 +109,7 @@ def _make_avgpool(shape, dtype_name, kh, kw, relu, interpret):
             out_specs=pl.BlockSpec((H, W, bc, bn), bmap),
             out_shape=jax.ShapeDtypeStruct((H, W, C, N), gt.dtype),
             interpret=interpret,
+            name="ff_avgpool_bwd",
         )(*((gt, yt) if relu else (gt,)))
 
     def fwd_xla(x):

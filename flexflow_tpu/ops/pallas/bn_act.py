@@ -119,6 +119,7 @@ def _make_bn_act(M, C, dtype_name, relu, interpret):
             out_specs=x_spec,
             out_shape=jax.ShapeDtypeStruct((M, C), dt),
             interpret=interpret,
+            name="ff_bn_act_fwd",
         )(x2, inv2, shift2)
 
     def bwd_call(x2, inv2, shift2, g2):
@@ -131,6 +132,7 @@ def _make_bn_act(M, C, dtype_name, relu, interpret):
                        jax.ShapeDtypeStruct((1, C), jnp.float32),
                        jax.ShapeDtypeStruct((1, C), jnp.float32)],
             interpret=interpret,
+            name="ff_bn_act_bwd",
         )(x2, inv2, shift2, g2)
 
     @jax.custom_vjp

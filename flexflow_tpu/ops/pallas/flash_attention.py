@@ -130,6 +130,7 @@ def _fwd_call(q, k, v, scale, causal, sk, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="ff_flash_fwd",
     )(q, k, v)
 
 
@@ -247,6 +248,7 @@ def _bwd_call(q, k, v, do, lse, delta, scale, causal, sk, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="ff_flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     dq_spec = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -264,6 +266,7 @@ def _bwd_call(q, k, v, do, lse, delta, scale, causal, sk, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="ff_flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

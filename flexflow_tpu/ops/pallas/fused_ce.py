@@ -101,6 +101,7 @@ def _fwd_call(x, w, b2, lab2, vocab, block_n, block_v, interpret):
             pltpu.VMEM((block_n, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="ff_ce_fwd",
     )(x, w, b2, lab2)
 
 
@@ -190,6 +191,7 @@ def _bwd_call(x, w, b2, lab2, lse, gp2, goh2, vocab, block_n, block_v,
         out_shape=jax.ShapeDtypeStruct((n_p, d_p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_n, d_p), jnp.float32)],
         interpret=interpret,
+        name="ff_ce_bwd_dx",
     )(x, w, b2, lab2, lse, gp2, goh2)
     # dw/db: vocab blocks outer, token blocks innermost
     dw, db = pl.pallas_call(
@@ -217,6 +219,7 @@ def _bwd_call(x, w, b2, lab2, lse, gp2, goh2, vocab, block_n, block_v,
             pltpu.VMEM((1, block_v), jnp.float32),
         ],
         interpret=interpret,
+        name="ff_ce_bwd_dw",
     )(x, w, b2, lab2, lse, gp2, goh2)
     return dx, dw, db
 

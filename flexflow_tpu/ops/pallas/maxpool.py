@@ -208,17 +208,8 @@ def _make_maxpool(shape, dtype_name, kh, kw, ph, pw, relu, interpret):
     gn, gc = _ceil(N, bn), _ceil(C, bc)
 
     # the pool1 working set (full-width rows + f32 compare temps) exceeds
-    # the 16 MB scoped-vmem default; raise the cap for this kernel.
-    # CompilerParams/TPUCompilerParams per the jax release (the class was
-    # renamed); a jax with neither cannot run this kernel at all.
-    from flexflow_tpu.ops.pallas import tpu_compiler_params
-
-    cparams_cls = tpu_compiler_params()
-    if cparams_cls is None:
-        raise NotImplementedError(
-            "pallas TPU compiler-params API unavailable in this jax "
-            "(neither pltpu.CompilerParams nor pltpu.TPUCompilerParams)")
-    cparams = cparams_cls(vmem_limit_bytes=48 * 1024 * 1024)
+    # the 16 MB scoped-vmem default; raise the cap for this kernel
+    cparams = pltpu.CompilerParams(vmem_limit_bytes=48 * 1024 * 1024)
 
     bwd_kernel = functools.partial(
         _bwd_kernel, H=H, OH=OH, W=W, OW=OW, kh=kh, kw=kw, ph=ph, pw=pw,
@@ -244,6 +235,7 @@ def _make_maxpool(shape, dtype_name, kh, kw, ph, pw, relu, interpret):
                             pltpu.VMEM((1, OW, bc, bn), jnp.bfloat16)],
             compiler_params=cparams,
             interpret=interpret,
+            name="ff_maxpool_bwd",
         )(gt, sel)
 
     def fwd_xla(x):

@@ -275,7 +275,13 @@ class Pool2D(Op):
         if self.pool_type == POOL_MAX:
             y = lax.reduce_window(x, -jnp.inf, lax.max, window, strides, pads)
         else:
-            ones = jnp.ones_like(x)
+            # the count of valid (un-padded) positions depends only on
+            # where the window sits in (H, W): one (1, H, W, 1) plane.  A
+            # batch x channel sized plane of ones is the same numbers, and
+            # XLA either constant-folds its reduce_window on the host
+            # (about 95 s of every cold Inception-v3 b256 step compile on
+            # a v5e host, PR 21) or recomputes it every step.
+            ones = jnp.ones((1,) + x.shape[1:3] + (1,), x.dtype)
             s = lax.reduce_window(x, 0.0, lax.add, window, strides, pads)
             cnt = lax.reduce_window(ones, 0.0, lax.add, window, strides, pads)
             y = s / cnt

@@ -66,16 +66,6 @@ def spmd_pipeline(stage_fn: Callable, stage_params, xs, mesh: Mesh,
 
     Returns (M, mb, ...) outputs, replicated over ``stage_axis``.
     """
-    import inspect
-    try:
-        from jax import shard_map  # jax >= 0.8
-        rep_kw = {"check_vma": False} \
-            if "check_vma" in inspect.signature(shard_map).parameters \
-            else {"check_rep": False}
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-        rep_kw = {"check_rep": False}
-
     s_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     num_stages = s_sizes[stage_axis]
     num_mb = xs.shape[0]
@@ -117,11 +107,11 @@ def spmd_pipeline(stage_fn: Callable, stage_params, xs, mesh: Mesh,
             stage_axis)
         return out
 
-    return shard_map(
+    return jax.shard_map(
         pipelined, mesh=mesh,
         in_specs=(param_spec, xs_spec),
         out_specs=xs_spec,
-        **rep_kw,
+        check_vma=False,
     )(stage_params, xs)
 
 

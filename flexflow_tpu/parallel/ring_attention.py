@@ -18,6 +18,7 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -77,20 +78,9 @@ def blockwise_attention(q, k, v, causal: bool = False,
 
 
 def unchecked_shard_map(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions (the
-    kw was renamed check_rep -> check_vma around jax 0.8)."""
-    import inspect
-
-    try:
-        from jax import shard_map  # jax >= 0.8
-        _check_kw = ("check_vma"
-                     if "check_vma" in inspect.signature(shard_map).parameters
-                     else "check_rep")
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-        _check_kw = "check_rep"
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{_check_kw: False})
+    """shard_map with the replication (varying-manual-axes) check off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def ring_attention(q, k, v, mesh, seq_axis: str, causal: bool = False):

@@ -24,8 +24,10 @@ def _load():
     if _lib is not None:
         return _lib
     # unconditional make: no-op when up to date, rebuilds on simulator.cc
-    # edits (the .so is not committed)
-    subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+    # edits (the .so is not committed).  Only this library's target: the
+    # default target also links libffdata.so against libjpeg, which the
+    # simulator does not need and a machine may lack.
+    subprocess.run(["make", "-C", _NATIVE_DIR, "libffsim.so"], check=True,
                    capture_output=True)
     lib = ctypes.CDLL(_LIB_PATH)
     lib.ffsim_create.restype = ctypes.c_void_p

@@ -51,24 +51,15 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-    import inspect
-    kw = {"check_vma": False} \
-        if "check_vma" in inspect.signature(shard_map).parameters \
-        else {"check_rep": False}
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-    kw = {"check_rep": False}
 dev = np.array(jax.devices()).reshape(2, half)
 mesh = Mesh(dev, ("proc", "loc"))
 
 def timed_psum(nelem, iters=6):
     x = jnp.ones((2, half, nelem), jnp.float32)
     x = jax.device_put(x, NamedSharding(mesh, P("proc", "loc")))
-    f = jax.jit(shard_map(lambda a: lax.psum(a, "proc"), mesh=mesh,
-                          in_specs=P("proc", "loc"),
-                          out_specs=P(None, "loc"), **kw))
+    f = jax.jit(jax.shard_map(lambda a: lax.psum(a, "proc"), mesh=mesh,
+                              in_specs=P("proc", "loc"),
+                              out_specs=P(None, "loc"), check_vma=False))
     y = f(x); y.block_until_ready()          # compile + warm
     t0 = time.perf_counter()
     for _ in range(iters):

@@ -16,7 +16,7 @@ Typical use (see apps/profile.py for the CLI):
         ... run steps ...
     times = device_op_times(logdir)          # {hlo op name: ms}
     cls = classify_ops(compiled.as_text(), times)
-    report = roofline_report(compiled, seconds_per_step, cls)
+    report = roofline_report(compiled, seconds_per_step, perf, cls)
 """
 
 from __future__ import annotations
@@ -121,18 +121,17 @@ def classify_ops(hlo_text: str, times: Dict[str, float]):
     return rows, dict(totals)
 
 
-def roofline_report(compiled, seconds_per_step: float,
+def roofline_report(compiled, seconds_per_step: float, perf,
                     class_totals: Optional[Dict[str, float]] = None,
-                    perf=None, n_devices: int = 1) -> Dict:
+                    n_devices: int = 1) -> Dict:
     """Roofline ceiling analysis of the compiled step: arithmetic
     intensity vs the chip balance point, the HBM-bound step-time floor,
     and the MFU ceiling that floor implies.  ``mfu_ceiling`` is the honest
     upper bound for THIS compiled program on this chip — raising it
-    requires removing bytes, not scheduling."""
-    from flexflow_tpu.sim.cost_model import TpuChipPerf
+    requires removing bytes, not scheduling.  ``perf`` is the peaks of
+    the chip the step was timed on (``sim.cost_model.chip_perf``)."""
     from flexflow_tpu.utils.profiling import compiled_roofline
 
-    perf = perf or TpuChipPerf()
     # single source for flops/bytes/utilizations (incl. the GLOBAL-flops-
     # under-SPMD convention documented there)
     rl = compiled_roofline(compiled, seconds_per_step, perf, n_devices)

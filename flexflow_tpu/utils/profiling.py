@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import re
 from typing import Dict, List, Optional
 
 
@@ -89,37 +90,34 @@ def time_op_shard(op, pc, dtype: str = "float32",
     local = op.local_clone(pc)
     if local is None:
         return None
-    try:
-        params = local.init_params(jax.random.PRNGKey(0))
-        xs = [jnp.zeros(t.shape, "int32") if t.dtype == "int32"
-              else jnp.ones(t.shape, dtype) for t in local.inputs]
-        state = local.init_state()
+    params = local.init_params(jax.random.PRNGKey(0))
+    xs = [jnp.zeros(t.shape, "int32") if t.dtype == "int32"
+          else jnp.ones(t.shape, dtype) for t in local.inputs]
+    state = local.init_state()
 
-        def loss_of(p, xs_):
-            res, _ = local.forward(p, state, xs_, True)
-            res = res[0] if isinstance(res, tuple) else res
-            return (res.astype("float32") ** 2).sum()
+    def loss_of(p, xs_):
+        res, _ = local.forward(p, state, xs_, True)
+        res = res[0] if isinstance(res, tuple) else res
+        return (res.astype("float32") ** 2).sum()
 
-        if params:
-            fn = jax.jit(lambda p, xs_: jax.grad(loss_of)(p, xs_))
-            args = (params, xs)
-        elif op.inputs and op.inputs[0].dtype != "int32":
-            fn = jax.jit(lambda xs_: jax.grad(
-                lambda x: loss_of({}, x))(list(xs_)))
-            args = (xs,)
-        else:
-            fn = jax.jit(lambda xs_: loss_of({}, xs_))
-            args = (xs,)
-        jax.block_until_ready(fn(*args))  # compile + warm
-        best = None
-        for _ in range(max(repeats, 1)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            dt = time.perf_counter() - t0
-            best = dt if best is None else min(best, dt)
-        return best if best and best > 0 else None
-    except Exception:
-        return None
+    if params:
+        fn = jax.jit(lambda p, xs_: jax.grad(loss_of)(p, xs_))
+        args = (params, xs)
+    elif op.inputs and op.inputs[0].dtype != "int32":
+        fn = jax.jit(lambda xs_: jax.grad(
+            lambda x: loss_of({}, x))(list(xs_)))
+        args = (xs,)
+    else:
+        fn = jax.jit(lambda xs_: loss_of({}, xs_))
+        args = (xs,)
+    jax.block_until_ready(fn(*args))  # compile + warm
+    best = None
+    for _ in range(max(repeats, 1)):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        dt = time.perf_counter() - t0
+        best = dt if best is None else min(best, dt)
+    return best if best and best > 0 else None
 
 
 @dataclasses.dataclass
@@ -191,12 +189,33 @@ class OpProfiler:
 
 
 def normalize_cost_analysis(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` as one flat dict (older jax returns one
-    dict per device program)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict, empty when the backend
+    reports none."""
+    return compiled.cost_analysis() or {}
+
+
+# a Mosaic kernel in optimized HLO text: a custom call to
+# ``tpu_custom_call`` whose op_name metadata ends in the kernel's
+# pallas_call ``name=`` scope (possibly wrapped, e.g.
+# ``transpose(jvp(ff_flash_bwd_dq))/pallas_call``)
+_TPU_CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+_PALLAS_KERNEL = re.compile(r'op_name="[^"]*?([A-Za-z0-9_.]+)\)*/pallas_call')
+
+
+def pallas_kernel_calls(hlo_text: str) -> Dict[str, int]:
+    """Pallas kernels Mosaic compiled into a program, from its optimized
+    HLO text: {kernel name: number of TPU custom calls}.  Empty off the
+    TPU (interpret mode lowers kernels to plain HLO) and when nothing
+    routed; a call whose kernel carries no ``name=`` counts under
+    ``"unnamed"``."""
+    out: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if _TPU_CUSTOM_CALL not in line:
+            continue
+        m = _PALLAS_KERNEL.search(line)
+        name = m.group(1) if m else "unnamed"
+        out[name] = out.get(name, 0) + 1
+    return out
 
 
 def compiled_cost(fn, *args) -> Dict[str, float]:
@@ -216,27 +235,31 @@ def compiled_roofline(compiled, seconds_per_step: Optional[float] = None,
                       perf=None, n_devices: int = 1) -> Dict[str, float]:
     """Roofline summary from an already-compiled executable (no extra
     compile): post-fusion FLOPs/bytes plus, when a measured step time is
-    supplied, achieved TFLOP/s, HBM GB/s and MXU utilization.
+    supplied, achieved TFLOP/s and HBM GB/s.  The figures that are a
+    fraction of a chip's peak (MXU / HBM utilization, the at-peak floor)
+    appear only when ``perf`` names that chip — pass
+    ``sim.cost_model.chip_perf(device_kind)`` of the device the time was
+    measured on; there is no default chip.
 
     ``cost_analysis()`` FLOPs are GLOBAL (pre-partitioning) under SPMD, so
     pass ``n_devices`` to compare against the whole machine's peak."""
-    from flexflow_tpu.sim.cost_model import TpuChipPerf
-
-    perf = perf or TpuChipPerf()
-    peak = perf.peak_flops * max(n_devices, 1)
-    hbm = perf.hbm_bandwidth * max(n_devices, 1)
     ca = normalize_cost_analysis(compiled)
     cost = {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
     out = dict(cost)
-    out["min_step_seconds_at_peak"] = cost["flops"] / peak if peak else 0.0
-    if seconds_per_step and seconds_per_step > 0:
+    timed = bool(seconds_per_step and seconds_per_step > 0)
+    if timed:
         out["achieved_tflops"] = cost["flops"] / seconds_per_step / 1e12
         out["achieved_hbm_gbps"] = (
             cost["bytes_accessed"] / seconds_per_step / 1e9)
-        out["mxu_utilization"] = cost["flops"] / seconds_per_step / peak
-        out["hbm_utilization"] = (
-            cost["bytes_accessed"] / seconds_per_step / hbm)
+    if perf is not None:
+        peak = perf.peak_flops * max(n_devices, 1)
+        hbm = perf.hbm_bandwidth * max(n_devices, 1)
+        out["min_step_seconds_at_peak"] = cost["flops"] / peak
+        if timed:
+            out["mxu_utilization"] = cost["flops"] / seconds_per_step / peak
+            out["hbm_utilization"] = (
+                cost["bytes_accessed"] / seconds_per_step / hbm)
     return out
 
 

@@ -4,8 +4,8 @@ Three views of the same invariant ("the per-step hot path makes zero
 host round-trips"), because each catches what the others cannot:
 
 * the **jaxpr** of the traced step sees host callbacks staged into the
-  program (``debug_callback`` / ``pure_callback`` / ``io_callback``)
-  before XLA rewrites them;
+  program (``debug_callback`` / ``debug_print`` / ``pure_callback`` /
+  ``io_callback``) before XLA rewrites them;
 * the **compiled HLO** sees what actually lowered: callback
   custom-calls, ``infeed``/``outfeed``, host-transfer send/recv;
 * the **source AST** of the fit hot path sees Python-side syncs the
@@ -24,8 +24,8 @@ from typing import List, Optional, Sequence
 from flexflow_tpu.verify.findings import Finding
 
 # jaxpr primitives that stage a host round-trip into the step
-JAXPR_HOST_PRIMS = ("debug_callback", "pure_callback", "io_callback",
-                    "infeed", "outfeed")
+JAXPR_HOST_PRIMS = ("debug_callback", "debug_print", "pure_callback",
+                    "io_callback", "infeed", "outfeed")
 
 # HLO custom-call targets that are python/host callbacks
 _HLO_CALLBACK = re.compile(

@@ -73,7 +73,8 @@ def _stream(flops=8e9, bytes_=1e9, wall=0.02):
     bud = build_step_budget(wall, compute_s=wall * 0.5, comm_s=wall * 0.3,
                             input_stall_s=wall * 0.1)
     return [
-        {"kind": "run_start", "devices": 8},
+        {"kind": "run_start", "devices": 8, "platform": "tpu",
+         "device_kind": "TPU v5 lite"},
         {"kind": "compile", "seconds": 1.0, "flops": flops,
          "bytes_accessed": bytes_},
         {"kind": "summary", "images_per_sec": 1000.0},
@@ -107,6 +108,20 @@ def test_waterfall_without_cost_analysis_is_seconds_only():
     assert wf["rows"]  # seconds still rank
     text = "\n".join(render_waterfall(wf))
     assert "seconds-only" in text
+
+
+def test_waterfall_peaks_are_the_stream_devices():
+    """A utilization is a fraction of the chip the stream was recorded
+    on: none for a CPU stream, an error for a TPU kind with no peaks."""
+    def on(**device):
+        return [dict(e, **device) if e["kind"] == "run_start" else e
+                for e in _stream()]
+
+    wf = mfu_waterfall(on(platform="cpu", device_kind="cpu"))
+    assert wf["mfu"] is None and wf["mfu_ceiling"] is None and wf["rows"]
+    assert wf["flops_per_step"] == 8e9       # the counts are still there
+    with pytest.raises(ValueError, match="no peak numbers"):
+        mfu_waterfall(on(device_kind="TPU v9"))
 
 
 def test_waterfall_requires_budget_record():
@@ -265,9 +280,11 @@ def test_fit_metrics_export_finite(budget_run):
     cfg, out, evs = budget_run
     assert out["metrics_path"] == cfg.metrics_path
     vals = read_textfile(cfg.metrics_path)
-    for key in ("mfu", "throughput_items_per_sec", "images_per_sec",
+    for key in ("throughput_items_per_sec", "images_per_sec",
                 "steps_total", "step_wall_seconds"):
         assert key in vals and math.isfinite(vals[key]), (key, vals)
+    # a CPU has no peak to be a fraction of: no utilization is published
+    assert "mfu" not in vals and "mfu_ceiling" not in vals
     assert vals["steps_total"] == 4
     # every published snapshot is mirrored into the obs stream
     mets = [e for e in evs if e["kind"] == "metrics"]
@@ -284,7 +301,8 @@ def test_fit_counter_lanes_from_real_stream(budget_run):
     assert validate_trace(trace) == []
     names = {e["name"] for e in trace["traceEvents"]
              if e.get("ph") == "C"}
-    assert "imgs/s" in names and "MFU" in names
+    # the MFU lane needs a TPU stream (test_counter_lanes_validate has one)
+    assert "imgs/s" in names and "MFU" not in names
 
 
 def test_report_budget_cli_on_obs_dir(budget_run, capsys):
@@ -319,7 +337,7 @@ def test_summarize_roundtrips_budget_and_metrics(budget_run):
     assert "step_budget" in s and "metrics" in s
     assert not check_budget({"step_wall_s": s["step_budget"]["step_wall_s"],
                              "buckets": s["step_budget"]["buckets"]})
-    assert math.isfinite(s["metrics"]["gauges"]["mfu"])
+    assert math.isfinite(s["metrics"]["gauges"]["step_wall_seconds"])
     # and the prose renderer names both
     text = render(evs)
     assert "step budget" in text and "metrics export" in text

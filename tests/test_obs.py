@@ -384,8 +384,7 @@ def test_bench_single_json_stdout_line(tmp_path, monkeypatch, capsys):
     finally:
         sys.path.pop(0)
 
-    def fake_run(model="inception", strategy_file=None, compile_cache=False,
-                 **kw):
+    def fake_run(model="inception", strategy_file=None, **kw):
         print("library noise on stdout")  # must NOT reach real stdout
         return (100.0, 800.0, 1.0, 0.5,
                 {"windows": 1, "min": 99.0, "max": 101.0},
@@ -420,8 +419,7 @@ def test_bench_records_trace_path(tmp_path, monkeypatch, capsys):
     finally:
         sys.path.pop(0)
 
-    def fake_run(model="inception", strategy_file=None, compile_cache=False,
-                 **kw):
+    def fake_run(model="inception", strategy_file=None, **kw):
         return (100.0, 800.0, 1.0, None,
                 {"windows": 1, "min": 99.0, "max": 101.0},
                 {"input_stall_s": 0.0, "regrid_hops": 0})

@@ -280,18 +280,6 @@ def test_lm_head_fusion_vocab_tp(machine8):
 # Pallas max-pool backward (ops/pallas/maxpool.py): parity with XLA
 # reduce_window autodiff — including first-max tie-breaking (integer-valued
 # inputs make ties certain) and the fused-ReLU sentinel path.
-#
-# Capability gate: the kernel needs the pallas-TPU compiler-params API
-# (CompilerParams / TPUCompilerParams, renamed across jax releases) to
-# raise the scoped-VMEM cap.  A jax with neither name cannot run it in
-# any mode — skip with the explicit reason instead of erroring, so a
-# tier-1 failure here always means a real regression.
-from flexflow_tpu.ops.pallas import tpu_compiler_params
-
-needs_maxpool_kernel = pytest.mark.skipif(
-    tpu_compiler_params() is None,
-    reason="pallas TPU compiler-params API unavailable in this jax "
-           "(neither pltpu.CompilerParams nor pltpu.TPUCompilerParams)")
 
 
 def _ref_maxpool(x, kh, kw, ph, pw, relu):
@@ -310,7 +298,6 @@ def _ref_maxpool(x, kh, kw, ph, pw, relu):
     (1, 8, 8, 2, 3, 1, False),    # tiny single-sample
     (2, 23, 19, 6, 3, 0, True),   # ragged H/W blocks
 ])
-@needs_maxpool_kernel
 def test_maxpool_parity(n, h, w, c, k, p, relu):
     from flexflow_tpu.ops.pallas.maxpool import maxpool2d
 
@@ -532,7 +519,6 @@ def test_batchnorm_routes_through_pallas_when_enabled(monkeypatch):
                                    rtol=1e-4, atol=1e-4)
 
 
-@needs_maxpool_kernel
 def test_pool2d_routes_through_pallas_when_enabled(monkeypatch):
     """Pool2D.forward takes the kernel path under the env gate and the
     result matches the XLA path bit-for-bit (interpret mode)."""

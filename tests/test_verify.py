@@ -125,8 +125,14 @@ def test_jaxpr_pass_catches_staged_host_callback():
 
     traced = jax.jit(step).trace(jnp.ones(4))
     fs = sync_lint.jaxpr_sync_findings(traced.jaxpr)
+    # jax.debug.print stages its own primitive (debug_print);
+    # jax.debug.callback stages debug_callback
     assert any(f.code == "jaxpr_host_prim"
-               and "debug_callback" in f.where for f in fs)
+               and "debug_print" in f.where for f in fs)
+    cb = jax.jit(lambda x: (jax.debug.callback(lambda v: None, x),
+                            x * 2.0)[1]).trace(jnp.ones(4))
+    assert any("debug_callback" in f.where
+               for f in sync_lint.jaxpr_sync_findings(cb.jaxpr))
 
     clean = jax.jit(lambda x: x * 2.0).trace(jnp.ones(4))
     assert sync_lint.jaxpr_sync_findings(clean.jaxpr) == []
