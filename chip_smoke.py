@@ -58,32 +58,16 @@ def check(cond, msg):
         raise SmokeFailure(msg)
 
 
-class CompileMeter:
-    """Seconds JAX spent in backend compilation (a persistent-cache hit
-    counts its retrieval) and the cache's hit/miss events, from JAX's own
-    monitoring hooks."""
+def compile_counters():
+    """(backend compile seconds, cache hits, cache misses) so far, from
+    the program's own ``compile.*`` counters (flexflow_tpu/obs/spans.py:
+    JAX's monitoring events; a persistent-cache hit counts its
+    retrieval under the backend seconds)."""
+    from flexflow_tpu import obs
 
-    def __init__(self):
-        import jax
-
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def _event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return (self.compile_s, self.hits, self.misses)
+    c = obs.snapshot()["counters"]
+    return (c.get("compile.backend_s", 0.0), c.get("compile.cache_hits", 0),
+            c.get("compile.cache_misses", 0))
 
 
 def _fit_checks(out, iters, what):
@@ -214,14 +198,13 @@ def main(argv):
           + ("  [CPU REHEARSAL — tiny sizes, not a chip pass]"
              if rehearsal else ""), flush=True)
     cache_dir = enable_compile_cache()
-    meter = CompileMeter()
     shutil.rmtree(OUT_DIR, ignore_errors=True)
 
     phases = {}
     for name, fn in PHASES:
-        t0, before = time.perf_counter(), meter.snapshot()
+        t0, before = time.perf_counter(), compile_counters()
         info = fn(rehearsal, device)
-        after = meter.snapshot()
+        after = compile_counters()
         info.update(wall_s=time.perf_counter() - t0,
                     compile_s=after[0] - before[0],
                     cache_hits=after[1] - before[1],

@@ -529,18 +529,20 @@ def main(argv=None, log=print) -> dict:
 
     from flexflow_tpu.sim.search import StrategySearch
 
-    search = StrategySearch(model, machine, cost_model=cost_model,
-                            obs=olog, objective=opts["objective"])
-    if opts["decompose"]:
-        strategy, info = search.search_decomposed(
-            iters=opts["iters"], seed=opts["seed"],
-            delta=opts.get("delta", "on") != "off",
-            block_budget_s=opts["block_budget_s"] or None,
-            boundary_refine_iters=opts["boundary_refine_iters"])
-    else:
-        strategy, info = search.search(iters=opts["iters"],
-                                       seed=opts["seed"],
-                                       **_search_kw(opts))
+    with _obs.span("ff:plan.search", proposals=opts["iters"],
+                   chains=opts["chains"], ops=len(model.layers)):
+        search = StrategySearch(model, machine, cost_model=cost_model,
+                                obs=olog, objective=opts["objective"])
+        if opts["decompose"]:
+            strategy, info = search.search_decomposed(
+                iters=opts["iters"], seed=opts["seed"],
+                delta=opts.get("delta", "on") != "off",
+                block_budget_s=opts["block_budget_s"] or None,
+                boundary_refine_iters=opts["boundary_refine_iters"])
+        else:
+            strategy, info = search.search(iters=opts["iters"],
+                                           seed=opts["seed"],
+                                           **_search_kw(opts))
     result = {
         "model": opts["model"],
         "objective": opts["objective"],
@@ -698,6 +700,7 @@ def main(argv=None, log=print) -> dict:
                 f"written to {sidecar}")
         strategy.save(opts["out"])
         log(f"strategy written to {opts['out']}")
+    olog.spans()
     olog.close()
     return {"strategy": strategy, **result}
 

@@ -33,9 +33,10 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 import warnings
 from typing import Iterator
+
+from flexflow_tpu import obs
 
 _STOP_POLL_S = 0.1
 
@@ -72,6 +73,7 @@ class DevicePrefetcher:
         self.depth = depth
         self.stall_s = 0.0
         self.batches = 0
+        self._placed = 0
         self.leaked = False
         self._olog = olog
         self._upstream = upstream
@@ -94,16 +96,25 @@ class DevicePrefetcher:
             return batch
         import jax
 
+        put_bytes = 0
+
         def put(leaf):
             # already-committed device arrays (sources that place their
             # own batches) pass through; host arrays get the sharded put
+            nonlocal put_bytes
             if isinstance(leaf, jax.Array) and getattr(
                     leaf, "sharding", None) is not None:
                 return leaf
+            put_bytes += int(getattr(leaf, "nbytes", 0))
             return jax.device_put(leaf, self._sharding)
 
-        return tuple(put(b) for b in batch) if isinstance(
-            batch, (tuple, list)) else put(batch)
+        with obs.span("ff:runtime.prefetch_put", batch=self._placed) as sp:
+            out = tuple(put(b) for b in batch) if isinstance(
+                batch, (tuple, list)) else put(batch)
+            sp.args["bytes"] = put_bytes
+        self._placed += 1
+        obs.count("runtime.prefetch_bytes", put_bytes)
+        return out
 
     def _worker(self):
         while not self._stop.is_set():
@@ -132,9 +143,9 @@ class DevicePrefetcher:
             raise StopIteration
         if self._stop.is_set():
             raise RuntimeError("DevicePrefetcher is closed")
-        t0 = time.perf_counter()
-        item = self._q.get()
-        self.stall_s += time.perf_counter() - t0
+        with obs.span("ff:runtime.prefetch_wait", batch=self.batches) as sp:
+            item = self._q.get()
+        self.stall_s += sp.seconds
         if isinstance(item, _End):
             self._exhausted = True
             self.close()

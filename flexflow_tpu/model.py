@@ -30,8 +30,10 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, List, Optional
 
+from flexflow_tpu import obs
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.machine import MachineModel
+from flexflow_tpu.obs.optrace import UPDATE_SCOPE
 from flexflow_tpu.ops import (Concat, Conv2D, Flat, Linear, Op, Pool2D,
                               Softmax, Tensor)
 from flexflow_tpu.ops.norm import BatchNorm
@@ -129,6 +131,42 @@ def _fully_partitioned(op) -> bool:
             if s > 1 and a not in present:
                 return False
     return True
+
+
+class _TrainStep:
+    """A jitted train step that remembers what it first ran on.
+
+    Calls go straight through to the ``jax.jit`` object, and so does
+    every attribute (``lower``, ``trace``, ...).  Before its first call it
+    keeps the arguments' shapes, dtypes and shardings as
+    ``first_call`` and names itself its model's ``_ran_step``:
+    ``FFModel.operator_table()`` lowers this same object for them, so the
+    table it reads is of the program that ran and of no other."""
+
+    def __init__(self, model, jitted):
+        self._model = model
+        self._jitted = jitted
+        self.first_call = None
+
+    def __call__(self, *args):
+        if self.first_call is None:
+            self._remember(args)
+        return self._jitted(*args)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+    def _remember(self, args):
+        import jax
+
+        leaves = jax.tree.leaves(args)
+        if any(isinstance(a, jax.core.Tracer) for a in leaves):
+            return            # called inside another trace: not a run
+        self.first_call = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None))
+            if hasattr(a, "shape") else a, args)
+        self._model._ran_step = self
 
 
 class FFModel:
@@ -342,7 +380,18 @@ class FFModel:
         regions, conv_2d.cu:374-419).  With ``abstract=True`` the same
         traversal yields sharding-annotated ShapeDtypeStructs and nothing
         is materialized (used by the DISABLE_COMPUTATION-analog dry
-        compile)."""
+        compile).  Under a caller's ``jax.jit`` the ``ff:entry.init``
+        span times the Python tracing of the draws, which is what
+        set-up pays."""
+        import jax
+
+        with obs.span("ff:entry.init", ops=len(self.layers),
+                      abstract=int(abstract)) as sp:
+            params, state = self._init(seed, abstract)
+            sp.args["leaves"] = len(jax.tree.leaves((params, state)))
+        return params, state
+
+    def _init(self, seed: Optional[int], abstract: bool):
         import jax
         import jax.numpy as jnp
 
@@ -512,14 +561,19 @@ class FFModel:
         from init_opt_state."""
         import jax.numpy as jnp
 
-        if not self._mixed_precision():
-            return None
-        return {key: {k + _MASTER_SUFFIX: v.astype(jnp.float32)
-                      for k, v in sub.items()
-                      if jnp.issubdtype(v.dtype, jnp.floating)}
-                for key, sub in params.items()}
+        with obs.span("ff:entry.opt_state", ops=len(params)):
+            if not self._mixed_precision():
+                return None
+            return {key: {k + _MASTER_SUFFIX: v.astype(jnp.float32)
+                          for k, v in sub.items()
+                          if jnp.issubdtype(v.dtype, jnp.floating)}
+                    for key, sub in params.items()}
 
     def init_opt_state(self, params):
+        with obs.span("ff:entry.opt_state", ops=len(params)):
+            return self._init_opt_state(params)
+
+    def _init_opt_state(self, params):
         import jax
 
         if not self._mixed_precision():
@@ -1019,11 +1073,12 @@ class FFModel:
         apply() and _apply(), so the pre-planned honored set always matches
         the schedule actually executed (both underlying planners cache)."""
         dump = self.config.print_intermediates
-        fusion = self._lm_head_fusion() if (train and not dump) else {}
-        if self.machine.num_devices > 1 and not dump:
-            schedule = self._placement_schedule(frozenset(fusion))
-        else:
-            schedule = range(len(self.layers))
+        with obs.span("ff:entry.graph_plan", ops=len(self.layers)):
+            fusion = self._lm_head_fusion() if (train and not dump) else {}
+            if self.machine.num_devices > 1 and not dump:
+                schedule = self._placement_schedule(frozenset(fusion))
+            else:
+                schedule = range(len(self.layers))
         return fusion, schedule
 
     def _regrid_plan_for(self, fusion, schedule):
@@ -1046,7 +1101,10 @@ class FFModel:
         if key not in cache:
             from flexflow_tpu.parallel.regrid import build_regrid_plan
 
-            cache[key] = build_regrid_plan(self, fusion, schedule)
+            with obs.span("ff:entry.regrid_plan",
+                          ops=len(self.layers)) as sp:
+                cache[key] = build_regrid_plan(self, fusion, schedule)
+                sp.args["edges"] = len(cache[key].edges)
         return cache[key]
 
     def regrid_plan_summary(self, train: bool = True):
@@ -1071,6 +1129,7 @@ class FFModel:
             return self._apply(params, state, inputs, train)
 
     def _apply(self, params, state, inputs: Dict[int, Any], train: bool):
+        import jax
         from jax import lax
 
         from flexflow_tpu.parallel.placement import (PlacementGroup,
@@ -1163,10 +1222,11 @@ class FFModel:
                 lin = fusion[i]
                 if lin is None:
                     continue  # projection folded into its loss op
-                values[op.output.tid] = self._run_fused_lm_head(
-                    lin, params.get(lin.param_key, {}),
-                    take(lin.inputs[0].tid),
-                    take(op.labels_tensor.tid))
+                with jax.named_scope(lin.name), jax.named_scope(op.name):
+                    values[op.output.tid] = self._run_fused_lm_head(
+                        lin, params.get(lin.param_key, {}),
+                        take(lin.inputs[0].tid),
+                        take(op.labels_tensor.tid))
                 continue
             xs = [take(t.tid) for t in op.inputs]
             if multi and plan is not None:
@@ -1174,10 +1234,14 @@ class FFModel:
                       for i, x in enumerate(xs)]
             elif multi:
                 xs = self._regrid_inputs(op, xs, specs)
-            res, st = op.forward(self._member_params(params, op),
-                                 self._member_state(state, op), xs, train)
-            if st:
-                st = self._restack_state(op, st)
+            # every device operation carries its operator's name in its
+            # ``op_name`` metadata (obs/optrace.py reads it back)
+            with jax.named_scope(op.name):
+                res, st = op.forward(self._member_params(params, op),
+                                     self._member_state(state, op), xs,
+                                     train)
+                if st:
+                    st = self._restack_state(op, st)
             ys = res if isinstance(res, tuple) else (res,)
             for t, y, spec in zip(op.all_outputs(), ys, op.output_specs()):
                 if multi and spec is not None:
@@ -1278,17 +1342,20 @@ class FFModel:
             targets = [self.machine.global_entries(m.pc, m.AXIS_NAMES,
                                                    spec, rank=t.ndim)
                        for spec, t in zip(ins, m.inputs)]
+        import jax
+
         out = []
-        for x, t, dst in zip(xs, m.inputs, targets):
+        for i, (x, t, dst) in enumerate(zip(xs, m.inputs, targets)):
             src = specs.get(t.tid)
             if dst is None or src is None or dst == src:
                 out.append(x)
                 continue
-            for step in self.machine.regrid_steps(src, dst) or []:
+            with jax.named_scope(f"ff_regrid.{m.name}.{i}"):
+                for step in self.machine.regrid_steps(src, dst) or []:
+                    x = lax.with_sharding_constraint(
+                        x, self.machine.entries_sharding(step))
                 x = lax.with_sharding_constraint(
-                    x, self.machine.entries_sharding(step))
-            x = lax.with_sharding_constraint(
-                x, self.machine.entries_sharding(dst))
+                    x, self.machine.entries_sharding(dst))
             out.append(x)
         return out
 
@@ -1307,8 +1374,10 @@ class FFModel:
         want = op.regrid_input_specs()
         if want is None:
             return xs
+        import jax
+
         out = []
-        for x, t, spec in zip(xs, op.inputs, want):
+        for i, (x, t, spec) in enumerate(zip(xs, op.inputs, want)):
             if spec is None:
                 out.append(x)
                 continue
@@ -1318,28 +1387,33 @@ class FFModel:
             if dst is None or dst == src:
                 out.append(x)
                 continue
-            if src is not None:
-                for step in self.machine.regrid_steps(src, dst) or []:
+            with jax.named_scope(f"ff_regrid.{op.name}.{i}"):
+                if src is not None:
+                    for step in self.machine.regrid_steps(src, dst) or []:
+                        x = lax.with_sharding_constraint(
+                            x, self.machine.entries_sharding(step))
+                else:
+                    # unknown producer layout (a placement-group exit
+                    # whose grid does not decompose onto the global
+                    # mesh): GSPMD's only general lowering to ``dst`` is
+                    # replicate-then-slice — state the waypoint so the
+                    # identical program compiles without the
+                    # involuntary-remat warning
                     x = lax.with_sharding_constraint(
-                        x, self.machine.entries_sharding(step))
-            else:
-                # unknown producer layout (a placement-group exit whose
-                # grid does not decompose onto the global mesh): GSPMD's
-                # only general lowering to ``dst`` is replicate-then-
-                # slice — state the waypoint so the identical program
-                # compiles without the involuntary-remat warning
+                        x, self.machine.replicated())
                 x = lax.with_sharding_constraint(
-                    x, self.machine.replicated())
-            x = lax.with_sharding_constraint(
-                x, self.machine.entries_sharding(dst))
+                    x, self.machine.entries_sharding(dst))
             out.append(x)
         return out
 
     def loss_fn(self, params, state, image, labels, train: bool = True):
+        import jax
+
         loss_op = self._loss_op()
         inputs = {self._inputs[0].tid: image}
         values, new_state = self.apply(params, state, inputs, train)
-        loss = loss_op.loss(values[loss_op.output.tid], labels)
+        with jax.named_scope(loss_op.name):
+            loss = loss_op.loss(values[loss_op.output.tid], labels)
         return loss, new_state
 
     def _donate(self, argnums):
@@ -1350,8 +1424,27 @@ class FFModel:
         return argnums if getattr(self.config, "donate", "on") != "off" \
             else ()
 
+    def _jit_step(self, train_step):
+        """The donated, jitted train step every step factory returns,
+        noted in ``obs`` as the program a device trace of training shows
+        (:class:`_TrainStep`: it remembers what it first ran on, which
+        ``operator_table()`` lowers it for again)."""
+        import jax
+
+        # the HLO module is named for this function, and a module's name
+        # is part of a compile-cache key where the operators' names
+        # (metadata) are not: under a name no program from before the
+        # scopes compiled, no cache directory holds this step without them
+        train_step.__name__ = "ff_train_step"
+        obs.note_program("train_step", self)
+        return _TrainStep(self, jax.jit(
+            train_step, donate_argnums=self._donate((0, 1, 2))))
+
     def make_train_step(self):
-        """Jitted full training iteration (forward+backward+update)."""
+        """Jitted full training iteration (forward+backward+update).  A
+        new step factory wraps its optimizer update in
+        ``jax.named_scope(UPDATE_SCOPE)`` and returns ``_jit_step(..)``,
+        as the four here do."""
         import jax
 
         cfg = self.config
@@ -1373,17 +1466,21 @@ class FFModel:
                 v = mu * v + g + wd * p
                 return p - lr * v, v
 
-            new_params_and_v = jax.tree.map(upd, params, grads, opt_state)
-            new_params = jax.tree.map(lambda t: t[0], new_params_and_v,
-                                      is_leaf=lambda t: isinstance(t, tuple))
-            new_v = jax.tree.map(lambda t: t[1], new_params_and_v,
-                                 is_leaf=lambda t: isinstance(t, tuple))
-            psh = self._param_shardings(new_params)
-            return (self._constrain_params(new_params, psh),
-                    self._constrain_state(new_state),
-                    self._constrain_params(new_v, psh), loss)
+            with jax.named_scope(UPDATE_SCOPE):
+                new_params_and_v = jax.tree.map(upd, params, grads,
+                                                opt_state)
+                new_params = jax.tree.map(
+                    lambda t: t[0], new_params_and_v,
+                    is_leaf=lambda t: isinstance(t, tuple))
+                new_v = jax.tree.map(
+                    lambda t: t[1], new_params_and_v,
+                    is_leaf=lambda t: isinstance(t, tuple))
+                psh = self._param_shardings(new_params)
+                new_params = self._constrain_params(new_params, psh)
+                new_v = self._constrain_params(new_v, psh)
+            return new_params, self._constrain_state(new_state), new_v, loss
 
-        return jax.jit(train_step, donate_argnums=self._donate((0, 1, 2)))
+        return self._jit_step(train_step)
 
     def _make_mixed_train_step(self, lr, wd, mu, cdtype):
         """Master-weight variant of make_train_step (param_dtype !=
@@ -1407,29 +1504,30 @@ class FFModel:
             (loss, new_state), grads = jax.value_and_grad(
                 lf, has_aux=True)(params)
             new_params, new_opt = {}, {}
-            for key, sub in params.items():
-                np_, no_, osub = {}, {}, opt_state[key]
-                for k, p in sub.items():
-                    mk = k + _MASTER_SUFFIX
-                    if mk in osub:
-                        g = grads[key][k].astype(jnp.float32)
-                        m, v = osub[mk], osub[k]
-                        v = mu * v + g + wd * m
-                        m = m - lr * v
-                        np_[k] = m.astype(p.dtype)
-                        no_[k], no_[mk] = v, m
-                    else:  # non-float leaf: in-dtype legacy update
-                        v = mu * osub[k] + grads[key][k] + wd * p
-                        np_[k], no_[k] = p - lr * v, v
-                new_params[key], new_opt[key] = np_, no_
-            psh = self._param_shardings(new_params)
-            return (self._constrain_params(new_params, psh),
-                    self._constrain_state(new_state),
-                    self._constrain_params(
-                        new_opt, self._opt_shardings(new_opt, psh)),
-                    loss)
+            with jax.named_scope(UPDATE_SCOPE):
+                for key, sub in params.items():
+                    np_, no_, osub = {}, {}, opt_state[key]
+                    for k, p in sub.items():
+                        mk = k + _MASTER_SUFFIX
+                        if mk in osub:
+                            g = grads[key][k].astype(jnp.float32)
+                            m, v = osub[mk], osub[k]
+                            v = mu * v + g + wd * m
+                            m = m - lr * v
+                            np_[k] = m.astype(p.dtype)
+                            no_[k], no_[mk] = v, m
+                        else:  # non-float leaf: in-dtype legacy update
+                            v = mu * osub[k] + grads[key][k] + wd * p
+                            np_[k], no_[k] = p - lr * v, v
+                    new_params[key], new_opt[key] = np_, no_
+                psh = self._param_shardings(new_params)
+                new_params = self._constrain_params(new_params, psh)
+                new_opt = self._constrain_params(
+                    new_opt, self._opt_shardings(new_opt, psh))
+            return new_params, self._constrain_state(new_state), new_opt, \
+                loss
 
-        return jax.jit(train_step, donate_argnums=self._donate((0, 1, 2)))
+        return self._jit_step(train_step)
 
     def make_sgd_step(self, lr: float):
         """Plain-SGD train step over ``self.loss_fn(params, state, *batch)``
@@ -1448,13 +1546,15 @@ class FFModel:
 
             (loss, new_state), grads = jax.value_and_grad(
                 lf, has_aux=True)(params)
-            new_params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
-            new_params = self._constrain_params(
-                new_params, self._param_shardings(new_params))
+            with jax.named_scope(UPDATE_SCOPE):
+                new_params = jax.tree.map(lambda p, g: p - lr * g, params,
+                                          grads)
+                new_params = self._constrain_params(
+                    new_params, self._param_shardings(new_params))
             return new_params, self._constrain_state(new_state), \
                 opt_state, loss
 
-        return jax.jit(train_step, donate_argnums=self._donate((0, 1, 2)))
+        return self._jit_step(train_step)
 
     def _make_mixed_sgd_step(self, lr: float):
         """Master-weight variant of make_sgd_step: float32 rate*grad
@@ -1474,29 +1574,30 @@ class FFModel:
             (loss, new_state), grads = jax.value_and_grad(
                 lf, has_aux=True)(params)
             new_params, new_opt = {}, {}
-            for key, sub in params.items():
-                np_, no_ = {}, {}
-                osub = (opt_state or {}).get(key, {})
-                for k, p in sub.items():
-                    mk = k + _MASTER_SUFFIX
-                    if mk in osub:
-                        m = osub[mk] - lr * grads[key][k].astype(
-                            jnp.float32)
-                        np_[k], no_[mk] = m.astype(p.dtype), m
-                    else:
-                        np_[k] = p - lr * grads[key][k]
-                new_params[key] = np_
-                if no_:
-                    new_opt[key] = no_
-            psh = self._param_shardings(new_params)
-            new_params = self._constrain_params(new_params, psh)
-            if new_opt:
-                new_opt = self._constrain_params(
-                    new_opt, self._opt_shardings(new_opt, psh))
+            with jax.named_scope(UPDATE_SCOPE):
+                for key, sub in params.items():
+                    np_, no_ = {}, {}
+                    osub = (opt_state or {}).get(key, {})
+                    for k, p in sub.items():
+                        mk = k + _MASTER_SUFFIX
+                        if mk in osub:
+                            m = osub[mk] - lr * grads[key][k].astype(
+                                jnp.float32)
+                            np_[k], no_[mk] = m.astype(p.dtype), m
+                        else:
+                            np_[k] = p - lr * grads[key][k]
+                    new_params[key] = np_
+                    if no_:
+                        new_opt[key] = no_
+                psh = self._param_shardings(new_params)
+                new_params = self._constrain_params(new_params, psh)
+                if new_opt:
+                    new_opt = self._constrain_params(
+                        new_opt, self._opt_shardings(new_opt, psh))
             return new_params, self._constrain_state(new_state), \
                 new_opt or opt_state, loss
 
-        return jax.jit(train_step, donate_argnums=self._donate((0, 1, 2)))
+        return self._jit_step(train_step)
 
     @staticmethod
     def _lower_step(step, params, state, opt_state, batch):
@@ -1512,6 +1613,10 @@ class FFModel:
         """(params, state, opt_state) as sharding-annotated
         ShapeDtypeStructs — the avals ``init()`` would produce (same
         traversal, ``abstract=True``) with nothing materialized."""
+        with obs.span("ff:entry.abstract_state", ops=len(self.layers)):
+            return self._abstract_train_state()
+
+    def _abstract_train_state(self):
         import jax
 
         params, state = self.init(abstract=True)
@@ -1547,6 +1652,40 @@ class FFModel:
         params, state, opt_state = self.abstract_train_state()
         return self._lower_step(self.make_train_step(), params, state,
                                 opt_state, batch).compile()
+
+    def operator_table(self, *batch):
+        """{instruction name: (operator, pass)} of the compiled train
+        step (obs/optrace.py): which operator's forward or backward, the
+        update, or which regrid each device operation of a trace belongs
+        to.  Without ``batch`` it is the table of the step that ran: the
+        very jitted object, lowered again for the shapes, dtypes and
+        shardings of its first call (found in jit's and the compile
+        cache).  With ``batch`` (data avals as for
+        :meth:`compile_train_step`) it compiles a fresh step for them."""
+        from flexflow_tpu.obs import optrace
+
+        if batch:
+            compiled = self.compile_train_step(*batch)
+        else:
+            step = getattr(self, "_ran_step", None)
+            if step is None:
+                raise ValueError("operator_table() needs the batch's "
+                                 "shapes: no train step has run yet")
+            compiled = step.lower(*step.first_call).compile()
+        names = {op.name for op in self.layers}
+        table = optrace.operator_table(compiled.as_text(), names)
+        named = {operator for operator, _ in table.values()}
+        unnamed = sorted(op.name for op in self.layers
+                         if op.name not in named and not op.IS_VIEW)
+        if unnamed:
+            # JAX leaves metadata out of a cache key: a directory written
+            # by a program with other scopes serves its names, or none
+            raise ValueError(
+                f"the compiled step names {len(names & named)} of "
+                f"{len(names)} operators and not {unnamed[:5]}: it came "
+                f"from a compile cache written without this program's "
+                f"scopes")
+        return table
 
     def make_eval_step(self):
         import jax
@@ -1628,7 +1767,6 @@ class FFModel:
         the graph on a surviving mesh after permanent device loss
         (``--elastic``, utils/elastic.py) — the drivers pass their
         builder; without it a device loss is fatal."""
-        from flexflow_tpu import obs
         from flexflow_tpu.utils import elastic as _elastic
         from flexflow_tpu.utils import faultinject
 
@@ -1962,19 +2100,24 @@ class FFModel:
                     batch = next(data_iter)
                     if it == warmup:
                         if loss is not None:
-                            # sync-ok: one-time warmup fence before the
-                            # timed window opens
-                            jax.block_until_ready(loss)
+                            with obs.span("ff:runtime.fit_sync", step=it,
+                                          what="warmup"):
+                                # sync-ok: one-time warmup fence before
+                                # the timed window opens
+                                jax.block_until_ready(loss)
                         start = time.perf_counter()
                     try:
-                        if sample_every and (it + 1) % sample_every == 0:
-                            params, state, opt_state, loss = \
-                                self._sampled_step(
-                                    step, sections, op_samples, it, loss,
-                                    params, state, opt_state, batch)
-                        else:
-                            params, state, opt_state, loss = step(
-                                params, state, opt_state, *batch)
+                        with obs.span("ff:runtime.fit_step", step=it + 1):
+                            if sample_every \
+                                    and (it + 1) % sample_every == 0:
+                                params, state, opt_state, loss = \
+                                    self._sampled_step(
+                                        step, sections, op_samples, it,
+                                        loss, params, state, opt_state,
+                                        batch)
+                            else:
+                                params, state, opt_state, loss = step(
+                                    params, state, opt_state, *batch)
                         if transient_retries:
                             healthy_streak += 1
                             if transient_reset \
@@ -2059,12 +2202,13 @@ class FFModel:
                             self._raise_device_loss(
                                 elastic_dead, it1, params, state,
                                 opt_state, losses, loss_base)
-                        tb0 = time.perf_counter()
-                        action = guard.check(
-                            losses[window_start - loss_base:],
-                            first_step=window_start + 1)
+                        with obs.span("ff:runtime.fit_sync", step=it1,
+                                      what="guard") as sp:
+                            action = guard.check(
+                                losses[window_start - loss_base:],
+                                first_step=window_start + 1)
+                        host_sync_s += sp.seconds
                         if action == "rollback":
-                            host_sync_s += time.perf_counter() - tb0
                             if wd is not None:
                                 wd.disarm()
                             if awriter is not None:
@@ -2082,13 +2226,13 @@ class FFModel:
                             it = rstep
                             continue
                         window_start = it1
-                        host_sync_s += time.perf_counter() - tb0
                     if at_print:
-                        tb0 = time.perf_counter()
-                        # sync-ok: print_freq-gated loss fetch, charged
-                        # to host_sync_s in the step budget
-                        log(f"iter {it1}: loss = {float(loss):.4f}")
-                        host_sync_s += time.perf_counter() - tb0
+                        with obs.span("ff:runtime.fit_sync", step=it1,
+                                      what="print") as sp:
+                            # sync-ok: print_freq-gated loss fetch,
+                            # charged to host_sync_s in the step budget
+                            log(f"iter {it1}: loss = {float(loss):.4f}")
+                        host_sync_s += sp.seconds
                     if at_ckpt:
                         t0 = time.perf_counter()
                         if awriter is not None:
@@ -2166,8 +2310,10 @@ class FFModel:
                         break
                     it += 1
                 if loss is not None:
-                    # sync-ok: closes the timed window
-                    jax.block_until_ready(loss)
+                    with obs.span("ff:runtime.fit_sync", step=it,
+                                  what="close"):
+                        # sync-ok: closes the timed window
+                        jax.block_until_ready(loss)
                 elapsed = time.perf_counter() - start
         except BaseException:
             # error exit (host crash, device loss handed to the elastic
@@ -2256,6 +2402,7 @@ class FFModel:
                 olog.event("regrid_plan", **rsum)
             if prefetcher is not None:
                 olog.event("prefetch", **prefetcher.summary())
+            olog.spans()
         if self.config.profiling:
             # Flag-gated profiling report (reference: per-task cudaEvent ms
             # when `profiling` is set, conv_2d.cu:514-545).  Lead with the
@@ -2508,7 +2655,7 @@ class FFModel:
         """One step of the sampled op-timing mode: drain the async
         pipeline, time the forward and forward+backward sections, then
         run the REAL training step host-synced — backward and optimizer
-        times fall out by subtraction.  jax.profiler annotations bracket
+        times fall out by subtraction.  ``ff:profiling.*`` spans bracket
         each section so an XProf trace of the same run carries the
         boundaries.  Raw samples are buffered; op_time records are
         written after the timed loop."""
@@ -2519,11 +2666,11 @@ class FFModel:
             jax.block_until_ready(prev_loss)  # drain the pipeline
         rec = {"step": it + 1}
         t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("op_time:forward"):
+        with obs.span("ff:profiling.forward", step=it + 1):
             jax.block_until_ready(fwd(params, state, *batch))
         rec["forward"] = time.perf_counter() - t0
         t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("op_time:forward_backward"):
+        with obs.span("ff:profiling.forward_backward", step=it + 1):
             jax.block_until_ready(fwd_bwd(params, state, *batch))
         rec["forward_backward"] = time.perf_counter() - t0
         t0 = time.perf_counter()

@@ -49,6 +49,12 @@ fished out of mixed stdout.  This package gives them ONE record schema:
     read today, and ``obs/trace.py`` exports per-op timelines as
     Chrome/Perfetto traces with sim-vs-real drift attribution.
 
+Spans and counters are process-global and need no handle:
+``obs.span("ff:<layer>.<what>", **args)`` and ``obs.count(name, value)``
+(obs/spans.py) keep a bounded in-memory aggregate and lie on the
+profiler's clock; a surface with a live :class:`RunLog` writes ONE
+``spans`` record from it when it finishes (``RunLog.spans()``).
+
 Telemetry is strictly OFF the device hot path: records carry host-side
 timestamps only and no instrumentation site may introduce a device sync
 (``fit()`` buffers per-step wall times and writes records after the timed
@@ -98,6 +104,9 @@ class NullRunLog:
 
     def timer(self, name: str, **fields):
         return contextlib.nullcontext()
+
+    def spans(self) -> None:
+        pass
 
     def close(self) -> None:
         pass
@@ -179,12 +188,26 @@ class RunLog:
 
     @contextlib.contextmanager
     def timer(self, name: str, **fields):
-        t0 = time.perf_counter()
+        """A ``timer`` record, measured by :func:`span` ``ff:timer.<name>``
+        so the interval also lies in the aggregate and, under a profiler
+        session, on the device events' clock."""
+        from flexflow_tpu.obs import spans
+
+        sp = spans.span("ff:timer." + name)
         try:
-            yield
+            with sp:
+                yield
         finally:
-            self.event("timer", name=name,
-                       seconds=time.perf_counter() - t0, **fields)
+            self.event("timer", name=name, seconds=sp.seconds, **fields)
+
+    def spans(self) -> None:
+        """Write the one ``spans`` record: the process's span and
+        counter aggregate as it stands (memory first, file at the end) —
+        what ``fit``, the serve engine and ``apps/search.py`` call when
+        they finish."""
+        from flexflow_tpu.obs import spans
+
+        self.event("spans", **spans.summary())
 
     # -- lifecycle ------------------------------------------------------
 
@@ -199,6 +222,22 @@ class RunLog:
     def __exit__(self, *exc):
         self.close()
         return False
+
+
+# the span and counter API (obs/spans.py) under ``obs.<name>``, imported
+# on first use: spans.py imports JAX, which the report tools that import
+# this package for its JSONL readers never need
+_SPANS_API = ("span", "count", "snapshot", "reset", "note_program",
+              "program", "counter_at")
+
+
+def __getattr__(name: str):
+    if name in _SPANS_API:
+        from flexflow_tpu.obs import spans
+
+        value = globals()[name] = getattr(spans, name)
+        return value
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def _jsonable(o):

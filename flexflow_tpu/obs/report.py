@@ -799,6 +799,25 @@ def _fleet_section(events: List[Dict]) -> List[str]:
     return lines
 
 
+def _spans_section(events: List[Dict]) -> List[str]:
+    """The newest ``spans`` record: the process's ``ff:`` span and
+    counter aggregate (obs/spans.py) as it stood when a surface
+    finished — cumulative, so the last one holds the others."""
+    recs = [e for e in events if e.get("kind") == "spans"]
+    if not recs:
+        return []
+    rec = recs[-1]
+    lines = ["== spans (count, total, self) =="]
+    for name, a in sorted((rec.get("spans") or {}).items(),
+                          key=lambda kv: -kv[1].get("total_s", 0.0)):
+        lines.append(f"  {name:<34s} {a.get('count', 0):>7d} "
+                     f"{_fmt_s(a.get('total_s', 0.0)):>10s} "
+                     f"{_fmt_s(a.get('self_s', 0.0)):>10s}")
+    for name, v in sorted((rec.get("counters") or {}).items()):
+        lines.append(f"  counter {name}: {v:.6g}")
+    return lines
+
+
 def _misc_section(events: List[Dict]) -> List[str]:
     known = {"run_start", "compile", "step", "summary", "checkpoint_save",
              "checkpoint_restore", "sim_drift", "sim_drift_unavailable",
@@ -818,7 +837,8 @@ def _misc_section(events: List[Dict]) -> List[str]:
              "router_summary", "serve_fault", "serve_retry",
              "kv_rebuild", "serve_shed", "replica_down",
              "fleet_job", "fleet_placement", "fleet_rebalance",
-             "fleet_summary", "fleet_wait", "fleet_util", "fleetsim"}
+             "fleet_summary", "fleet_wait", "fleet_util", "fleetsim",
+             "spans"}
     lines = []
     for e in events:
         kind = e.get("kind")
@@ -849,7 +869,8 @@ def render(events: Iterable[Dict]) -> str:
                 _fleet_section(events),
                 _search_section(events),
                 _audit_bench_section(events), _lint_section(events),
-                _trace_section(events), _misc_section(events)]
+                _trace_section(events), _spans_section(events),
+                _misc_section(events)]
     return "\n".join("\n".join(s) for s in sections if s)
 
 

@@ -1,0 +1,208 @@
+"""The program's spans and counters: one process-global API, on the
+profiler's clock.
+
+    from flexflow_tpu import obs
+
+    with obs.span("ff:serve.step", step=3, active=2):
+        ...
+    obs.count("serve.host_bytes", nbytes)
+    obs.snapshot()          # the aggregate; obs.reset() clears it
+
+A span is named ``ff:<layer>.<what>``.  It records its name, start, end,
+the enclosing span of the same thread and its ``args`` into a bounded
+in-memory aggregate (per name: a count, total seconds, self seconds, and
+the last ``RECORDS_PER_NAME`` raw records), and opens a
+``jax.profiler.TraceAnnotation(name, **args)`` over the same interval:
+under a profiler session the span lies in the ``.xplane.pb`` on the
+device events' clock with its ``args`` as the event's stats, and with no
+session the annotation is one dormant TraceMe.  There is no switch: a
+span costs two ``perf_counter`` calls, that TraceMe and a dict update.
+
+Importing this module registers, once, ``jax.monitoring`` listeners that
+add JAX's own tracing, lowering and compilation seconds and the compile
+cache's events to the ``compile.*`` counters.  ``flexflow_tpu.obs``
+re-exports the API lazily, so the jax-free report tools that import
+``obs`` for the JSONL readers still never import JAX.
+"""
+
+from __future__ import annotations
+
+import collections
+import threading
+import time
+import weakref
+from typing import Dict
+
+import jax
+
+# raw records kept per span name; counter history entries kept per counter
+RECORDS_PER_NAME = 256
+HISTORY_PER_COUNTER = 512
+# a counter's history holds its cumulative value once per this many
+# seconds in which it moved: enough to ask "what did it read at time t"
+_HISTORY_BUCKET_S = 0.25
+
+_COMPILE_SECONDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile.backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "compile.cache_fetch_s",
+}
+_COMPILE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+
+_lock = threading.Lock()
+_tls = threading.local()
+_spans: Dict[str, list] = {}          # name -> [count, total_s, self_s]
+_records: Dict[str, collections.deque] = {}
+_counters: Dict[str, float] = {}
+_history: Dict[str, collections.deque] = {}   # name -> [t_first, t_last, v]
+_programs: Dict[str, weakref.ref] = {}
+
+
+class span:
+    """Context manager; see the module docstring.  ``seconds`` holds the
+    duration once the block has ended.  ``args`` may be added to inside
+    the block (a byte count known only at the end): late keys reach the
+    in-memory record, not the profiler's event, whose stats are fixed
+    when it opens."""
+
+    __slots__ = ("name", "args", "start", "seconds", "_children_s",
+                 "_parent", "_ann")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+        self.seconds = 0.0
+        self._children_s = 0.0
+
+    def __enter__(self):
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self._parent = stack[-1] if stack else None
+        stack.append(self)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter()
+        self._ann.__exit__(*exc)
+        _tls.stack.pop()
+        self.seconds = dur = end - self.start
+        parent = self._parent
+        if parent is not None:
+            parent._children_s += dur
+        own = max(dur - self._children_s, 0.0)
+        rec = {"name": self.name, "start": self.start, "end": end,
+               "parent": parent.name if parent is not None else None,
+               "self_s": own, "thread": threading.get_ident(),
+               "args": self.args}
+        with _lock:
+            agg = _spans.get(self.name)
+            if agg is None:
+                agg = _spans[self.name] = [0, 0.0, 0.0]
+                _records[self.name] = collections.deque(
+                    maxlen=RECORDS_PER_NAME)
+            agg[0] += 1
+            agg[1] += dur
+            agg[2] += own
+            _records[self.name].append(rec)
+        return False
+
+
+def count(name: str, value: float = 1) -> None:
+    """Add ``value`` to the process-global counter ``name``."""
+    now = time.perf_counter()
+    with _lock:
+        v = _counters[name] = _counters.get(name, 0) + value
+        hist = _history.get(name)
+        if hist is None:
+            hist = _history[name] = collections.deque(
+                maxlen=HISTORY_PER_COUNTER)
+        if hist and now - hist[-1][0] < _HISTORY_BUCKET_S:
+            hist[-1][1], hist[-1][2] = now, v
+        else:
+            hist.append([now, now, v])
+
+
+def counter_at(snap: Dict, name: str, t: float) -> float:
+    """What counter ``name`` of ``snap`` read at ``perf_counter`` time
+    ``t``: its value after the last recorded moment at or before ``t``
+    (0 before the first)."""
+    value = 0.0
+    for _t_first, t_last, v in snap["counter_history"].get(name, ()):
+        if t_last > t:
+            break
+        value = v
+    return value
+
+
+def _aggregate() -> Dict:
+    return {"spans": {k: {"count": c, "total_s": t, "self_s": s}
+                      for k, (c, t, s) in _spans.items()},
+            "counters": dict(_counters)}
+
+
+def snapshot() -> Dict:
+    """A copy of the aggregate: ``spans`` (per name ``count``,
+    ``total_s``, ``self_s``), ``counters``, ``records`` (the kept raw
+    span records, by start) and ``counter_history``."""
+    with _lock:
+        return dict(
+            _aggregate(),
+            records=sorted((dict(r) for d in _records.values() for r in d),
+                           key=lambda r: r["start"]),
+            counter_history={k: [tuple(h) for h in d]
+                             for k, d in _history.items()})
+
+
+def summary() -> Dict:
+    """The aggregate without its raw records: the body of the one
+    ``spans`` record a surface writes to a live RunLog when it
+    finishes."""
+    with _lock:
+        return _aggregate()
+
+
+def reset() -> None:
+    with _lock:
+        _spans.clear()
+        _records.clear()
+        _counters.clear()
+        _history.clear()
+
+
+def note_program(name: str, model) -> None:
+    """Remember (weakly) which model built the program ``name``, so that
+    a trace reader can ask that model which operator each compiled
+    instruction belongs to."""
+    _programs[name] = weakref.ref(model)
+
+
+def program(name: str):
+    """The model noted under ``name``; None once it has been collected
+    or when none was noted."""
+    ref = _programs.get(name)
+    return ref() if ref is not None else None
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    name = _COMPILE_SECONDS.get(event)
+    if name is not None:
+        count(name, secs)
+
+
+def _on_event(event: str, **kw) -> None:
+    name = _COMPILE_EVENTS.get(event)
+    if name is not None:
+        count(name)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)

@@ -101,6 +101,11 @@ class Op:
     #: subclasses override, e.g. ("w", "h", "c", "n") for 4-D CNN ops.
     AXIS_NAMES: Tuple[str, ...] = ("n",)
 
+    #: a pure reshape of its input, which may compile to a bitcast that
+    #: carries no metadata: the one kind of operator a compiled step need
+    #: not name (FFModel.operator_table)
+    IS_VIEW: bool = False
+
     def __init__(self, name: str, pc: ParallelConfig,
                  inputs: Sequence[Tensor]):
         if len(pc.dims) != len(self.AXIS_NAMES):

@@ -22,6 +22,7 @@ from flexflow_tpu.strategy import ParallelConfig
 
 class Flat(Op):
     AXIS_NAMES = ("c", "n")
+    IS_VIEW = True
 
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor):
         super().__init__(name, pc, [input])

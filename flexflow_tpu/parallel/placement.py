@@ -823,8 +823,9 @@ def _run_group_set(machine, group: PlacementGroup,
                 else:
                     ls = {k: _point_slice(v, sspecs[k], sizes, idx)
                           for k, v in st.items()}
-                outs, new_st = ops[m].point_forward(
-                    lp, ls, xs_by_member[m], idx, sizes, train)
+                with jax.named_scope(ops[m].name):
+                    outs, new_st = ops[m].point_forward(
+                        lp, ls, xs_by_member[m], idx, sizes, train)
                 outs = outs + tuple(new_st[k] for k in state_keys)
                 return tuple(jnp.expand_dims(o, 0) for o in outs)
             return br
@@ -990,14 +991,18 @@ def _run_group_homogeneous(machine, group: PlacementGroup,
         # for every member UNCONDITIONALLY — member inputs are replicated
         # over the group axis, so this is uniform across device blocks;
         # collectives inside the switch branches would be illegal SPMD
-        aux_by_member = [ops[m].placed_prelude(xs_by_member[m], train)
-                         for m in range(len(ops))]
+        aux_by_member = []
+        for m in range(len(ops)):
+            with jax.named_scope(ops[m].name):
+                aux_by_member.append(
+                    ops[m].placed_prelude(xs_by_member[m], train))
 
         def branch_for(m):
             def br(_):
-                res, new_st = ops[m].sharded_forward(
-                    local_params, local_state, xs_by_member[m], train,
-                    aux=aux_by_member[m])
+                with jax.named_scope(ops[m].name):
+                    res, new_st = ops[m].sharded_forward(
+                        local_params, local_state, xs_by_member[m], train,
+                        aux=aux_by_member[m])
                 outs = res if isinstance(res, tuple) else (res,)
                 outs = outs + tuple(new_st[k] for k in state_keys)
                 return tuple(jnp.expand_dims(o, 0) for o in outs)
@@ -1323,9 +1328,11 @@ def _run_group_hetero(machine, group: PlacementGroup,
         # over the group axis; collectives inside branches are illegal).
         # Guests are point-local by construction, so their preludes are
         # no-ops
-        aux_by_member = [
-            ops[m].placed_prelude(list(flat[offs[m]:offs[m + 1]]), train)
-            for m in range(len(ops))]
+        aux_by_member = []
+        for m in range(len(ops)):
+            with jax.named_scope(ops[m].name):
+                aux_by_member.append(ops[m].placed_prelude(
+                    list(flat[offs[m]:offs[m + 1]]), train))
 
         def raw_branch(m):
             def br(_):
@@ -1336,9 +1343,10 @@ def _run_group_hetero(machine, group: PlacementGroup,
                 else:
                     p = unravel(local_vec, metas[m])
                 s = unravel(local_svec, smetas[m])
-                res, new_st = ops[m].sharded_forward(
-                    p, s, list(flat[offs[m]:offs[m + 1]]), train,
-                    aux=aux_by_member[m])
+                with jax.named_scope(ops[m].name):
+                    res, new_st = ops[m].sharded_forward(
+                        p, s, list(flat[offs[m]:offs[m + 1]]), train,
+                        aux=aux_by_member[m])
                 outs = res if isinstance(res, tuple) else (res,)
                 nsv, _ = ravel_tree(new_st, "state", ops[m].name)
                 nsv = jnp.pad(nsv, (0, smax - nsv.shape[0]))

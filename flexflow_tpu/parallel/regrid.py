@@ -391,11 +391,15 @@ class RegridPlan:
             return x
         from jax import lax
 
+        import jax
+
         ck = ep.share_key
         if ck is not None and ck in cache:
             return cache[ck]
-        for sh in ep.shardings:
-            x = lax.with_sharding_constraint(x, sh)
+        # a fan-out reshard is traced once, under its first consumer
+        with jax.named_scope(f"ff_regrid.{op_name}.{input_idx}"):
+            for sh in ep.shardings:
+                x = lax.with_sharding_constraint(x, sh)
         if ck is not None:
             cache[ck] = x
         return x

@@ -62,6 +62,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from flexflow_tpu import obs
 from flexflow_tpu.serve.batcher import (ContinuousBatcher, RequestQueue,
                                         batch_requests)
 from flexflow_tpu.serve.kv_cache import KVCache, KVCacheLayout
@@ -97,8 +98,6 @@ class ServeEngine:
                  queue_hi: int = 0, idle_boundaries: int = 0,
                  shrink_to: int = 0, kv_window: Optional[int] = None,
                  pad_id: int = 0, phase: str = "full", pool: str = ""):
-        from flexflow_tpu import obs
-
         if phase not in ("full", "prefill", "decode"):
             raise ValueError(
                 f"phase must be 'full', 'prefill' or 'decode', "
@@ -421,56 +420,9 @@ class ServeEngine:
 
         # one decode step over the full rectangle
         active = batcher.active()
-        pre_lengths = {i: sl.length for i, sl in active}
-        tokens = batcher.token_matrix(self.pad_id)
-        t0 = time.perf_counter()
-        outs = self._predict(self.params, self.state, tokens,
-                             *s["extra"])
-        logprobs = np.asarray(outs[0])
-        step_wall = time.perf_counter() - t0
-        self._fill_kv(outs[1:], active, pre_lengths)
-        step_s = self.step_time_s
-        if self.phase == "decode":
-            # injected straggler: this step's virtual service time
-            # stretches, delaying every token it lands — the p99 tail
-            # the hedged-decode mode protects against.  Host-side only:
-            # with no injector armed the branch is byte-inert.
-            inj = faultinject.get()
-            if inj.enabled and inj.fire("slow_replica", site=self.pool):
-                step_s *= SLOW_REPLICA_FACTOR
-        done_v = vnow + step_s  # this step's tokens land here
-        for slot_idx, slot in active:
-            nxt_tok = int(np.argmax(logprobs[slot_idx,
-                                             slot.length - 1]))
-            slot.req.wall_s += step_wall
-            batcher.record_token(slot_idx, nxt_tok)
-            if slot.generated == 1:
-                # the request's FIRST token materialized this step —
-                # the TTFT stamp every serve_request record carries.
-                # A handed-off request re-enters the decode pool with
-                # ``generated == len(carried_tokens) >= 1`` already, so
-                # the prefill pool's stamp is never overwritten.
-                slot.req.first_token_v = done_v
-        s["vnow"] = vnow = done_v
-        s["steps"] += 1
-        if self.phase == "prefill":
-            # the prompt pass is done: every still-running slot leaves
-            # this pool carrying its generated token(s) and its exported
-            # KV rows — the router routes it to a decode replica.
-            # (Slots that finished outright — 1-token budget or instant
-            # EOS — fall through to the normal reclaim below.)
-            for slot_idx, slot in active:
-                if slot.done:
-                    continue
-                req = slot.req
-                req.carried_tokens = slot.tokens[len(req.tokens):]
-                if self.kv_cache is not None:
-                    req.kv_payload = self.kv_cache.export_request(
-                        slot_idx)
-                    self.kv_cache.reclaim(slot_idx)
-                self._kv_filled[slot_idx] = 0
-                batcher.release(slot_idx)
-                s["handoffs"].append(req)
+        with obs.span("ff:serve.step", step=s["steps"] + 1,
+                      active=len(active)):
+            vnow = self._decode_step(s, active, vnow)
         for slot_idx, req in batcher.reclaim(vnow):
             if self.kv_cache is not None:
                 self.kv_cache.reclaim(slot_idx)
@@ -494,6 +446,77 @@ class ServeEngine:
                         **self._kv_occupancy())
         self._update_gauges(s["completed"], depth, vnow)
         return True
+
+    def _to_host(self, x) -> np.ndarray:
+        """A device array on the host, its bytes counted: every copy the
+        decode step makes goes through here."""
+        out = np.asarray(x)
+        obs.count("serve.host_bytes", out.nbytes)
+        return out
+
+    def _decode_step(self, s, active, vnow: float) -> float:
+        """The forward, the copy to the host, the token choice and the
+        KV fill of one decode step (the children of ``ff:serve.step``);
+        returns the virtual time its tokens land at.  ``ff:serve.forward``
+        times the dispatch alone and ``ff:serve.to_host`` owns the wait
+        for the device (no span adds a sync: the device's own share of a
+        step is the trace's to say)."""
+        batcher = s["batcher"]
+        pre_lengths = {i: sl.length for i, sl in active}
+        tokens = batcher.token_matrix(self.pad_id)
+        with obs.span("ff:serve.forward") as fwd:
+            outs = self._predict(self.params, self.state, tokens,
+                                 *s["extra"])
+        with obs.span("ff:serve.to_host") as copy:
+            logprobs = self._to_host(outs[0])
+        step_wall = fwd.seconds + copy.seconds
+        with obs.span("ff:serve.kv_fill"):
+            self._fill_kv(outs[1:], active, pre_lengths)
+        step_s = self.step_time_s
+        if self.phase == "decode":
+            # injected straggler: this step's virtual service time
+            # stretches, delaying every token it lands — the p99 tail
+            # the hedged-decode mode protects against.  Host-side only:
+            # with no injector armed the branch is byte-inert.
+            inj = faultinject.get()
+            if inj.enabled and inj.fire("slow_replica", site=self.pool):
+                step_s *= SLOW_REPLICA_FACTOR
+        done_v = vnow + step_s  # this step's tokens land here
+        with obs.span("ff:serve.sample"):
+            for slot_idx, slot in active:
+                nxt_tok = int(np.argmax(logprobs[slot_idx,
+                                                 slot.length - 1]))
+                slot.req.wall_s += step_wall
+                batcher.record_token(slot_idx, nxt_tok)
+                if slot.generated == 1:
+                    # the request's FIRST token materialized this step —
+                    # the TTFT stamp every serve_request record carries.
+                    # A handed-off request re-enters the decode pool with
+                    # ``generated == len(carried_tokens) >= 1`` already,
+                    # so the prefill pool's stamp is never overwritten.
+                    slot.req.first_token_v = done_v
+            obs.count("serve.tokens_out", len(active))
+        s["vnow"] = vnow = done_v
+        s["steps"] += 1
+        if self.phase == "prefill":
+            # the prompt pass is done: every still-running slot leaves
+            # this pool carrying its generated token(s) and its exported
+            # KV rows — the router routes it to a decode replica.
+            # (Slots that finished outright — 1-token budget or instant
+            # EOS — fall through to the normal reclaim below.)
+            for slot_idx, slot in active:
+                if slot.done:
+                    continue
+                req = slot.req
+                req.carried_tokens = slot.tokens[len(req.tokens):]
+                if self.kv_cache is not None:
+                    req.kv_payload = self.kv_cache.export_request(
+                        slot_idx)
+                    self.kv_cache.reclaim(slot_idx)
+                self._kv_filled[slot_idx] = 0
+                batcher.release(slot_idx)
+                s["handoffs"].append(req)
+        return vnow
 
     def finish(self) -> Dict:
         """Close the session: emit ``serve_summary`` and return it.
@@ -538,7 +561,7 @@ class ServeEngine:
         captured per-layer attention inputs."""
         if self.kv_cache is None:
             return
-        xs = [np.asarray(x).astype(np.float32) for x in attn_ins]
+        xs = [self._to_host(x).astype(np.float32) for x in attn_ins]
         h, hd = self.kv_layout.num_heads, self.kv_layout.head_dim
         for li, (wk, wv) in enumerate(self._kv_w):
             x = xs[li]
@@ -780,5 +803,6 @@ class ServeEngine:
             "pool": self.pool,
         }
         self.olog.event("serve_summary", **summary)
+        self.olog.spans()
         self._update_gauges(completed, 0, vnow)
         return summary

@@ -793,6 +793,7 @@ class StrategySearch(StrategySearchDecomposedMixin):
     def _build(self):
         import logging
 
+        from flexflow_tpu import obs as _obs
         from flexflow_tpu.sim.cost_model import TpuChipPerf
 
         logger = logging.getLogger(__name__)
@@ -959,13 +960,15 @@ class StrategySearch(StrategySearchDecomposedMixin):
         # split encountered before any measured sibling of its kind no
         # longer falls back to an unanchored analytic number.  Estimates
         # are never cached, so the re-derivation is what lands in costs.
-        if hasattr(self.cost_model, "flush"):
-            for _, op, pc in cost_pairs:
-                self.cost_model.op_cost(op, pc)
-        for i, op, pc in cost_pairs:
-            costs[i] = self.cost_model.op_cost(op, pc)
-        if hasattr(self.cost_model, "flush"):
-            self.cost_model.flush()
+        with _obs.span("ff:plan.measure_ops", candidates=len(cost_pairs),
+                       cost_model=type(self.cost_model).__name__):
+            if hasattr(self.cost_model, "flush"):
+                for _, op, pc in cost_pairs:
+                    self.cost_model.op_cost(op, pc)
+            for i, op, pc in cost_pairs:
+                costs[i] = self.cost_model.op_cost(op, pc)
+            if hasattr(self.cost_model, "flush"):
+                self.cost_model.flush()
         if self.objective in ("latency", "decode"):
             # forward-only pricing (constructor docstring): the cost
             # model's 3.0x fwd+bwd+wgrad convention makes the forward
@@ -1046,7 +1049,9 @@ class StrategySearch(StrategySearchDecomposedMixin):
         dbls.extend(costs)
         dbls.extend(replicas)
         dbls.extend(colls)
-        self.sim = NativeSimulator(ints, dbls, len(self.ops))
+        with _obs.span("ff:plan.native_build", ops=len(self.ops),
+                       candidates=len(costs)):
+            self.sim = NativeSimulator(ints, dbls, len(self.ops))
         # The optimizer's parameter-stream pass, previously unmodeled
         # (calibration on v5e: NMT's ~1 GB of fp32 params cost ~4 ms/step
         # of pure HBM streaming that no per-op compute time contains).
@@ -1445,6 +1450,8 @@ class StrategySearch(StrategySearchDecomposedMixin):
         per-(chunk, chain) trajectory for programmatic callers."""
         import time as _time
 
+        from flexflow_tpu import obs as _obs
+
         dp = self.dp_assignment()
         dp_time = self.simulate(dp)
         init = list(start) if start is not None else list(dp)
@@ -1473,11 +1480,14 @@ class StrategySearch(StrategySearchDecomposedMixin):
             it_n = iters // chunks + (1 if ci < iters % chunks else 0)
             if it_n <= 0:
                 continue
-            t0 = _time.perf_counter()
-            curs, bests, times, stats = self.sim.mcmc_chains_chunk(
-                curs, bests, times, it_n, beta=beta,
-                seed=seed * 1_000_003 + ci)
-            wall = _time.perf_counter() - t0
+            with _obs.span("ff:plan.mcmc", proposals=it_n * chains,
+                           chains=chains) as sp:
+                curs, bests, times, stats = self.sim.mcmc_chains_chunk(
+                    curs, bests, times, it_n, beta=beta,
+                    seed=seed * 1_000_003 + ci)
+            _obs.count("plan.proposals",
+                       sum(st["proposed"] for st in stats))
+            wall = sp.seconds
             tot_wall += wall
             done += it_n
             for chain_i in range(chains):

@@ -210,12 +210,18 @@ class Strategy(dict):
 
     @classmethod
     def load(cls, path: str) -> "Strategy":
-        with open(path, "rb") as f:
-            raw = f.read()
-        stripped = raw.lstrip()
-        if stripped.startswith(b"{"):
-            return cls.from_json(raw.decode("utf-8"))
-        return cls.from_proto_bytes(raw)
+        from flexflow_tpu import obs
+
+        with obs.span("ff:entry.strategy_load") as sp:
+            with open(path, "rb") as f:
+                raw = f.read()
+            stripped = raw.lstrip()
+            if stripped.startswith(b"{"):
+                out = cls.from_json(raw.decode("utf-8"))
+            else:
+                out = cls.from_proto_bytes(raw)
+            sp.args["ops"] = len(out)
+        return out
 
 
 # ---------------------------------------------------------------------------

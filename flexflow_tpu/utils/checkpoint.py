@@ -48,6 +48,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from flexflow_tpu import obs
+
 _SEP = "/"
 
 
@@ -190,6 +192,13 @@ def save_checkpoint(ckpt_dir: str, step: int, params: Dict, state: Dict,
     ``require_finite`` (the default) non-finite float leaves abort the
     save BEFORE anything touches disk.  Returns the committed
     directory."""
+    with obs.span("ff:runtime.checkpoint_save", step=int(step)):
+        return _save_checkpoint(ckpt_dir, step, params, state, opt_state,
+                                strategy, keep, require_finite)
+
+
+def _save_checkpoint(ckpt_dir, step, params, state, opt_state, strategy,
+                     keep, require_finite) -> str:
     os.makedirs(ckpt_dir, exist_ok=True)
     _sweep_stale(ckpt_dir)
     tmp = os.path.join(ckpt_dir, f"tmp.{step}")
@@ -376,8 +385,6 @@ def restore_checkpoint(ckpt_dir: str, model=None,
     is emitted on ``olog``, and only when EVERY committed step fails does
     this raise :class:`CheckpointCorruptError`.  An explicit ``step`` is
     verified but never cascaded (the caller asked for that one)."""
-    from flexflow_tpu import obs
-
     olog = olog if olog is not None else obs.NULL
     _sweep_stale(ckpt_dir)
     if step is not None:
@@ -468,8 +475,6 @@ class AsyncCheckpointWriter:
         import queue
         import threading
 
-        from flexflow_tpu import obs
-
         self.olog = olog if olog is not None else obs.NULL
         self.log = log or (lambda *a: None)
         self.keep = keep
@@ -495,13 +500,17 @@ class AsyncCheckpointWriter:
         self.wait()
         import time as _time
 
-        job = {
-            "dir": ckpt_dir, "step": int(step),
-            "params": snapshot_tree(params),
-            "state": snapshot_tree(state),
-            "opt": snapshot_tree(opt_state),
-            "strategy": strategy, "t_submit": _time.perf_counter(),
-        }
+        # the host snapshot is the part of an async save the training
+        # loop waits for
+        with obs.span("ff:runtime.checkpoint_save", step=int(step),
+                      mode="snapshot"):
+            job = {
+                "dir": ckpt_dir, "step": int(step),
+                "params": snapshot_tree(params),
+                "state": snapshot_tree(state),
+                "opt": snapshot_tree(opt_state),
+                "strategy": strategy, "t_submit": _time.perf_counter(),
+            }
         with self._lock:
             self.inflight += 1
         self._idle.clear()

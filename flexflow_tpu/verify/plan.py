@@ -550,9 +550,13 @@ def check_plan(model, strategy, machine=None, *,
     degrade-with-a-warning under ``allow_degraded``)."""
     import sys
 
-    findings, _summary = plan_findings(
-        model, strategy, machine, allow_degraded=allow_degraded,
-        check_memory=check_memory, hbm_capacity=hbm_capacity)
+    from flexflow_tpu import obs
+
+    with obs.span("ff:entry.plan_check", ops=len(model.layers)) as sp:
+        findings, _summary = plan_findings(
+            model, strategy, machine, allow_degraded=allow_degraded,
+            check_memory=check_memory, hbm_capacity=hbm_capacity)
+        sp.args["findings"] = len(findings)
     errors = [f for f in findings
               if f.severity == "error" and not f.exempted]
     if findings:
