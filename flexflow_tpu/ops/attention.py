@@ -87,21 +87,20 @@ class MultiHeadAttention(Op):
         from flexflow_tpu.parallel.ring_attention import ring_attention
 
         (x,) = xs
-        b, s, d = x.shape
-        h, hd = self.num_heads, self.head_dim
 
-        def proj(w):
-            y = jnp.einsum("bsd,de->bse", x, w.astype(x.dtype),
-                           preferred_element_type=jnp.float32).astype(x.dtype)
-            return y.reshape(b, s, h, hd).transpose(0, 2, 1, 3)  # (B,H,S,hd)
+        def proj(w):  # (B, S, H*hd): head i in columns i*hd:(i+1)*hd
+            return jnp.einsum("bsd,de->bse", x, w.astype(x.dtype),
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
 
         q, k, v = proj(params["wq"]), proj(params["wk"]), proj(params["wv"])
         if self._use_ring():
             mesh = self.machine.mesh_for(self.pc, self.AXIS_NAMES)
-            out = ring_attention(q, k, v, mesh, "s", self.causal)
+            out = self._merge_heads(ring_attention(
+                *map(self._split_heads, (q, k, v)), mesh, "s", self.causal))
         else:
-            out = self._flash_or_blockwise(q, k, v, s)
-        out = out.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, s, d)
+            out = self._flash_or_blockwise(q, k, v)
+        out = out.astype(x.dtype)
         if (self.machine is not None and self.machine.num_devices > 1
                 and self.pc.dims[1] > 1):
             # head TP: keep the merged activation head-sharded along d so
@@ -120,20 +119,25 @@ class MultiHeadAttention(Op):
                        preferred_element_type=jnp.float32).astype(x.dtype)
         return y + params["bo"].astype(x.dtype), state
 
-    def _flash_or_blockwise(self, q, k, v, s: int):
-        """Non-ring attention body: the Pallas flash kernel on TPU (direct
-        on one device; per-shard under shard_map on a canonical multi-device
-        grid, where head/batch sharding is embarrassingly parallel),
-        otherwise the XLA streaming-softmax path with GSPMD sharding."""
-        from flexflow_tpu.ops.pallas import flash_attention, flash_enabled
+    def _flash_or_blockwise(self, q, k, v):
+        """Non-ring attention body on the projections' (B, S, H*hd) layout:
+        the Pallas flash kernel on TPU, which reads that layout as it is
+        (direct on one device; per-shard under shard_map on a canonical
+        multi-device grid, where head/batch sharding is embarrassingly
+        parallel), otherwise the XLA streaming-softmax path with GSPMD
+        sharding."""
+        from flexflow_tpu.ops.pallas import flash_enabled
+        from flexflow_tpu.ops.pallas.flash_attention import \
+            flash_attention_packed
         from flexflow_tpu.parallel.ring_attention import blockwise_attention
 
+        b, s, _ = q.shape
+        h = self.num_heads
         if flash_enabled():
             nd = self.machine.num_devices if self.machine is not None else 1
             if nd == 1 or len(self.pc.devices) == 1:
-                return flash_attention(q, k, v, self.causal)
+                return flash_attention_packed(q, k, v, h, self.causal)
             _, ph, pn = self.pc.dims
-            b, h = q.shape[0], q.shape[1]
             if (self.machine.is_canonical(self.pc)
                     and b % max(pn, 1) == 0 and h % max(ph, 1) == 0):
                 from jax.sharding import PartitionSpec as P
@@ -142,14 +146,27 @@ class MultiHeadAttention(Op):
                     unchecked_shard_map
 
                 mesh = self.machine.mesh_for(self.pc, self.AXIS_NAMES)
-                spec = P("n" if pn > 1 else None, "h" if ph > 1 else None,
-                         None, None)
+                spec = P("n" if pn > 1 else None, None,
+                         "h" if ph > 1 else None)
                 return unchecked_shard_map(
-                    lambda ql, kl, vl: flash_attention(ql, kl, vl,
-                                                       self.causal),
+                    lambda ql, kl, vl: flash_attention_packed(
+                        ql, kl, vl, h // max(ph, 1), self.causal),
                     mesh, (spec, spec, spec), spec)(q, k, v)
-        return blockwise_attention(q, k, v, self.causal,
-                                   block_size=min(s, 512))
+
+        return self._merge_heads(blockwise_attention(
+            *map(self._split_heads, (q, k, v)), self.causal,
+            block_size=min(s, 512)))
+
+    def _split_heads(self, y):
+        """(B, S, H*hd) -> (B, H, S, hd)."""
+        b, s, _ = y.shape
+        return y.reshape(b, s, self.num_heads, -1).transpose(0, 2, 1, 3)
+
+    @staticmethod
+    def _merge_heads(y):
+        """(B, H, S, hd) -> (B, S, H*hd)."""
+        b, h, s, hd = y.shape
+        return y.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
 
     def local_clone(self, pc: ParallelConfig):
         ps, ph, pn = pc.dims

@@ -8,17 +8,62 @@ Backward is the standard flash recomputation: softmax probabilities are
 rebuilt per tile from the saved log-sum-exp, so residual memory is O(S)
 per row (out + lse) instead of O(S^2).
 
-Layout/tiling (per /opt/skills/guides/pallas_guide.md): grid = (batch*heads,
-S_q/block_q, S_k/block_k) with the K dimension innermost, so the
-(block_q, d) output block is revisited across K steps and accumulated in
-f32 VMEM scratch; blocks default to 512x512 score tiles (measured fastest
-on v5e; clamped down for short sequences, always 128-aligned); the running
-max/denominator live in (block_q, 128)-lane scratch; per-row lse/delta are
-carried as (S, 1) column tensors so no lane<->sublane relayout is needed.
-Causal tiles strictly above the diagonal skip their matmuls entirely.
+Layout.  The kernels work on the projections' own ``(B, S, H*hd)``
+arrays, in the operands' type: a block is ``(block, W)`` with ``W`` a
+multiple of 128 lanes holding ``G`` whole heads, so nothing is padded or
+transposed in HBM for the usual head widths (:func:`_layout`):
+
+  * hd divides 128 (32, 64) and H is a multiple of 128/hd: ``G = 128/hd``
+    heads share one 128-lane block (``pack<G>``).  Head g's scores come
+    from operands whose other lanes are zeroed, which costs the MXU what
+    padding hd to 128 would, and its lanes of a W-wide product are
+    selected afterwards;
+  * hd a multiple of 128: one head a block (``pack1``);
+  * anything else (hd 80, or H not a multiple of G): hd is zero-padded to
+    the next multiple of 128 (``pad<hd_p>``), the one case that pads.
+
+Accumulators, scores, softmax, lse and delta are float32 in VMEM; out,
+dq, dk and dv leave the kernels in the operands' type (the partial form
+keeps a float32 out, since :func:`combine_partials` merges them).  lse and
+delta are lane-dense ``(B, H, 1, S)`` rows.
+
+Tiles.  grid = (B, H/G, outer blocks, inner blocks); both passes work on
+transposed ``(block_k, block_q)`` score tiles, so the softmax statistics,
+lse and delta are lane-dense rows that reduce and broadcast along
+sublanes, a head's share of a W-row result is a sublane slice, and no
+tile-sized operand is transposed.  Blocks are 1024 x 1024 (a shorter
+sequence is one block; :func:`_pick_block`).  A causal tile wholly above
+the diagonal is skipped and its operands are not fetched (the index maps
+clamp to the nearest live block); a tile on the diagonal is walked in
+square pieces (512 forward, 256 backward) of which those above the
+diagonal are dropped and only those on it build a mask; the last K block
+of a padded length builds one too, and no other tile does; ``1/sqrt(hd)``
+is folded into the hoisted copy of q (forward) or k (backward).  The
+backward is one kernel when ``S*W*4`` bytes of dq fit the VMEM budget
+(``fused``: K blocks outer, Q inner, one recomputation of the
+probabilities a tile, dq^T accumulated in scratch and written once a head
+group) and two beyond it (``split``: ``ff_flash_bwd_dkv`` and
+``ff_flash_bwd_dq``).
+
+Sequence lengths that are not a block multiple are zero-padded (padded K
+columns masked, padded Q rows sliced off).  Which variant a call took is
+counted once a trace in ``kernels.flash.<layout>.<backward>``.
 
 On TPU the kernels compile via Mosaic; elsewhere they run in interpreter
 mode, so the identical code path is exercised by the CPU test suite.
+
+Measured on a v5e (PERF.md section 6, PR 27; b16 h12 s1024 d64 causal
+bf16, ms forward / backward a call, 40 pipelined calls): XLA blockwise
+4.94 / 7.39; the kernels this file held before (hd padded to 128, float32
+results, (block_q, block_k) tiles with (block, 1) column statistics, two
+backward kernels) 2.27 / 3.61; this layout with column statistics 1.40 /
+1.36 at 512 x 512, and 64-lane blocks of a (B*H, S, 64) layout 1.91 / 2.04;
+transposed tiles 0.71 / 1.26 at 512 x 512, 1.33 / 1.50 at 256 x 256, 0.59 /
+1.38 at 1024 x 1024; with the diagonal walked in pieces 0.51 / 1.08 (pieces
+of 512) and 0.81 / 0.93 (256), which is why the passes take different
+pieces; inside the GPT-2 step 0.50 / 0.78.  The split backward costs 0.5-1.0
+ms more than the fused one.  The kernels beat XLA's blockwise attention
+at every shape tried (S 512 to 8192, hd 64 and 128, bf16 and float32).
 
 This is the framework's hand-written-kernel layer — the role the CUDA leaf
 tasks play in the reference (e.g. conv_2d.cu:523-536), applied to the one
@@ -36,243 +81,442 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK = 512
+from flexflow_tpu import obs
+
+LANES = 128
 _NEG_INF = float("-inf")
+# dq^T of one head group stays in VMEM across the fused backward
+_FUSED_DQ_BYTES = 2 * 1024 * 1024
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# score tiles, and the pieces a tile on the diagonal is walked in: sizes
+# picked on the v5e (PERF.md section 6, PR 27)
+_BLOCK = 1024
+_FWD_PIECE = 512
+_BWD_PIECE = 256
+_F32 = jnp.float32
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _block_mask(q_off, k_off, shape, sk: int, causal: bool):
-    """Validity mask for one (block_q, block_k) score tile: mask padded K
-    columns (kpos >= sk) and, when causal, future positions."""
-    kpos = k_off + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    valid = kpos < sk
-    if causal:
-        qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        valid = jnp.logical_and(valid, qpos >= kpos)
-    return valid
+def _layout(h: int, hd: int):
+    """(G heads a block, head width in the kernel's arrays, name)."""
+    g = LANES // hd if LANES % hd == 0 else 0
+    if g >= 1 and h % g == 0:
+        return g, hd, f"pack{g}"
+    if hd % LANES == 0:
+        return 1, hd, "pack1"
+    hd_p = _round_up(hd, LANES)
+    return 1, hd_p, f"pad{hd_p}"
+
+
+def _pick_block(s: int, interpret: bool) -> int:
+    """Score-tile size along a sequence of length s.  A short sequence is
+    one block of its own length (8-aligned in interpret mode, 128-aligned
+    on hardware); a long one takes the block that pads it least."""
+    one = _round_up(s, 8 if interpret else LANES)
+    if one <= _BLOCK:
+        return one
+    return min((_BLOCK, 3 * _BLOCK // 4, _BLOCK // 2),
+               key=lambda b: _round_up(s, b))
+
+
+def _nt(a, b):
+    """a (m, c) x b (n, c)^T -> (m, n) float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=_F32)
+
+
+def _nn(a, b):
+    """a (m, c) x b (c, n) -> (m, n) float32."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=_F32)
+
+
+def _only_head(x, g: int, heads: int, hd: int):
+    """x with the lanes of every head but g zeroed."""
+    if heads == 1:
+        return x
+    i = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((i >= g * hd) & (i < (g + 1) * hd), x, 0.0)
+
+
+def _by_head(parts, hd: int):
+    """One array whose head-g lanes come from ``parts[g]``."""
+    out = parts[0]
+    if len(parts) > 1:
+        i = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for g in range(1, len(parts)):
+            out = jnp.where(i >= g * hd, parts[g], out)
+    return out
+
+
+class _Tiles:
+    """Static geometry of one call's score tiles: which are live (causal:
+    not wholly above the diagonal), which need a mask (straddle the
+    diagonal, or hold padded K columns), and how a tile on the diagonal of
+    square blocks splits into ``piece``-sized squares of which those above
+    the diagonal are dropped."""
+
+    def __init__(self, causal, sk, block_q, block_k, n_q, n_k, piece):
+        self.causal, self.sk = causal, sk
+        self.bq, self.bk, self.n_q, self.n_k = block_q, block_k, n_q, n_k
+        self.k_padded = sk % block_k != 0
+        split = causal and block_q == block_k and block_q % piece == 0
+        self.n_sub = block_q // piece if split else 1
+
+    def live(self, qi, ki):
+        if not self.causal:
+            return True
+        return qi * self.bq + self.bq - 1 >= ki * self.bk
+
+    def masked(self, qi, ki):
+        """None when no tile of the call needs a mask."""
+        m = None
+        if self.causal:  # some column of the tile lies right of some row
+            m = ki * self.bk + self.bk - 1 > qi * self.bq
+        if self.k_padded:
+            last = ki == self.n_k - 1
+            m = last if m is None else jnp.logical_or(m, last)
+        return m
+
+    def valid(self, qi, ki, qs: slice, ks: slice):
+        """Mask of the (k piece, q piece) of transposed tile (qi, ki)."""
+        shape = (ks.stop - ks.start, qs.stop - qs.start)
+        kpos = ki * self.bk + ks.start + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0)
+        ok = kpos < self.sk if self.k_padded else None
+        if self.causal:
+            qpos = qi * self.bq + qs.start + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 1)
+            ok = qpos >= kpos if ok is None else ok & (qpos >= kpos)
+        return ok
+
+    def first_live_q(self, ki):
+        if not self.causal:
+            return 0
+        return jnp.minimum((ki * self.bk) // self.bq, self.n_q - 1)
+
+    def last_live_k(self, qi):
+        if not self.causal:
+            return self.n_k - 1
+        return jnp.minimum((qi * self.bq + self.bq - 1) // self.bk,
+                           self.n_k - 1)
+
+    def run(self, qi, ki, body):
+        """Call ``body(pieces)`` under the grid-step conditions it needs;
+        ``pieces`` is a static list of (q slice, k slice, masked)."""
+        whole = (slice(0, self.bq), slice(0, self.bk))
+        live, masked = self.live(qi, ki), self.masked(qi, ki)
+        if masked is None:
+            pl.when(live)(lambda: body([(*whole, False)]))
+            return
+        pl.when(jnp.logical_and(live, jnp.logical_not(masked)))(
+            lambda: body([(*whole, False)]))
+        if self.n_sub > 1:
+            # square causal blocks: the straddling tiles are qi == ki
+            step = self.bq // self.n_sub
+            cut = [slice(i * step, (i + 1) * step)
+                   for i in range(self.n_sub)]
+            pl.when(qi == ki)(lambda: body(
+                [(cut[a], cut[c], c == a or self.k_padded)
+                 for a in range(self.n_sub) for c in range(a + 1)]))
+            if not self.k_padded:
+                return
+            masked = jnp.logical_and(masked, qi != ki)
+        pl.when(jnp.logical_and(live, masked))(
+            lambda: body([(*whole, True)]))
+
+
+def _params(interpret):
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary",
+                             "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT_BYTES)}
 
 
 # ---------------------------------------------------------------------------
-# forward
+# Both passes work on transposed (block_k, block_q) score tiles: the
+# softmax statistics, lse and delta are then lane-dense (1, block_q) rows
+# that reduce and broadcast along sublanes, a head's share of a W-row
+# result is a sublane slice, and no tile-sized operand is ever transposed.
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, sk, block_q, block_k):
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    q_off = pl.program_id(1) * block_q
-    k_off = ki * block_k
+def _head_rows(g: int, hd: int):
+    return slice(g * hd, (g + 1) * hd)
+
+
+def _transposed(x, dtype):
+    """x^T through float32, the one type every Mosaic transposes."""
+    return x.astype(_F32).T.astype(dtype)
+
+
+# forward: Q blocks outer, K blocks inner
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
+                acc_scr, *, t: _Tiles, scale, heads, hd):
+    qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[:] = jnp.full(m_scr.shape, _NEG_INF, m_scr.dtype)
-        l_scr[:] = jnp.zeros(l_scr.shape, l_scr.dtype)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
+        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, m_scr.dtype)
+        l_scr[...] = jnp.zeros(l_scr.shape, l_scr.dtype)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
+        q = q_ref[0].astype(_F32) * scale
+        for g in range(heads):
+            qs_scr[g] = _only_head(q, g, heads, hd).astype(qs_scr.dtype)
 
-    live = q_off + block_q - 1 >= k_off if causal else True
+    def tile(pieces):
+        v_t = _transposed(v_ref[0], v_ref.dtype)              # (W, block_k)
+        for qs, ks, masked in pieces:
+            k = k_ref[0, ks, :]
+            valid = t.valid(qi, ki, qs, ks) if masked else None
+            for g in range(heads):
+                rows = _head_rows(g, hd)
+                s_t = _nt(k, qs_scr[g, qs, :])
+                if masked:
+                    s_t = jnp.where(valid, s_t, _NEG_INF)
+                # K block 0 comes first and holds a visible column for
+                # every query, so m is finite from the first piece on
+                m_prev = m_scr[g, :, qs]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s_t, axis=0, keepdims=True))
+                p_t = jnp.exp(s_t - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_scr[g, :, qs] = l_scr[g, :, qs] * corr + jnp.sum(
+                    p_t, axis=0, keepdims=True)
+                acc_scr[rows, qs] = acc_scr[rows, qs] * corr + _nn(
+                    v_t[rows, ks], p_t.astype(v_t.dtype))
+                m_scr[g, :, qs] = m_new
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        valid = _block_mask(q_off, k_off, s.shape, sk, causal)
-        s = jnp.where(valid, s, _NEG_INF)
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # fully-masked rows keep m = -inf; exp(-inf - -inf) would be nan
-        safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(valid, jnp.exp(s - safe_m), 0.0)
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-        l_new = l_scr[:, 0:1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    t.run(qi, ki, tile)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == t.n_k - 1)
     def _finish():
-        m = m_scr[:, 0:1]
-        l = jnp.maximum(l_scr[:, 0:1], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(jnp.isfinite(m), m + jnp.log(l), _NEG_INF)
+        for g in range(heads):
+            rows = _head_rows(g, hd)
+            acc_scr[rows, :] = acc_scr[rows, :] / l_scr[g]
+            lse_ref[0, g] = m_scr[g] + jnp.log(l_scr[g])
+        o_ref[0] = acc_scr[...].T.astype(o_ref.dtype)
 
 
-def _fwd_call(q, k, v, scale, causal, sk, block_q, block_k, interpret):
-    """sk is the UNPADDED key length (mask bound); array shapes are padded."""
-    bh, sq, d = q.shape
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               sk=sk, block_q=block_q, block_k=block_k)
+def _fwd_call(q, k, v, *, t: _Tiles, heads, hd, scale, out_dtype, interpret):
+    b, sq, width = q.shape
+    w = heads * hd
+    groups = width // w
+    bq, bk = t.bq, t.bk
+    kv_spec = pl.BlockSpec(
+        (1, bk, w), lambda b_, j, qi, ki: (b_, jnp.minimum(
+            ki, t.last_live_k(qi)), j))
     return pl.pallas_call(
-        kernel,
-        grid=(bh, sq // block_q, k.shape[1] // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
+        functools.partial(_fwd_kernel, t=t, scale=scale, heads=heads, hd=hd),
+        grid=(b, groups, t.n_q, t.n_k),
+        in_specs=[pl.BlockSpec((1, bq, w), lambda b_, j, qi, ki: (b_, qi, j)),
+                  kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, w), lambda b_, j, qi, ki: (b_, qi, j)),
+            pl.BlockSpec((1, heads, 1, bq),
+                         lambda b_, j, qi, ki: (b_, j, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, out_dtype),
+            jax.ShapeDtypeStruct((b, groups * heads, 1, sq), _F32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((heads, bq, w), q.dtype),
+            pltpu.VMEM((heads, 1, bq), _F32),
+            pltpu.VMEM((heads, 1, bq), _F32),
+            pltpu.VMEM((w, bq), _F32),
         ],
         interpret=interpret,
         name="ff_flash_fwd",
+        **_params(interpret),
     )(q, k, v)
 
 
 # ---------------------------------------------------------------------------
-# backward: recompute p per tile from saved lse; delta = rowsum(do * o)
+# backward: p^T is rebuilt from the saved lse; delta = rowsum(do * o) (less
+# the lse cotangent)
 
 
-def _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_off, k_off,
-          scale, sk, causal):
-    """Recompute probabilities p and score-gradient ds for one tile."""
-    q = q_ref[0]
-    k = k_ref[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    valid = _block_mask(q_off, k_off, s.shape, sk, causal)
-    lse = lse_ref[0]                     # (block_q, 1)
-    safe_lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
-    p = jnp.where(valid, jnp.exp(s - safe_lse), 0.0)
-    do = do_ref[0]
-    dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0]) * scale
-    return p, ds, do, q
+def _p_ds_t(ks, vm, q, do, lse, delta, valid):
+    """p^T and ds^T of one head's tile.  ``ks`` is k scaled and ``vm`` is
+    v, both with the other heads' lanes zeroed; lse, delta: (1, block_q)."""
+    p_t = jnp.exp(_nt(ks, q) - lse)
+    if valid is not None:
+        p_t = jnp.where(valid, p_t, 0.0)
+    return p_t, p_t * (_nt(vm, do) - delta)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, sk, block_q, block_k):
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-    q_off = qi * block_q
-    k_off = pl.program_id(1) * block_k
+def _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd):
+    """This K block's operands: per head k scaled and v, each with the
+    other heads' lanes zeroed, and (k scaled)^T, whose rows split by head."""
+    k = k_ref[0].astype(_F32) * scale
+    v = v_ref[0].astype(_F32)
+    for g in range(heads):
+        ks_scr[g] = _only_head(k, g, heads, hd).astype(ks_scr.dtype)
+        vm_scr[g] = _only_head(v, g, heads, hd).astype(vm_scr.dtype)
+    if kst_scr is not None:
+        kst_scr[...] = k.T.astype(kst_scr.dtype)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                t: _Tiles, scale, heads, hd, with_dq):
+    """K blocks outer, Q blocks inner: dk, dv accumulate over the inner
+    loop; with ``with_dq`` (the fused form) dq^T of the whole head group
+    accumulates across both loops and leaves with the last K block."""
+    if with_dq:
+        (dq_ref, dk_ref, dv_ref,
+         ks_scr, vm_scr, dk_scr, dv_scr, kst_scr, dqt_scr) = rest
+    else:
+        dk_ref, dv_ref, ks_scr, vm_scr, dk_scr, dv_scr = rest
+        kst_scr = dqt_scr = None
+    ki, qi = pl.program_id(2), pl.program_id(3)
 
     @pl.when(qi == 0)
     def _init():
-        dk_scr[:] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
-        dv_scr[:] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
+        dk_scr[...] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
+        _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd)
 
-    live = q_off + block_q - 1 >= k_off if causal else True
+    if with_dq:
+        @pl.when(ki == 0)
+        def _init_dq():
+            dqt_scr[qi] = jnp.zeros(dqt_scr.shape[1:], dqt_scr.dtype)
 
-    @pl.when(live)
-    def _compute():
-        p, ds, do, q = _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                             q_off, k_off, scale, sk, causal)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile(pieces):
+        for qs, ks, masked in pieces:
+            q, do = q_ref[0, qs, :], do_ref[0, qs, :]
+            valid = t.valid(qi, ki, qs, ks) if masked else None
+            dvs, dks = [], []
+            for g in range(heads):
+                p_t, ds_t = _p_ds_t(ks_scr[g, ks, :], vm_scr[g, ks, :], q,
+                                    do, lse_ref[0, g, :, qs],
+                                    delta_ref[0, g, :, qs], valid)
+                ds_t = ds_t.astype(q.dtype)
+                dvs.append(_nn(p_t.astype(do.dtype), do))
+                dks.append(_nn(ds_t, q))
+                if with_dq:
+                    rows = _head_rows(g, hd)
+                    dqt_scr[qi, rows, qs] += _nn(kst_scr[rows, ks], ds_t)
+            dv_scr[ks, :] += _by_head(dvs, hd)
+            dk_scr[ks, :] += _by_head(dks, hd)
 
-    @pl.when(qi == nq - 1)
+    t.run(qi, ki, tile)
+
+    @pl.when(qi == t.n_q - 1)
     def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when(ki == t.n_k - 1)
+        def _finish_dq():
+            rows = pl.ds(pl.multiple_of(qi * t.bq, t.bq), t.bq)
+            dq_ref[0, rows, :] = dqt_scr[qi].T.astype(dq_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_scr, *, scale, causal, sk, block_q, block_k):
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    q_off = pl.program_id(1) * block_q
-    k_off = ki * block_k
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   ks_scr, vm_scr, kst_scr, dqt_scr,
+                   *, t: _Tiles, scale, heads, hd):
+    """Q blocks outer, K blocks inner (the split form's second kernel)."""
+    qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
-        dq_scr[:] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
+        dqt_scr[...] = jnp.zeros(dqt_scr.shape, dqt_scr.dtype)
 
-    live = q_off + block_q - 1 >= k_off if causal else True
+    def tile(pieces):
+        _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd)
+        for qs, ks, masked in pieces:
+            q, do = q_ref[0, qs, :], do_ref[0, qs, :]
+            valid = t.valid(qi, ki, qs, ks) if masked else None
+            for g in range(heads):
+                rows = _head_rows(g, hd)
+                _, ds_t = _p_ds_t(ks_scr[g, ks, :], vm_scr[g, ks, :], q, do,
+                                  lse_ref[0, g, :, qs],
+                                  delta_ref[0, g, :, qs], valid)
+                dqt_scr[rows, qs] += _nn(kst_scr[rows, ks],
+                                         ds_t.astype(q.dtype))
 
-    @pl.when(live)
-    def _compute():
-        _, ds, _, _ = _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                            q_off, k_off, scale, sk, causal)
-        k = k_ref[0]
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    t.run(qi, ki, tile)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == t.n_k - 1)
     def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = dqt_scr[...].T.astype(dq_ref.dtype)
 
 
-def _bwd_call(q, k, v, do, lse, delta, scale, causal, sk, block_q, block_k,
-              interpret):
-    """sk is the UNPADDED key length (mask bound); array shapes are padded."""
-    bh, sq, d = q.shape
-    sk_p = k.shape[1]
-    common = dict(scale=scale, causal=causal, sk=sk,
-                  block_q=block_q, block_k=block_k)
-    # dk/dv: K blocks outer, Q innermost (accumulated across Q in scratch)
-    dkv_spec = [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # v
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),   # do
-        pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),   # lse
-        pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),   # delta
-    ]
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **common),
-        grid=(bh, sk_p // block_k, sq // block_q),
-        in_specs=dkv_spec,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, jnp.float32),
-            jax.ShapeDtypeStruct(v.shape, jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, scale,
+              fused, interpret):
+    b, sq, width = q.shape
+    w = heads * hd
+    groups = width // w
+    bq, bk = t.bq, t.bk
+    common = dict(t=t, scale=scale, heads=heads, hd=hd)
+
+    # K blocks outer, Q blocks inner
+    q_spec = pl.BlockSpec(
+        (1, bq, w), lambda b_, j, ki, qi: (b_, jnp.maximum(
+            qi, t.first_live_q(ki)), j))
+    row_spec = pl.BlockSpec(
+        (1, heads, 1, bq), lambda b_, j, ki, qi: (b_, j, 0, jnp.maximum(
+            qi, t.first_live_q(ki))))
+    kv_spec = pl.BlockSpec((1, bk, w), lambda b_, j, ki, qi: (b_, ki, j))
+    kv_scratch = [pltpu.VMEM((heads, bk, w), q.dtype),
+                  pltpu.VMEM((heads, bk, w), q.dtype)]
+    acc_scratch = [pltpu.VMEM((bk, w), _F32), pltpu.VMEM((bk, w), _F32)]
+    dq_scratch = [pltpu.VMEM((w, bk), q.dtype)]
+    out_specs = [kv_spec, kv_spec]
+    out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    scratch = kv_scratch + acc_scratch
+    if fused:
+        out_specs.insert(0, pl.BlockSpec(
+            (1, sq, w), lambda b_, j, ki, qi: (b_, 0, j)))
+        out_shape.insert(0, jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch += dq_scratch + [pltpu.VMEM((t.n_q, w, bq), _F32)]
+    outs = pl.pallas_call(
+        functools.partial(_bwd_kernel, with_dq=fused, **common),
+        grid=(b, groups, t.n_k, t.n_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
-        name="ff_flash_bwd_dkv",
+        name="ff_flash_bwd" if fused else "ff_flash_bwd_dkv",
+        **_params(interpret),
     )(q, k, v, do, lse, delta)
-    dq_spec = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-    ]
+    if fused:
+        return outs
+
+    # Q blocks outer, K blocks inner
+    q_spec = pl.BlockSpec((1, bq, w), lambda b_, j, qi, ki: (b_, qi, j))
+    row_spec = pl.BlockSpec((1, heads, 1, bq),
+                            lambda b_, j, qi, ki: (b_, j, 0, qi))
+    kv_spec = pl.BlockSpec(
+        (1, bk, w), lambda b_, j, qi, ki: (b_, jnp.minimum(
+            ki, t.last_live_k(qi)), j))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
-        grid=(bh, sq // block_q, sk_p // block_k),
-        in_specs=dq_spec,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        grid=(b, groups, t.n_q, t.n_k),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=kv_scratch + dq_scratch + [pltpu.VMEM((w, bq), _F32)],
         interpret=interpret,
         name="ff_flash_bwd_dq",
+        **_params(interpret),
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return (dq, *outs)
 
 
 # ---------------------------------------------------------------------------
-# public op: (B, H, S, d) -> (B, H, Sq, d) float32, differentiable
+# public op, differentiable.  ``packed``: (B, S, H*hd) in and out;
+# otherwise (B, H, S, hd) in and out
 
 
 def _should_interpret() -> bool:
@@ -281,10 +525,11 @@ def _should_interpret() -> bool:
 
 @functools.lru_cache(maxsize=None)
 def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
-                interpret, with_lse=False):
+                interpret, with_lse=False, packed=False):
     """Build a custom-VJP flash op specialized for one static configuration
     (shapes/dtypes/blocks are Python constants closed over by the kernels;
-    the VJP residuals are pure arrays).
+    the VJP residuals are pure arrays), and name its variant.  ``q_shape``
+    and ``k_shape`` are (B, H, S, hd) whatever the arrays' layout.
 
     With ``with_lse`` the op returns ``(out, lse)`` — the *partial*
     attention form used by ring/context parallelism, where per-chunk
@@ -295,67 +540,92 @@ def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
     b, h, sq, d = q_shape
     sk = k_shape[2]
     scale = 1.0 / math.sqrt(d)
-    if interpret:
-        bq = min(block_q, _round_up(sq, 8))
-        bk = min(block_k, _round_up(sk, 8))
-        d_p = d
-    else:
-        # on hardware, lane dims (d) want full 128 tiles; clamp blocks so a
-        # short sequence is not padded all the way to the default block
-        bq = min(block_q, _round_up(sq, 128))
-        bk = min(block_k, _round_up(sk, 128))
-        d_p = _round_up(d, 128)
+    heads, hd, layout = _layout(h, d)
+    bq = (_pick_block(sq, interpret) if block_q is None
+          else min(block_q, _round_up(sq, 8)))
+    bk = (_pick_block(sk, interpret) if block_k is None
+          else min(block_k, _round_up(sk, 8)))
     sq_p, sk_p = _round_up(sq, bq), _round_up(sk, bk)
+    fwd_tiles, bwd_tiles = (
+        _Tiles(causal, sk, bq, bk, sq_p // bq, sk_p // bk, piece)
+        for piece in (_FWD_PIECE, _BWD_PIECE))
+    fused = sq_p * heads * hd * 4 <= _FUSED_DQ_BYTES
+    variant = f"{layout}.{'fused' if fused else 'split'}"
+    out_dtype = jnp.float32 if with_lse else qdt
+    # inline jits: a model's layers share one trace of each kernel body
+    # (tracing them is most of what a call costs before it compiles),
+    # while every call site keeps its own operator name in the program
+    fwd_call = jax.jit(functools.partial(
+        _fwd_call, t=fwd_tiles, heads=heads, hd=hd, scale=scale,
+        out_dtype=out_dtype, interpret=interpret), inline=True)
+    bwd_call = jax.jit(functools.partial(
+        _bwd_call, t=bwd_tiles, heads=heads, hd=hd, scale=scale, fused=fused,
+        interpret=interpret), inline=True)
 
     def prep(x, s_p):
-        # (B,H,S,d) -> (B*H, S_pad, d_pad); zero d-columns do not change
-        # scores, padded K rows are masked via sk, padded Q rows sliced off
-        x = x.reshape(b * h, x.shape[2], d)
-        return jnp.pad(x, ((0, 0), (0, s_p - x.shape[1]), (0, d_p - d)))
+        """-> (B, S_pad, H*hd_kernel); zero head columns do not change
+        scores, padded K rows are masked via sk, padded Q rows sliced off"""
+        s = x.shape[1] if packed else x.shape[2]
+        if not packed:
+            x = x.transpose(0, 2, 1, 3)
+        if hd != d:
+            x = jnp.pad(x.reshape(b, s, h, d),
+                        ((0, 0), (0, 0), (0, 0), (0, hd - d)))
+        x = x.reshape(b, s, h * hd)
+        if s_p != s:
+            x = jnp.pad(x, ((0, 0), (0, s_p - s), (0, 0)))
+        return x
+
+    def unprep(x, s):
+        """The inverse of prep for a kernel result of true length s."""
+        if x.shape[1] != s:
+            x = x[:, :s]
+        if hd != d:
+            x = x.reshape(b, s, h, hd)[..., :d]
+        if packed:
+            return x.reshape(b, s, h * d)
+        return x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+
+    def rows(x):
+        """(B, H, Sq) float32 -> the kernels' (B, H, 1, Sq_pad)."""
+        x = x.astype(jnp.float32).reshape(b, h, 1, sq)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, sq_p - sq)))
 
     def run_fwd(q, k, v):
         qp, kp, vp = prep(q, sq_p), prep(k, sk_p), prep(v, sk_p)
-        out, lse = _fwd_call(qp, kp, vp, scale, causal, sk, bq, bk, interpret)
-        return out, lse, (qp, kp, vp, lse, out)
+        out, lse = fwd_call(qp, kp, vp)
+        return unprep(out, sq), lse, (qp, kp, vp, lse, out)
 
     def run_bwd(res, g, g_lse=None):
         qp, kp, vp, lse, out = res
-        do = jnp.pad(g.astype(jnp.float32).reshape(b * h, sq, d),
-                     ((0, 0), (0, sq_p - sq), (0, d_p - d)))
-        do_k = do.astype(qdt)  # kernel operand in the primal compute dtype
+        do = prep(g, sq_p)
         # delta is zero on padded Q rows (do = 0 there), so they contribute
         # nothing to dk/dv even though their lse is arbitrary
-        delta = jnp.sum(do * out, axis=-1, keepdims=True)
+        delta = jnp.einsum("bshd,bshd->bhs",
+                           do.reshape(b, sq_p, h, hd).astype(jnp.float32),
+                           out.reshape(b, sq_p, h, hd).astype(jnp.float32)
+                           )[:, :, None, :]
         if g_lse is not None:
-            glse_p = jnp.pad(g_lse.astype(jnp.float32).reshape(b * h, sq, 1),
-                             ((0, 0), (0, sq_p - sq), (0, 0)))
-            delta = delta - glse_p  # ds = p (dp - delta + g_lse)
-        dq, dk, dv = _bwd_call(qp, kp, vp, do_k, lse, delta, scale, causal,
-                               sk, bq, bk, interpret)
-        return (dq[:, :sq, :d].reshape(b, h, sq, d).astype(qdt),
-                dk[:, :sk, :d].reshape(b, h, sk, d).astype(kdt),
-                dv[:, :sk, :d].reshape(b, h, sk, d).astype(vdt))
+            delta = delta - rows(g_lse)  # ds = p (dp - delta + g_lse)
+        dq, dk, dv = bwd_call(qp, kp, vp, do.astype(qdt), lse, delta)
+        return (unprep(dq, sq).astype(qdt), unprep(dk, sk).astype(kdt),
+                unprep(dv, sk).astype(vdt))
 
     if not with_lse:
 
         @jax.custom_vjp
         def flash(q, k, v):
-            out, _, _ = run_fwd(q, k, v)
-            return out[:, :sq, :d].reshape(b, h, sq, d)
+            return run_fwd(q, k, v)[0]
 
         def flash_fwd(q, k, v):
             out, _, res = run_fwd(q, k, v)
-            return out[:, :sq, :d].reshape(b, h, sq, d), res
+            return out, res
 
-        def flash_bwd(res, g):
-            return run_bwd(res, g)
-
-        flash.defvjp(flash_fwd, flash_bwd)
-        return flash
+        flash.defvjp(flash_fwd, run_bwd)
+        return flash, variant
 
     def unpack(out, lse):
-        return (out[:, :sq, :d].reshape(b, h, sq, d),
-                lse[:, :sq, 0].reshape(b, h, sq))
+        return out, lse[:, :, 0, :sq]
 
     @jax.custom_vjp
     def flash_p(q, k, v):
@@ -367,38 +637,55 @@ def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
         return unpack(out, lse), res
 
     def flash_p_bwd(res, gs):
-        g, g_lse = gs
-        return run_bwd(res, g, g_lse)
+        return run_bwd(res, *gs)
 
     flash_p.defvjp(flash_p_fwd, flash_p_bwd)
-    return flash_p
+    return flash_p, variant
 
 
-def flash_attention(q, k, v, causal=False, block_q=DEFAULT_BLOCK,
-                    block_k=DEFAULT_BLOCK, interpret=None):
-    """softmax(q kᵀ / sqrt(d) [+ causal mask]) v without materializing the
-    score matrix.  q, k, v: (B, H, S, d); returns float32 (B, H, Sq, d)."""
+def _call(q, k, v, q_shape, k_shape, causal, block_q, block_k, interpret,
+          **form):
     interpret = _should_interpret() if interpret is None else interpret
-    f = _make_flash(tuple(q.shape), tuple(k.shape), q.dtype.name,
-                    k.dtype.name, v.dtype.name, bool(causal), block_q,
-                    block_k, interpret)
+    f, variant = _make_flash(q_shape, k_shape, q.dtype.name, k.dtype.name,
+                             v.dtype.name, bool(causal), block_q, block_k,
+                             interpret, **form)
+    obs.count(f"kernels.flash.{variant}")
     return f(q, k, v)
 
 
-def flash_attention_partial(q, k, v, causal=False, block_q=DEFAULT_BLOCK,
-                            block_k=DEFAULT_BLOCK, interpret=None):
+def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
+                    interpret=None):
+    """softmax(q kᵀ / sqrt(d) [+ causal mask]) v without materializing the
+    score matrix.  q, k, v: (B, H, S, d); returns (B, H, Sq, d) in q's
+    type.  Blocks default to what the shapes select."""
+    return _call(q, k, v, tuple(q.shape), tuple(k.shape), causal, block_q,
+                 block_k, interpret)
+
+
+def flash_attention_packed(q, k, v, num_heads, causal=False, block_q=None,
+                           block_k=None, interpret=None):
+    """:func:`flash_attention` on the projections' own layout: q, k, v are
+    (B, S, H*d) with head h in columns ``h*d:(h+1)*d``, and so is the
+    result — no (B,S,H,d) <-> (B,H,S,d) transpose on either side."""
+    def bhsd(x):
+        b, s, width = x.shape
+        return (b, num_heads, s, width // num_heads)
+
+    return _call(q, k, v, bhsd(q), bhsd(k), causal, block_q, block_k,
+                 interpret, packed=True)
+
+
+def flash_attention_partial(q, k, v, causal=False, block_q=None,
+                            block_k=None, interpret=None):
     """Partial attention over one K/V chunk: returns ``(out, lse)`` where
-    ``out`` is the chunk-normalized attention and ``lse`` (B, H, Sq) the
-    log-sum-exp of its scores.  Chunks merge exactly via
+    ``out`` (float32) is the chunk-normalized attention and ``lse``
+    (B, H, Sq) the log-sum-exp of its scores.  Chunks merge exactly via
     :func:`combine_partials` — the building block of the Pallas ring-
     attention path (each ring step attends Q against the resident K/V
     block, then results merge by lse weight).  Differentiable in both
     outputs."""
-    interpret = _should_interpret() if interpret is None else interpret
-    f = _make_flash(tuple(q.shape), tuple(k.shape), q.dtype.name,
-                    k.dtype.name, v.dtype.name, bool(causal), block_q,
-                    block_k, interpret, with_lse=True)
-    return f(q, k, v)
+    return _call(q, k, v, tuple(q.shape), tuple(k.shape), causal, block_q,
+                 block_k, interpret, with_lse=True)
 
 
 def combine_partials(o1, lse1, o2, lse2):

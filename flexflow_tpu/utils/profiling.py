@@ -197,7 +197,7 @@ def normalize_cost_analysis(compiled) -> Dict[str, float]:
 # a Mosaic kernel in optimized HLO text: a custom call to
 # ``tpu_custom_call`` whose op_name metadata ends in the kernel's
 # pallas_call ``name=`` scope (possibly wrapped, e.g.
-# ``transpose(jvp(ff_flash_bwd_dq))/pallas_call``)
+# ``transpose(jvp(ff_flash_bwd))/pallas_call``)
 _TPU_CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
 _PALLAS_KERNEL = re.compile(r'op_name="[^"]*?([A-Za-z0-9_.]+)\)*/pallas_call')
 

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flexflow_tpu.ops.pallas import flash_attention
 from flexflow_tpu.parallel.ring_attention import blockwise_attention
 
 
@@ -34,58 +33,207 @@ def flash_env(value="1"):
             os.environ["FLEXFLOW_TPU_FLASH"] = prev
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("b,h,s,d", [(2, 3, 16, 8), (1, 2, 40, 16)])
-def test_flash_forward_parity(causal, b, h, s, d):
-    rng = np.random.RandomState(0)
-    q, k, v = (_rand(rng, b, h, s, d) for _ in range(3))
-    ref = blockwise_attention(q, k, v, causal)
-    got = flash_attention(q, k, v, causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+def _dense_attention(q, k, v, causal):
+    """(out, lse) of plain softmax attention in float32, (B, H, S, d)."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest"
+                   ) / np.sqrt(q.shape[-1])
+    if causal:
+        qi = jnp.arange(q.shape[2])[:, None]
+        s = jnp.where(qi >= jnp.arange(k.shape[2])[None, :], s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v,
+                     precision="highest")
+    return out, lse
 
 
-def test_flash_padding_path():
-    # S=20 with block 16 exercises the zero-pad + key-mask path
-    rng = np.random.RandomState(1)
-    q, k, v = (_rand(rng, 1, 2, 20, 8) for _ in range(3))
-    ref = blockwise_attention(q, k, v, True)
-    got = flash_attention(q, k, v, True, block_q=16, block_k=16)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+# id: (b, h, sq, sk, d), dtype, causal, (block_q, block_k), form, variant.
+# One case for each way the kernels' variants split on the shapes
+# (_layout, _Tiles, fused or split backward), at interpret-mode sizes.
+_FLASH_CASES = {
+    "hd64_bf16_causal_cell_layout":
+        ((2, 4, 64, 64, 64), "bfloat16", True, (32, 32), "packed",
+         "pack2.fused"),
+    "hd64_f32_causal":
+        ((2, 2, 64, 64, 64), "float32", True, (16, 16), "bhsd",
+         "pack2.fused"),
+    "hd128_one_head_a_block":
+        ((1, 2, 40, 40, 128), "float32", True, (16, 16), "bhsd",
+         "pack1.fused"),
+    "hd32_four_heads_a_block":
+        ((1, 4, 48, 48, 32), "float32", True, (16, 16), "packed",
+         "pack4.fused"),
+    "hd80_padded_to_128":
+        ((1, 2, 40, 40, 80), "float32", True, (16, 16), "bhsd",
+         "pad128.fused"),
+    "odd_head_count_padded":
+        ((1, 3, 24, 24, 64), "float32", False, (16, 16), "packed",
+         "pad128.fused"),
+    "hd8_noncausal":
+        ((2, 3, 16, 16, 8), "float32", False, (None, None), "bhsd",
+         "pad128.fused"),
+    "hd16_default_blocks":
+        ((1, 2, 40, 40, 16), "float32", True, (None, None), "bhsd",
+         "pad128.fused"),
+    "length_not_a_block_multiple":
+        ((1, 2, 20, 20, 64), "float32", True, (16, 16), "bhsd",
+         "pack2.fused"),
+    "padded_keys_noncausal":
+        ((1, 2, 24, 20, 64), "float32", False, (16, 16), "bhsd",
+         "pack2.fused"),
+    "padded_keys_below_the_diagonal":
+        ((1, 2, 48, 24, 64), "float32", True, (16, 16), "bhsd",
+         "pack2.fused"),
+    "sq_shorter_than_sk":
+        ((1, 2, 16, 48, 64), "float32", True, (16, 16), "bhsd",
+         "pack2.fused"),
+    "sq_longer_than_sk":
+        ((1, 2, 48, 16, 64), "float32", True, (16, 16), "bhsd",
+         "pack2.fused"),
+    "rectangular_blocks":
+        ((1, 2, 64, 64, 64), "float32", True, (32, 16), "bhsd",
+         "pack2.fused"),
+    "four_by_four_pieces_padded_length":
+        ((1, 2, 72, 72, 64), "float32", True, (32, 32), "bhsd",
+         "pack2.fused"),
+    "wide_key_blocks":
+        ((1, 2, 64, 64, 64), "float32", True, (16, 32), "bhsd",
+         "pack2.fused"),
+    "split_backward":
+        ((1, 2, 48, 48, 64), "float32", True, (16, 16), "bhsd",
+         "pack2.split"),
+    "split_backward_padded_bf16":
+        ((1, 2, 40, 24, 80), "bfloat16", False, (16, 16), "packed",
+         "pad128.split"),
+    "partial_with_lse_cotangent":
+        ((1, 2, 32, 48, 64), "float32", False, (16, 16), "partial",
+         "pack2.fused"),
+    "partial_causal_bf16":
+        ((1, 2, 32, 32, 64), "bfloat16", True, (16, 16), "partial",
+         "pack2.fused"),
+}
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_grad_parity(causal):
+@pytest.fixture
+def small_flash(monkeypatch):
+    """The flash module with its diagonal pieces cut to test sizes (16 x 16
+    blocks: one forward piece, 2 x 2 backward pieces; 32 x 32: 2 x 2 and
+    4 x 4), so interpret mode walks what the chip walks at 1024."""
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_FWD_PIECE", 16)
+    monkeypatch.setattr(fa, "_BWD_PIECE", 8)
+    fa._make_flash.cache_clear()
+    yield fa
+    fa._make_flash.cache_clear()
+
+
+@pytest.mark.parametrize("case", sorted(_FLASH_CASES))
+def test_flash_parity(case, small_flash, monkeypatch):
+    """Forward and all three gradients against plain attention, the
+    result's type, and the variant the shapes selected."""
+    from flexflow_tpu import obs
+
+    fa = small_flash
+    (b, h, sq, sk, d), dtype, causal, (bq, bk), form, variant = \
+        _FLASH_CASES[case]
+    if variant.endswith("split"):
+        monkeypatch.setattr(fa, "_FUSED_DQ_BYTES", 0)
     rng = np.random.RandomState(2)
-    q, k, v = (_rand(rng, 2, 2, 24, 8) for _ in range(3))
+    q = _rand(rng, b, h, sq, d).astype(dtype)
+    k, v = (_rand(rng, b, h, sk, d).astype(dtype) for _ in range(2))
+    w, u = _rand(rng, b, h, sq, d), _rand(rng, b, h, sq)
 
-    def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, causal, block_q=16,
-                                block_k=16) ** 2).sum()
+    def pack(x):
+        return x.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[2], -1)
 
-    def loss_ref(q, k, v):
-        return (blockwise_attention(q, k, v, causal) ** 2).sum()
+    def flash(q, k, v):
+        kw = dict(causal=causal, block_q=bq, block_k=bk)
+        if form == "partial":
+            return fa.flash_attention_partial(q, k, v, **kw)
+        if form == "packed":
+            out = fa.flash_attention_packed(pack(q), pack(k), pack(v), h,
+                                            **kw)
+            return out.reshape(b, sq, h, d).transpose(0, 2, 1, 3), None
+        return fa.flash_attention(q, k, v, **kw), None
 
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-4)
+    def loss(attn):
+        def f(q, k, v):
+            out, lse = attn(q, k, v)
+            total = (out.astype(jnp.float32) * w).sum()
+            if form == "partial":  # a non-zero lse cotangent
+                total = total + (lse * u).sum()
+            return total, (out, lse)
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    name = f"kernels.flash.{variant}"
+    before = obs.snapshot()["counters"].get(name, 0)
+    (_, (out, lse)), grads = loss(flash)(q, k, v)
+    assert obs.snapshot()["counters"].get(name, 0) == before + 1
+
+    (_, (ref_out, ref_lse)), ref_grads = loss(
+        lambda q, k, v: _dense_attention(q, k, v, causal))(q, k, v)
+    assert out.dtype == (jnp.float32 if form == "partial" else q.dtype)
+    tol = 3e-2 if dtype == "bfloat16" else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref_out), rtol=tol, atol=tol)
+    if form == "partial":
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                                   rtol=tol, atol=tol)
+    gtol = 6e-2 if dtype == "bfloat16" else 1e-4
+    for got, want, x in zip(grads, ref_grads, (q, k, v)):
+        assert got.dtype == x.dtype  # cotangents in the primal's type
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=gtol, atol=gtol)
 
 
-def test_flash_bf16_inputs():
-    rng = np.random.RandomState(3)
-    q, k, v = (_rand(rng, 1, 2, 16, 8).astype(jnp.bfloat16)
-               for _ in range(3))
-    ref = blockwise_attention(q, k, v, False)
-    got = flash_attention(q, k, v, False)
-    assert got.dtype == jnp.float32
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-2, atol=2e-2)
-    # cotangents must come back in the primal dtype
-    g = jax.grad(lambda q: flash_attention(q, k, v, False).sum())(q)
-    assert g.dtype == jnp.bfloat16
+def _top_level_eqns(jaxpr):
+    """Equations XLA sees around the kernels: everything but the bodies
+    of the pallas_calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _top_level_eqns(sub)
+
+
+def test_flash_cell_layout_has_no_pad_and_no_float32_copy():
+    """At the GPT-2 cell's layout (bf16, hd 64, S 1024, causal, the
+    projections' (B, S, H*hd)) nothing is padded or widened in HBM: no
+    pad, no transpose, no float32 array as large as a padded
+    (B*H, S, 128); one forward and one backward kernel; the result and
+    the gradients in the operands' type; one count a traced call."""
+    from flexflow_tpu import obs
+    from flexflow_tpu.ops.pallas.flash_attention import \
+        flash_attention_packed
+
+    b, s, h, hd = 2, 1024, 12, 64
+    x = jax.ShapeDtypeStruct((b, s, h * hd), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention_packed(q, k, v, h, causal=True)
+        assert out.dtype == jnp.bfloat16 and out.shape == (b, s, h * hd)
+        return out.astype(jnp.float32).sum()
+
+    name = "kernels.flash.pack2.fused"
+    before = obs.snapshot()["counters"].get(name, 0)
+    closed = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    assert obs.snapshot()["counters"][name] == before + 1
+    assert all(v.aval.dtype == jnp.bfloat16 for v in closed.jaxpr.outvars)
+    eqns = list(_top_level_eqns(closed.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert "pad" not in names and "transpose" not in names, names
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels == ["ff_flash_fwd", "ff_flash_bwd"], kernels
+    for e in eqns:
+        for var in e.outvars:
+            aval = var.aval
+            assert not (aval.dtype == jnp.float32
+                        and aval.size >= b * h * s * 128), (e, aval)
 
 
 def test_partial_combine_matches_full():

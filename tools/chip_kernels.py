@@ -12,7 +12,8 @@ benchmark).  A case that fails to compile is reported with the compiler's
 message and the run exits 1 after the other cases have been tried.
 
 Cases (issue 21 section 4): flash attention fwd+bwd at the BERT-base
-training shape and at S=8192 d=64 causal; fused projection+CE at 8k tokens
+training shape, at the GPT-2 benchmark cell's own shape (b16 h12 s1024
+d64 causal, bfloat16) and at S=8192 d=64 causal; fused projection+CE at 8k tokens
 x 32k vocab and its vocab-TP partial form; maxpool backward on Inception's
 two large pools; avgpool on the 8x8x2048 global pool; bn_act on two
 Inception activations.
@@ -49,16 +50,28 @@ def _grads(f, n):
 
 
 def case_flash(b, h, s, d, causal):
-    from flexflow_tpu.ops.pallas.flash_attention import flash_attention
+    """Both sides on the projections' (B, S, H*hd) layout, as
+    ops/attention.py calls them: the kernel reads it as it is, the XLA
+    path transposes to heads and back."""
+    from flexflow_tpu.ops.pallas.flash_attention import \
+        flash_attention_packed
     from flexflow_tpu.parallel.ring_attention import blockwise_attention
 
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    args = [_rand(k, (b, h, s, d), jnp.bfloat16) for k in ks]
-    kern = _grads(lambda q, k, v: flash_attention(q, k, v, causal,
-                                                  interpret=False), 3)
-    ref = _grads(lambda q, k, v: blockwise_attention(q, k, v, causal,
-                                                     block_size=512), 3)
-    return kern, ref, args
+    args = [_rand(k, (b, s, h * d), jnp.bfloat16) for k in ks]
+
+    def heads(x):
+        return x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+
+    def xla(q, k, v):
+        out = blockwise_attention(heads(q), heads(k), heads(v), causal,
+                                  block_size=512)
+        return out.transpose(0, 2, 1, 3).reshape(b, s, h * d
+                                                 ).astype(q.dtype)
+
+    kern = _grads(lambda q, k, v: flash_attention_packed(
+        q, k, v, h, causal, interpret=False), 3)
+    return kern, _grads(xla, 3), args
 
 
 def case_fused_ce(n, d, v, partial):
@@ -132,6 +145,7 @@ def case_bn_act(n, h, w, c):
 CASES = [
     ("flash b16 h12 s512 d64 causal", case_flash, (16, 12, 512, 64, True)),
     ("flash b16 h12 s512 d64 full", case_flash, (16, 12, 512, 64, False)),
+    ("flash b16 h12 s1024 d64 causal", case_flash, (16, 12, 1024, 64, True)),
     ("flash b1 h4 s8192 d64 causal", case_flash, (1, 4, 8192, 64, True)),
     ("fused_ce n8192 d768 v32768", case_fused_ce, (8192, 768, 32768, False)),
     ("fused_ce partial n8192 d768 v8192 (vocab TP /4)", case_fused_ce,
