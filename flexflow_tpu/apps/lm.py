@@ -8,8 +8,14 @@ transformers); flags follow the house style of the reference parsers
     python -m flexflow_tpu.apps.lm --causal -b 16 -s 512 -l 12 \
         --d-model 768 --heads 12 --d-ff 3072 --vocab 32768
     python -m flexflow_tpu.apps.lm --experts 8 --strategy moe.json
+    python -m flexflow_tpu.apps.lm --model-config \
+        benchmarks/configs/moonlight_16b_a3b.json --preset rehearsal -b 2 -s 32
 
-Data is synthetic random tokens; labels are the tokens themselves (causal
+``--model-config`` names a file of a public ``config.json``'s keys
+(``model_type`` ``deepseek_v3``: latent attention and expert layers,
+``models/latent_moe.py``; ``--preset`` lays one of the file's own named
+groups of keys over it); without it the flags describe a
+``TransformerLM``.  Data is synthetic random tokens; labels are the tokens themselves (causal
 models learn next-token prediction via the internal shift; see
 TransformerLM).
 """
@@ -65,6 +71,10 @@ def parse_args(argv) -> TransformerConfig:
             cfg.seed = int(val())
         elif a == "--strategy":
             strategy_file = val()
+        elif a == "--model-config":
+            cfg._model_config = val()
+        elif a == "--preset":
+            cfg._preset = val()
         elif a == "--params-ones":
             cfg.params_init = "ones"
         elif a == "--print-intermediates":
@@ -216,10 +226,77 @@ def _main_pipelined(cfg, machine, log) -> dict:
             "tokens_per_sec": tput * cfg.seq_length, "elapsed_s": elapsed}
 
 
+def _main_latent_moe(cfg, argv, machine, log) -> dict:
+    """--model-config path: a ``deepseek_v3`` configuration file through
+    ``LatentMoELM`` and ``FFModel.fit``.  The file gives the model; only
+    the flags the command line really carries (-b, -s, -i, --lr, --dtype,
+    --seed, -obs-dir) lie over it."""
+    import json
+
+    from flexflow_tpu.models.latent_moe import LatentMoEConfig, LatentMoELM
+
+    with open(cfg._model_config) as f:
+        config = json.load(f)
+    preset = getattr(cfg, "_preset", "")
+    if preset:
+        config.update(config[preset])
+    if config.get("model_type") != "deepseek_v3":
+        raise SystemExit(f"--model-config: model_type "
+                         f"{config.get('model_type')!r}; this driver builds "
+                         f"deepseek_v3 files only")
+    if getattr(cfg, "_strategy_file", "") \
+            or getattr(cfg, "_pipeline_stages", 0):
+        raise SystemExit("--model-config takes no --strategy or "
+                         "--pipeline-stages yet (its operators run on the "
+                         "grid (1, ..) only)")
+    given = set(argv)
+    over = {"num_iterations": cfg.num_iterations}
+    for flags, key, value in (
+            (("-b",), "batch_size", cfg.batch_size),
+            (("-s", "--seq"), "seq_length", cfg.seq_length),
+            (("--lr",), "learning_rate", cfg.learning_rate),
+            (("--dtype",), "compute_dtype", cfg.compute_dtype),
+            (("--seed",), "seed", cfg.seed)):
+        if given & set(flags):
+            over[key] = value
+    if cfg.obs_dir:
+        over["ff"] = {"obs_dir": cfg.obs_dir, "run_id": cfg.run_id}
+    if machine.num_devices > 1:
+        import jax
+
+        log(f"--model-config: the model's operators run on the grid "
+            f"(1, ..) only; training on one of {machine.num_devices} "
+            f"devices")
+        machine = MachineModel(jax.devices()[:1])
+    t = LatentMoEConfig.from_config(config, **over)
+    if t.seq_length > int(config.get("max_position_embeddings",
+                                     t.seq_length)):
+        raise SystemExit(f"{t.seq_length} positions, the configuration has "
+                         f"{config['max_position_embeddings']}")
+    model = LatentMoELM(t, machine)
+    log(f"LM: {config.get('name', cfg._model_config)}, {t.num_layers} "
+        f"blocks, hidden {t.hidden_size}, experts "
+        f"[{t.experts_held[0]}, {t.experts_held[1]}) of "
+        f"{t.router_outputs} held, seq {t.seq_length}, vocab "
+        f"{t.vocab_size}, batch {t.batch_size}, {machine.num_devices} "
+        f"devices")
+    data = synthetic_lm_batches(machine, t.batch_size, t.seq_length,
+                                t.vocab_size, seed=t.seed)
+    out = model.fit(data, log=log,
+                    rebuild=lambda ff_cfg, m: LatentMoELM(t, m))
+    out["tokens_per_sec"] = (out.get("images_per_sec") or 0.0) \
+        * t.seq_length
+    out.pop("params", None)
+    out.pop("state", None)
+    return out
+
+
 def main(argv=None, log=print) -> dict:
     argv = list(sys.argv[1:] if argv is None else argv)
     cfg = parse_args(argv)
     machine = MachineModel()
+    if getattr(cfg, "_model_config", ""):
+        return _main_latent_moe(cfg, argv, machine, log)
     sf = getattr(cfg, "_strategy_file", "")
     loaded_strategies = Strategy.load(sf) if sf else None
     if loaded_strategies is not None:

@@ -321,12 +321,13 @@ class FFModel:
     # ---- sequence-model builders (transformer/NMT op family) ----------
 
     def embed(self, name, input, vocab_size, embed_size,
-              param_key: str = None) -> Tensor:
+              param_key: str = None, init_std: float = 0.05) -> Tensor:
         from flexflow_tpu.ops.embed import Embed
 
         return self._add(Embed(name, self._pc(name, 1), input, vocab_size,
                                embed_size, param_key,
-                               compute_dtype=self.config.compute_dtype))
+                               compute_dtype=self.config.compute_dtype,
+                               init_std=init_std))
 
     def pos_embed(self, name, input) -> Tensor:
         from flexflow_tpu.ops.seq_common import PosEmbed
@@ -360,11 +361,46 @@ class FFModel:
             capacity_factor, machine=self.machine))
 
     def seq_linear(self, name, input, out_channels,
-                   param_key: str = None) -> Tensor:
+                   param_key: str = None, use_bias: bool = True) -> Tensor:
         from flexflow_tpu.ops.rnn_linear import RnnLinear
 
         return self._add(RnnLinear(name, self._pc(name, 2), input,
-                                   out_channels, param_key))
+                                   out_channels, param_key, use_bias))
+
+    def rms_norm(self, name, input, eps: float = 1e-5) -> Tensor:
+        from flexflow_tpu.ops.seq_gated import RMSNormSeq
+
+        return self._add(RMSNormSeq(name, self._pc(name, 2), input, eps))
+
+    def gated_ffn(self, name, input, d_ff) -> Tensor:
+        from flexflow_tpu.ops.seq_gated import GatedFFNSeq
+
+        return self._add(GatedFFNSeq(name, self._pc(name, 2), input, d_ff))
+
+    def latent_attention(self, name, input, num_heads, kv_rank, nope_dim,
+                         rope_dim, v_dim, rope_theta,
+                         eps: float = 1e-5) -> Tensor:
+        from flexflow_tpu.ops.latent_attention import LatentAttention
+
+        return self._add(LatentAttention(
+            name, self._pc(name, 3), input, num_heads, kv_rank, nope_dim,
+            rope_dim, v_dim, rope_theta, eps))
+
+    def sigmoid_router(self, name, input, n_router, top_k, scale,
+                       bias_update_rate: float = 1e-3) -> Tensor:
+        from flexflow_tpu.ops.expert_share import SigmoidRouter
+
+        return self._add(SigmoidRouter(name, self._pc(name, 2), input,
+                                       n_router, top_k, scale,
+                                       bias_update_rate))
+
+    def held_experts(self, name, input, gates, d_ff, experts_held, top_k,
+                     capacity_factor: float = 2.0) -> Tensor:
+        from flexflow_tpu.ops.expert_share import HeldExperts
+
+        return self._add(HeldExperts(name, self._pc(name, 2), input, gates,
+                                     d_ff, experts_held, top_k,
+                                     capacity_factor))
 
     def softmax_seq(self, name, logits: Tensor, labels: Tensor) -> Tensor:
         from flexflow_tpu.ops.softmax_dp import SoftmaxDP
@@ -503,16 +539,18 @@ class FFModel:
                                           v.dtype).at[slot].set(v),
                                 sh[k])
                             for k, v in s.items()}
-                elif abstract:
-                    state[op.name] = jax.tree.map(
-                        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), s)
                 else:
                     # commit to a concrete (replicated) sharding so the first
                     # train step's input avals match later steps' outputs —
                     # uncommitted state would cost one extra full recompile
+                    # (the abstract traversal names the same sharding: a
+                    # caller that makes the state in one jitted call lays
+                    # it out by these)
                     repl = self.machine.replicated()
                     state[op.name] = jax.tree.map(
-                        lambda v: jax.device_put(v, repl), s)
+                        (lambda v: jax.ShapeDtypeStruct(
+                            v.shape, v.dtype, sharding=repl)) if abstract
+                        else (lambda v: jax.device_put(v, repl)), s)
         return params, state
 
     # ------------------------------------------------------------------
@@ -726,16 +764,20 @@ class FFModel:
                 and lin.out_channels % pc_c == 0)
 
     def _run_fused_lm_head(self, lin, lin_params, x, labels):
+        import jax.numpy as jnp
+
         from flexflow_tpu.ops.pallas.fused_ce import (fused_linear_ce,
                                                       fused_linear_ce_partial)
 
         b_, s_, d_ = x.shape
         xf = x.reshape(b_ * s_, d_)
         labf = labels.reshape(-1)
-        w, bias = lin_params["kernel"], lin_params["bias"]
+        w = lin_params["kernel"]
+        bias = lin_params.get("bias")
+        if bias is None:        # a head without bias: the kernel adds 0
+            bias = jnp.zeros((lin.out_channels,), jnp.float32)
         pc_c = lin.pc.dims[0]
         if self.machine.num_devices > 1 and len(lin.pc.devices) > 1:
-            import jax.numpy as jnp
             from jax import lax
             from jax.sharding import PartitionSpec as P
 
@@ -1164,7 +1206,21 @@ class FFModel:
             for t in self._inputs:
                 specs[t.tid] = self.machine.global_entries(
                     dp, ("n",), P("n"), rank=t.ndim)
+        recomputed = self._recompute_plan(fusion) if train else {}
+        if recomputed and (multi or dump):
+            raise ValueError(
+                "a model class that recomputes its blocks in the backward "
+                "pass runs on one device, without print_intermediates")
+        inside: set = set()
         for entry in schedule:
+            if recomputed and entry in inside:
+                continue        # ran with the first operator of its block
+            if recomputed and entry in recomputed:
+                blk = recomputed[entry]
+                self._run_recomputed(blk, params, state, values, take,
+                                     new_state)
+                inside.update(blk["ops"])
+                continue
             if isinstance(entry, PlacementGroup):
                 block = getattr(self, "_block_params", {})
                 block_state = getattr(self, "_block_state", {})
@@ -1257,6 +1313,76 @@ class FFModel:
                 new_state[op.name] = st
         return values, new_state
 
+    def _recompute_plan(self, fusion) -> Dict[int, Dict]:
+        """{first layer index: block} for the blocks the model class
+        recomputes in the backward pass (``recompute_blocks``: ranges of
+        layer indices, fixed where the class builds its graph; no model
+        without the attribute takes any of this).  A block saves only
+        the values that cross its boundary: ``inputs`` are the tids it
+        reads from outside, in order of first use, and ``outputs`` those
+        it produces that a later operator reads."""
+        blocks = getattr(self, "recompute_blocks", ())
+        key = frozenset(fusion)
+        cached = getattr(self, "_recompute_cache", None)
+        if cached is not None and cached[0] == key:
+            return cached[1]
+        plan: Dict[int, Dict] = {}
+        for rng in blocks:
+            idx = list(rng)
+            if any(i in fusion for i in idx):
+                raise ValueError("a recomputed block may not hold the "
+                                 "fused vocabulary head")
+            ops = [self.layers[i] for i in idx]
+            made = {t.tid for op in ops for t in op.all_outputs()}
+            inputs = list(dict.fromkeys(
+                t.tid for op in ops for t in op.inputs
+                if t.tid not in made))
+            later = {t.tid for i, op in enumerate(self.layers)
+                     if i not in rng for t in op.inputs}
+            outputs = [t.tid for op in ops for t in op.all_outputs()
+                       if t.tid in later]
+            plan[idx[0]] = {"ops": idx, "inputs": inputs,
+                            "outputs": outputs}
+        self._recompute_cache = (key, plan)
+        return plan
+
+    def _run_recomputed(self, blk, params, state, values, take, new_state):
+        """Run one block under ``jax.checkpoint``: its forward keeps the
+        block's inputs only, and the backward pass runs the block's
+        operators once more (each under its own name, so the operator
+        table still charges every instruction) before differentiating
+        them."""
+        import jax
+
+        ops = [self.layers[i] for i in blk["ops"]]
+        obs.count("runtime.recomputed_blocks")
+
+        def body(p, s, xs):
+            vals = dict(zip(blk["inputs"], xs))
+            st_out = {}
+            for op in ops:
+                with jax.named_scope(op.name):
+                    res, st = op.forward(
+                        self._member_params(p, op),
+                        self._member_state(s, op),
+                        [vals[t.tid] for t in op.inputs], True)
+                    if st:
+                        st = self._restack_state(op, st)
+                ys = res if isinstance(res, tuple) else (res,)
+                for t, y in zip(op.all_outputs(), ys):
+                    vals[t.tid] = y
+                if st:
+                    st_out[op.name] = st
+            return [vals[t] for t in blk["outputs"]], st_out
+
+        outs, st = jax.checkpoint(body)(
+            {op.param_key: params[op.param_key] for op in ops
+             if op.param_key in params},
+            {op.name: state[op.name] for op in ops if op.name in state},
+            [take(t) for t in blk["inputs"]])
+        values.update(zip(blk["outputs"], outs))
+        new_state.update(st)
+
     def _consumer_counts(self, fusion, schedule):
         """How many times _apply reads each tid, mirroring its control
         flow exactly (placement groups, folded lm-head fusions, plain
@@ -1266,7 +1392,14 @@ class FFModel:
         from flexflow_tpu.parallel.placement import PlacementGroup
 
         counts: Counter = Counter()
+        recomputed = self._recompute_plan(fusion)
+        inside = {i for blk in recomputed.values() for i in blk["ops"]}
         for entry in schedule:
+            if recomputed and entry in recomputed:
+                # a block reads each of its inputs once
+                counts.update(recomputed[entry]["inputs"])
+            if recomputed and entry in inside:
+                continue
             if isinstance(entry, PlacementGroup):
                 for m in entry.members:
                     for t in m.inputs:
@@ -1415,6 +1548,26 @@ class FFModel:
         with jax.named_scope(loss_op.name):
             loss = loss_op.loss(values[loss_op.output.tid], labels)
         return loss, new_state
+
+    def _publish_state_counters(self, state) -> None:
+        """Counters that operators keep in their ``state`` (an expert
+        layer's load and the pairs its buffer could not take), published
+        through ``obs.count`` at a point where ``fit`` has just waited
+        for the step that wrote them: the step itself gains no
+        synchronisation.  Over the operators a name's values are merged
+        as the operator says (``max`` or ``sum``)."""
+        merged: Dict[str, float] = {}
+        for op in self.layers:
+            read = getattr(op, "state_counters", None)
+            if read is None or op.name not in (state or {}):
+                continue
+            for name, (value, how) in read(
+                    self._member_state(state, op)).items():
+                merged[name] = value if name not in merged else (
+                    max(merged[name], value) if how == "max"
+                    else merged[name] + value)
+        for name, value in merged.items():
+            obs.count(name, value, level=True)
 
     def _donate(self, argnums):
         """donate_argnums gated by config.donate — "off" is the A/B arm
@@ -2232,6 +2385,7 @@ class FFModel:
                             # sync-ok: print_freq-gated loss fetch,
                             # charged to host_sync_s in the step budget
                             log(f"iter {it1}: loss = {float(loss):.4f}")
+                            self._publish_state_counters(state)
                         host_sync_s += sp.seconds
                     if at_ckpt:
                         t0 = time.perf_counter()
@@ -2314,6 +2468,7 @@ class FFModel:
                                   what="close"):
                         # sync-ok: closes the timed window
                         jax.block_until_ready(loss)
+                        self._publish_state_counters(state)
                 elapsed = time.perf_counter() - start
         except BaseException:
             # error exit (host crash, device loss handed to the elastic

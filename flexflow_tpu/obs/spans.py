@@ -116,11 +116,14 @@ class span:
         return False
 
 
-def count(name: str, value: float = 1) -> None:
-    """Add ``value`` to the process-global counter ``name``."""
+def count(name: str, value: float = 1, *, level: bool = False) -> None:
+    """Add ``value`` to the process-global counter ``name``; with
+    ``level`` the counter is set to ``value`` instead (a size, a ratio:
+    what reads wrong summed over calls)."""
     now = time.perf_counter()
     with _lock:
-        v = _counters[name] = _counters.get(name, 0) + value
+        v = _counters[name] = value if level \
+            else _counters.get(name, 0) + value
         hist = _history.get(name)
         if hist is None:
             hist = _history[name] = collections.deque(

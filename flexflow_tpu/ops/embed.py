@@ -20,7 +20,8 @@ class Embed(Op):
 
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  vocab_size: int, embed_size: int,
-                 param_key: str = None, compute_dtype: str = "float32"):
+                 param_key: str = None, compute_dtype: str = "float32",
+                 init_std: float = 0.05):
         super().__init__(name, pc, [input])
         assert input.ndim == 2, "embed input must be (batch, length) int ids"
         self.vocab_size = vocab_size
@@ -30,6 +31,7 @@ class Embed(Op):
         # every downstream seq op follows x.dtype (the CNN path's analog
         # is make_train_step's image.astype)
         self.compute_dtype = compute_dtype
+        self.init_std = float(init_std)
         if param_key:
             self.param_key = param_key
         n, length = input.shape
@@ -39,9 +41,11 @@ class Embed(Op):
     def init_params(self, rng) -> Dict:
         import jax
 
-        # normal(0.01) like reference's rnn_randomize (uniform small init)
+        # small normal like the reference's rnn_randomize by default; a
+        # model whose blocks renormalise their input may ask for more
         table = jax.random.normal(
-            rng, (self.vocab_size, self.embed_size), "float32") * 0.05
+            rng, (self.vocab_size, self.embed_size), "float32") \
+            * self.init_std
         return {"table": table}
 
     def param_specs(self):

@@ -20,7 +20,12 @@ transposed in HBM for the usual head widths (:func:`_layout`):
     selected afterwards;
   * hd a multiple of 128: one head a block (``pack1``);
   * anything else (hd 80, or H not a multiple of G): hd is zero-padded to
-    the next multiple of 128 (``pad<hd_p>``), the one case that pads.
+    the next multiple of 128 (``pad<hd_p>``), the one case that pads;
+  * a value width of its own (latent attention: q, k 192 wide, v and the
+    result 128): one head a block, each side rounded up to whole lanes by
+    itself (``pad256v128``: q, k, dq, dk padded to 256, which costs the
+    MXU nothing since it contracts 128 lanes at a time; v, out, do and dv
+    stay 128 wide and nothing of theirs is padded).
 
 Accumulators, scores, softmax, lse and delta are float32 in VMEM; out,
 dq, dk and dv leave the kernels in the operands' type (the partial form
@@ -100,15 +105,23 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _layout(h: int, hd: int):
-    """(G heads a block, head width in the kernel's arrays, name)."""
+def _layout(h: int, hd: int, hdv: int):
+    """(G heads a block, query/key and value head widths in the kernel's
+    arrays, name).  A value width of its own (latent attention: 192
+    against 128) takes one head a block, each width rounded up to whole
+    lanes by itself, so the narrower side is not padded to the wider."""
+    if hdv != hd:
+        hd_p, hdv_p = _round_up(hd, LANES), _round_up(hdv, LANES)
+        return 1, hd_p, hdv_p, (
+            f"{'pack' if hd_p == hd else 'pad'}{hd_p}"
+            f"{'v' if hdv_p == hdv else 'vpad'}{hdv_p}")
     g = LANES // hd if LANES % hd == 0 else 0
     if g >= 1 and h % g == 0:
-        return g, hd, f"pack{g}"
+        return g, hd, hd, f"pack{g}"
     if hd % LANES == 0:
-        return 1, hd, "pack1"
+        return 1, hd, hd, "pack1"
     hd_p = _round_up(hd, LANES)
-    return 1, hd_p, f"pad{hd_p}"
+    return 1, hd_p, hd_p, f"pad{hd_p}"
 
 
 def _pick_block(s: int, interpret: bool) -> int:
@@ -258,7 +271,7 @@ def _transposed(x, dtype):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
-                acc_scr, *, t: _Tiles, scale, heads, hd):
+                acc_scr, *, t: _Tiles, scale, heads, hd, hdv):
     qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -276,7 +289,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
             k = k_ref[0, ks, :]
             valid = t.valid(qi, ki, qs, ks) if masked else None
             for g in range(heads):
-                rows = _head_rows(g, hd)
+                rows = _head_rows(g, hdv)
                 s_t = _nt(k, qs_scr[g, qs, :])
                 if masked:
                     s_t = jnp.where(valid, s_t, _NEG_INF)
@@ -298,39 +311,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
     @pl.when(ki == t.n_k - 1)
     def _finish():
         for g in range(heads):
-            rows = _head_rows(g, hd)
+            rows = _head_rows(g, hdv)
             acc_scr[rows, :] = acc_scr[rows, :] / l_scr[g]
             lse_ref[0, g] = m_scr[g] + jnp.log(l_scr[g])
         o_ref[0] = acc_scr[...].T.astype(o_ref.dtype)
 
 
-def _fwd_call(q, k, v, *, t: _Tiles, heads, hd, scale, out_dtype, interpret):
+def _fwd_call(q, k, v, *, t: _Tiles, heads, hd, hdv, scale, out_dtype,
+              interpret):
     b, sq, width = q.shape
-    w = heads * hd
+    w, wv = heads * hd, heads * hdv
     groups = width // w
     bq, bk = t.bq, t.bk
-    kv_spec = pl.BlockSpec(
-        (1, bk, w), lambda b_, j, qi, ki: (b_, jnp.minimum(
-            ki, t.last_live_k(qi)), j))
+
+    def kv_spec(width_):
+        return pl.BlockSpec(
+            (1, bk, width_), lambda b_, j, qi, ki: (b_, jnp.minimum(
+                ki, t.last_live_k(qi)), j))
+
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, t=t, scale=scale, heads=heads, hd=hd),
+        functools.partial(_fwd_kernel, t=t, scale=scale, heads=heads, hd=hd,
+                          hdv=hdv),
         grid=(b, groups, t.n_q, t.n_k),
         in_specs=[pl.BlockSpec((1, bq, w), lambda b_, j, qi, ki: (b_, qi, j)),
-                  kv_spec, kv_spec],
+                  kv_spec(w), kv_spec(wv)],
         out_specs=[
-            pl.BlockSpec((1, bq, w), lambda b_, j, qi, ki: (b_, qi, j)),
+            pl.BlockSpec((1, bq, wv), lambda b_, j, qi, ki: (b_, qi, j)),
             pl.BlockSpec((1, heads, 1, bq),
                          lambda b_, j, qi, ki: (b_, j, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, out_dtype),
+            jax.ShapeDtypeStruct((b, sq, groups * wv), out_dtype),
             jax.ShapeDtypeStruct((b, groups * heads, 1, sq), _F32),
         ],
         scratch_shapes=[
             pltpu.VMEM((heads, bq, w), q.dtype),
             pltpu.VMEM((heads, 1, bq), _F32),
             pltpu.VMEM((heads, 1, bq), _F32),
-            pltpu.VMEM((w, bq), _F32),
+            pltpu.VMEM((wv, bq), _F32),
         ],
         interpret=interpret,
         name="ff_flash_fwd",
@@ -352,20 +370,20 @@ def _p_ds_t(ks, vm, q, do, lse, delta, valid):
     return p_t, p_t * (_nt(vm, do) - delta)
 
 
-def _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd):
+def _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd, hdv):
     """This K block's operands: per head k scaled and v, each with the
     other heads' lanes zeroed, and (k scaled)^T, whose rows split by head."""
     k = k_ref[0].astype(_F32) * scale
     v = v_ref[0].astype(_F32)
     for g in range(heads):
         ks_scr[g] = _only_head(k, g, heads, hd).astype(ks_scr.dtype)
-        vm_scr[g] = _only_head(v, g, heads, hd).astype(vm_scr.dtype)
+        vm_scr[g] = _only_head(v, g, heads, hdv).astype(vm_scr.dtype)
     if kst_scr is not None:
         kst_scr[...] = k.T.astype(kst_scr.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                t: _Tiles, scale, heads, hd, with_dq):
+                t: _Tiles, scale, heads, hd, hdv, with_dq):
     """K blocks outer, Q blocks inner: dk, dv accumulate over the inner
     loop; with ``with_dq`` (the fused form) dq^T of the whole head group
     accumulates across both loops and leaves with the last K block."""
@@ -381,7 +399,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
         dv_scr[...] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
-        _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd)
+        _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd,
+                  hdv)
 
     if with_dq:
         @pl.when(ki == 0)
@@ -403,7 +422,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 if with_dq:
                     rows = _head_rows(g, hd)
                     dqt_scr[qi, rows, qs] += _nn(kst_scr[rows, ks], ds_t)
-            dv_scr[ks, :] += _by_head(dvs, hd)
+            dv_scr[ks, :] += _by_head(dvs, hdv)
             dk_scr[ks, :] += _by_head(dks, hd)
 
     t.run(qi, ki, tile)
@@ -422,7 +441,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    ks_scr, vm_scr, kst_scr, dqt_scr,
-                   *, t: _Tiles, scale, heads, hd):
+                   *, t: _Tiles, scale, heads, hd, hdv):
     """Q blocks outer, K blocks inner (the split form's second kernel)."""
     qi, ki = pl.program_id(2), pl.program_id(3)
 
@@ -431,7 +450,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dqt_scr[...] = jnp.zeros(dqt_scr.shape, dqt_scr.dtype)
 
     def tile(pieces):
-        _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd)
+        _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd,
+                  hdv)
         for qs, ks, masked in pieces:
             q, do = q_ref[0, qs, :], do_ref[0, qs, :]
             valid = t.valid(qi, ki, qs, ks) if masked else None
@@ -450,27 +470,33 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = dqt_scr[...].T.astype(dq_ref.dtype)
 
 
-def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, scale,
+def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
               fused, interpret):
     b, sq, width = q.shape
-    w = heads * hd
+    w, wv = heads * hd, heads * hdv
     groups = width // w
     bq, bk = t.bq, t.bk
-    common = dict(t=t, scale=scale, heads=heads, hd=hd)
+    common = dict(t=t, scale=scale, heads=heads, hd=hd, hdv=hdv)
 
     # K blocks outer, Q blocks inner
-    q_spec = pl.BlockSpec(
-        (1, bq, w), lambda b_, j, ki, qi: (b_, jnp.maximum(
-            qi, t.first_live_q(ki)), j))
+    def q_spec(width_):
+        return pl.BlockSpec(
+            (1, bq, width_), lambda b_, j, ki, qi: (b_, jnp.maximum(
+                qi, t.first_live_q(ki)), j))
+
     row_spec = pl.BlockSpec(
         (1, heads, 1, bq), lambda b_, j, ki, qi: (b_, j, 0, jnp.maximum(
             qi, t.first_live_q(ki))))
-    kv_spec = pl.BlockSpec((1, bk, w), lambda b_, j, ki, qi: (b_, ki, j))
+
+    def kv_spec(width_):
+        return pl.BlockSpec((1, bk, width_),
+                            lambda b_, j, ki, qi: (b_, ki, j))
+
     kv_scratch = [pltpu.VMEM((heads, bk, w), q.dtype),
-                  pltpu.VMEM((heads, bk, w), q.dtype)]
-    acc_scratch = [pltpu.VMEM((bk, w), _F32), pltpu.VMEM((bk, w), _F32)]
+                  pltpu.VMEM((heads, bk, wv), q.dtype)]
+    acc_scratch = [pltpu.VMEM((bk, w), _F32), pltpu.VMEM((bk, wv), _F32)]
     dq_scratch = [pltpu.VMEM((w, bk), q.dtype)]
-    out_specs = [kv_spec, kv_spec]
+    out_specs = [kv_spec(w), kv_spec(wv)]
     out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
     scratch = kv_scratch + acc_scratch
@@ -482,7 +508,8 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, scale,
     outs = pl.pallas_call(
         functools.partial(_bwd_kernel, with_dq=fused, **common),
         grid=(b, groups, t.n_k, t.n_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec(w), kv_spec(w), kv_spec(wv), q_spec(wv), row_spec,
+                  row_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
@@ -494,17 +521,24 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, scale,
         return outs
 
     # Q blocks outer, K blocks inner
-    q_spec = pl.BlockSpec((1, bq, w), lambda b_, j, qi, ki: (b_, qi, j))
+    def q_spec(width_):
+        return pl.BlockSpec((1, bq, width_),
+                            lambda b_, j, qi, ki: (b_, qi, j))
+
     row_spec = pl.BlockSpec((1, heads, 1, bq),
                             lambda b_, j, qi, ki: (b_, j, 0, qi))
-    kv_spec = pl.BlockSpec(
-        (1, bk, w), lambda b_, j, qi, ki: (b_, jnp.minimum(
-            ki, t.last_live_k(qi)), j))
+
+    def kv_spec(width_):
+        return pl.BlockSpec(
+            (1, bk, width_), lambda b_, j, qi, ki: (b_, jnp.minimum(
+                ki, t.last_live_k(qi)), j))
+
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         grid=(b, groups, t.n_q, t.n_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[q_spec(w), kv_spec(w), kv_spec(wv), q_spec(wv), row_spec,
+                  row_spec],
+        out_specs=q_spec(w),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=kv_scratch + dq_scratch + [pltpu.VMEM((w, bq), _F32)],
         interpret=interpret,
@@ -524,12 +558,14 @@ def _should_interpret() -> bool:
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
-                interpret, with_lse=False, packed=False):
+def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
+                block_k, interpret, with_lse=False, packed=False):
     """Build a custom-VJP flash op specialized for one static configuration
     (shapes/dtypes/blocks are Python constants closed over by the kernels;
     the VJP residuals are pure arrays), and name its variant.  ``q_shape``
-    and ``k_shape`` are (B, H, S, hd) whatever the arrays' layout.
+    and ``k_shape`` are (B, H, S, hd) whatever the arrays' layout; ``dv``
+    is the value's head width (v, the result and their gradients), which
+    latent attention sets apart from the query/key width.
 
     With ``with_lse`` the op returns ``(out, lse)`` — the *partial*
     attention form used by ring/context parallelism, where per-chunk
@@ -540,7 +576,7 @@ def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
     b, h, sq, d = q_shape
     sk = k_shape[2]
     scale = 1.0 / math.sqrt(d)
-    heads, hd, layout = _layout(h, d)
+    heads, hd, hdv, layout = _layout(h, d, dv)
     bq = (_pick_block(sq, interpret) if block_q is None
           else min(block_q, _round_up(sq, 8)))
     bk = (_pick_block(sk, interpret) if block_k is None
@@ -556,13 +592,13 @@ def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
     # (tracing them is most of what a call costs before it compiles),
     # while every call site keeps its own operator name in the program
     fwd_call = jax.jit(functools.partial(
-        _fwd_call, t=fwd_tiles, heads=heads, hd=hd, scale=scale,
+        _fwd_call, t=fwd_tiles, heads=heads, hd=hd, hdv=hdv, scale=scale,
         out_dtype=out_dtype, interpret=interpret), inline=True)
     bwd_call = jax.jit(functools.partial(
-        _bwd_call, t=bwd_tiles, heads=heads, hd=hd, scale=scale, fused=fused,
-        interpret=interpret), inline=True)
+        _bwd_call, t=bwd_tiles, heads=heads, hd=hd, hdv=hdv, scale=scale,
+        fused=fused, interpret=interpret), inline=True)
 
-    def prep(x, s_p):
+    def prep(x, s_p, d=d, hd=hd):
         """-> (B, S_pad, H*hd_kernel); zero head columns do not change
         scores, padded K rows are masked via sk, padded Q rows sliced off"""
         s = x.shape[1] if packed else x.shape[2]
@@ -576,7 +612,7 @@ def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
             x = jnp.pad(x, ((0, 0), (0, s_p - s), (0, 0)))
         return x
 
-    def unprep(x, s):
+    def unprep(x, s, d=d, hd=hd):
         """The inverse of prep for a kernel result of true length s."""
         if x.shape[1] != s:
             x = x[:, :s]
@@ -586,30 +622,33 @@ def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
             return x.reshape(b, s, h * d)
         return x.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
+    prep_v = functools.partial(prep, d=dv, hd=hdv)
+    unprep_v = functools.partial(unprep, d=dv, hd=hdv)
+
     def rows(x):
         """(B, H, Sq) float32 -> the kernels' (B, H, 1, Sq_pad)."""
         x = x.astype(jnp.float32).reshape(b, h, 1, sq)
         return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, sq_p - sq)))
 
     def run_fwd(q, k, v):
-        qp, kp, vp = prep(q, sq_p), prep(k, sk_p), prep(v, sk_p)
+        qp, kp, vp = prep(q, sq_p), prep(k, sk_p), prep_v(v, sk_p)
         out, lse = fwd_call(qp, kp, vp)
-        return unprep(out, sq), lse, (qp, kp, vp, lse, out)
+        return unprep_v(out, sq), lse, (qp, kp, vp, lse, out)
 
     def run_bwd(res, g, g_lse=None):
         qp, kp, vp, lse, out = res
-        do = prep(g, sq_p)
+        do = prep_v(g, sq_p)
         # delta is zero on padded Q rows (do = 0 there), so they contribute
         # nothing to dk/dv even though their lse is arbitrary
         delta = jnp.einsum("bshd,bshd->bhs",
-                           do.reshape(b, sq_p, h, hd).astype(jnp.float32),
-                           out.reshape(b, sq_p, h, hd).astype(jnp.float32)
+                           do.reshape(b, sq_p, h, hdv).astype(jnp.float32),
+                           out.reshape(b, sq_p, h, hdv).astype(jnp.float32)
                            )[:, :, None, :]
         if g_lse is not None:
             delta = delta - rows(g_lse)  # ds = p (dp - delta + g_lse)
         dq, dk, dv = bwd_call(qp, kp, vp, do.astype(qdt), lse, delta)
         return (unprep(dq, sq).astype(qdt), unprep(dk, sk).astype(kdt),
-                unprep(dv, sk).astype(vdt))
+                unprep_v(dv, sk).astype(vdt))
 
     if not with_lse:
 
@@ -643,12 +682,12 @@ def _make_flash(q_shape, k_shape, qdt, kdt, vdt, causal, block_q, block_k,
     return flash_p, variant
 
 
-def _call(q, k, v, q_shape, k_shape, causal, block_q, block_k, interpret,
-          **form):
+def _call(q, k, v, q_shape, k_shape, dv, causal, block_q, block_k,
+          interpret, **form):
     interpret = _should_interpret() if interpret is None else interpret
-    f, variant = _make_flash(q_shape, k_shape, q.dtype.name, k.dtype.name,
-                             v.dtype.name, bool(causal), block_q, block_k,
-                             interpret, **form)
+    f, variant = _make_flash(q_shape, k_shape, dv, q.dtype.name,
+                             k.dtype.name, v.dtype.name, bool(causal),
+                             block_q, block_k, interpret, **form)
     obs.count(f"kernels.flash.{variant}")
     return f(q, k, v)
 
@@ -656,23 +695,25 @@ def _call(q, k, v, q_shape, k_shape, causal, block_q, block_k, interpret,
 def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
                     interpret=None):
     """softmax(q kᵀ / sqrt(d) [+ causal mask]) v without materializing the
-    score matrix.  q, k, v: (B, H, S, d); returns (B, H, Sq, d) in q's
-    type.  Blocks default to what the shapes select."""
-    return _call(q, k, v, tuple(q.shape), tuple(k.shape), causal, block_q,
-                 block_k, interpret)
+    score matrix.  q, k: (B, H, S, d) and v: (B, H, S, dv); returns
+    (B, H, Sq, dv) in q's type.  Blocks default to what the shapes
+    select."""
+    return _call(q, k, v, tuple(q.shape), tuple(k.shape), v.shape[-1],
+                 causal, block_q, block_k, interpret)
 
 
 def flash_attention_packed(q, k, v, num_heads, causal=False, block_q=None,
                            block_k=None, interpret=None):
-    """:func:`flash_attention` on the projections' own layout: q, k, v are
-    (B, S, H*d) with head h in columns ``h*d:(h+1)*d``, and so is the
-    result — no (B,S,H,d) <-> (B,H,S,d) transpose on either side."""
+    """:func:`flash_attention` on the projections' own layout: q and k are
+    (B, S, H*d) with head h in columns ``h*d:(h+1)*d``, v is (B, S, H*dv)
+    and so is the result — no (B,S,H,d) <-> (B,H,S,d) transpose on either
+    side."""
     def bhsd(x):
         b, s, width = x.shape
         return (b, num_heads, s, width // num_heads)
 
-    return _call(q, k, v, bhsd(q), bhsd(k), causal, block_q, block_k,
-                 interpret, packed=True)
+    return _call(q, k, v, bhsd(q), bhsd(k), v.shape[-1] // num_heads, causal,
+                 block_q, block_k, interpret, packed=True)
 
 
 def flash_attention_partial(q, k, v, causal=False, block_q=None,
@@ -684,8 +725,8 @@ def flash_attention_partial(q, k, v, causal=False, block_q=None,
     attention path (each ring step attends Q against the resident K/V
     block, then results merge by lse weight).  Differentiable in both
     outputs."""
-    return _call(q, k, v, tuple(q.shape), tuple(k.shape), causal, block_q,
-                 block_k, interpret, with_lse=True)
+    return _call(q, k, v, tuple(q.shape), tuple(k.shape), v.shape[-1],
+                 causal, block_q, block_k, interpret, with_lse=True)
 
 
 def combine_partials(o1, lse1, o2, lse2):

@@ -18,12 +18,14 @@ class RnnLinear(Op):
     AXIS_NAMES = ("c", "n")
 
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
-                 out_channels: int, param_key: str = None):
+                 out_channels: int, param_key: str = None,
+                 use_bias: bool = True):
         super().__init__(name, pc, [input])
         assert input.ndim == 3, "rnn linear input must be (batch, len, d)"
         n, length, d = input.shape
         self.in_channels = d
         self.out_channels = out_channels
+        self.use_bias = bool(use_bias)
         if param_key:
             self.param_key = param_key
         self.output = Tensor((n, length, out_channels), "float32", self, name)
@@ -34,13 +36,16 @@ class RnnLinear(Op):
 
         kernel = jax.nn.initializers.glorot_uniform()(
             rng, (self.in_channels, self.out_channels), "float32")
+        if not self.use_bias:
+            return {"kernel": kernel}
         bias = jnp.zeros((self.out_channels,), "float32")
         return {"kernel": kernel, "bias": bias}
 
     def param_specs(self):
         from jax.sharding import PartitionSpec as P
 
-        return {"kernel": P(None, "c"), "bias": P("c")}
+        specs = {"kernel": P(None, "c"), "bias": P("c")}
+        return specs if self.use_bias else {"kernel": specs["kernel"]}
 
     def output_spec(self):
         from jax.sharding import PartitionSpec as P
@@ -58,6 +63,8 @@ class RnnLinear(Op):
         return [P("n", None, None)]
 
     def placement_signature(self):
+        if not self.use_bias:
+            return (self.in_channels, self.out_channels, "no_bias")
         return (self.in_channels, self.out_channels)
 
     def forward(self, params, state, xs: List, train: bool):
@@ -66,7 +73,9 @@ class RnnLinear(Op):
         (x,) = xs
         y = jnp.einsum("bld,dv->blv", x, params["kernel"].astype(x.dtype),
                        preferred_element_type=jnp.float32)
-        return (y + params["bias"]).astype(x.dtype), state
+        if self.use_bias:
+            y = y + params["bias"]
+        return y.astype(x.dtype), state
 
     def local_clone(self, pc: ParallelConfig):
         pc_, pn = pc.dims
@@ -75,10 +84,11 @@ class RnnLinear(Op):
             return None
         t = Tensor((n // pn, length, d))
         return RnnLinear(self.name, ParallelConfig((1, 1), (0,)), t,
-                         self.out_channels // pc_)
+                         self.out_channels // pc_, use_bias=self.use_bias)
 
     def flops_per_sample(self) -> float:
         return 2.0 * self.output.shape[1] * self.in_channels * self.out_channels
 
     def param_bytes(self) -> int:
-        return 4 * (self.in_channels * self.out_channels + self.out_channels)
+        return 4 * (self.in_channels * self.out_channels
+                    + self.use_bias * self.out_channels)
