@@ -1212,6 +1212,9 @@ class FFModel:
                 "a model class that recomputes its blocks in the backward "
                 "pass runs on one device, without print_intermediates")
         inside: set = set()
+        if recomputed:
+            # a level: the bytes one traced step's blocks keep
+            obs.count("runtime.kept_bytes", 0, level=True)
         for entry in schedule:
             if recomputed and entry in inside:
                 continue        # ran with the first operator of its block
@@ -1348,14 +1351,31 @@ class FFModel:
 
     def _run_recomputed(self, blk, params, state, values, take, new_state):
         """Run one block under ``jax.checkpoint``: its forward keeps the
-        block's inputs only, and the backward pass runs the block's
+        block's inputs and the results the kernels name
+        (``ops/pallas.KEPT_RESULTS``: the flash forward's ``out`` and
+        ``lse``, which its backward kernels read and only the kernel
+        could make again), and the backward pass runs the block's other
         operators once more (each under its own name, so the operator
         table still charges every instruction) before differentiating
-        them."""
+        them.  A kernel's results are kept where they cost less to hold
+        than to make: 68 MB against 6.84 ms a Moonlight layer (PERF.md
+        section 6, PR 29)."""
         import jax
+
+        from flexflow_tpu.ops.pallas import KEPT_RESULTS
 
         ops = [self.layers[i] for i in blk["ops"]]
         obs.count("runtime.recomputed_blocks")
+        named = jax.checkpoint_policies.save_only_these_names(*KEPT_RESULTS)
+
+        def keep(prim, *avals, **params):
+            # JAX asks once a value while it builds the block's backward
+            kept = named(prim, *avals, **params)
+            if kept:
+                obs.count("runtime.kept_results")
+                obs.count("runtime.kept_bytes",
+                          avals[0].size * avals[0].dtype.itemsize)
+            return kept
 
         def body(p, s, xs):
             vals = dict(zip(blk["inputs"], xs))
@@ -1375,7 +1395,7 @@ class FFModel:
                     st_out[op.name] = st
             return [vals[t] for t in blk["outputs"]], st_out
 
-        outs, st = jax.checkpoint(body)(
+        outs, st = jax.checkpoint(body, policy=keep)(
             {op.param_key: params[op.param_key] for op in ops
              if op.param_key in params},
             {op.name: state[op.name] for op in ops if op.name in state},

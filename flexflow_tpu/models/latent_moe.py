@@ -13,9 +13,14 @@ The part of an expert layer's result that other chips' experts would add
 is left out, as ``ops/expert_share.py`` says.
 
 Every block is recomputed in the backward pass (``recompute_blocks``):
-only the block boundaries are kept, which is what lets 8192-token
-sequences through at all.  The unit and its placement are this class's
-and no option's.
+the block boundaries are kept, which is what lets 8192-token sequences
+through at all, and with them what the kernels name as dearer to make
+again than to hold (``ops/pallas.KEPT_RESULTS``: the flash forward's
+``out`` and ``lse``, 68 MB a layer at 2 x 8192 tokens, which the flash
+backward reads and only a second run of the kernel, 6.84 ms, would give
+back; PERF.md section 6, PR 29).  Norms, projections, the rotary, the
+router and both kinds of experts run once more.  The unit and its
+placement are this class's and no option's.
 """
 
 from __future__ import annotations

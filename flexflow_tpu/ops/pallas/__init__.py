@@ -26,9 +26,17 @@ and single-experiment escape hatch.
 
 import os
 
-from flexflow_tpu.ops.pallas.flash_attention import flash_attention
+from flexflow_tpu.ops.pallas.flash_attention import \
+    KEPT_RESULTS as _FLASH_RESULTS, flash_attention
 
 _POLICY = "auto"
+
+# Results the kernels name (``jax.ad_checkpoint.checkpoint_name``) because
+# they are dearer to make again than to hold: a block that a model class
+# recomputes in the backward pass keeps these and nothing else
+# (FFModel._run_recomputed).  A kernel joins by naming its results in its
+# own module and adding them here.
+KEPT_RESULTS = (*_FLASH_RESULTS,)
 
 
 def set_policy(policy: str) -> None:
@@ -127,6 +135,6 @@ def bnrelu_enabled() -> bool:
     return _POLICY == "on"
 
 
-__all__ = ["avgpool_enabled", "bnrelu_enabled", "flash_attention",
-           "flash_enabled", "get_policy", "maxpool_cost_gated",
-           "maxpool_enabled", "set_policy"]
+__all__ = ["KEPT_RESULTS", "avgpool_enabled", "bnrelu_enabled",
+           "flash_attention", "flash_enabled", "get_policy",
+           "maxpool_cost_gated", "maxpool_enabled", "set_policy"]

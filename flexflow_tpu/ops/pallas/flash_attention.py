@@ -83,6 +83,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -99,6 +100,12 @@ _BLOCK = 1024
 _FWD_PIECE = 512
 _BWD_PIECE = 256
 _F32 = jnp.float32
+# the forward kernel's two results by name: a recomputed block keeps them
+# (FFModel._run_recomputed) instead of running the kernel once more for
+# the backward kernels, which read both; out and lse are 68 MB a layer of
+# the Moonlight cell against 6.84 ms of ff_flash_fwd (PERF.md section 6,
+# PR 29).  Outside a jax.checkpoint a name is an identity
+KEPT_RESULTS = ("ff_flash_out", "ff_flash_lse")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -632,7 +639,9 @@ def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
 
     def run_fwd(q, k, v):
         qp, kp, vp = prep(q, sq_p), prep(k, sk_p), prep_v(v, sk_p)
-        out, lse = fwd_call(qp, kp, vp)
+        out, lse = map(checkpoint_name, fwd_call(qp, kp, vp), KEPT_RESULTS)
+        # the named arrays are both what the caller gets and what the
+        # backward holds, so one kept array serves both
         return unprep_v(out, sq), lse, (qp, kp, vp, lse, out)
 
     def run_bwd(res, g, g_lse=None):

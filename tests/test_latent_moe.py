@@ -8,6 +8,7 @@ uncut reference of ``benchmarks/reference/moonlight_16b_a3b.py``."""
 import importlib
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -476,7 +477,70 @@ def test_operator_table_charges_a_recomputed_block_to_backward(tiny_model):
         == ("blk1_mla", "forward")
 
 
-def test_a_model_without_recompute_blocks_lowers_as_before():
+def _kernel_calls(jaxpr):
+    """The names of the pallas_calls of a jaxpr, nested jaxprs included
+    (not the kernels' own bodies)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_calls(sub)
+
+
+def _equations(jaxpr):
+    """How often a jaxpr applies each primitive to which shapes, nested
+    jaxprs included: what a program computes and keeps, whatever
+    sub-jaxprs JAX's caches let it share."""
+    import collections
+
+    seen = collections.Counter()
+    for eqn in jaxpr.eqns:
+        seen[(eqn.primitive.name,
+              *(v.aval.str_short() for v in eqn.invars))] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            seen.update(_equations(sub))
+    return seen
+
+
+@pytest.mark.parametrize("attention", ["kernels", "blockwise"])
+def test_a_recomputed_block_keeps_what_the_kernels_name(
+        tiny_model, attention, monkeypatch):
+    """With the flash kernels on (interpret mode here, through the gate
+    ``flash_enabled`` reads) the differentiated step runs the forward
+    kernel once a layer, not twice: each block keeps the kernel's
+    ``out`` and ``lse``, and the two counters read what the shapes say.
+    On XLA's blockwise path no name is traced, the policy keeps nothing
+    and the step is what a bare ``jax.checkpoint`` gives."""
+    from flexflow_tpu import obs
+
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH",
+                       "1" if attention == "kernels" else "0")
+    args = (*tiny_model.abstract_train_state(),
+            *[jax.ShapeDtypeStruct((2, 16), jnp.int32)] * 2)
+    before = obs.snapshot()["counters"]
+    traced = tiny_model.make_train_step().trace(*args)
+    after = obs.snapshot()["counters"]
+    calls = sorted(_kernel_calls(traced.jaxpr.jaxpr))
+    kept = after.get("runtime.kept_results", 0) \
+        - before.get("runtime.kept_results", 0)
+    if attention == "kernels":
+        # heads of 24 and 16 each ride 128 lanes; float32 at the tiny
+        # preset: out (2, 16, 4 * 128) and lse (2, 4, 1, 16) a layer
+        assert calls == ["ff_flash_bwd"] * 3 + ["ff_flash_fwd"] * 3
+        assert kept == 6
+        assert after["runtime.kept_bytes"] \
+            == 3 * 4 * (2 * 16 * 4 * 128 + 2 * 4 * 16)
+        return
+    assert calls == [] and kept == 0
+    assert after["runtime.kept_bytes"] == 0
+    bare = jax.checkpoint
+    monkeypatch.setattr(jax, "checkpoint", lambda body, policy: bare(body))
+    assert _equations(tiny_model.make_train_step().trace(*args).jaxpr.jaxpr) \
+        == _equations(traced.jaxpr.jaxpr)
+
+
+def test_a_model_without_recompute_blocks_lowers_as_before(monkeypatch):
     """TransformerLM names no block to recompute: its step holds no
     checkpoint, and its plan is empty."""
     from flexflow_tpu.models.transformer import (TransformerConfig,
@@ -493,6 +557,29 @@ def test_a_model_without_recompute_blocks_lowers_as_before():
                                       toks).as_text()
     assert "checkpoint" not in text and "remat" not in text
     assert "optimization_barrier" not in text
+    # outside a jax.checkpoint the names the flash kernels give their
+    # results are identities: with the kernels on, the step lowers to
+    # the text it has without them
+    from flexflow_tpu import obs
+
+    fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH", "1")
+
+    def lowered():
+        # without the counter MLIR's symbol table ends a repeated
+        # function name with: it moves with what else was lowered
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                      ff.make_train_step().lower(params, state, opt, toks,
+                                                 toks).as_text())
+
+    flash = "kernels.flash.pad128.fused"        # 4 heads of 8
+    before = obs.snapshot()["counters"].get(flash, 0)
+    named = lowered()
+    assert obs.snapshot()["counters"][flash] == before + 2
+    assert named != text and "checkpoint" not in named
+    # run_fwd looks the function up when the op is traced
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    assert lowered() == named
 
 
 def test_apps_lm_trains_the_model_from_its_configuration_file(tmp_path):
@@ -541,3 +628,51 @@ ENTRY %main (p0: f32[8]) -> f32[8] {
                                               "backward")
     assert table["other.1"] == ("", "other")
     assert table["copy.1"] == ("", "other")      # no metadata: as before
+
+
+def test_trace_instructions_sets_the_recomputed_forward_apart(
+        tmp_path, monkeypatch, capsys):
+    """``tools/trace_instructions.py <cell> <text> <steps>``: the forward
+    a block runs once more (``rematted_computation/``) is a part of its
+    own beside the backward proper, a rewritten custom call follows its
+    operands there too, and a text of another program is refused."""
+    import sys
+
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
+    monkeypatch.delitem(sys.modules, "trace_instructions", raising=False)
+    tool = importlib.import_module("trace_instructions")
+    path = "jit(ff_train_step)/transpose(jvp(jvp()))/checkpoint/"
+    hlo = tmp_path / "step.txt"
+    hlo.write_text(f"""HloModule jit_ff_train_step
+
+ENTRY %main (p0: f32[8]) -> f32[8] {{
+  %p0 = f32[8]{{0}} parameter(0)
+  %fusion.1 = f32[8]{{0}} fusion(%p0), kind=kLoop, calls=%f, metadata={{op_name="jit(ff_train_step)/jvp(blk1_mla)/mul"}}
+  %fusion.2 = f32[8]{{0}} fusion(%p0), kind=kLoop, calls=%g, metadata={{op_name="{path}rematted_computation/blk1_mla/mul"}}
+  %ragged-dot-none.1 = f32[8]{{0}} custom-call(%fusion.2), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+  %fusion.3 = f32[8]{{0}} fusion(%p0), kind=kLoop, calls=%h, metadata={{op_name="{path}blk1_mla/mul"}}
+  ROOT %copy.1 = f32[8]{{0}} copy(%fusion.3)
+}}
+""")
+    monkeypatch.setattr(tool, "ROOT", str(tmp_path))
+    os.makedirs(tmp_path / "chiprun_out")
+    seconds = {"fusion.1": 0.004, "fusion.2": 0.002,
+               "ragged-dot-none.1": 0.001, "fusion.3": 0.006,
+               "copy.1": 0.0005}
+
+    def dump(d):
+        with open(tmp_path / "chiprun_out" / "instructions.cell.json",
+                  "w") as f:
+            json.dump(d, f)
+
+    dump(seconds)
+    assert tool.by_operator("cell", str(hlo), 2) == 0
+    out = json.loads(capsys.readouterr().out)
+    assert out["ms_per_step"]["blk1_mla"] == {
+        "forward": 2.0, "recomputed": 1.5, "backward": 3.0}
+    assert out["ms_per_step"]["(none)"] == {"other": 0.25}
+    assert out["sum"] == {"forward": 2.0, "recomputed": 1.5,
+                          "backward": 3.0}
+    dump(dict(seconds, **{"fusion.9": 0.001}))
+    with pytest.raises(SystemExit, match="not the traced program"):
+        tool.by_operator("cell", str(hlo), 2)

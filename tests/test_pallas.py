@@ -236,6 +236,59 @@ def test_flash_cell_layout_has_no_pad_and_no_float32_copy():
                         and aval.size >= b * h * s * 128), (e, aval)
 
 
+@pytest.mark.parametrize("backward", ["fused", "split"])
+@pytest.mark.parametrize("form", ["packed", "partial"])
+def test_a_checkpoint_that_keeps_the_named_results_runs_the_forward_once(
+        form, backward, small_flash, monkeypatch):
+    """The forward kernel names its two results (KEPT_RESULTS).  Under a
+    bare jax.checkpoint the backward runs the kernel once more to have
+    them again; under the policy a recomputed block uses
+    (FFModel._run_recomputed) they are kept, the kernel runs once, and
+    the backward kernels read the very arrays they read without a
+    checkpoint: all three gradients are equal bit for bit."""
+    from flexflow_tpu.ops.pallas import KEPT_RESULTS
+
+    fa = small_flash
+    assert KEPT_RESULTS == ("ff_flash_out", "ff_flash_lse")
+    if backward == "split":
+        monkeypatch.setattr(fa, "_FUSED_DQ_BYTES", 0)
+    b, h, s, d = 1, 2, 32, 64
+    rng = np.random.RandomState(11)
+    kw = dict(causal=True, block_q=16, block_k=16)
+    if form == "packed":
+        q, k, v = (_rand(rng, b, s, h * d) for _ in range(3))
+        w = _rand(rng, b, s, h * d)
+
+        def f(q, k, v):
+            out = fa.flash_attention_packed(q * 2.0, k, v, h, **kw)
+            return (out * w).sum()
+    else:
+        q, k, v = (_rand(rng, b, h, s, d) for _ in range(3))
+        w, u = _rand(rng, b, h, s, d), _rand(rng, b, h, s)
+
+        def f(q, k, v):
+            out, lse = fa.flash_attention_partial(q * 2.0, k, v, **kw)
+            return (out * w).sum() + (lse * u).sum()
+
+    def kernels(g):
+        closed = jax.make_jaxpr(jax.grad(g, argnums=(0, 1, 2)))(q, k, v)
+        return sorted(e.params["name"] for e in _top_level_eqns(closed.jaxpr)
+                      if e.primitive.name == "pallas_call")
+
+    bwd = ["ff_flash_bwd"] if backward == "fused" \
+        else ["ff_flash_bwd_dkv", "ff_flash_bwd_dq"]
+    keeping = jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(
+            *KEPT_RESULTS))
+    assert kernels(f) == bwd + ["ff_flash_fwd"]
+    assert kernels(jax.checkpoint(f)) == bwd + ["ff_flash_fwd"] * 2
+    assert kernels(keeping) == bwd + ["ff_flash_fwd"]
+    want = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    got = jax.grad(keeping, argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+
 def test_partial_combine_matches_full():
     """Two K/V chunks merged by combine_partials == one full attention."""
     from flexflow_tpu.ops.pallas.flash_attention import (

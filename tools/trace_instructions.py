@@ -7,15 +7,24 @@ run of the cell, in the same checkout:
     python3 benchmarks/run.py --workload <cell> --seed 1 --trace 1
     python3 tools/trace_instructions.py <cell>
 
-Join it with ``FFModel.operator_table()`` (or ``obs/optrace.py`` on the
-step's compiled text) for the operator and pass of each.  No JAX device
-is touched.
+With the compiled step's text (``FFModel.compile_train_step(..)
+.as_text()``, made on the chip or for a described v5e) and the number of
+traced steps (``traced_steps`` of the run's ``operators`` line) it joins
+that file, wherever it was brought, to the operators and prints ms a step
+by operator: forward, the forward a recomputed block runs once more
+(instructions under ``rematted_computation/``, which the operator table
+charges to ``backward`` together with the backward proper) and backward:
+
+    python3 tools/trace_instructions.py <cell> <step.hlo.txt> <steps>
+
+No JAX device is touched.
 """
 
 import collections
 import glob
 import json
 import os
+import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -52,5 +61,45 @@ def main(cell: str) -> int:
     return 0
 
 
+RECOMPUTED = "recomputed"
+# a scope of its own for the second forward, so that the table's rules
+# for fusions and rewritten custom calls hold for it as for any operator
+_REMATTED = re.compile(r"rematted_computation/(\w+)")
+
+
+def by_operator(cell: str, hlo_file: str, steps: int) -> int:
+    """Print ``{operator: {forward, recomputed, backward}}`` in ms a
+    step, largest first, and the three sums.  Refuses a text that lacks
+    an instruction of the trace: it is of another program."""
+    from flexflow_tpu.obs import optrace
+
+    with open(os.path.join(ROOT, "chiprun_out",
+                           f"instructions.{cell}.json")) as f:
+        seconds = json.load(f)
+    with open(hlo_file) as f:
+        table = optrace.operator_table(
+            _REMATTED.sub(RECOMPUTED + r".\1", f.read()))
+    missing = sorted(set(seconds) - set(table))
+    if missing:
+        raise SystemExit(f"{hlo_file} has no {missing[:5]} ({len(missing)} "
+                         f"of {len(seconds)}): not the traced program")
+    ms = collections.defaultdict(lambda: collections.defaultdict(float))
+    for name, s in seconds.items():
+        operator, part = table[name]
+        if operator.startswith(RECOMPUTED + "."):
+            operator, part = operator[len(RECOMPUTED) + 1:], RECOMPUTED
+        ms[operator or "(none)"][part] += 1e3 * s / steps
+    parts = (optrace.FORWARD, RECOMPUTED, optrace.BACKWARD)
+    rows = {op: {p: round(v, 3) for p, v in d.items()}
+            for op, d in sorted(ms.items(),
+                                key=lambda kv: -sum(kv[1].values()))}
+    print(json.dumps({"ms_per_step": rows, "sum": {
+        p: round(sum(d.get(p, 0.0) for d in ms.values()), 3)
+        for p in parts}}))
+    return 0
+
+
 if __name__ == "__main__":
+    if len(sys.argv) > 2:
+        sys.exit(by_operator(sys.argv[1], sys.argv[2], int(sys.argv[3])))
     sys.exit(main(sys.argv[1]))
