@@ -10,8 +10,7 @@ means:
   device      jax.devices() is a TPU
   train_cnn   apps.cnn.main -> FFModel.fit: Inception-v3 299x299, bf16,
               batch 256 per chip, 10 steps; finite losses, train state
-              resident on every TPU device; says whether the Pallas maxpool
-              backward routed
+              resident on every TPU device
   train_lm    apps.lm.main: causal LM b16 s512 l12 d768 h12 vocab 32k,
               bf16, 5 steps; finite losses AND the compiled step holds the
               flash-attention and fused projection+CE Mosaic custom calls
@@ -112,9 +111,7 @@ def phase_train_cnn(rehearsal, device):
         log=lambda *a: None)
     info = _fit_checks(out, iters, "train_cnn")
     info["images_per_sec"] = out["images_per_sec"]
-    kernels = _compiled_kernels(out)
-    info["pallas_kernels"] = kernels
-    info["maxpool_kernel_routed"] = "ff_maxpool_bwd" in kernels
+    info["pallas_kernels"] = _compiled_kernels(out)
     return info
 
 

@@ -65,8 +65,6 @@ def parse_args(argv) -> TransformerConfig:
             cfg.compute_dtype = val()
         elif a in ("-param-dtype", "--param-dtype"):
             cfg.param_dtype = val()
-        elif a in ("-pallas", "--pallas"):
-            cfg.pallas = val()
         elif a == "--seed":
             cfg.seed = int(val())
         elif a == "--strategy":

@@ -54,8 +54,6 @@ def parse_args(argv) -> RnnConfig:
             cfg.compute_dtype = val()
         elif a in ("-param-dtype", "--param-dtype"):
             cfg.param_dtype = val()
-        elif a in ("-pallas", "--pallas"):
-            cfg.pallas = val()
         elif a == "--seed":
             cfg.seed = int(val())
         elif a == "--strategy":

@@ -10,13 +10,6 @@ from typing import Sequence
 from flexflow_tpu.strategy import Strategy
 
 
-def _checked_pallas(v: str) -> str:
-    """Validate a --pallas value at parse time (like --on-divergence)."""
-    if v not in ("auto", "on", "off"):
-        raise SystemExit(f"--pallas must be auto|on|off, got {v!r}")
-    return v
-
-
 def _checked_policy(v: str) -> str:
     """Validate an --on-divergence value at parse time (like -delta)."""
     if v not in ("halt", "warn", "rollback"):
@@ -207,14 +200,6 @@ class FFConfig:
     # chain.  Bit-identical for fan-out <= 3, reassociates (tolerance-
     # level) beyond.  No CLI flag: a compilation property, like donate.
     grad_fanout: str = "tree"
-    # Pallas kernel policy (round 13): one switch over the per-kernel
-    # env gates (FLEXFLOW_TPU_{FLASH,MAXPOOL,AVGPOOL,BNRELU}, which
-    # still override per-kernel for tests/experiments).  "auto" (the
-    # default) routes a kernel only when its supported() gate holds AND
-    # the HBM cost model predicts a win on the concrete geometry
-    # (ops/pallas/__init__.set_policy); "on" forces every supported
-    # kernel; "off" keeps everything on the stock XLA path.
-    pallas: str = "auto"
     # serving runtime (serve/ package, apps/serve.py): --max-batch caps
     # the continuous batcher's decode slots (0 = the model's batch_size);
     # --serve-queue-hi is the queue-depth watermark that triggers a
@@ -381,8 +366,6 @@ class FFConfig:
                 cfg.fleet_search_budget_s = float(val())
             elif a == "--allow-degraded":
                 cfg.allow_degraded = True
-            elif a in ("-pallas", "--pallas"):
-                cfg.pallas = _checked_pallas(val())
             elif a == "--ckpt-dir":
                 cfg.ckpt_dir = val()
             elif a == "--ckpt-freq":

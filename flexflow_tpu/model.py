@@ -174,12 +174,6 @@ class FFModel:
                  machine: Optional[MachineModel] = None):
         self.config = config or FFConfig()
         self.machine = machine or MachineModel()
-        # install the kernel routing policy (--pallas auto|on|off) before
-        # any op's _use_pallas runs; the per-kernel env vars still
-        # override (ops/pallas/__init__.set_policy)
-        from flexflow_tpu.ops.pallas import set_policy
-
-        set_policy(getattr(self.config, "pallas", "auto") or "auto")
         validate_strategy(self.config.strategies, self.machine.num_devices)
         self.machine = self._permuted_machine_view(self.machine)
         self.layers: List[Op] = []
@@ -713,38 +707,39 @@ class FFModel:
     # region between them (nmt/linear.cu -> nmt/softmax_data_parallel.cu).
 
     def _lm_head_fusion(self):
-        from flexflow_tpu.ops.pallas import flash_enabled
-
-        enabled = flash_enabled()
-        # cache keyed on flash_enabled() so toggling FLEXFLOW_TPU_FLASH on a
-        # live model recomputes the plan instead of silently reusing it
         cached = getattr(self, "_fusion_plan", None)
-        if cached is not None and cached[0] == enabled:
-            return cached[1]
+        if cached is not None:
+            return cached
         from flexflow_tpu.ops.rnn_linear import RnnLinear
         from flexflow_tpu.ops.softmax_dp import SoftmaxDP
 
         plan: Dict[int, Any] = {}
-        if enabled:
-            consumers: Dict[int, int] = {}
-            for op in self.layers:
-                for t in op.inputs:
-                    consumers[t.tid] = consumers.get(t.tid, 0) + 1
-            index = {id(op): i for i, op in enumerate(self.layers)}
-            for i, op in enumerate(self.layers):
-                if not isinstance(op, SoftmaxDP):
-                    continue
-                prod = op.inputs[0].producer
-                if (isinstance(prod, RnnLinear)
-                        and consumers.get(prod.output.tid) == 1
-                        and id(prod) in index
-                        and self._fusion_ok(prod)):
-                    plan[index[id(prod)]] = None   # folded away
-                    plan[i] = prod                 # loss op runs fused
-        self._fusion_plan = (enabled, plan)
+        consumers: Dict[int, int] = {}
+        for op in self.layers:
+            for t in op.inputs:
+                consumers[t.tid] = consumers.get(t.tid, 0) + 1
+        index = {id(op): i for i, op in enumerate(self.layers)}
+        for i, op in enumerate(self.layers):
+            if not isinstance(op, SoftmaxDP):
+                continue
+            prod = op.inputs[0].producer
+            if (isinstance(prod, RnnLinear)
+                    and consumers.get(prod.output.tid) == 1
+                    and id(prod) in index
+                    and self._fusion_ok(prod)):
+                plan[index[id(prod)]] = None   # folded away
+                plan[i] = prod                 # loss op runs fused
+        self._fusion_plan = plan
         return plan
 
     def _fusion_ok(self, lin) -> bool:
+        """The fused head's whole rule: the backend (the gate every
+        kernel shares, asked only of a model that has such a head, so a
+        CNN never loads the kernels), then shapes, then placement."""
+        from flexflow_tpu.ops.pallas import flash_enabled
+
+        if not flash_enabled():
+            return False
         pc_c, pn = lin.pc.dims
         b, s = lin.inputs[0].shape[0], lin.inputs[0].shape[1]
         d = lin.in_channels

@@ -42,9 +42,6 @@ class TransformerConfig:
     # parameter storage dtype ("bfloat16" = mixed precision with f32
     # masters in the optimizer state; forwarded to FFConfig)
     param_dtype: str = "float32"
-    # Pallas kernel routing policy auto|on|off (forwarded to FFConfig;
-    # ops/pallas/__init__.set_policy)
-    pallas: str = "auto"
     seed: int = 0
     # verification mechanisms (forwarded to FFConfig; SURVEY.md §4)
     params_init: str = "default"
@@ -103,7 +100,6 @@ class TransformerLM(FFModel):
             num_iterations=self.t.num_iterations,
             compute_dtype=self.t.compute_dtype,
             param_dtype=self.t.param_dtype,
-            pallas=self.t.pallas,
             seed=self.t.seed,
             params_init=self.t.params_init,
             print_intermediates=self.t.print_intermediates,

@@ -53,9 +53,6 @@ class RnnConfig:
     # parameter storage dtype ("bfloat16" = mixed precision with f32
     # masters in the optimizer state; forwarded to FFConfig)
     param_dtype: str = "float32"
-    # Pallas kernel routing policy auto|on|off (forwarded to FFConfig;
-    # ops/pallas/__init__.set_policy)
-    pallas: str = "auto"
     seed: int = 0
     # verification mechanisms (forwarded to FFConfig; SURVEY.md §4)
     params_init: str = "default"
@@ -174,7 +171,6 @@ class RnnModel(FFModel):
             num_iterations=self.rnn.num_iterations,
             compute_dtype=self.rnn.compute_dtype,
             param_dtype=self.rnn.param_dtype,
-            pallas=self.rnn.pallas,
             seed=self.rnn.seed,
             params_init=self.rnn.params_init,
             print_intermediates=self.rnn.print_intermediates,

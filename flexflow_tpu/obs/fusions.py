@@ -23,14 +23,15 @@ Per-row floors, by fusion class:
 * ``mxu`` — byte floors cannot see matrix-unit inefficiency, so the
   floor is ``measured * mxu_eff_during_matmul`` (the profile's own
   flops/(peak * mxu_ms)): what the row would take at 100% MXU.
-* ``select_and_scatter`` (raw, no root shapes: unfusable scatter) — the
-  measured Pallas maxpool-backward A/B from ops/pallas/maxpool.py (2.9
-  ms kernel vs 5.0 ms XLA on the two big inception pools, ratio 0.58)
-  prices the floor; the row records the kernel and its predicted win.
+* a row whose root line carries no shapes (``select_and_scatter``, the
+  max-pool backward's unfusable scatter) is priced at its measured time
+  and says so (``unpriced``): the Pallas kernel that once priced it
+  beat the scatter alone and lost both its cells end to end (PERF.md
+  section 6, PR 30).
 
 Every row carries a machine-applied verdict — ``fusable`` (elementwise
 excess XLA could fold into a producer/consumer), ``pallas_worthy``
-(unfusable op with a shipped/known kernel route), or ``irreducible``
+(unvectorized code above its byte floor), or ``irreducible``
 (at its floor, or MXU-internal utilization no byte rewrite recovers).
 
 jax-free on purpose: ``make fusion-smoke`` runs against the committed
@@ -57,11 +58,6 @@ _OPCODE = re.compile(r"([a-z][a-z0-9_\-]*)\(")
 _IN_MULT = {"add": 2.0, "subtract": 2.0, "multiply": 2.0, "divide": 2.0,
             "maximum": 2.0, "minimum": 2.0, "select": 2.25,
             "select-n": 2.25, "select_n": 2.25}
-
-# measured Pallas maxpool-backward / XLA select_and_scatter time ratio
-# (ops/pallas/maxpool.py: 2.9 ms vs 5.0 ms summed over the two big
-# inception pools on v5e) — the floor for unfusable scatter rows
-_SS_PALLAS_RATIO = 2.9 / 5.0
 
 # balanced-tree gradient fanout (ops/fanout.py): an n-way branch sum as
 # one (n+1)-operand fusion moves (n+1) units vs the add_any chain's
@@ -109,15 +105,6 @@ def _price_row(row: dict, mxu_eff: float, hbm_bw: float) -> dict:
         out["note"] = (f"at {mxu_eff:.0%} MXU during matmul; excess is "
                        f"matrix-unit utilization, not HBM traffic")
         return out
-    if name.startswith("select_and_scatter"):
-        out["floor_ms"] = ms * _SS_PALLAS_RATIO
-        out["floor_source"] = "pallas_kernel_measured"
-        out["kernel"] = "pallas_maxpool_bwd"
-        out["predicted_win_ms"] = round(ms * (1 - _SS_PALLAS_RATIO), 3)
-        out["note"] = ("unfusable scatter; floor = measured Pallas "
-                       "maxpool-backward ratio "
-                       f"({_SS_PALLAS_RATIO:.2f}x, ops/pallas/maxpool)")
-        return out
     priced = _root_bytes(root)
     if priced is None:
         # no shapes on the root line: price at measured (excess 0) and
@@ -150,7 +137,7 @@ def _verdict(row: dict) -> str:
         return "irreducible"
     if row["class"] == "mxu":
         return "irreducible"
-    if row["class"] == "raw" or "kernel" in row:
+    if row["class"] == "raw":
         return "pallas_worthy"
     return "fusable"
 

@@ -22,6 +22,9 @@ from __future__ import annotations
 
 from typing import Dict, List
 
+# loaded with the operator (1.3 s of jax.experimental.pallas), not inside
+# the first trace; looked up through the module so a test can patch the gate
+from flexflow_tpu.ops import pallas
 from flexflow_tpu.ops.base import Op, Tensor
 from flexflow_tpu.strategy import ParallelConfig
 
@@ -126,14 +129,13 @@ class MultiHeadAttention(Op):
         multi-device grid, where head/batch sharding is embarrassingly
         parallel), otherwise the XLA streaming-softmax path with GSPMD
         sharding."""
-        from flexflow_tpu.ops.pallas import flash_enabled
         from flexflow_tpu.ops.pallas.flash_attention import \
             flash_attention_packed
         from flexflow_tpu.parallel.ring_attention import blockwise_attention
 
         b, s, _ = q.shape
         h = self.num_heads
-        if flash_enabled():
+        if pallas.flash_enabled():
             nd = self.machine.num_devices if self.machine is not None else 1
             if nd == 1 or len(self.pc.devices) == 1:
                 return flash_attention_packed(q, k, v, h, self.causal)

@@ -26,6 +26,7 @@ from __future__ import annotations
 import math
 from typing import Dict, List
 
+from flexflow_tpu.ops import pallas     # with the operator: ops/attention.py
 from flexflow_tpu.ops.base import Op, Tensor
 from flexflow_tpu.ops.seq_gated import apply_rope, rms_norm, rope_angles
 from flexflow_tpu.strategy import ParallelConfig
@@ -119,7 +120,6 @@ class LatentAttention(Op):
     def forward(self, params, state, xs: List, train: bool):
         import jax.numpy as jnp
 
-        from flexflow_tpu.ops.pallas import flash_enabled
         from flexflow_tpu.ops.pallas.flash_attention import \
             flash_attention_packed
 
@@ -147,7 +147,7 @@ class LatentAttention(Op):
             axis=-1)
         q, k = q.reshape(b, s, -1), k.reshape(b, s, -1)
         v = kv[..., nope:].reshape(b, s, h * vd)
-        if flash_enabled():
+        if pallas.flash_enabled():
             out = flash_attention_packed(q, k, v, h, causal=True)
         else:
             out = causal_attention(q, k, v, h)

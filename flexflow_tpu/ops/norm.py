@@ -163,20 +163,6 @@ class BatchNorm(Op):
             y = jax.nn.relu(y)
         return y, state
 
-    def _use_pallas(self, x) -> bool:
-        """Route the single-device normalize+ReLU through the fused
-        Pallas kernel pair (ops/pallas/bn_act.py): the backward emits dx
-        and both per-channel sums from one pass over (x, dy), where
-        XLA's VJP splits the reductions off the elementwise producer.
-        The statistics (and their VJP chain) stay in XLA either way."""
-        from flexflow_tpu.ops.pallas import bnrelu_enabled
-        from flexflow_tpu.ops.pallas.bn_act import supported
-
-        return (bnrelu_enabled()
-                and supported(*x.shape)
-                and len(self.pc.devices) <= 1
-                and all(d == 1 for d in self.pc.dims))
-
     def forward(self, params, state, xs: List, train: bool):
         import jax
         import jax.numpy as jnp
@@ -200,10 +186,6 @@ class BatchNorm(Op):
         # reductions are read-only and cheap); per-channel vectors are tiny.
         inv = jax.lax.rsqrt(var + self.eps) * params["scale"]
         shift = params["bias"] - mean * inv
-        if self._use_pallas(x):
-            from flexflow_tpu.ops.pallas.bn_act import bn_act
-
-            return bn_act(x, inv, shift, relu=self.relu), state
         y = x * inv.astype(x.dtype) + shift.astype(x.dtype)
         if self.relu:
             y = jax.nn.relu(y)

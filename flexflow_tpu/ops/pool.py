@@ -202,72 +202,12 @@ class Pool2D(Op):
 
         return [P("n", "h", "w", "c")]
 
-    def _use_pallas(self, x) -> bool:
-        """Route single-device LARGE max pools through the Pallas kernel
-        pair (ops/pallas/maxpool.py): backward reads dy + a selection
-        plane instead of running XLA's unvectorized select_and_scatter,
-        and the pool input drops out of the VJP residuals.  Small deep
-        pools (and multi-device grids) keep the XLA path: measured on the
-        compiled Inception step, XLA's fwd reduce_window there rides
-        producer fusions for ~free, which a standalone kernel pass cannot
-        beat (see the maxpool module docstring).
-
-        AVG pools with exactly-tiling windows (stride == kernel, or the
-        global pool) route through ops/pallas/avgpool.py under their own
-        gate — there the backward is a pure block upsample of dy."""
-        if len(self.pc.devices) > 1 or any(d != 1 for d in self.pc.dims):
-            return False
-        _, h, w, _ = self.inputs[0].shape
-        if self.pool_type == POOL_AVG:
-            from flexflow_tpu.ops.pallas import avgpool_enabled
-            from flexflow_tpu.ops.pallas.avgpool import supported as avg_ok
-
-            return (avgpool_enabled()
-                    and avg_ok(self.kernel_h, self.kernel_w, self.stride_h,
-                               self.stride_w, self.padding_h, self.padding_w,
-                               h, w))
-        from flexflow_tpu.ops.pallas import (maxpool_cost_gated,
-                                             maxpool_enabled)
-        from flexflow_tpu.ops.pallas.maxpool import (
-            roofline_predicted_win_ms, supported)
-
-        if not (maxpool_enabled()
-                and supported(self.kernel_h, self.kernel_w, self.stride_h,
-                              self.stride_w, self.padding_h,
-                              self.padding_w, self.pool_type)):
-            return False
-        if maxpool_cost_gated():
-            # --pallas auto: the per-geometry HBM roofline predictor
-            # replaces the old min(h, w) >= 48 size guess — route only
-            # when pricing BOTH the backward win and the forward
-            # sel-plane pass comes out ahead
-            nb, hb, wb, cb = self.inputs[0].shape
-            from flexflow_tpu.sim.cost_model import dtype_bytes as _db
-
-            return roofline_predicted_win_ms(
-                nb, hb, wb, cb, self.kernel_h, self.padding_h,
-                _db(str(self.inputs[0].dtype))) > 0.0
-        return True
-
     def forward(self, params, state, xs: List, train: bool):
         import jax
         import jax.numpy as jnp
         from jax import lax
 
         (x,) = xs
-        if self._use_pallas(x):
-            if self.pool_type == POOL_AVG:
-                from flexflow_tpu.ops.pallas.avgpool import avgpool2d
-
-                return avgpool2d(x, self.kernel_h, self.kernel_w,
-                                 self.stride_h, self.stride_w,
-                                 self.padding_h, self.padding_w,
-                                 relu=self.relu), state
-            from flexflow_tpu.ops.pallas.maxpool import maxpool2d
-
-            return maxpool2d(x, self.kernel_h, self.kernel_w,
-                             self.padding_h, self.padding_w,
-                             relu=self.relu), state
         window = (1, self.kernel_h, self.kernel_w, 1)
         strides = (1, self.stride_h, self.stride_w, 1)
         pads = ((0, 0), (self.padding_h, self.padding_h),

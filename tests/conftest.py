@@ -5,6 +5,7 @@ simulator).  Must run before jax initializes a backend.  The platform is
 pinned through jax.config as well as the tier-1 command's JAX_PLATFORMS=cpu,
 so that a bare ``pytest`` on a machine with a chip never takes the chip."""
 
+import contextlib
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
@@ -69,3 +70,22 @@ def machine1():
     from flexflow_tpu.machine import MachineModel
 
     return MachineModel(devices=jax.devices()[:1])
+
+
+@pytest.fixture
+def pallas_kernels():
+    """``with pallas_kernels():`` opens the one kernel gate
+    (``ops/pallas.flash_enabled``, which follows the backend and so is
+    shut on this CPU mesh): inside the block the flash and fused-CE
+    kernels run, in interpret mode.  The callers look the gate up when
+    an operator is traced, so a model built inside the block takes the
+    kernels and one built outside keeps XLA's paths."""
+    from flexflow_tpu.ops import pallas
+
+    @contextlib.contextmanager
+    def opened():
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(pallas, "flash_enabled", lambda: True)
+            yield
+
+    return opened

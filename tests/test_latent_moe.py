@@ -5,6 +5,7 @@ recomputed blocks, and the model class that ``apps/lm.py`` trains: each
 against plain ``jax.numpy``, and the expert layer's shares against the
 uncut reference of ``benchmarks/reference/moonlight_16b_a3b.py``."""
 
+import contextlib
 import importlib
 import json
 import os
@@ -505,7 +506,7 @@ def _equations(jaxpr):
 
 @pytest.mark.parametrize("attention", ["kernels", "blockwise"])
 def test_a_recomputed_block_keeps_what_the_kernels_name(
-        tiny_model, attention, monkeypatch):
+        tiny_model, attention, monkeypatch, pallas_kernels):
     """With the flash kernels on (interpret mode here, through the gate
     ``flash_enabled`` reads) the differentiated step runs the forward
     kernel once a layer, not twice: each block keeps the kernel's
@@ -514,12 +515,12 @@ def test_a_recomputed_block_keeps_what_the_kernels_name(
     and the step is what a bare ``jax.checkpoint`` gives."""
     from flexflow_tpu import obs
 
-    monkeypatch.setenv("FLEXFLOW_TPU_FLASH",
-                       "1" if attention == "kernels" else "0")
     args = (*tiny_model.abstract_train_state(),
             *[jax.ShapeDtypeStruct((2, 16), jnp.int32)] * 2)
     before = obs.snapshot()["counters"]
-    traced = tiny_model.make_train_step().trace(*args)
+    with (pallas_kernels() if attention == "kernels"
+          else contextlib.nullcontext()):
+        traced = tiny_model.make_train_step().trace(*args)
     after = obs.snapshot()["counters"]
     calls = sorted(_kernel_calls(traced.jaxpr.jaxpr))
     kept = after.get("runtime.kept_results", 0) \
@@ -540,7 +541,8 @@ def test_a_recomputed_block_keeps_what_the_kernels_name(
         == _equations(traced.jaxpr.jaxpr)
 
 
-def test_a_model_without_recompute_blocks_lowers_as_before(monkeypatch):
+def test_a_model_without_recompute_blocks_lowers_as_before(
+        monkeypatch, pallas_kernels):
     """TransformerLM names no block to recompute: its step holds no
     checkpoint, and its plan is empty."""
     from flexflow_tpu.models.transformer import (TransformerConfig,
@@ -563,14 +565,14 @@ def test_a_model_without_recompute_blocks_lowers_as_before(monkeypatch):
     from flexflow_tpu import obs
 
     fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
-    monkeypatch.setenv("FLEXFLOW_TPU_FLASH", "1")
 
     def lowered():
         # without the counter MLIR's symbol table ends a repeated
         # function name with: it moves with what else was lowered
-        return re.sub(r"@(\w+?)_\d+\b", r"@\1",
-                      ff.make_train_step().lower(params, state, opt, toks,
-                                                 toks).as_text())
+        with pallas_kernels():
+            return re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                          ff.make_train_step().lower(
+                              params, state, opt, toks, toks).as_text())
 
     flash = "kernels.flash.pad128.fused"        # 4 heads of 8
     before = obs.snapshot()["counters"].get(flash, 0)

@@ -3,7 +3,6 @@ streaming-softmax reference path (interpret mode on the CPU test mesh —
 the identical kernel code compiles via Mosaic on TPU)."""
 
 import contextlib
-import os
 
 import jax
 import jax.numpy as jnp
@@ -15,22 +14,6 @@ from flexflow_tpu.parallel.ring_attention import blockwise_attention
 
 def _rand(rng, *shape):
     return jnp.asarray(rng.randn(*shape).astype("float32"))
-
-
-@contextlib.contextmanager
-def flash_env(value="1"):
-    """Set FLEXFLOW_TPU_FLASH for the block, restoring any pre-existing
-    value afterwards (a bare pop would clobber a user-set value for the
-    rest of the session)."""
-    prev = os.environ.get("FLEXFLOW_TPU_FLASH")
-    os.environ["FLEXFLOW_TPU_FLASH"] = value
-    try:
-        yield
-    finally:
-        if prev is None:
-            os.environ.pop("FLEXFLOW_TPU_FLASH", None)
-        else:
-            os.environ["FLEXFLOW_TPU_FLASH"] = prev
 
 
 def _dense_attention(q, k, v, causal):
@@ -317,7 +300,7 @@ def test_partial_combine_matches_full():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_flash_path(machine8, causal):
+def test_ring_attention_flash_path(machine8, causal, pallas_kernels):
     """Ring attention on the Pallas partial kernel == global reference,
     values and gradients, on a 4-way sequence mesh."""
     from jax.sharding import Mesh
@@ -330,7 +313,7 @@ def test_ring_attention_flash_path(machine8, causal):
     ref = blockwise_attention(q, k, v, causal)
     gref = jax.grad(lambda q, k, v: (blockwise_attention(q, k, v, causal)
                                      ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-    with flash_env():
+    with pallas_kernels():
         got = ring_attention(q, k, v, mesh, "s", causal)
         gfl = jax.grad(lambda q, k, v: (ring_attention(q, k, v, mesh, "s",
                                                        causal) ** 2).sum(),
@@ -342,7 +325,7 @@ def test_ring_attention_flash_path(machine8, causal):
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_transformer_forward_matches_with_flash_forced(machine8):
+def test_transformer_forward_matches_with_flash_forced(machine8, pallas_kernels):
     """End-to-end: forcing the flash path (shard-mapped over the canonical
     DP grid) must reproduce the default XLA attention loss."""
     from flexflow_tpu.models.transformer import (TransformerConfig,
@@ -361,7 +344,7 @@ def test_transformer_forward_matches_with_flash_forced(machine8):
         return float(loss)
 
     base = run()
-    with flash_env():
+    with pallas_kernels():
         flashed = run()
     assert abs(base - flashed) < 1e-4, (base, flashed)
 
@@ -394,7 +377,7 @@ def test_fused_linear_ce_parity():
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_lm_head_fusion_matches_unfused(machine8):
+def test_lm_head_fusion_matches_unfused(machine8, pallas_kernels):
     """The apply-time RnnLinear->SoftmaxDP fusion must reproduce the
     unfused training loss (here under the shard-mapped DP path)."""
     from flexflow_tpu.models.transformer import (TransformerConfig,
@@ -413,12 +396,12 @@ def test_lm_head_fusion_matches_unfused(machine8):
         return float(loss)
 
     base = run()
-    with flash_env():
+    with pallas_kernels():
         fused = run()
     assert abs(base - fused) < 1e-3, (base, fused)
 
 
-def test_lm_head_fusion_grads_match(machine8):
+def test_lm_head_fusion_grads_match(machine8, pallas_kernels):
     """Gradients through the fused head equal the unfused path."""
     from flexflow_tpu.models.transformer import (TransformerConfig,
                                                  TransformerLM)
@@ -437,14 +420,14 @@ def test_lm_head_fusion_grads_match(machine8):
         return jax.tree.leaves(g)
 
     base = grads()
-    with flash_env():
+    with pallas_kernels():
         fused = grads()
     for a, c in zip(base, fused):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                    rtol=2e-3, atol=2e-3)
 
 
-def test_lm_head_fusion_vocab_tp(machine8):
+def test_lm_head_fusion_vocab_tp(machine8, pallas_kernels):
     """Vocab-TP fused head (c=4 x n=2 grid, per-shard kernels + lse/corr
     combine) == unfused GSPMD loss and grads."""
     from flexflow_tpu.models.transformer import (TransformerConfig,
@@ -460,7 +443,7 @@ def test_lm_head_fusion_vocab_tp(machine8):
                        "int32")
 
     def run(fused):
-        ctx = flash_env() if fused else flash_env("0")
+        ctx = pallas_kernels() if fused else contextlib.nullcontext()
         with ctx:
             tlm = TransformerLM(tcfg, machine8, s)
             params, state = tlm.init(seed=0)
@@ -477,372 +460,115 @@ def test_lm_head_fusion_vocab_tp(machine8):
                                    rtol=2e-3, atol=2e-3)
 
 
-# ---------------------------------------------------------------------------
-# Pallas max-pool backward (ops/pallas/maxpool.py): parity with XLA
-# reduce_window autodiff — including first-max tie-breaking (integer-valued
-# inputs make ties certain) and the fused-ReLU sentinel path.
-
-
-def _ref_maxpool(x, kh, kw, ph, pw, relu):
-    from jax import lax
-
-    y = lax.reduce_window(x, -jnp.inf, lax.max, (1, kh, kw, 1),
-                          (1, 2, 2, 1), ((0, 0), (ph, ph), (pw, pw), (0, 0)))
-    return jax.nn.relu(y) if relu else y
-
-
-@pytest.mark.parametrize("n,h,w,c,k,p,relu", [
-    (2, 9, 9, 3, 3, 0, False),    # odd extents, VALID (Inception pools)
-    (2, 16, 16, 5, 3, 0, True),   # even extents + fused relu
-    (3, 15, 17, 4, 3, 1, True),   # pad 1 (ResNet/DenseNet pool1), h != w
-    (2, 12, 12, 3, 2, 0, False),  # 2x2 (VGG pools)
-    (1, 8, 8, 2, 3, 1, False),    # tiny single-sample
-    (2, 23, 19, 6, 3, 0, True),   # ragged H/W blocks
-])
-def test_maxpool_parity(n, h, w, c, k, p, relu):
-    from flexflow_tpu.ops.pallas.maxpool import maxpool2d
-
-    rng = np.random.RandomState(0)
-    # small-integer inputs: every window has ties, negatives exercise the
-    # relu-clamped sentinel
-    x = jnp.asarray(rng.randint(-3, 4, size=(n, h, w, c)), jnp.float32)
-    g = jnp.asarray(rng.randn(n, *_ref_maxpool(x, k, k, p, p, relu).shape[1:3],
-                              c), jnp.float32)
-
-    def f_pallas(x):
-        return maxpool2d(x, k, k, p, p, relu, interpret=True)
-
-    def f_ref(x):
-        return _ref_maxpool(x, k, k, p, p, relu)
-
-    np.testing.assert_array_equal(np.asarray(f_pallas(x)),
-                                  np.asarray(f_ref(x)))
-    gp = jax.grad(lambda x: jnp.vdot(f_pallas(x), g))(x)
-    gr = jax.grad(lambda x: jnp.vdot(f_ref(x), g))(x)
-    np.testing.assert_allclose(np.asarray(gp), np.asarray(gr),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_maxpool_supported_gate():
-    from flexflow_tpu.ops.pallas.maxpool import supported
-
-    assert supported(3, 3, 2, 2, 0, 0)
-    assert supported(3, 3, 2, 2, 1, 1)
-    assert supported(2, 2, 2, 2, 0, 0)
-    assert not supported(3, 3, 1, 1, 1, 1)        # stride-1 pools stay XLA
-    assert not supported(5, 5, 2, 2, 0, 0)        # unsupported kernel size
-    assert not supported(3, 3, 2, 2, 0, 0, "avg")  # avg pools stay XLA
 
 
 # ---------------------------------------------------------------------------
-# Pallas avg-pool backward (ops/pallas/avgpool.py): the non-overlapping /
-# global geometries where dx is a pure block upsample of dy — parity with
-# the canonical sum/count reduce_window pair under autodiff, including the
-# fused-ReLU mask from the pooled-output residual.
+# The rule for which kernel runs (PR 30): one gate that follows the
+# backend, shapes beside each kernel, no switch.  A convolutional model
+# takes no kernel on one device or on a grid.
 
 
-def _ref_avgpool(x, kh, kw, sh, sw, relu):
-    from jax import lax
+def test_the_kernel_gate_follows_the_backend_and_no_environment(monkeypatch):
+    import os
 
-    ones = jnp.ones_like(x)
-    s = lax.reduce_window(x, 0.0, lax.add, (1, kh, kw, 1), (1, sh, sw, 1),
-                          ((0, 0),) * 4)
-    cnt = lax.reduce_window(ones, 0.0, lax.add, (1, kh, kw, 1),
-                            (1, sh, sw, 1), ((0, 0),) * 4)
-    y = s / cnt
-    return jax.nn.relu(y) if relu else y
-
-
-@pytest.mark.parametrize("relu", [False, True])
-@pytest.mark.parametrize("n,h,w,c,kh,kw,sh,sw", [
-    (2, 8, 8, 16, 8, 8, 1, 1),    # global pool, stride 1 (Inception tail)
-    (4, 8, 8, 3, 2, 2, 2, 2),     # 2x2 exact tiling, ragged C block
-    (2, 12, 9, 24, 3, 3, 3, 3),   # 3x3 tiling, h != w
-])
-def test_avgpool_parity(n, h, w, c, kh, kw, sh, sw, relu):
-    from flexflow_tpu.ops.pallas.avgpool import avgpool2d, supported
-
-    assert supported(kh, kw, sh, sw, 0, 0, h, w)
-    rng = np.random.RandomState(11)
-    x = jnp.asarray(rng.randn(n, h, w, c), jnp.float32)
-
-    def f_pallas(x):
-        return avgpool2d(x, kh, kw, sh, sw, 0, 0, relu, interpret=True)
-
-    def f_ref(x):
-        return _ref_avgpool(x, kh, kw, sh, sw, relu)
-
-    y = f_pallas(x)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(f_ref(x)),
-                               rtol=1e-6, atol=1e-6)
-    g = jnp.asarray(rng.randn(*y.shape), jnp.float32)
-    gp = jax.grad(lambda x: jnp.vdot(f_pallas(x), g))(x)
-    gr = jax.grad(lambda x: jnp.vdot(f_ref(x), g))(x)
-    np.testing.assert_allclose(np.asarray(gp), np.asarray(gr),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_avgpool_supported_gate():
-    from flexflow_tpu.ops.pallas.avgpool import supported
-
-    assert supported(8, 8, 1, 1, 0, 0, 8, 8)       # global, any stride
-    assert supported(2, 2, 2, 2, 0, 0, 12, 12)     # exact tiling
-    assert not supported(3, 3, 1, 1, 1, 1, 35, 35)  # overlap/pad stay XLA
-    assert not supported(3, 3, 3, 3, 0, 0, 10, 10)  # remainder rows
-    assert not supported(2, 2, 2, 2, 0, 0, 12, 12, "max")  # max stays XLA
-
-
-def test_pool2d_avg_routes_through_pallas_when_enabled(monkeypatch):
-    from flexflow_tpu.ops.base import Tensor
-    from flexflow_tpu.ops.pool import POOL_AVG, Pool2D
-    from flexflow_tpu.strategy import ParallelConfig
-
-    monkeypatch.setenv("FLEXFLOW_TPU_AVGPOOL", "1")
-    t = Tensor((2, 8, 8, 16))
-    op = Pool2D("p", ParallelConfig((1, 1, 1, 1), (0,)), t, 8, 8, 1, 1,
-                0, 0, POOL_AVG, relu=True)
-    assert op._use_pallas(None)
-    rng = np.random.RandomState(12)
-    x = jnp.asarray(rng.randn(2, 8, 8, 16), jnp.float32)
-    y_pal, _ = op.forward({}, {}, [x], train=True)
-    monkeypatch.setenv("FLEXFLOW_TPU_AVGPOOL", "0")
-    assert not op._use_pallas(None)
-    y_xla, _ = op.forward({}, {}, [x], train=True)
-    # 1/64 is a power of two: the kernel's constant-scale forward is
-    # bit-equal to the XLA path's sum/count divide here
-    np.testing.assert_array_equal(np.asarray(y_pal), np.asarray(y_xla))
-
-
-# ---------------------------------------------------------------------------
-# Fused batchnorm normalize+ReLU (ops/pallas/bn_act.py): one-pass backward
-# emitting dx plus both per-channel sums — parity with the unfused XLA
-# chain under autodiff for values and all three gradients.
-
-
-def _ref_bn_act(x, inv, shift, relu):
-    y = x * inv.astype(x.dtype) + shift.astype(x.dtype)
-    return jax.nn.relu(y) if relu else y
-
-
-@pytest.mark.parametrize("relu", [False, True])
-@pytest.mark.parametrize("n,h,w,c", [
-    (4, 4, 4, 16),    # single channel block
-    (4, 4, 4, 130),   # ragged C block (gc = 2, 2-lane tail)
-    (8, 1, 1, 7),     # post-flatten-like tiny channels
-])
-def test_bn_act_parity(n, h, w, c, relu):
-    from flexflow_tpu.ops.pallas.bn_act import bn_act, supported
-
-    assert supported(n, h, w, c)
-    rng = np.random.RandomState(13)
-    x = jnp.asarray(rng.randn(n, h, w, c), jnp.float32)
-    inv = jnp.asarray(rng.randn(c), jnp.float32)
-    shift = jnp.asarray(rng.randn(c), jnp.float32)
-    g = jnp.asarray(rng.randn(n, h, w, c), jnp.float32)
-
-    def f_pallas(x, inv, shift):
-        return bn_act(x, inv, shift, relu=relu, interpret=True)
-
-    np.testing.assert_allclose(
-        np.asarray(f_pallas(x, inv, shift)),
-        np.asarray(_ref_bn_act(x, inv, shift, relu)), rtol=1e-6, atol=1e-6)
-    gp = jax.grad(lambda *a: jnp.vdot(f_pallas(*a), g),
-                  argnums=(0, 1, 2))(x, inv, shift)
-    gr = jax.grad(lambda *a: jnp.vdot(_ref_bn_act(*a, relu), g),
-                  argnums=(0, 1, 2))(x, inv, shift)
-    for a, b in zip(gp, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-5)
-
-
-def test_bn_act_supported_gate():
-    from flexflow_tpu.ops.pallas.bn_act import supported
-
-    assert supported(8, 4, 4, 64)
-    # M = 50 has no power-of-two row-block divisor: ragged rows would
-    # pollute the channel-sum accumulators, so the gate refuses
-    assert not supported(2, 5, 5, 64)
-
-
-def test_bn_act_bf16_inputs():
-    from flexflow_tpu.ops.pallas.bn_act import bn_act
-
-    rng = np.random.RandomState(14)
-    x = jnp.asarray(rng.randn(4, 4, 4, 16), jnp.bfloat16)
-    inv = jnp.asarray(rng.randn(16), jnp.float32)
-    shift = jnp.asarray(rng.randn(16), jnp.float32)
-    y = bn_act(x, inv, shift, relu=True, interpret=True)
-    assert y.dtype == jnp.bfloat16
-    np.testing.assert_allclose(
-        np.asarray(y, np.float32),
-        np.asarray(_ref_bn_act(x, inv, shift, True), np.float32),
-        rtol=2e-2, atol=2e-2)
-    gx = jax.grad(lambda x: bn_act(x, inv, shift, relu=True,
-                                   interpret=True).astype(jnp.float32)
-                  .sum())(x)
-    assert gx.dtype == jnp.bfloat16  # cotangents in the primal dtype
-
-
-def test_batchnorm_routes_through_pallas_when_enabled(monkeypatch):
-    """BatchNorm.forward takes the fused kernel under the env gate; loss
-    values, running stats, and the FULL gradient chain (through the
-    folded statistics, not just the elementwise tail) match the XLA
-    path."""
-    from flexflow_tpu.ops.base import Tensor
-    from flexflow_tpu.ops.norm import BatchNorm
-    from flexflow_tpu.strategy import ParallelConfig
-
-    t = Tensor((4, 8, 8, 16))
-    bn = BatchNorm("b", ParallelConfig((1, 1, 1, 1), (0,)), t, relu=True)
-    rng = np.random.RandomState(15)
-    x = jnp.asarray(rng.randn(4, 8, 8, 16), jnp.float32)
-    params = bn.init_params(jax.random.PRNGKey(0))
-    params = {"scale": params["scale"] + 0.3, "bias": params["bias"] - 0.1}
-    state = bn.init_state()
-
-    def run(p):
-        y, st = bn.forward(p, state, [x], train=True)
-        return jnp.sum(y * y), (y, st)
-
-    monkeypatch.setenv("FLEXFLOW_TPU_BNRELU", "1")
-    assert bn._use_pallas(x)
-    (l1, (y1, st1)), g1 = jax.value_and_grad(run, has_aux=True)(params)
-    monkeypatch.setenv("FLEXFLOW_TPU_BNRELU", "0")
-    assert not bn._use_pallas(x)
-    (l2, (y2, st2)), g2 = jax.value_and_grad(run, has_aux=True)(params)
-    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
-                               rtol=1e-5, atol=1e-5)
-    for k in st1:
-        np.testing.assert_array_equal(np.asarray(st1[k]), np.asarray(st2[k]))
-    for k in g1:
-        np.testing.assert_allclose(np.asarray(g1[k]), np.asarray(g2[k]),
-                                   rtol=1e-4, atol=1e-4)
-
-
-def test_pool2d_routes_through_pallas_when_enabled(monkeypatch):
-    """Pool2D.forward takes the kernel path under the env gate and the
-    result matches the XLA path bit-for-bit (interpret mode)."""
-    from flexflow_tpu.ops.base import Tensor
-    from flexflow_tpu.ops.pool import Pool2D
-    from flexflow_tpu.strategy import ParallelConfig
-
-    monkeypatch.setenv("FLEXFLOW_TPU_MAXPOOL", "1")
-    t = Tensor((2, 64, 64, 3))
-    op = Pool2D("p", ParallelConfig((1, 1, 1, 1), (0,)), t, 3, 3, 2, 2,
-                0, 0, relu=True)
-    assert op._use_pallas(None)
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.randint(-2, 3, size=(2, 64, 64, 3)), jnp.float32)
-    y_pal, _ = op.forward({}, {}, [x], train=True)
-    monkeypatch.setenv("FLEXFLOW_TPU_MAXPOOL", "0")
-    assert not op._use_pallas(None)
-    y_xla, _ = op.forward({}, {}, [x], train=True)
-    np.testing.assert_array_equal(np.asarray(y_pal), np.asarray(y_xla))
-
-
-# ---------------------------------------------------------------------------
-# Round-13 routing policy: one --pallas auto|on|off switch (installed by
-# FFModel from FFConfig.pallas) + the per-geometry maxpool cost model
-# that replaces the old min(h, w) >= 48 size guess under auto.
-
-
-def test_set_policy_validates_eagerly():
     from flexflow_tpu.ops import pallas
 
-    before = pallas.get_policy()
-    with pytest.raises(ValueError):
-        pallas.set_policy("sometimes")
-    assert pallas.get_policy() == before
+    assert not pallas.flash_enabled()            # this CPU mesh
+
+    class Untouched(dict):
+        def _read(self, *a):
+            raise AssertionError(f"the kernel gate read the environment: {a}")
+
+        get = __getitem__ = __contains__ = _read
+
+    for backend in ("tpu", "cpu", "gpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        with monkeypatch.context() as patch:
+            patch.setattr(os, "environ", Untouched())
+            assert pallas.flash_enabled() == (backend == "tpu")
+    assert pallas.__all__ == ["KEPT_RESULTS", "flash_attention",
+                              "flash_enabled"]
 
 
-def test_policy_forced_modes(monkeypatch):
-    from flexflow_tpu.ops import pallas
-
-    for var in ("FLEXFLOW_TPU_FLASH", "FLEXFLOW_TPU_MAXPOOL",
-                "FLEXFLOW_TPU_AVGPOOL", "FLEXFLOW_TPU_BNRELU"):
-        monkeypatch.delenv(var, raising=False)
-    try:
-        pallas.set_policy("on")
-        assert pallas.flash_enabled() and pallas.maxpool_enabled()
-        assert pallas.avgpool_enabled() and pallas.bnrelu_enabled()
-        assert not pallas.maxpool_cost_gated()  # forced: no cost model
-        pallas.set_policy("off")
-        assert not (pallas.flash_enabled() or pallas.maxpool_enabled()
-                    or pallas.avgpool_enabled() or pallas.bnrelu_enabled())
-        pallas.set_policy("auto")
-        # CPU backend: TPU-candidate kernels off, pending-measurement
-        # kernels (avgpool/bnrelu) off by design until a TPU run says so
-        assert not pallas.maxpool_enabled()
-        assert not pallas.avgpool_enabled()
-        assert pallas.maxpool_cost_gated()
-    finally:
-        pallas.set_policy("auto")
-
-
-def test_env_vars_override_policy_per_kernel(monkeypatch):
-    from flexflow_tpu.ops import pallas
-
-    try:
-        pallas.set_policy("off")
-        monkeypatch.setenv("FLEXFLOW_TPU_MAXPOOL", "1")
-        assert pallas.maxpool_enabled()          # env beats policy off
-        assert not pallas.maxpool_cost_gated()   # explicit = no gate
-        assert not pallas.avgpool_enabled()      # other kernels stay off
-        pallas.set_policy("on")
-        monkeypatch.setenv("FLEXFLOW_TPU_MAXPOOL", "0")
-        assert not pallas.maxpool_enabled()      # env beats policy on
-        assert pallas.bnrelu_enabled()
-    finally:
-        pallas.set_policy("auto")
-
-
-def test_ffmodel_installs_the_policy(machine1):
+def _traced_cnn_step(build, machine, batch, size):
     from flexflow_tpu.config import FFConfig
-    from flexflow_tpu.model import FFModel
-    from flexflow_tpu.ops import pallas
+    from flexflow_tpu.data.synthetic import _batch_sharding
 
-    try:
-        FFModel(FFConfig(batch_size=8, input_height=16, input_width=16,
-                         num_classes=8, pallas="off"), machine1)
-        assert pallas.get_policy() == "off"
-    finally:
-        pallas.set_policy("auto")
-
-
-def test_maxpool_cost_model_prices_both_sides():
-    from flexflow_tpu.ops.pallas.maxpool import roofline_predicted_win_ms
-
-    # Inception's first big pool (2, 147, 147, 64), 3x3/2 pad 0: in f32
-    # the backward byte saving beats the extra forward sel-plane pass...
-    assert roofline_predicted_win_ms(2, 147, 147, 64, 3, 0, 4) > 0
-    # ...in bf16 it does not (x halves, the bf16 sel plane does not) —
-    # reproducing the measured end-to-end neutrality of the naive swap
-    assert roofline_predicted_win_ms(2, 147, 147, 64, 3, 0, 2) < 0
-    # deeper window, same trend but monotone in the input byte volume
-    assert roofline_predicted_win_ms(2, 147, 147, 64, 3, 0, 4) > \
-        roofline_predicted_win_ms(2, 71, 71, 64, 3, 0, 4)
+    ff = build(FFConfig(batch_size=batch, input_height=size,
+                        input_width=size, num_classes=16,
+                        compute_dtype="bfloat16"), machine)
+    sharding = _batch_sharding(machine)
+    avals = (jax.ShapeDtypeStruct((batch, size, size, 3), jnp.float32,
+                                  sharding=sharding),
+             jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=sharding))
+    return ff, ff.make_train_step().trace(*ff.abstract_train_state(),
+                                          *avals)
 
 
-def test_pool2d_auto_routes_by_predicted_win(monkeypatch):
-    from flexflow_tpu.ops import pallas
-    from flexflow_tpu.ops.base import Tensor
-    from flexflow_tpu.ops.pool import Pool2D
-    from flexflow_tpu.strategy import ParallelConfig
+def _kernel_calls(traced):
+    """The names of a traced step's pallas_calls (in interpret mode a
+    kernel lowers to plain loops, so the lowered text cannot say)."""
+    return [e.params["name"] for e in _top_level_eqns(traced.jaxpr.jaxpr)
+            if e.primitive.name == "pallas_call"]
 
-    monkeypatch.delenv("FLEXFLOW_TPU_MAXPOOL", raising=False)
-    # stand in for the TPU-backend candidacy so auto reaches the model
-    monkeypatch.setattr(pallas, "maxpool_enabled", lambda: True)
-    try:
-        pallas.set_policy("auto")
-        pc = ParallelConfig((1, 1, 1, 1), (0,))
-        op32 = Pool2D("p32", pc, Tensor((2, 147, 147, 64)), 3, 3, 2, 2,
-                      0, 0, relu=False)
-        assert op32._use_pallas(None)        # f32: predicted win
-        op16 = Pool2D("p16", pc, Tensor((2, 147, 147, 64), "bfloat16"),
-                      3, 3, 2, 2, 0, 0, relu=False)
-        assert not op16._use_pallas(None)    # bf16: predicted loss
-        pallas.set_policy("on")
-        assert op16._use_pallas(None)        # forced mode skips the gate
-    finally:
-        pallas.set_policy("auto")
+
+def _pool_instructions(traced):
+    """The result types of the step's pooling instructions, in program
+    order."""
+    import re
+
+    return re.findall(r'"stablehlo\.(?:reduce_window|select_and_scatter)"'
+                      r'.*?\) -> (tensor<[^>]*>)', traced.lower().as_text(),
+                      flags=re.S)
+
+
+def test_alexnet_lowers_the_same_pools_on_one_device_and_on_four(
+        pallas_kernels):
+    """What a one-chip control cell needs: the routing may not depend on
+    the device count.  Three max pools forward (``reduce_window``) and
+    three backward (``select_and_scatter``) either way, and no kernel,
+    with the kernel gate open as it is on a TPU."""
+    from flexflow_tpu.machine import MachineModel
+    from flexflow_tpu.models import build_alexnet
+
+    with pallas_kernels():
+        _, one = _traced_cnn_step(
+            build_alexnet, MachineModel(jax.devices()[:1]), 8, 64)
+        ff, four = _traced_cnn_step(
+            build_alexnet, MachineModel(jax.devices()[:4]), 8, 64)
+    assert all(len(op.pc.devices) == 4 for op in ff.layers)
+    pools = _pool_instructions(one)
+    assert len(pools) == 6 and pools == _pool_instructions(four)
+    for traced in (one, four):
+        assert _kernel_calls(traced) == []
+
+
+def test_inception_lowers_no_kernel(pallas_kernels, machine1):
+    from flexflow_tpu.models import build_inception_v3
+    from flexflow_tpu.ops.pool import POOL_MAX, Pool2D
+
+    with pallas_kernels():
+        ff, traced = _traced_cnn_step(build_inception_v3, machine1, 2, 299)
+    pools = [op.pool_type for op in ff.layers if isinstance(op, Pool2D)]
+    assert len(pools) == 14 and pools.count(POOL_MAX) == 4
+    assert _kernel_calls(traced) == []
+    assert traced.lower().as_text().count(
+        "stablehlo.select_and_scatter") == 4
+
+
+def test_ffconfig_has_no_kernel_switch():
+    import dataclasses
+
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import TransformerConfig
+    from flexflow_tpu.nmt.rnn_model import RnnConfig
+
+    for cls in (FFConfig, TransformerConfig, RnnConfig):
+        assert "pallas" not in {f.name for f in dataclasses.fields(cls)}
+    # an unknown flag and its value are passed over, as the reference
+    # parser does; the flags around it still land
+    cfg = FFConfig.from_args(["-b", "32", "--pallas", "off", "--lr", "0.5"])
+    assert (cfg.batch_size, cfg.learning_rate) == (32, 0.5)
+    assert not hasattr(cfg, "pallas")
+    with pytest.raises(TypeError):
+        FFConfig(pallas="off")

@@ -14,9 +14,7 @@ message and the run exits 1 after the other cases have been tried.
 Cases (issue 21 section 4): flash attention fwd+bwd at the BERT-base
 training shape, at the GPT-2 benchmark cell's own shape (b16 h12 s1024
 d64 causal, bfloat16) and at S=8192 d=64 causal; fused projection+CE at 8k tokens
-x 32k vocab and its vocab-TP partial form; maxpool backward on Inception's
-two large pools; avgpool on the 8x8x2048 global pool; bn_act on two
-Inception activations.
+x 32k vocab and its vocab-TP partial form.
 """
 
 import json
@@ -106,42 +104,6 @@ def case_fused_ce(n, d, v, partial):
     return kern, ref, [x, w, b, labels]
 
 
-def case_maxpool(n, h, w, c):
-    from flexflow_tpu.ops.pallas.maxpool import maxpool2d
-
-    x = _rand(jax.random.PRNGKey(2), (n, h, w, c), jnp.bfloat16)
-    kern = _grads(lambda x: maxpool2d(x, 3, 3, 0, 0, interpret=False), 1)
-    ref = _grads(lambda x: jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-        ((0, 0),) * 4), 1)
-    return kern, ref, [x]
-
-
-def case_avgpool(n, h, w, c):
-    from flexflow_tpu.ops.pallas.avgpool import avgpool2d
-
-    x = _rand(jax.random.PRNGKey(3), (n, h, w, c), jnp.bfloat16)
-    kern = _grads(lambda x: avgpool2d(x, h, w, 1, 1, 0, 0,
-                                      interpret=False), 1)
-    ref = _grads(lambda x: jnp.mean(x.astype(jnp.float32), axis=(1, 2),
-                                    keepdims=True).astype(x.dtype), 1)
-    return kern, ref, [x]
-
-
-def case_bn_act(n, h, w, c):
-    from flexflow_tpu.ops.pallas.bn_act import bn_act
-
-    ks = jax.random.split(jax.random.PRNGKey(4), 3)
-    x = _rand(ks[0], (n, h, w, c), jnp.bfloat16)
-    inv = 1.0 + 0.1 * _rand(ks[1], (c,), jnp.float32)
-    shift = 0.1 * _rand(ks[2], (c,), jnp.float32)
-    kern = _grads(lambda x, i, s: bn_act(x, i, s, relu=True,
-                                         interpret=False), 3)
-    ref = _grads(lambda x, i, s: jnp.maximum(
-        x.astype(jnp.float32) * i + s, 0.0).astype(x.dtype), 3)
-    return kern, ref, [x, inv, shift]
-
-
 CASES = [
     ("flash b16 h12 s512 d64 causal", case_flash, (16, 12, 512, 64, True)),
     ("flash b16 h12 s512 d64 full", case_flash, (16, 12, 512, 64, False)),
@@ -150,16 +112,6 @@ CASES = [
     ("fused_ce n8192 d768 v32768", case_fused_ce, (8192, 768, 32768, False)),
     ("fused_ce partial n8192 d768 v8192 (vocab TP /4)", case_fused_ce,
      (8192, 768, 8192, True)),
-    ("maxpool bwd 256x147x147x64 (Inception pool1)", case_maxpool,
-     (256, 147, 147, 64)),
-    ("maxpool bwd 256x71x71x192 (Inception pool2)", case_maxpool,
-     (256, 71, 71, 192)),
-    ("avgpool bwd 256x8x8x2048 (Inception global pool)", case_avgpool,
-     (256, 8, 8, 2048)),
-    ("bn_act 256x149x149x32 (Inception conv1)", case_bn_act,
-     (256, 149, 149, 32)),
-    ("bn_act 256x17x17x768 (Inception mixed 17x17)", case_bn_act,
-     (256, 17, 17, 768)),
 ]
 
 
