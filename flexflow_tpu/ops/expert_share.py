@@ -16,13 +16,26 @@ out, here and wherever the result goes next.  Nothing is dropped at a
 per-expert capacity: the step's (token, held expert) pairs, expert by
 expert, fill one buffer of ``rows_capacity`` rows (twice the balanced
 load by default) whichever experts they fall on, the three products of
-each gated feed-forward run as grouped products over the ragged groups
-(``jax.lax.ragged_dot``, which XLA's TPU backend compiles to one grouped
-Mosaic matmul a product and every other backend to masked dense ones),
+each gated feed-forward run as grouped products over the ragged groups,
 and the rows go back to their tokens weighted.  Pairs beyond the buffer
 are counted in ``state["dropped"]``, which must read 0.  Rows move by
 gathers in both passes (a token reads the row of each of its held
 experts, or a row of zeros): the TPU serialises scatters.
+
+Which path the grouped products take (:func:`grouped_gated_ffn`) is
+observed, never set.  On a TPU (``ops/pallas.flash_enabled``), at widths
+of whole lanes and a buffer a row tile divides, the nine products of a
+layer (three forward, six backward) are the ``ff_gmm`` kernels of
+``ops/pallas/grouped_mm.py`` under one ``custom_vjp``: tiles that take a
+group's whole matrix, results written once in the type the next
+operation reads, zeros in the rows of no group.  Everywhere else they
+are ``jax.lax.ragged_dot``, which XLA's TPU backend compiles to a
+grouped Mosaic matmul of its own at 512 x 512 x 128 tiles (2.5 to 5
+times slower at a held expert's shapes, and it leaves the rows of no
+group as they were: PERF.md section 6, PR 31) and every other backend to
+masked dense products.  Both round where the other does.  A traced layer
+counts ``kernels.gmm.ff_gmm.<rows>x<depth>x<columns>`` or
+``kernels.gmm.ragged_dot``.
 
 Grid ('e', 'n').  Only (1, 1) is implemented: the exchange that 'e' > 1
 needs (tokens to the chip that holds their expert and back) does not
@@ -36,6 +49,7 @@ import math
 from typing import Dict, List, Tuple
 
 from flexflow_tpu import obs
+from flexflow_tpu.ops import pallas     # with the operator: ops/attention.py
 from flexflow_tpu.ops.base import Op, Tensor
 from flexflow_tpu.strategy import ParallelConfig
 
@@ -221,14 +235,26 @@ def route_rows(held_gates, rows_capacity: int):
 
 def grouped_gated_ffn(rows, group_sizes, w_gate, w_up, w_down):
     """The gated SiLU feed-forward of each group's expert on its rows:
-    three grouped products with float32 accumulation."""
+    three grouped products with float32 accumulation (nine with their
+    backward), through the ``ff_gmm`` kernels where the backend is a TPU
+    and the shapes are theirs, else ``jax.lax.ragged_dot``."""
     import jax
     import jax.numpy as jnp
 
+    w_gate, w_up, w_down = (w.astype(rows.dtype)
+                            for w in (w_gate, w_up, w_down))
+    if pallas.flash_enabled():
+        from flexflow_tpu.ops.pallas import grouped_mm
+
+        made = grouped_mm.gated_ffn(rows, group_sizes, w_gate, w_up, w_down)
+        if made is not None:
+            y, tiles = made
+            obs.count("kernels.gmm.ff_gmm." + "x".join(map(str, tiles)))
+            return y
     obs.count("kernels.gmm.ragged_dot")
 
     def gmm(a, w):
-        return jax.lax.ragged_dot(a, w.astype(a.dtype), group_sizes,
+        return jax.lax.ragged_dot(a, w, group_sizes,
                                   preferred_element_type=jnp.float32)
 
     h = (jax.nn.silu(gmm(rows, w_gate)) * gmm(rows, w_up)).astype(rows.dtype)

@@ -10,7 +10,11 @@ What a kernel must show to be in this package: a benchmark cell it won on
 the chip, end to end, named here.  ``flash_attention.py`` won
 ``gpt2_small.train_1chip_b16_s1024`` (+45.8%, PR 27) and carries
 ``moonlight_16b_a3b.train_1chip_b2_s8192``; ``fused_ce.py`` is what lets
-those cells' vocabulary-sized heads fit beside their activations.  The
+those cells' vocabulary-sized heads fit beside their activations;
+``grouped_mm.py`` (``ff_gmm``, ``ff_gmm_t``, ``ff_gmm_dw``: the grouped
+products of the experts a chip holds) won the Moonlight cell, +11.3%
+(PR 31: a layer's product 0.49-0.58 ms where XLA's own grouped matmul
+under ``jax.lax.ragged_dot`` took 0.94-2.3, 85.8 -> 31.4 ms a step).  The
 max-pool, avg-pool and batch-norm kernels that lost their cells left in
 PR 30 (PERF.md section 6).
 
@@ -18,7 +22,8 @@ Which path an operator takes is decided from what the code observes and
 never by a switch: the backend (:func:`flash_enabled`, the one gate every
 caller shares) and the shapes and types, by the rules that live beside
 each kernel (``flash_attention._layout``, ``_pick_block``;
-``FFModel._fusion_ok``).  A new kernel joins the same way.
+``grouped_mm._pick_tiles``; ``FFModel._fusion_ok``).  A new kernel joins
+the same way.
 
 Kernels run compiled (Mosaic) on TPU; interpreter mode is for the CPU test
 suite, whose ``pallas_kernels`` fixture (tests/conftest.py) patches the

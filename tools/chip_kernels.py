@@ -1,6 +1,7 @@
 """Run every Pallas kernel through Mosaic at the shapes the models use.
 
     python tools/chip_kernels.py               # on the chip
+    python tools/chip_kernels.py gmm           # the grouped products alone
 
 The CPU suite runs these kernels in interpret mode at test shapes, and the
 compiled branch picks other block shapes (flash_attention._make_flash,
@@ -14,7 +15,13 @@ message and the run exits 1 after the other cases have been tried.
 Cases (issue 21 section 4): flash attention fwd+bwd at the BERT-base
 training shape, at the GPT-2 benchmark cell's own shape (b16 h12 s1024
 d64 causal, bfloat16) and at S=8192 d=64 causal; fused projection+CE at 8k tokens
-x 32k vocab and its vocab-TP partial form.
+x 32k vocab and its vocab-TP partial form; the grouped products of a held
+expert at the Moonlight cell's shape (24 576 buffer rows, 8 groups at
+uneven loads that fill half of it, 2048 x 1408, bfloat16): each ``ff_gmm``
+form against the ``jax.lax.ragged_dot`` call autodiff makes in its place,
+at the tiles the shapes pick and at the candidates of ``GMM_TILES``, 20
+pipelined calls each, and the whole gated feed-forward, forward and
+backward, through the kernels and through ``ragged_dot``.
 """
 
 import json
@@ -115,6 +122,166 @@ CASES = [
 ]
 
 
+# grouped products: (rows, d, d_ff, groups) of the Moonlight cell, and the
+# row / depth / column tiles timed beside the rule's own (None); a depth or
+# column entry of 0 is the whole dimension, and a fourth entry the pieces
+# a row tile that a group boundary cuts is walked in (the row forms';
+# as many rows as the tile: the whole tile a visit)
+GMM_SHAPE = (24576, 2048, 1408, 8)
+GMM_TILES = (None, (256, 0, 0), (512, 0, 0), (1024, 0, 0), (512, 0, 0, 512),
+             (512, 0, 768), (512, 1024, 0))
+
+
+def _gmm_inputs():
+    m, d, f, g = GMM_SHAPE
+    rng = np.random.RandomState(31)
+    # loads of 0.85-1.17 of the balanced 1536 rows, as the cell's routers
+    # give (PERF.md section 6, PR 28)
+    sizes = np.round(m / 2 / g * rng.uniform(0.85, 1.17, g)).astype(np.int32)
+    ks = jax.random.split(jax.random.PRNGKey(2), 6)
+    bf = jnp.bfloat16
+    return dict(
+        sizes=jnp.asarray(sizes), rows=_rand(ks[0], (m, d), bf),
+        h=_rand(ks[1], (m, f), bf), d_y=_rand(ks[2], (m, d), bf),
+        d_gate=_rand(ks[3], (m, f), bf),
+        w_up=_rand(ks[4], (g, d, f), bf, 0.03),
+        w_down=_rand(ks[5], (g, f, d), bf, 0.03))
+
+
+def _pipelined(fn, args, calls=20):
+    """ms a call with ``calls`` of them in flight: the device's time, not
+    the host's dispatch, where a call takes a millisecond."""
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        outs = [fn(*args) for _ in range(calls)]
+        jax.block_until_ready(outs)
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return round(best * 1e3, 4)
+
+
+def run_gmm():
+    """One record a product of the layer: ragged_dot's ms, the kernel's
+    at each candidate, their agreement; then the whole feed-forward."""
+    from flexflow_tpu.ops import expert_share
+    from flexflow_tpu.ops.pallas import grouped_mm as gm
+
+    x = _gmm_inputs()
+    sizes, f32, pieces = x["sizes"], jnp.float32, gm._SUB_ROWS
+    m, d, f, g = GMM_SHAPE
+
+    def ragged(a, w):
+        return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=f32)
+
+    def pulled(which, a, w, ct):
+        """What autodiff of ``ragged(a, w)`` runs for one operand's
+        gradient under a float32 cotangent."""
+        if which == "a":
+            return jax.vjp(lambda a_: ragged(a_, w), a)[1](
+                ct.astype(f32))[0]
+        return jax.vjp(lambda w_: ragged(a, w_), w)[1](ct.astype(f32))[0]
+
+    products = [
+        # name, form, kernel args, out type, reference
+        ("up: rows x w_up -> f32", gm.ff_gmm, (x["rows"], x["w_up"]), f32,
+         lambda: ragged(x["rows"], x["w_up"])),
+        ("down: h x w_down -> bf16", gm.ff_gmm, (x["h"], x["w_down"]),
+         None, lambda: ragged(x["h"], x["w_down"]).astype(jnp.bfloat16)),
+        ("d_h: d_y x w_down^T", gm.ff_gmm_t, (x["d_y"], x["w_down"]), None,
+         lambda: pulled("a", x["h"], x["w_down"], x["d_y"])),
+        ("d_rows: d_gate x w_up^T", gm.ff_gmm_t, (x["d_gate"], x["w_up"]),
+         None, lambda: pulled("a", x["rows"], x["w_up"], x["d_gate"])),
+        ("dw_up: rows^T x d_gate", gm.ff_gmm_dw, (x["rows"], x["d_gate"]),
+         None, lambda: pulled("w", x["rows"], x["w_up"], x["d_gate"])),
+        ("dw_down: h^T x d_y", gm.ff_gmm_dw, (x["h"], x["d_y"]), None,
+         lambda: pulled("w", x["h"], x["w_down"], x["d_y"])),
+    ]
+    failed = 0
+    live = int(sizes.sum())
+    for name, form, args, out_dtype, ref in products:
+        rec = {"case": f"gmm {name}", "group_sizes": sizes.tolist()}
+        rows_out = form is not gm.ff_gmm_dw
+        try:
+            ref_fn = jax.jit(ref)
+            want = np.asarray(jax.block_until_ready(ref_fn()), np.float32)
+            rec["ragged_dot_ms"] = _pipelined(ref_fn, ())
+            if rows_out:
+                # what ragged_dot leaves in the rows of no group
+                rec["ragged_dot_tail_is_zero"] = not want[live:].any()
+                want = want[:live]
+            rec["kernel_ms"], rec["rel_err"] = {}, {}
+            k, n = args[0].shape[1], want.shape[-1]
+            for cand in GMM_TILES:
+                tiles = cand and (cand[0], cand[1] or k, cand[2] or n)
+                if tiles and rows_out and k % tiles[1]:
+                    continue        # a depth tile divides the depth
+                gm._SUB_ROWS = cand[3] if cand and len(cand) > 3 else pieces
+                fn = jax.jit(lambda a, b, t=tiles: form(
+                    a, b, sizes, out_dtype, t))
+                got = np.asarray(jax.block_until_ready(fn(*args)),
+                                 np.float32)
+                key = "rule" if cand is None else "x".join(map(str, tiles)) \
+                    + (f"/{cand[3]}" if len(cand) > 3 else "")
+                rec["kernel_ms"][key] = _pipelined(fn, args)
+                if rows_out:
+                    if got[live:].any():
+                        raise RuntimeError(f"{key}: tail rows not 0")
+                    got = got[:live]
+                rec["rel_err"][key] = round(_rel_err(got, want), 5)
+                if rec["rel_err"][key] > 3e-2:
+                    raise RuntimeError(f"{key}: disagrees with ragged_dot")
+            rec["ok"] = True
+        except Exception as e:
+            failed += 1
+            rec.update(ok=False, error=type(e).__name__,
+                       message=str(e)[-3000:])
+            traceback.print_exc(limit=3)
+        finally:
+            gm._SUB_ROWS = pieces
+        print(json.dumps(rec), flush=True)
+
+    # the whole feed-forward with its backward, as HeldExperts calls it
+    ws = [_rand(k, sh, f32, 0.03) for k, sh in zip(
+        jax.random.split(jax.random.PRNGKey(3), 3),
+        ((g, d, f), (g, d, f), (g, f, d)))]
+
+    # the cotangent of a row of no group is 0, as combine's backward
+    # makes it; the rows' gradient is compared over the groups' rows
+    d_y = jnp.where(jnp.arange(m)[:, None] < live, x["d_y"], 0)
+
+    def step(rows, *ws):
+        loss, (d_rows, *d_ws) = jax.value_and_grad(
+            lambda rows, *ws: (expert_share.grouped_gated_ffn(
+                rows, sizes, *ws).astype(f32) * d_y).sum(),
+            (0, 1, 2, 3))(rows, *ws)
+        return (loss, d_rows[:live], *d_ws)
+
+    rec = {"case": "gmm gated feed-forward, forward + backward"}
+    try:
+        gate = expert_share.pallas.flash_enabled
+        got_fn = jax.jit(step)
+        got = jax.block_until_ready(got_fn(x["rows"], *ws))
+        rec["kernel_ms"] = _pipelined(got_fn, (x["rows"], *ws), 5)
+        expert_share.pallas.flash_enabled = lambda: False
+        try:
+            want_fn = jax.jit(lambda *a: step(*a))
+            want = jax.block_until_ready(want_fn(x["rows"], *ws))
+            rec["ragged_dot_ms"] = _pipelined(want_fn, (x["rows"], *ws), 5)
+        finally:
+            expert_share.pallas.flash_enabled = gate
+        rec["rel_err"] = [round(_rel_err(a, b), 5) for a, b in zip(
+            jax.tree.leaves(got), jax.tree.leaves(want))]
+        rec["ok"] = max(rec["rel_err"]) <= 3e-2
+        failed += not rec["ok"]
+    except Exception as e:
+        failed += 1
+        rec.update(ok=False, error=type(e).__name__, message=str(e)[-3000:])
+        traceback.print_exc(limit=3)
+    print(json.dumps(rec), flush=True)
+    return failed
+
+
 def _rel_err(a, b):
     a = np.asarray(a, np.float32)
     b = np.asarray(b, np.float32)
@@ -155,14 +322,15 @@ def run_case(name, make, shape, tol=3e-2):
 
 
 def main(argv):
-    if argv:
-        raise SystemExit(f"chip_kernels: takes no arguments, got {argv}")
+    if argv not in ([], ["gmm"]):
+        raise SystemExit(f"chip_kernels: takes no argument or 'gmm' (the "
+                         f"grouped products alone), got {argv}")
     if jax.default_backend() != "tpu":
         raise SystemExit(
             f"chip_kernels: backend {jax.default_backend()!r} is not a "
             f"TPU; Mosaic compiles only there")
     failed = 0
-    for name, make, shape in CASES:
+    for name, make, shape in ([] if argv else CASES):
         try:
             rec = run_case(name, make, shape)
             rec["ok"] = True
@@ -172,6 +340,7 @@ def main(argv):
                    "message": str(e)[-3000:]}
             traceback.print_exc(limit=3)
         print(json.dumps(rec), flush=True)
+    failed += run_gmm()
     return 1 if failed else 0
 
 
