@@ -10,11 +10,14 @@ transformers); flags follow the house style of the reference parsers
     python -m flexflow_tpu.apps.lm --experts 8 --strategy moe.json
     python -m flexflow_tpu.apps.lm --model-config \
         benchmarks/configs/moonlight_16b_a3b.json --preset rehearsal -b 2 -s 32
+    python -m flexflow_tpu.apps.lm --model-config \
+        benchmarks/configs/granite_4_0_h_micro.json --preset rehearsal -b 2
 
 ``--model-config`` names a file of a public ``config.json``'s keys
 (``model_type`` ``deepseek_v3``: latent attention and expert layers,
-``models/latent_moe.py``; ``--preset`` lays one of the file's own named
-groups of keys over it); without it the flags describe a
+``models/latent_moe.py``; ``granitemoehybrid``: Mamba-2 and grouped-query
+attention layers, ``models/hybrid_ssm.py``; ``--preset`` lays one of the
+file's own named groups of keys over it); without it the flags describe a
 ``TransformerLM``.  Data is synthetic random tokens; labels are the tokens themselves (causal
 models learn next-token prediction via the internal shift; see
 TransformerLM).
@@ -224,24 +227,49 @@ def _main_pipelined(cfg, machine, log) -> dict:
             "tokens_per_sec": tput * cfg.seq_length, "elapsed_s": elapsed}
 
 
-def _main_latent_moe(cfg, argv, machine, log) -> dict:
-    """--model-config path: a ``deepseek_v3`` configuration file through
-    ``LatentMoELM`` and ``FFModel.fit``.  The file gives the model; only
-    the flags the command line really carries (-b, -s, -i, --lr, --dtype,
-    --seed, -obs-dir) lie over it."""
-    import json
-
+def _latent_moe(config, over):
     from flexflow_tpu.models.latent_moe import LatentMoEConfig, LatentMoELM
+
+    t = LatentMoEConfig.from_config(config, **over)
+    return t, LatentMoELM, (
+        f"{t.num_layers} blocks, hidden {t.hidden_size}, experts "
+        f"[{t.experts_held[0]}, {t.experts_held[1]}) of "
+        f"{t.router_outputs} held")
+
+
+def _hybrid_ssm(config, over):
+    from flexflow_tpu.models.hybrid_ssm import HybridSSMConfig, HybridSSMLM
+
+    t = HybridSSMConfig.from_config(config, **over)
+    return t, HybridSSMLM, (
+        f"{len(t.layer_types)} blocks ({t.layer_types.count('mamba')} "
+        f"mamba, {t.layer_types.count('attention')} attention), hidden "
+        f"{t.hidden_size}, {t.num_attention_heads} query heads on "
+        f"{t.num_key_value_heads}, chunk {t.mamba_chunk_size}")
+
+
+#: ``model_type`` of a configuration file -> the class that builds it
+MODEL_TYPES = {"deepseek_v3": _latent_moe, "granitemoehybrid": _hybrid_ssm}
+
+
+def _main_model_config(cfg, argv, machine, log) -> dict:
+    """--model-config path: a configuration file of a public
+    ``config.json``'s keys through the model class of its ``model_type``
+    and ``FFModel.fit``.  The file gives the model; only the flags the
+    command line really carries (-b, -s, -i, --lr, --dtype, --seed,
+    -obs-dir) lie over it."""
+    import json
 
     with open(cfg._model_config) as f:
         config = json.load(f)
     preset = getattr(cfg, "_preset", "")
     if preset:
         config.update(config[preset])
-    if config.get("model_type") != "deepseek_v3":
+    build = MODEL_TYPES.get(config.get("model_type"))
+    if build is None:
         raise SystemExit(f"--model-config: model_type "
                          f"{config.get('model_type')!r}; this driver builds "
-                         f"deepseek_v3 files only")
+                         f"{sorted(MODEL_TYPES)} files only")
     if getattr(cfg, "_strategy_file", "") \
             or getattr(cfg, "_pipeline_stages", 0):
         raise SystemExit("--model-config takes no --strategy or "
@@ -266,22 +294,19 @@ def _main_latent_moe(cfg, argv, machine, log) -> dict:
             f"(1, ..) only; training on one of {machine.num_devices} "
             f"devices")
         machine = MachineModel(jax.devices()[:1])
-    t = LatentMoEConfig.from_config(config, **over)
+    t, model_class, what = build(config, over)
     if t.seq_length > int(config.get("max_position_embeddings",
                                      t.seq_length)):
         raise SystemExit(f"{t.seq_length} positions, the configuration has "
                          f"{config['max_position_embeddings']}")
-    model = LatentMoELM(t, machine)
-    log(f"LM: {config.get('name', cfg._model_config)}, {t.num_layers} "
-        f"blocks, hidden {t.hidden_size}, experts "
-        f"[{t.experts_held[0]}, {t.experts_held[1]}) of "
-        f"{t.router_outputs} held, seq {t.seq_length}, vocab "
-        f"{t.vocab_size}, batch {t.batch_size}, {machine.num_devices} "
-        f"devices")
+    model = model_class(t, machine)
+    log(f"LM: {config.get('name', cfg._model_config)}, {what}, seq "
+        f"{t.seq_length}, vocab {t.vocab_size}, batch {t.batch_size}, "
+        f"{machine.num_devices} devices")
     data = synthetic_lm_batches(machine, t.batch_size, t.seq_length,
                                 t.vocab_size, seed=t.seed)
     out = model.fit(data, log=log,
-                    rebuild=lambda ff_cfg, m: LatentMoELM(t, m))
+                    rebuild=lambda ff_cfg, m: model_class(t, m))
     out["tokens_per_sec"] = (out.get("images_per_sec") or 0.0) \
         * t.seq_length
     out.pop("params", None)
@@ -294,7 +319,7 @@ def main(argv=None, log=print) -> dict:
     cfg = parse_args(argv)
     machine = MachineModel()
     if getattr(cfg, "_model_config", ""):
-        return _main_latent_moe(cfg, argv, machine, log)
+        return _main_model_config(cfg, argv, machine, log)
     sf = getattr(cfg, "_strategy_file", "")
     loaded_strategies = Strategy.load(sf) if sf else None
     if loaded_strategies is not None:

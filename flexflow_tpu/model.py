@@ -315,13 +315,23 @@ class FFModel:
     # ---- sequence-model builders (transformer/NMT op family) ----------
 
     def embed(self, name, input, vocab_size, embed_size,
-              param_key: str = None, init_std: float = 0.05) -> Tensor:
+              param_key: str = None, init_std: float = 0.05,
+              multiplier: float = 1.0) -> Tensor:
         from flexflow_tpu.ops.embed import Embed
 
         return self._add(Embed(name, self._pc(name, 1), input, vocab_size,
                                embed_size, param_key,
                                compute_dtype=self.config.compute_dtype,
-                               init_std=init_std))
+                               init_std=init_std, multiplier=multiplier))
+
+    def tied_head(self, name, input, embed: Tensor,
+                  logits_scaling: float = 1.0) -> Tensor:
+        """The vocabulary projection through the matrix of the embedding
+        that produced ``embed`` (one parameter under one key)."""
+        from flexflow_tpu.ops.embed import TiedHead
+
+        return self._add(TiedHead(name, self._pc(name, 2), input,
+                                  embed.producer, logits_scaling))
 
     def pos_embed(self, name, input) -> Tensor:
         from flexflow_tpu.ops.seq_common import PosEmbed
@@ -333,10 +343,11 @@ class FFModel:
 
         return self._add(LayerNormSeq(name, self._pc(name, 2), input))
 
-    def add_seq(self, name, x: Tensor, y: Tensor) -> Tensor:
+    def add_seq(self, name, x: Tensor, y: Tensor,
+                scale: float = 1.0) -> Tensor:
         from flexflow_tpu.ops.seq_common import AddSeq
 
-        return self._add(AddSeq(name, self._pc(name, 2), [x, y]))
+        return self._add(AddSeq(name, self._pc(name, 2), [x, y], scale))
 
     def attention(self, name, input, num_heads,
                   causal: bool = False) -> Tensor:
@@ -379,6 +390,30 @@ class FFModel:
         return self._add(LatentAttention(
             name, self._pc(name, 3), input, num_heads, kv_rank, nope_dim,
             rope_dim, v_dim, rope_theta, eps))
+
+    def grouped_query_attention(self, name, input, num_heads, num_kv_heads,
+                                head_dim, scale) -> Tensor:
+        from flexflow_tpu.ops.attention import GroupedQueryAttention
+
+        return self._add(GroupedQueryAttention(
+            name, self._pc(name, 3), input, num_heads, num_kv_heads,
+            head_dim, scale))
+
+    def ssm_mixer(self, name, input, num_heads, head_dim, d_state, d_conv,
+                  chunk, conv_bias: bool = True,
+                  eps: float = 1e-5) -> Tensor:
+        """A Mamba-2 mixer as its three operators (ops/ssm.py):
+        ``<name>_in``, ``<name>_scan`` and ``<name>_out``."""
+        from flexflow_tpu.ops.ssm import SSMIn, SSMOut, SSMScan
+
+        first = SSMIn(name + "_in", self._pc(name + "_in", 2), input,
+                      num_heads, head_dim, d_state, d_conv, conv_bias)
+        self._add(first)
+        y = self._add(SSMScan(name + "_scan", self._pc(name + "_scan", 2),
+                              first.xbc, first.delta, num_heads, head_dim,
+                              d_state, chunk))
+        return self._add(SSMOut(name + "_out", self._pc(name + "_out", 2),
+                                y, first.z, input.shape[2], eps))
 
     def sigmoid_router(self, name, input, n_router, top_k, scale,
                        bias_update_rate: float = 1e-3) -> Tensor:
@@ -767,7 +802,7 @@ class FFModel:
         b_, s_, d_ = x.shape
         xf = x.reshape(b_ * s_, d_)
         labf = labels.reshape(-1)
-        w = lin_params["kernel"]
+        xf, w = lin.head_operands(lin_params, xf)
         bias = lin_params.get("bias")
         if bias is None:        # a head without bias: the kernel adds 0
             bias = jnp.zeros((lin.out_channels,), jnp.float32)

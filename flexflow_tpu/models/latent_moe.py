@@ -30,7 +30,7 @@ from typing import Dict, Optional, Tuple
 
 from flexflow_tpu.config import FFConfig
 from flexflow_tpu.machine import MachineModel
-from flexflow_tpu.model import FFModel
+from flexflow_tpu.models.next_token import NextTokenLM, sgd_settings
 from flexflow_tpu.strategy import Strategy
 
 
@@ -87,17 +87,12 @@ class LatentMoEConfig:
         kw = {k: v for k, v in config.items() if k in own
               and k not in ("ff", "learning_rate")}
         kw["experts_held"] = tuple(config["experts_held"])
-        opt = config.get("optimizer", {})
-        if opt.get("kind", "sgd") != "sgd" or opt.get("weight_decay", 0.0):
-            raise ValueError("token models train under plain SGD without "
-                             "weight decay (FFModel.make_sgd_step)")
-        if "learning_rate" in opt:
-            kw["learning_rate"] = float(opt["learning_rate"])
+        kw.update(sgd_settings(config))
         kw.update(overrides)
         return cls(**kw)
 
 
-class LatentMoELM(FFModel):
+class LatentMoELM(NextTokenLM):
     def __init__(self, t_config: LatentMoEConfig = None,
                  machine: Optional[MachineModel] = None,
                  strategies: Optional[Strategy] = None):
@@ -153,28 +148,3 @@ class LatentMoELM(FFModel):
         logits = self.seq_linear("lm_head", x, t.vocab_size, use_bias=False)
         self.softmax_seq("softmax", logits, self.labels)
         self.loss_op = self.layers[-1]
-
-    def loss_fn(self, params, state, tokens, labels, train: bool = True):
-        """Mean next-token cross-entropy: position i predicts
-        ``labels[i + 1]`` and the last position has no target, as
-        ``TransformerLM.loss_fn`` shifts them.  No balance loss."""
-        import jax
-        import jax.numpy as jnp
-
-        labels = jnp.concatenate(
-            [labels[:, 1:],
-             jnp.full((labels.shape[0], 1), -1, labels.dtype)], axis=1)
-        inputs = {self.tokens.tid: tokens, self.labels.tid: labels}
-        values, new_state = self.apply(params, state, inputs, train)
-        op = self.loss_op
-        with jax.named_scope(op.name):
-            total = op.loss(values[op.output.tid],
-                            values[op.labels_tensor.tid])
-        return total / (self.t.batch_size * (self.t.seq_length - 1)), \
-            new_state
-
-    def make_train_step(self):
-        return self.make_sgd_step(self.t.learning_rate)
-
-    def init_opt_state(self, params):
-        return self.master_opt_state(params)

@@ -15,6 +15,11 @@ Execution paths:
     parallel/ring_attention.py) — K/V blocks rotate on neighbor links, O(S/P)
     memory per chip.
 
+
+:class:`GroupedQueryAttention` is the causal layer of the 2023-on decoder
+blocks: more query heads than key-value heads, no bias, no positions of
+its own and a softmax scale the model gives.
+
 New capability relative to the reference (which has no attention ops,
 SURVEY.md §2.6); cited rows: CP/ring-attention, SP."""
 
@@ -192,3 +197,136 @@ class MultiHeadAttention(Op):
 
     def param_bytes(self) -> int:
         return 4 * (4 * self.d_model * self.d_model + self.d_model)
+
+
+def grouped_causal_attention(q, k, v, num_heads: int, num_kv_heads: int,
+                             scale: float):
+    """softmax(scale q k^T, causal) v in plain XLA, query heads
+    ``g j .. g j + g - 1`` reading key-value head ``j``: q (B, S, H*d), k
+    and v (B, S, KV*d) -> (B, S, H*d).  The path off the TPU (CPU tests,
+    tiny sizes): the score matrix is whole, and no key is repeated."""
+    import jax
+    import jax.numpy as jnp
+
+    b, s, _ = q.shape
+    g = num_heads // num_kv_heads
+    qh = q.reshape(b, s, num_kv_heads, g, -1)
+    kh, vh = (x.reshape(b, s, num_kv_heads, -1) for x in (k, v))
+    scores = jnp.einsum("bqjgd,bkjd->bjgqk", qh, kh,
+                        preferred_element_type=jnp.float32) * scale
+    mask = jnp.tril(jnp.ones((s, s), bool))
+    p = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("bjgqk,bkjd->bqjgd", p.astype(v.dtype), vh,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, s, -1).astype(q.dtype)
+
+
+class GroupedQueryAttention(Op):
+    """Causal attention with ``num_heads`` query heads on ``num_kv_heads``
+    key-value heads (query heads ``g j .. g j + g - 1`` read key-value head
+    ``j``), ``softmax(scale q k^T + causal mask) v`` with ``scale`` a
+    given number, no bias and no positions of any kind (a model that
+    rotates its queries does so before).  On the TPU the scores run in
+    the flash kernels, which take one key a query head: k and v are
+    repeated to ``num_heads`` heads in HBM first (``attn.kv_groups``
+    counts the copies a key gets; PERF.md section 3 has the bytes).
+    Grid ('s', 'h', 'n') as the attention operator's, of which only
+    (1, 1, 1) is implemented."""
+
+    AXIS_NAMES = ("s", "h", "n")
+
+    def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
+                 num_heads: int, num_kv_heads: int, head_dim: int,
+                 scale: float):
+        super().__init__(name, pc, [input])
+        assert input.ndim == 3
+        if num_heads % num_kv_heads:
+            raise ValueError(f"op {name!r}: {num_heads} query heads do not "
+                             f"divide over {num_kv_heads} key-value heads")
+        self.d_model = input.shape[2]
+        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.scale = float(scale)
+        self.output = Tensor(input.shape, input.dtype, self, name)
+
+    def _shapes(self) -> Dict:
+        d, hd = self.d_model, self.head_dim
+        return {"wq": (d, self.num_heads * hd),
+                "wk": (d, self.num_kv_heads * hd),
+                "wv": (d, self.num_kv_heads * hd),
+                "wo": (self.num_heads * hd, d)}
+
+    def init_params(self, rng) -> Dict:
+        import jax
+
+        shapes = self._shapes()
+        keys = jax.random.split(rng, len(shapes))
+        init = jax.nn.initializers.glorot_uniform()
+        return {k: init(key, shape, "float32")
+                for key, (k, shape) in zip(keys, shapes.items())}
+
+    def param_specs(self):
+        from jax.sharding import PartitionSpec as P
+
+        return {k: P(None, None) for k in self._shapes()}
+
+    def output_spec(self):
+        from jax.sharding import PartitionSpec as P
+
+        return P("n", "s", None)
+
+    def regrid_input_specs(self):
+        from jax.sharding import PartitionSpec as P
+
+        return [P("n", "s", None)]
+
+    def validate_partitioning(self):
+        super().validate_partitioning()
+        if any(p != 1 for p in self.pc.dims):
+            raise ValueError(
+                f"op {self.name!r}: grouped-query attention runs on the "
+                f"grid (1, 1, 1) only; {self.pc.dims} (sequence, head or "
+                f"batch parts) is not implemented")
+
+    def forward(self, params, state, xs: List, train: bool):
+        import jax.numpy as jnp
+
+        from flexflow_tpu import obs
+        from flexflow_tpu.ops.pallas.flash_attention import \
+            flash_attention_packed
+
+        (x,) = xs
+        b, s, _ = x.shape
+        h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+
+        def proj(a, w):
+            return jnp.einsum("bsd,de->bse", a, w.astype(a.dtype),
+                              preferred_element_type=jnp.float32
+                              ).astype(a.dtype)
+
+        q, k, v = (proj(x, params[w]) for w in ("wq", "wk", "wv"))
+        obs.count("attn.kv_groups", h // kv, level=True)
+        if pallas.flash_enabled():
+            def repeat(a):      # (B, S, KV*hd) -> (B, S, H*hd)
+                a = a.reshape(b, s, kv, 1, hd)
+                return jnp.broadcast_to(
+                    a, (b, s, kv, h // kv, hd)).reshape(b, s, h * hd)
+
+            out = flash_attention_packed(q, repeat(k), repeat(v), h,
+                                         causal=True, scale=self.scale)
+        else:
+            out = grouped_causal_attention(q, k, v, h, kv, self.scale)
+        return proj(out.astype(x.dtype), params["wo"]), state
+
+    def cost_signature(self) -> tuple:
+        return (self.num_heads, self.num_kv_heads, self.head_dim, self.scale)
+
+    def flops_per_sample(self) -> float:
+        s = self.output.shape[1]
+        proj = sum(2.0 * a * b_ for a, b_ in self._shapes().values())
+        # a query at position i meets i + 1 keys
+        attn = 4.0 * self.num_heads * self.head_dim * (s + 1) / 2
+        return s * (proj + attn)
+
+    def param_bytes(self) -> int:
+        return 4 * sum(a * b_ for a, b_ in self._shapes().values())

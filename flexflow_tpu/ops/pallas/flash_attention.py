@@ -566,13 +566,16 @@ def _should_interpret() -> bool:
 
 @functools.lru_cache(maxsize=None)
 def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
-                block_k, interpret, with_lse=False, packed=False):
+                block_k, interpret, with_lse=False, packed=False,
+                scale=None):
     """Build a custom-VJP flash op specialized for one static configuration
     (shapes/dtypes/blocks are Python constants closed over by the kernels;
     the VJP residuals are pure arrays), and name its variant.  ``q_shape``
     and ``k_shape`` are (B, H, S, hd) whatever the arrays' layout; ``dv``
     is the value's head width (v, the result and their gradients), which
-    latent attention sets apart from the query/key width.
+    latent attention sets apart from the query/key width; ``scale`` is
+    what the scores are multiplied by, ``1/sqrt(hd)`` unless a model
+    gives its own number.
 
     With ``with_lse`` the op returns ``(out, lse)`` — the *partial*
     attention form used by ring/context parallelism, where per-chunk
@@ -582,7 +585,7 @@ def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
     ds = p (dp - delta + g_lse) — no kernel changes."""
     b, h, sq, d = q_shape
     sk = k_shape[2]
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     heads, hd, hdv, layout = _layout(h, d, dv)
     bq = (_pick_block(sq, interpret) if block_q is None
           else min(block_q, _round_up(sq, 8)))
@@ -712,17 +715,17 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
 
 
 def flash_attention_packed(q, k, v, num_heads, causal=False, block_q=None,
-                           block_k=None, interpret=None):
+                           block_k=None, interpret=None, scale=None):
     """:func:`flash_attention` on the projections' own layout: q and k are
     (B, S, H*d) with head h in columns ``h*d:(h+1)*d``, v is (B, S, H*dv)
     and so is the result — no (B,S,H,d) <-> (B,H,S,d) transpose on either
-    side."""
+    side.  ``scale`` multiplies the scores in place of ``1/sqrt(d)``."""
     def bhsd(x):
         b, s, width = x.shape
         return (b, num_heads, s, width // num_heads)
 
     return _call(q, k, v, bhsd(q), bhsd(k), v.shape[-1] // num_heads, causal,
-                 block_q, block_k, interpret, packed=True)
+                 block_q, block_k, interpret, packed=True, scale=scale)
 
 
 def flash_attention_partial(q, k, v, causal=False, block_q=None,

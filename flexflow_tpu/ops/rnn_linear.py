@@ -67,11 +67,16 @@ class RnnLinear(Op):
             return (self.in_channels, self.out_channels, "no_bias")
         return (self.in_channels, self.out_channels)
 
+    def head_operands(self, params, x):
+        """The projection's input and its (d, vocabulary) matrix, as the
+        plain and the fused head both read them."""
+        return x, params["kernel"]
+
     def forward(self, params, state, xs: List, train: bool):
         import jax.numpy as jnp
 
-        (x,) = xs
-        y = jnp.einsum("bld,dv->blv", x, params["kernel"].astype(x.dtype),
+        x, kernel = self.head_operands(params, xs[0])
+        y = jnp.einsum("bld,dv->blv", x, kernel.astype(x.dtype),
                        preferred_element_type=jnp.float32)
         if self.use_bias:
             y = y + params["bias"]

@@ -69,12 +69,20 @@ class LayerNormSeq(_SeqElementwise):
 
 
 class AddSeq(_SeqElementwise):
-    def __init__(self, name: str, pc: ParallelConfig, inputs: List[Tensor]):
+    """``x + scale * y``: a residual sum, ``scale`` a published
+    ``residual_multiplier`` on the branch (1 leaves it as it is)."""
+
+    def __init__(self, name: str, pc: ParallelConfig, inputs: List[Tensor],
+                 scale: float = 1.0):
         super().__init__(name, pc, inputs)
         assert len(inputs) == 2 and inputs[0].shape == inputs[1].shape
+        self.scale = float(scale)
         self.output = Tensor(inputs[0].shape, inputs[0].dtype, self, name)
 
     def forward(self, params, state, xs: List, train: bool):
+        if self.scale != 1.0:       # one rounding, of the sum
+            x, y = (v.astype("float32") for v in xs)
+            return (x + self.scale * y).astype(xs[0].dtype), state
         return xs[0] + xs[1], state
 
     def flops_per_sample(self) -> float:
