@@ -778,7 +778,11 @@ class FFModel:
         pc_c, pn = lin.pc.dims
         b, s = lin.inputs[0].shape[0], lin.inputs[0].shape[1]
         d = lin.in_channels
-        if d > 4096:  # VMEM-oversized d: unfused
+        if d > 4096:
+            # beyond it the backward's tile shrinks in 64 MiB of VMEM
+            # (fused_ce._pick_tiles: 512 x 128 at d 5120, 256 x 128 at
+            # 8192) until its sum through HBM no longer hides behind the
+            # products; no such head has been timed: unfused
             return False
         if b * s < 2048:
             # small token counts (e.g. NMT's 640-token chunks) leave the

@@ -10,7 +10,11 @@ What a kernel must show to be in this package: a benchmark cell it won on
 the chip, end to end, named here.  ``flash_attention.py`` won
 ``gpt2_small.train_1chip_b16_s1024`` (+45.8%, PR 27) and carries
 ``moonlight_16b_a3b.train_1chip_b2_s8192``; ``fused_ce.py`` is what lets
-those cells' vocabulary-sized heads fit beside their activations;
+the token cells' vocabulary-sized heads fit beside their activations
+(Granite's logits would be 6.6 GB), and since PR 33, with one backward
+kernel for dX, dW and db and tiles picked from the width, won
+``gpt2_small.train_1chip_b16_s1024`` (+13.2%) and
+``granite_4_0_h_micro.train_1chip_b2_s8192_ref2`` (+5.9%);
 ``grouped_mm.py`` (``ff_gmm``, ``ff_gmm_t``, ``ff_gmm_dw``: the grouped
 products of the experts a chip holds) won the Moonlight cell, +11.3%
 (PR 31: a layer's product 0.49-0.58 ms where XLA's own grouped matmul
@@ -22,7 +26,8 @@ Which path an operator takes is decided from what the code observes and
 never by a switch: the backend (:func:`flash_enabled`, the one gate every
 caller shares) and the shapes and types, by the rules that live beside
 each kernel (``flash_attention._layout``, ``_pick_block``;
-``grouped_mm._pick_tiles``; ``FFModel._fusion_ok``).  A new kernel joins
+``grouped_mm._pick_tiles``; ``FFModel._fusion_ok`` and
+``fused_ce._pick_tiles``).  A new kernel joins
 the same way.
 
 Kernels run compiled (Mosaic) on TPU; interpreter mode is for the CPU test

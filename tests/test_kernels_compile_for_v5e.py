@@ -1,0 +1,53 @@
+"""The fused head's kernels compiled by Mosaic for a described TPU v5e,
+at the widths the benchmark's cells run and at the widest the fusion
+takes: interpret mode says nothing about what the chip's compiler
+accepts (VMEM above all), and a compile here costs no chip time.  Nothing
+runs: a pass is not a measurement."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("d,v,dtype", [
+    (768, 50257, "bfloat16"),      # gpt2_small
+    (2048, 100352, "bfloat16"),    # granite_4_0_h_micro (moonlight: 20480)
+    (4096, 32000, "bfloat16"),     # the widest head _fusion_ok lets in
+    (2048, 32000, "float32"),
+])
+def test_fused_head_compiles_at_the_tiles_the_shapes_pick(one_chip, d, v,
+                                                          dtype):
+    from flexflow_tpu.ops.pallas import fused_ce as ce
+    n = 16384
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def step(x, w, b, labels):
+        loss = lambda x, w, b: ce.fused_linear_ce(
+            x, w, b, labels, interpret=False).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w, b)
+
+    text = jax.jit(step).lower(
+        shape((n, d), dtype), shape((d, v), jnp.float32),
+        shape((v,), jnp.float32), shape((n,), jnp.int32)
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2, \
+        "forward and one backward kernel"
+    logits = [m for m in re.findall(r"\w+\[(\d+),(\d+)\]", text)
+              if int(m[0]) >= n and int(m[1]) >= v]
+    assert not logits, "an array of tokens x vocabulary reached HBM"

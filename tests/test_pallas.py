@@ -349,32 +349,186 @@ def test_transformer_forward_matches_with_flash_forced(machine8, pallas_kernels)
     assert abs(base - flashed) < 1e-4, (base, flashed)
 
 
-def test_fused_linear_ce_parity():
-    from flexflow_tpu.ops.pallas.fused_ce import fused_linear_ce
+# (n, v, block_n, block_v): token blocks x vocabulary blocks of the grid
+CE_TILES = [
+    (40, 100, 16, 16),    # 3 x 7, neither size a multiple of its tile
+    (40, 100, 40, 104),   # 1 x 1
+    (40, 100, 40, 16),    # 1 x 7: dW and db are written once and never read
+    (40, 100, 16, 104),   # 3 x 1: the sum stays in the output block
+    (48, 96, 16, 32),     # 3 x 3, both sizes whole tiles
+    (40, 100, 8, 56),     # 5 x 2
+]
+
+
+@pytest.mark.parametrize("interpreter", ["generic", "hbm"])
+@pytest.mark.parametrize("form", ["plain", "partial"])
+@pytest.mark.parametrize("n,v,block_n,block_v", CE_TILES)
+def test_fused_linear_ce_parity(n, v, block_n, block_v, form, interpreter):
+    """Forward and the one backward kernel against log_softmax, under a
+    cotangent that differs row by row.  The backward sums dW and db over
+    the token blocks through HBM (an input aliased to the output): the
+    ``hbm`` interpreter models that buffer as the chip has it, the
+    generic one (what every CPU run of a model takes) keeps the two
+    apart and the kernel reads its earlier sum from the output block.
+    ``partial``: the vocabulary-sharded form, labels outside the slice
+    (below 0, in the padded tail, beyond it) and a cotangent on ``lse``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from flexflow_tpu.ops.pallas.fused_ce import (fused_linear_ce,
+                                                  fused_linear_ce_partial)
 
     rng = np.random.RandomState(7)
-    n, d, v = 40, 24, 100
+    d = 24
     x = jnp.asarray(rng.randn(n, d), "float32")
     w = jnp.asarray(rng.randn(d, v) * 0.1, "float32")
     b = jnp.asarray(rng.randn(v) * 0.1, "float32")
-    lab = jnp.asarray(rng.randint(0, v, (n,)), "int32")
+    partial = form == "partial"
+    lab = jnp.asarray(rng.randint(-v if partial else 0,
+                                  2 * v if partial else v, (n,)), "int32")
+    lab = lab.at[0].set(v + 1) if partial else lab   # in the padded tail
+    interpret = True if interpreter == "generic" else pltpu.InterpretParams()
+    wgt = jnp.arange(1.0, n + 1)    # weighted cotangents exercise g scaling
+    wgt_lse = jnp.cos(jnp.arange(n, dtype=jnp.float32))
 
     def ref(x, w, b):
-        lp = jax.nn.log_softmax(x @ w + b, axis=-1)
-        return -jnp.take_along_axis(lp, lab[:, None], axis=1)[:, 0]
+        logits = x @ w + b
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        inside = (lab >= 0) & (lab < v)
+        corr = jnp.take_along_axis(
+            logits, jnp.clip(lab, 0, v - 1)[:, None], axis=1)[:, 0]
+        return lse - jnp.where(inside, corr, 0.0), lse
 
-    got = fused_linear_ce(x, w, b, lab, block_n=16, block_v=16)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref(x, w, b)),
-                               rtol=1e-5, atol=1e-5)
-    wgt = jnp.arange(1.0, n + 1)  # weighted cotangent exercises g scaling
-    g1 = jax.grad(lambda x, w, b: (fused_linear_ce(
-        x, w, b, lab, block_n=16, block_v=16) * wgt).sum(),
-        argnums=(0, 1, 2))(x, w, b)
-    g2 = jax.grad(lambda x, w, b: (ref(x, w, b) * wgt).sum(),
-                  argnums=(0, 1, 2))(x, w, b)
+    def kernel(x, w, b):
+        if partial:
+            return fused_linear_ce_partial(x, w, b, lab, block_n=block_n,
+                                           block_v=block_v,
+                                           interpret=interpret)
+        return fused_linear_ce(x, w, b, lab, block_n=block_n,
+                               block_v=block_v, interpret=interpret), 0.0
+
+    def weighted(f):
+        def loss(x, w, b):
+            nll, lse = f(x, w, b)
+            return (nll * wgt).sum() + \
+                ((lse * wgt_lse).sum() if partial else 0.0)
+        return loss
+
+    for got, want in zip(kernel(x, w, b)[:1 + partial], ref(x, w, b)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    g1 = jax.grad(weighted(kernel), argnums=(0, 1, 2))(x, w, b)
+    g2 = jax.grad(weighted(ref), argnums=(0, 1, 2))(x, w, b)
     for a, c in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                    rtol=1e-4, atol=1e-4)
+
+
+def test_fused_head_step_holds_two_kernels_and_no_logits(machine1,
+                                                         pallas_kernels):
+    """A model's loss and gradients through the fused head: the forward
+    kernel and ONE backward kernel (dX, dW and db from each logits tile),
+    no array of tokens x vocabulary outside them, and one count a trace
+    of the tiles that ran."""
+    from flexflow_tpu import obs
+    from flexflow_tpu.models.transformer import (TransformerConfig,
+                                                 TransformerLM)
+
+    vocab, tokens = 200, 8 * 256
+    tcfg = TransformerConfig(batch_size=8, seq_length=256, num_layers=1,
+                             d_model=16, num_heads=4, d_ff=32,
+                             vocab_size=vocab, causal=True)
+    toks = jnp.zeros((8, 256), "int32")
+    counted = ("kernels.ce.fwd.1024x256", "kernels.ce.fused_bwd.1024x256")
+    before = [obs.snapshot()["counters"].get(c, 0) for c in counted]
+    with pallas_kernels():
+        tlm = TransformerLM(tcfg, machine1)
+        params, state = tlm.init(seed=0)
+        closed = jax.make_jaxpr(jax.grad(
+            lambda p: tlm.loss_fn(p, state, toks, toks, train=True)[0]))(
+                params)
+    after = [obs.snapshot()["counters"].get(c, 0) for c in counted]
+    assert after == [n + 1 for n in before], (before, after)
+    eqns = list(_top_level_eqns(closed.jaxpr))
+    head = [e.params["name"] for e in eqns
+            if e.primitive.name == "pallas_call"
+            and e.params["name"].startswith("ff_ce_")]
+    assert head == ["ff_ce_fwd", "ff_ce_bwd"], head
+    for e in eqns:
+        for var in e.outvars:
+            shape = getattr(var.aval, "shape", ())
+            assert not (any(s >= tokens for s in shape)
+                        and any(vocab <= s < tokens for s in shape)), \
+                (e.primitive.name, shape)
+
+
+@pytest.mark.parametrize("d", [768, 2048, 4096])
+def test_fused_ce_tiles_fit_the_vmem_budget(d):
+    """The tile rule is a function of shapes and types: within the VMEM
+    the kernels ask Mosaic for (64 MiB, as the flash and grouped-product
+    kernels), whole lanes, a tile of at most 1024 x 1024 logits, the
+    forward wide and the backward tall, and a backward sweep of the
+    vocabulary of one block or at least four.
+    (tests/test_kernels_compile_for_v5e.py hands the picks to Mosaic.)"""
+    from flexflow_tpu.ops.pallas import fused_ce as ce
+    assert ce._VMEM_LIMIT_BYTES == 64 * 1024 * 1024
+    for n, v in ((16384, 50257), (16384, 100352), (16384, 20480),
+                 (2048, 32000), (4096, 384), (2048, 200)):
+        for itemsize in (2, 4):
+            fwd = ce._pick_tiles(n, v, d, itemsize, backward=False)
+            bwd = ce._pick_tiles(n, v, d, itemsize, backward=True)
+            assert ce._fwd_bytes(*fwd, d, itemsize) <= ce._VMEM_LIMIT_BYTES
+            assert ce._bwd_bytes(*bwd, d, itemsize) <= ce._VMEM_LIMIT_BYTES
+            for bn, bv in (fwd, bwd):
+                assert bn % 128 == 0 and bv % 128 == 0, (fwd, bwd)
+                assert bn * bv <= ce._TILE_ELEMENTS
+            sweep = -(-v // bwd[1])
+            assert sweep == 1 or sweep >= ce._MIN_SWEEP, (v, bwd)
+    # the three token cells (bfloat16, 16 384 tokens)
+    cells = {768: ((512, 2048), (1024, 1024)),
+             2048: ((512, 2048), (1024, 512)),
+             4096: ((512, 2048), (512, 256))}
+    assert (ce._pick_tiles(16384, 50257, d, 2, backward=False),
+            ce._pick_tiles(16384, 50257, d, 2, backward=True)) == cells[d]
+
+
+def test_fused_ce_refuses_a_compiled_sweep_of_two_or_three_blocks():
+    """Forced tiles that would have the compiled backward fetch a block of
+    dW whose write-back may still be in flight (read wrong on the chip at
+    a sweep of two, PR 33) are refused while the call is traced; the
+    interpreters, which have no write in flight, take them."""
+    from flexflow_tpu.ops.pallas.fused_ce import fused_linear_ce
+
+    x = jax.ShapeDtypeStruct((256, 128), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((128, 256), jnp.float32)
+    b = jax.ShapeDtypeStruct((256,), jnp.float32)
+    lab = jax.ShapeDtypeStruct((256,), jnp.int32)
+
+    def grads(block_v, interpret):
+        return jax.eval_shape(jax.grad(lambda x, w, b, lab: fused_linear_ce(
+            x, w, b, lab, block_n=128, block_v=block_v,
+            interpret=interpret).sum(), argnums=(0, 1, 2)), x, w, b, lab)
+
+    with pytest.raises(ValueError, match="in flight"):
+        grads(128, False)          # a sweep of two
+    grads(256, False)              # one block: the sum stays resident
+    grads(128, True)
+
+
+@pytest.mark.parametrize("n,block_n", [
+    (16384, 1024), (2048, 1024),
+    (2100, 256),    # 1024 pads to 3072 and 512 to 2560: over a tenth
+    (2400, 512),    # 1024 pads to 3072; 512 to 2560, 160 of 2400
+    (5000, 1024),   # 5120: 120 rows of padding
+    (100, 128),     # under a lane tile: one block of 128
+])
+def test_fused_ce_token_block_steps_down_when_padding_passes_a_tenth(
+        n, block_n):
+    from flexflow_tpu.ops.pallas import fused_ce as ce
+    # the backward takes the tallest token block the axis allows; the
+    # forward stops at the tile's 1024 x 1024 beside its 2048 columns
+    assert ce._pick_tiles(n, 50257, 768, 2, backward=True)[0] == block_n
+    assert ce._pick_tiles(n, 50257, 768, 2, backward=False)[0] == \
+        min(block_n, 512)
 
 
 def test_lm_head_fusion_matches_unfused(machine8, pallas_kernels):

@@ -2,6 +2,7 @@
 
     python tools/chip_kernels.py               # on the chip
     python tools/chip_kernels.py gmm           # the grouped products alone
+    python tools/chip_kernels.py ce            # the fused head alone
 
 The CPU suite runs these kernels in interpret mode at test shapes, and the
 compiled branch picks other block shapes (flash_attention._make_flash,
@@ -15,7 +16,11 @@ message and the run exits 1 after the other cases have been tried.
 Cases (issue 21 section 4): flash attention fwd+bwd at the BERT-base
 training shape, at the GPT-2 benchmark cell's own shape (b16 h12 s1024
 d64 causal, bfloat16) and at S=8192 d=64 causal; fused projection+CE at 8k tokens
-x 32k vocab and its vocab-TP partial form; the grouped products of a held
+x 32k vocab and its vocab-TP partial form, and at the three token cells'
+own shapes (16 384 tokens at d 768 / V 50 257, d 2048 / V 100 352, d 2048
+/ V 20 480, and a quarter of the second as the partial form), where the
+forward is timed alone as well and the XLA reference walks the tokens in
+chunks of 2048 (its float32 logits would be 6.6 GB whole); the grouped products of a held
 expert at the Moonlight cell's shape (24 576 buffer rows, 8 groups at
 uneven loads that fill half of it, 2048 x 1408, bfloat16): each ``ff_gmm``
 form against the ``jax.lax.ragged_dot`` call autodiff makes in its place,
@@ -79,7 +84,10 @@ def case_flash(b, h, s, d, causal):
     return kern, _grads(xla, 3), args
 
 
-def case_fused_ce(n, d, v, partial):
+def case_fused_ce(n, d, v, partial, chunk=None):
+    """``chunk``: the XLA reference takes the tokens that many at a time
+    (``lax.map``; its logits never stand whole), where all of them at
+    once would not fit beside the gradients."""
     from flexflow_tpu.ops.pallas.fused_ce import (fused_linear_ce,
                                                   fused_linear_ce_partial)
 
@@ -100,14 +108,23 @@ def case_fused_ce(n, d, v, partial):
             logits, jnp.clip(labels, 0, v - 1)[:, None], axis=-1)[:, 0]
         return lse - jnp.where(inside, corr, 0.0), lse
 
-    if partial:
-        kern = _grads(lambda x, w, b, l: fused_linear_ce_partial(
-            x, w, b, l, interpret=False), 3)
-        ref = _grads(ref_stats, 3)
-    else:
-        kern = _grads(lambda x, w, b, l: fused_linear_ce(
-            x, w, b, l, interpret=False), 3)
-        ref = _grads(lambda x, w, b, l: ref_stats(x, w, b, l)[0], 3)
+    def ref_chunked(x, w, b, labels):
+        # recomputed in the backward: a chunk's logits are not kept
+        nll, lse = jax.lax.map(
+            jax.checkpoint(lambda c: ref_stats(c[0], w, b, c[1])),
+            (x.reshape(-1, chunk, d), labels.reshape(-1, chunk)))
+        return nll.reshape(n), lse.reshape(n)
+
+    stats = ref_chunked if chunk else ref_stats
+    form = fused_linear_ce_partial if partial else fused_linear_ce
+
+    def forward(x, w, b, labels):
+        return form(x, w, b, labels, interpret=False)
+
+    kern = _grads(forward, 3)
+    kern.forward = forward      # run_case times it alone as well
+    ref = _grads(stats if partial
+                 else lambda x, w, b, l: stats(x, w, b, l)[0], 3)
     return kern, ref, [x, w, b, labels]
 
 
@@ -119,6 +136,18 @@ CASES = [
     ("fused_ce n8192 d768 v32768", case_fused_ce, (8192, 768, 32768, False)),
     ("fused_ce partial n8192 d768 v8192 (vocab TP /4)", case_fused_ce,
      (8192, 768, 8192, True)),
+]
+# the token cells' own heads (gpt2_small, granite_4_0_h_micro,
+# moonlight_16b_a3b) and a quarter of Granite's as a vocabulary shard
+CE_CASES = [
+    ("fused_ce n16384 d768 v50257", case_fused_ce,
+     (16384, 768, 50257, False, 2048)),
+    ("fused_ce n16384 d2048 v100352", case_fused_ce,
+     (16384, 2048, 100352, False, 2048)),
+    ("fused_ce n16384 d2048 v20480", case_fused_ce,
+     (16384, 2048, 20480, False, 2048)),
+    ("fused_ce partial n16384 d2048 v25088 (vocab TP /4)", case_fused_ce,
+     (16384, 2048, 25088, True, 2048)),
 ]
 
 
@@ -301,6 +330,7 @@ def run_case(name, make, shape, tol=3e-2):
     from flexflow_tpu.utils.profiling import pallas_kernel_calls
 
     kern, ref, args = make(*shape)
+    forward = getattr(kern, "forward", None)
     rec = {"case": name}
     t0 = time.perf_counter()
     compiled = jax.jit(kern).lower(*args).compile()
@@ -315,6 +345,13 @@ def run_case(name, make, shape, tol=3e-2):
                                       jax.tree.leaves(want))]
     rec["kernel_ms"] = round(_timed(compiled, args) * 1e3, 3)
     rec["xla_ref_ms"] = round(_timed(jax.jit(ref), args) * 1e3, 3)
+    if forward is not None:
+        # the forward alone, and by difference the backward; a few calls
+        # in flight, so the device's time and not the host's dispatch
+        rec["forward_ms"] = _pipelined(jax.jit(forward), args, 5)
+        rec["forward_backward_ms"] = _pipelined(compiled, args, 5)
+        rec["backward_ms"] = round(
+            rec["forward_backward_ms"] - rec["forward_ms"], 4)
     if max(rec["rel_err"]) > tol:
         raise RuntimeError(f"kernel disagrees with its XLA reference: "
                            f"relative errors {rec['rel_err']} > {tol}")
@@ -322,15 +359,17 @@ def run_case(name, make, shape, tol=3e-2):
 
 
 def main(argv):
-    if argv not in ([], ["gmm"]):
-        raise SystemExit(f"chip_kernels: takes no argument or 'gmm' (the "
-                         f"grouped products alone), got {argv}")
+    if argv not in ([], ["gmm"], ["ce"]):
+        raise SystemExit(f"chip_kernels: takes no argument, 'gmm' (the "
+                         f"grouped products alone) or 'ce' (the fused "
+                         f"head alone), got {argv}")
     if jax.default_backend() != "tpu":
         raise SystemExit(
             f"chip_kernels: backend {jax.default_backend()!r} is not a "
             f"TPU; Mosaic compiles only there")
     failed = 0
-    for name, make, shape in ([] if argv else CASES):
+    cases = {"gmm": [], "ce": CE_CASES}.get("".join(argv), CASES + CE_CASES)
+    for name, make, shape in cases:
         try:
             rec = run_case(name, make, shape)
             rec["ok"] = True
@@ -340,7 +379,8 @@ def main(argv):
                    "message": str(e)[-3000:]}
             traceback.print_exc(limit=3)
         print(json.dumps(rec), flush=True)
-    failed += run_gmm()
+    if argv != ["ce"]:
+        failed += run_gmm()
     return 1 if failed else 0
 
 
