@@ -12,11 +12,15 @@ transformers); flags follow the house style of the reference parsers
         benchmarks/configs/moonlight_16b_a3b.json --preset rehearsal -b 2 -s 32
     python -m flexflow_tpu.apps.lm --model-config \
         benchmarks/configs/granite_4_0_h_micro.json --preset rehearsal -b 2
+    python -m flexflow_tpu.apps.lm --model-config \
+        benchmarks/configs/laguna_s_2_1.json --preset rehearsal -b 2 -s 32
 
 ``--model-config`` names a file of a public ``config.json``'s keys
 (``model_type`` ``deepseek_v3``: latent attention and expert layers,
 ``models/latent_moe.py``; ``granitemoehybrid``: Mamba-2 and grouped-query
-attention layers, ``models/hybrid_ssm.py``; ``--preset`` lays one of the
+attention layers, ``models/hybrid_ssm.py``; ``laguna``: sliding-window and
+full attention layers over a softmax top-k expert layer,
+``models/laguna.py``; ``--preset`` lays one of the
 file's own named groups of keys over it); without it the flags describe a
 ``TransformerLM``.  Data is synthetic random tokens; labels are the tokens themselves (causal
 models learn next-token prediction via the internal shift; see
@@ -248,8 +252,24 @@ def _hybrid_ssm(config, over):
         f"{t.num_key_value_heads}, chunk {t.mamba_chunk_size}")
 
 
+def _laguna(config, over):
+    from flexflow_tpu.models.laguna import LagunaConfig, LagunaLM
+
+    t = LagunaConfig.from_config(config, **over)
+    kinds = t.layer_types[:t.num_layers]
+    return t, LagunaLM, (
+        f"{t.num_layers} blocks ({kinds.count('sliding_attention')} of "
+        f"window {t.sliding_window}, {kinds.count('full_attention')} full), "
+        f"hidden {t.hidden_size}, "
+        f"{sorted(set(t.num_attention_heads_per_layer[:t.num_layers]))} "
+        f"query heads on {t.num_key_value_heads}, experts "
+        f"[{t.experts_held[0]}, {t.experts_held[1]}) of "
+        f"{t.router_outputs} held")
+
+
 #: ``model_type`` of a configuration file -> the class that builds it
-MODEL_TYPES = {"deepseek_v3": _latent_moe, "granitemoehybrid": _hybrid_ssm}
+MODEL_TYPES = {"deepseek_v3": _latent_moe, "granitemoehybrid": _hybrid_ssm,
+               "laguna": _laguna}
 
 
 def _main_model_config(cfg, argv, machine, log) -> dict:

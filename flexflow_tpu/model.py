@@ -392,12 +392,13 @@ class FFModel:
             rope_dim, v_dim, rope_theta, eps))
 
     def grouped_query_attention(self, name, input, num_heads, num_kv_heads,
-                                head_dim, scale) -> Tensor:
+                                head_dim, scale, rope=None, window=None,
+                                gate: bool = False) -> Tensor:
         from flexflow_tpu.ops.attention import GroupedQueryAttention
 
         return self._add(GroupedQueryAttention(
             name, self._pc(name, 3), input, num_heads, num_kv_heads,
-            head_dim, scale))
+            head_dim, scale, rope, window, gate))
 
     def ssm_mixer(self, name, input, num_heads, head_dim, d_state, d_conv,
                   chunk, conv_bias: bool = True,
@@ -415,13 +416,14 @@ class FFModel:
         return self._add(SSMOut(name + "_out", self._pc(name + "_out", 2),
                                 y, first.z, input.shape[2], eps))
 
-    def sigmoid_router(self, name, input, n_router, top_k, scale,
-                       bias_update_rate: float = 1e-3) -> Tensor:
-        from flexflow_tpu.ops.expert_share import SigmoidRouter
+    def top_k_router(self, name, input, n_router, top_k, scale,
+                     bias_update_rate: float = 1e-3,
+                     score: str = "sigmoid") -> Tensor:
+        from flexflow_tpu.ops.expert_share import TopKRouter
 
-        return self._add(SigmoidRouter(name, self._pc(name, 2), input,
-                                       n_router, top_k, scale,
-                                       bias_update_rate))
+        return self._add(TopKRouter(name, self._pc(name, 2), input,
+                                    n_router, top_k, scale,
+                                    bias_update_rate, score))
 
     def held_experts(self, name, input, gates, d_ff, experts_held, top_k,
                      capacity_factor: float = 2.0) -> Tensor:

@@ -130,10 +130,10 @@ class LatentMoELM(NextTokenLM):
             if i < t.first_k_dense_replace:
                 h = self.gated_ffn(f"blk{i}_ffn", h, t.intermediate_size)
             else:
-                gates = self.sigmoid_router(
+                gates = self.top_k_router(
                     f"blk{i}_moe_router", h, t.router_outputs,
                     t.num_experts_per_tok, t.routed_scaling_factor,
-                    t.bias_update_rate)
+                    t.bias_update_rate, score="sigmoid")
                 routed = self.held_experts(
                     f"blk{i}_moe_experts", h, gates,
                     t.moe_intermediate_size, t.experts_held,

@@ -17,8 +17,9 @@ Execution paths:
 
 
 :class:`GroupedQueryAttention` is the causal layer of the 2023-on decoder
-blocks: more query heads than key-value heads, no bias, no positions of
-its own and a softmax scale the model gives.
+blocks: more query heads than key-value heads, no bias, a softmax scale
+the model gives and, each where the model asks for it, rotary positions
+on q and k, a sliding window and a per-head gate on the result.
 
 New capability relative to the reference (which has no attention ops,
 SURVEY.md §2.6); cited rows: CP/ring-attention, SP."""
@@ -200,11 +201,13 @@ class MultiHeadAttention(Op):
 
 
 def grouped_causal_attention(q, k, v, num_heads: int, num_kv_heads: int,
-                             scale: float):
+                             scale: float, window: int = None):
     """softmax(scale q k^T, causal) v in plain XLA, query heads
     ``g j .. g j + g - 1`` reading key-value head ``j``: q (B, S, H*d), k
-    and v (B, S, KV*d) -> (B, S, H*d).  The path off the TPU (CPU tests,
-    tiny sizes): the score matrix is whole, and no key is repeated."""
+    and v (B, S, KV*d) -> (B, S, H*d); under ``window`` a query sees
+    itself and the ``window - 1`` keys before it.  The path off the TPU
+    (CPU tests, tiny sizes): the score matrix is whole, and no key is
+    repeated."""
     import jax
     import jax.numpy as jnp
 
@@ -215,6 +218,8 @@ def grouped_causal_attention(q, k, v, num_heads: int, num_kv_heads: int,
     scores = jnp.einsum("bqjgd,bkjd->bjgqk", qh, kh,
                         preferred_element_type=jnp.float32) * scale
     mask = jnp.tril(jnp.ones((s, s), bool))
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((s, s), bool), -int(window))
     p = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
     out = jnp.einsum("bjgqk,bkjd->bqjgd", p.astype(v.dtype), vh,
                      preferred_element_type=jnp.float32)
@@ -225,19 +230,32 @@ class GroupedQueryAttention(Op):
     """Causal attention with ``num_heads`` query heads on ``num_kv_heads``
     key-value heads (query heads ``g j .. g j + g - 1`` read key-value head
     ``j``), ``softmax(scale q k^T + causal mask) v`` with ``scale`` a
-    given number, no bias and no positions of any kind (a model that
-    rotates its queries does so before).  On the TPU the scores run in
-    the flash kernels, which take one key a query head: k and v are
-    repeated to ``num_heads`` heads in HBM first (``attn.kv_groups``
-    counts the copies a key gets; PERF.md section 3 has the bytes).
-    Grid ('s', 'h', 'n') as the attention operator's, of which only
-    (1, 1, 1) is implemented."""
+    given number and no bias.  Three things a model may add, and without
+    them the operator is what it was, parameter for parameter:
+
+    * ``rope``: a rotary rule (``ops/seq_gated.rotary_table``: the
+      dimensions of a head that turn, theta, default or YaRN) applied to q
+      and k here, where alone they exist;
+    * ``window``: a query sees itself and the ``window - 1`` keys before
+      it (the flash kernels skip the tiles left of the window as they skip
+      those above the diagonal; ``attn.window`` reads it);
+    * ``gate``: one more matrix ``wg`` (hidden x heads) and ``sigmoid(x
+      wg)``, one number a head and token, on that head's result before
+      ``wo`` (arXiv:2505.06708's headwise gate).
+
+    On the TPU the scores run in the flash kernels, which take one key a
+    query head: k and v are repeated to ``num_heads`` heads in HBM first
+    (``attn.kv_groups`` reads the copies a key gets and
+    ``attn.kv_groups.<g>`` counts the traced layers of each group size;
+    PERF.md section 3 has the bytes).  Grid ('s', 'h', 'n') as the
+    attention operator's, of which only (1, 1, 1) is implemented."""
 
     AXIS_NAMES = ("s", "h", "n")
 
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  num_heads: int, num_kv_heads: int, head_dim: int,
-                 scale: float):
+                 scale: float, rope: Dict = None, window: int = None,
+                 gate: bool = False):
         super().__init__(name, pc, [input])
         assert input.ndim == 3
         if num_heads % num_kv_heads:
@@ -247,14 +265,23 @@ class GroupedQueryAttention(Op):
         self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
         self.head_dim = int(head_dim)
         self.scale = float(scale)
+        self.rope = dict(rope) if rope else None
+        if self.rope and not 0 < int(self.rope["dim"]) <= self.head_dim:
+            raise ValueError(f"op {name!r}: {self.rope['dim']} rotary "
+                             f"dimensions in a head of {self.head_dim}")
+        self.window = None if window is None else int(window)
+        self.gate = bool(gate)
         self.output = Tensor(input.shape, input.dtype, self, name)
 
     def _shapes(self) -> Dict:
         d, hd = self.d_model, self.head_dim
-        return {"wq": (d, self.num_heads * hd),
-                "wk": (d, self.num_kv_heads * hd),
-                "wv": (d, self.num_kv_heads * hd),
-                "wo": (self.num_heads * hd, d)}
+        shapes = {"wq": (d, self.num_heads * hd),
+                  "wk": (d, self.num_kv_heads * hd),
+                  "wv": (d, self.num_kv_heads * hd),
+                  "wo": (self.num_heads * hd, d)}
+        if self.gate:
+            shapes["wg"] = (d, self.num_heads)
+        return shapes
 
     def init_params(self, rng) -> Dict:
         import jax
@@ -289,6 +316,7 @@ class GroupedQueryAttention(Op):
                 f"batch parts) is not implemented")
 
     def forward(self, params, state, xs: List, train: bool):
+        import jax
         import jax.numpy as jnp
 
         from flexflow_tpu import obs
@@ -305,7 +333,20 @@ class GroupedQueryAttention(Op):
                               ).astype(a.dtype)
 
         q, k, v = (proj(x, params[w]) for w in ("wq", "wk", "wv"))
+        if self.rope:
+            from flexflow_tpu.ops.seq_gated import apply_rope, rotary_table
+
+            cos, sin = rotary_table(self.rope, s)
+            q = apply_rope(q.reshape(b, s, h, hd), cos, sin
+                           ).reshape(b, s, h * hd)
+            k = apply_rope(k.reshape(b, s, kv, hd), cos, sin
+                           ).reshape(b, s, kv * hd)
+        # the level is what a one-group model's cell reads; the count by
+        # group size tells a model's layers apart
         obs.count("attn.kv_groups", h // kv, level=True)
+        obs.count(f"attn.kv_groups.{h // kv}")
+        if self.window is not None:
+            obs.count("attn.window", self.window, level=True)
         if pallas.flash_enabled():
             def repeat(a):      # (B, S, KV*hd) -> (B, S, H*hd)
                 a = a.reshape(b, s, kv, 1, hd)
@@ -313,19 +354,36 @@ class GroupedQueryAttention(Op):
                     a, (b, s, kv, h // kv, hd)).reshape(b, s, h * hd)
 
             out = flash_attention_packed(q, repeat(k), repeat(v), h,
-                                         causal=True, scale=self.scale)
+                                         causal=True, scale=self.scale,
+                                         window=self.window)
         else:
-            out = grouped_causal_attention(q, k, v, h, kv, self.scale)
-        return proj(out.astype(x.dtype), params["wo"]), state
+            out = grouped_causal_attention(q, k, v, h, kv, self.scale,
+                                           self.window)
+        out = out.astype(x.dtype)
+        if self.gate:
+            g = jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", x, params["wg"].astype(x.dtype),
+                preferred_element_type=jnp.float32))
+            out = (out.reshape(b, s, h, hd) * g[..., None]
+                   ).astype(x.dtype).reshape(b, s, h * hd)
+        return proj(out, params["wo"]), state
 
     def cost_signature(self) -> tuple:
-        return (self.num_heads, self.num_kv_heads, self.head_dim, self.scale)
+        sig = (self.num_heads, self.num_kv_heads, self.head_dim, self.scale)
+        if self.rope or self.window is not None or self.gate:
+            sig += (tuple(sorted(self.rope.items())) if self.rope else None,
+                    self.window, self.gate)
+        return sig
 
     def flops_per_sample(self) -> float:
         s = self.output.shape[1]
         proj = sum(2.0 * a * b_ for a, b_ in self._shapes().values())
-        # a query at position i meets i + 1 keys
-        attn = 4.0 * self.num_heads * self.head_dim * (s + 1) / 2
+        # a query at position i meets i + 1 keys, at most the window's
+        met = (s + 1) / 2
+        if self.window is not None and self.window < s:
+            w = self.window
+            met = (w * (w + 1) / 2 + (s - w) * w) / s
+        attn = 4.0 * self.num_heads * self.head_dim * met
         return s * (proj + attn)
 
     def param_bytes(self) -> int:

@@ -1,13 +1,19 @@
 """One chip's share of an expert layer whose experts are spread over
-several chips (DeepSeek-V3's layer, as Moonlight-16B-A3B has it).
+several chips (DeepSeek-V3's layer, as Moonlight-16B-A3B has it, and the
+Qwen2-MoE family's, as Laguna-S-2.1 has it).
 
-:class:`SigmoidRouter` scores every token against ALL ``n_router``
-experts of the layer in float32 (sigmoid), picks the ``top_k`` largest of
-``score + bias`` and hands on a dense ``(batch, seq, n_router)`` array of
-combine weights: ``scale * score / sum of the selected scores`` for the
-selected experts, 0 elsewhere.  The bias only selects; it is state, moved
-after every training step by ``rate * sign(mean load - load)`` and never
-differentiated (the auxiliary-loss-free balancing of arXiv:2412.19437).
+:class:`TopKRouter` scores every token against ALL ``n_router`` experts
+of the layer in float32, picks the ``top_k`` largest and hands on a dense
+``(batch, seq, n_router)`` array of combine weights: ``scale * score /
+sum of the selected scores`` for the selected experts, 0 elsewhere.  The
+score rule (``SCORE_RULES``) is the model's:
+
+* ``sigmoid``: each logit's sigmoid, the selection by ``score + bias``.
+  The bias only selects; it is state, moved after every training step by
+  ``rate * sign(mean load - load)`` and never differentiated (the
+  auxiliary-loss-free balancing of arXiv:2412.19437);
+* ``softmax``: the softmax over all the layer's logits, the selection by
+  the score itself; no bias and no state.
 
 :class:`HeldExperts` is told which experts it holds (``experts_held``, a
 range), and computes their part of ``sum_e weight_e * E_e(x)`` for the
@@ -62,17 +68,24 @@ def _refuse_parts(op: Op, what: str) -> None:
             f"is not implemented")
 
 
-class SigmoidRouter(Op):
+SCORE_RULES = ("sigmoid", "softmax")
+
+
+class TopKRouter(Op):
     AXIS_NAMES = ("e", "n")
 
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  n_router: int, top_k: int, scale: float,
-                 bias_update_rate: float = 1e-3):
+                 bias_update_rate: float = 1e-3, score: str = "sigmoid"):
         super().__init__(name, pc, [input])
         assert input.ndim == 3
+        if score not in SCORE_RULES:
+            raise ValueError(f"op {name!r}: score rule {score!r}, one of "
+                             f"{SCORE_RULES}")
         self.d = input.shape[2]
         self.n_router, self.top_k = int(n_router), int(top_k)
         self.scale = float(scale)
+        self.score = score
         self.bias_update_rate = float(bias_update_rate)
         self.output = Tensor(input.shape[:2] + (self.n_router,), "float32",
                              self, name)
@@ -86,6 +99,8 @@ class SigmoidRouter(Op):
     def init_state(self) -> Dict:
         import jax.numpy as jnp
 
+        if self.score != "sigmoid":
+            return {}
         return {"bias": jnp.zeros((self.n_router,), "float32")}
 
     def param_specs(self):
@@ -113,14 +128,18 @@ class SigmoidRouter(Op):
         logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
                             params["kernel"].astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        score = jax.nn.sigmoid(logits)
-        _, chosen = jax.lax.top_k(
-            jax.lax.stop_gradient(score) + state["bias"], self.top_k)
+        if self.score == "sigmoid":
+            score = jax.nn.sigmoid(logits)
+            ranked = jax.lax.stop_gradient(score) + state["bias"]
+        else:
+            score = jax.nn.softmax(logits, axis=-1)
+            ranked = jax.lax.stop_gradient(score)
+        _, chosen = jax.lax.top_k(ranked, self.top_k)
         mask = jnp.sum(jax.nn.one_hot(chosen, self.n_router,
                                       dtype=jnp.float32), axis=-2)
         picked = score * mask
         gates = self.scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
-        if not train:
+        if not train or self.score != "sigmoid":
             return gates, state
         load = jnp.sum(mask, axis=(0, 1))
         bias = state["bias"] + self.bias_update_rate * jnp.sign(

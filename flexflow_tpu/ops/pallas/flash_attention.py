@@ -50,9 +50,21 @@ probabilities a tile, dq^T accumulated in scratch and written once a head
 group) and two beyond it (``split``: ``ff_flash_bwd_dkv`` and
 ``ff_flash_bwd_dq``).
 
+Window.  Under ``window`` (causal self-attention) query ``i`` sees the
+keys ``i - window < t <= i``.  The blocks are the largest of 1024, 512, 256,
+128 that divides the window (512 at a window of 512), a tile wholly left
+of every query's window in it is skipped exactly as one above the diagonal
+is, and the inner grid axis walks only the band of blocks a block can meet
+(two steps a block at a window of one block, where the causal call walks
+sixteen at S 8192), the fused backward excepted; the tile the window's
+left edge cuts is walked in pieces like the one on the diagonal (128
+forward, 256 backward), those below ITS diagonal dropped.  The kernels of
+such a call are named ``ff_flash_win_*``.
+
 Sequence lengths that are not a block multiple are zero-padded (padded K
 columns masked, padded Q rows sliced off).  Which variant a call took is
-counted once a trace in ``kernels.flash.<layout>.<backward>``.
+counted once a trace in ``kernels.flash.<layout>.<backward>``, with
+``.w<window>`` after it under a window.
 
 On TPU the kernels compile via Mosaic; elsewhere they run in interpreter
 mode, so the identical code path is exercised by the CPU test suite.
@@ -99,6 +111,14 @@ _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _BLOCK = 1024
 _FWD_PIECE = 512
 _BWD_PIECE = 256
+# under a window: the blocks tried, largest first, and the pieces the two
+# tiles the mask cuts (diagonal and left edge) are walked in, forward and
+# backward (PERF.md section 6, PR 34: at 72 heads under a window of 512
+# the forward read 6.28 ms in pieces of 128, 7.60 in 256, 7.08 whole; the
+# backward 27.6, 25.2, 26.9; blocks of 256 lost both, 12.4 and 33.1)
+_WINDOW_BLOCKS = (1024, 512, 256, 128)
+_WINDOW_FWD_PIECE = 128
+_WINDOW_BWD_PIECE = 256
 _F32 = jnp.float32
 # the forward kernel's two results by name: a recomputed block keeps them
 # (FFModel._run_recomputed) instead of running the kernel once more for
@@ -131,10 +151,18 @@ def _layout(h: int, hd: int, hdv: int):
     return 1, hd_p, hd_p, f"pad{hd_p}"
 
 
-def _pick_block(s: int, interpret: bool) -> int:
+def _pick_block(s: int, interpret: bool, window: int = None) -> int:
     """Score-tile size along a sequence of length s.  A short sequence is
     one block of its own length (8-aligned in interpret mode, 128-aligned
-    on hardware); a long one takes the block that pads it least."""
+    on hardware); a long one takes the block that pads it least.  Under a
+    window shorter than the sequence the block is the largest that
+    divides the window (512 at a window of 512): the tile the window's
+    left edge cuts is then square like the one on the diagonal and is
+    walked in the same pieces, and no tile is wider than the window."""
+    if window is not None and window < s:
+        fits = [b for b in _WINDOW_BLOCKS if window % b == 0]
+        if fits:
+            return fits[0]
     one = _round_up(s, 8 if interpret else LANES)
     if one <= _BLOCK:
         return one
@@ -174,35 +202,92 @@ def _by_head(parts, hd: int):
 
 class _Tiles:
     """Static geometry of one call's score tiles: which are live (causal:
-    not wholly above the diagonal), which need a mask (straddle the
-    diagonal, or hold padded K columns), and how a tile on the diagonal of
-    square blocks splits into ``piece``-sized squares of which those above
-    the diagonal are dropped."""
+    not wholly above the diagonal; under a ``window``: not wholly left of
+    every query's window either), which need a mask (straddle the
+    diagonal or the window's left edge, or hold padded K columns), and how
+    a tile on the diagonal of square blocks splits into ``piece``-sized
+    squares of which those above the diagonal are dropped.
 
-    def __init__(self, causal, sk, block_q, block_k, n_q, n_k, piece):
+    Under a window (query ``i`` sees the keys ``i - window < t <= i``;
+    self-attention only) the tile the left edge cuts, ``window / block``
+    blocks left of the diagonal one, splits the same way with the pieces
+    below ITS diagonal dropped, and with ``banded`` the inner grid axis
+    walks only the blocks some query of the outer block can see
+    (``inner_q``, ``inner_k`` steps; :meth:`q_at`, :meth:`k_at` give the
+    block of a step), so the tiles the window leaves out cost no grid
+    step.  Without a window the inner axis is every block, as it was."""
+
+    def __init__(self, causal, sk, block_q, block_k, n_q, n_k, piece,
+                 window=None, banded=False):
         self.causal, self.sk = causal, sk
         self.bq, self.bk, self.n_q, self.n_k = block_q, block_k, n_q, n_k
         self.k_padded = sk % block_k != 0
-        split = causal and block_q == block_k and block_q % piece == 0
-        self.n_sub = block_q // piece if split else 1
+        self.window = window
+        square = causal and block_q == block_k and block_q % piece == 0
+        if window is not None:
+            square = square and window % block_q == 0
+        self.n_sub = block_q // piece if square else 1
+        self.banded = banded and window is not None
+        self.inner_q, self.inner_k = n_q, n_k
+        if self.banded:
+            self.inner_k = max(
+                self._last_k(q, min) - self._first_k(q, max) + 1
+                for q in range(n_q))
+            self.inner_q = max(
+                self._last_q(k, min) - self._first_q(k, min) + 1
+                for k in range(n_k))
+
+    # the first and last block of one side that a block of the other side
+    # meets, on Python ints (mn, mx = min, max) and on grid indices alike
+    def _first_q(self, ki, mn):
+        return mn((ki * self.bk) // self.bq, self.n_q - 1)
+
+    def _last_q(self, ki, mn):
+        return mn((ki * self.bk + self.bk + self.window - 2) // self.bq,
+                  self.n_q - 1)
+
+    def _first_k(self, qi, mx):
+        return mx((qi * self.bq - self.window + 1) // self.bk, 0)
+
+    def _last_k(self, qi, mn):
+        return mn((qi * self.bq + self.bq - 1) // self.bk, self.n_k - 1)
+
+    def q_at(self, ki, j):
+        """The Q block of inner step ``j`` under K block ``ki``; past the
+        last one the window lets ``ki`` meet it names no live tile."""
+        return self._first_q(ki, jnp.minimum) + j if self.banded else j
+
+    def k_at(self, qi, j):
+        return self._first_k(qi, jnp.maximum) + j if self.banded else j
 
     def live(self, qi, ki):
         if not self.causal:
             return True
-        return qi * self.bq + self.bq - 1 >= ki * self.bk
+        seen = qi * self.bq + self.bq - 1 >= ki * self.bk
+        if self.window is None:
+            return seen
+        # the nearest pair of the tile lies inside the window
+        near = qi * self.bq - (ki * self.bk + self.bk - 1) < self.window
+        inside = jnp.logical_and(qi < self.n_q, ki < self.n_k)
+        return jnp.logical_and(jnp.logical_and(seen, near), inside)
 
     def masked(self, qi, ki):
         """None when no tile of the call needs a mask."""
         m = None
         if self.causal:  # some column of the tile lies right of some row
             m = ki * self.bk + self.bk - 1 > qi * self.bq
+        if self.window is not None:  # its farthest pair leaves the window
+            far = qi * self.bq + self.bq - 1 - ki * self.bk >= self.window
+            m = jnp.logical_or(m, far)
         if self.k_padded:
             last = ki == self.n_k - 1
             m = last if m is None else jnp.logical_or(m, last)
         return m
 
-    def valid(self, qi, ki, qs: slice, ks: slice):
-        """Mask of the (k piece, q piece) of transposed tile (qi, ki)."""
+    def valid(self, qi, ki, qs: slice, ks: slice, edges=True):
+        """Mask of the (k piece, q piece) of transposed tile (qi, ki).
+        ``edges`` names the one edge a piece is known to meet
+        (``"causal"``, ``"window"``); True: every edge the call has."""
         shape = (ks.stop - ks.start, qs.stop - qs.start)
         kpos = ki * self.bk + ks.start + jax.lax.broadcasted_iota(
             jnp.int32, shape, 0)
@@ -210,25 +295,43 @@ class _Tiles:
         if self.causal:
             qpos = qi * self.bq + qs.start + jax.lax.broadcasted_iota(
                 jnp.int32, shape, 1)
-            ok = qpos >= kpos if ok is None else ok & (qpos >= kpos)
+            if edges is True or edges == "causal":
+                ok = qpos >= kpos if ok is None else ok & (qpos >= kpos)
+            if self.window is not None and (edges is True
+                                            or edges == "window"):
+                near = qpos - kpos < self.window
+                ok = near if ok is None else ok & near
         return ok
 
     def first_live_q(self, ki):
-        if not self.causal:
-            return 0
-        return jnp.minimum((ki * self.bk) // self.bq, self.n_q - 1)
+        return self._first_q(ki, jnp.minimum) if self.causal else 0
 
     def last_live_k(self, qi):
-        if not self.causal:
-            return self.n_k - 1
-        return jnp.minimum((qi * self.bq + self.bq - 1) // self.bk,
-                           self.n_k - 1)
+        return self._last_k(qi, jnp.minimum) if self.causal \
+            else self.n_k - 1
+
+    def q_block(self, ki, j):
+        """The Q block to fetch at inner step ``j``: a live one, so a
+        dead step moves nothing."""
+        if self.window is None:
+            return jnp.maximum(j, self.first_live_q(ki))
+        return jnp.clip(self.q_at(ki, j), self.first_live_q(ki),
+                        self._last_q(ki, jnp.minimum))
+
+    def k_block(self, qi, j):
+        if self.window is None:
+            return jnp.minimum(j, self.last_live_k(qi))
+        return jnp.clip(self.k_at(qi, j), self._first_k(qi, jnp.maximum),
+                        self.last_live_k(qi))
 
     def run(self, qi, ki, body):
         """Call ``body(pieces)`` under the grid-step conditions it needs;
-        ``pieces`` is a static list of (q slice, k slice, masked)."""
+        ``pieces`` is a static list of (q slice, k slice, masked), where
+        ``masked`` is False, True or the one edge to mask for."""
         whole = (slice(0, self.bq), slice(0, self.bk))
         live, masked = self.live(qi, ki), self.masked(qi, ki)
+        if self.window is not None:
+            return self._run_windowed(qi, ki, body, whole, live, masked)
         if masked is None:
             pl.when(live)(lambda: body([(*whole, False)]))
             return
@@ -247,6 +350,34 @@ class _Tiles:
             masked = jnp.logical_and(masked, qi != ki)
         pl.when(jnp.logical_and(live, masked))(
             lambda: body([(*whole, True)]))
+
+    def _run_windowed(self, qi, ki, body, whole, live, masked):
+        if self.n_sub == 1:     # blocks the window is no multiple of
+            pl.when(jnp.logical_and(live, jnp.logical_not(masked)))(
+                lambda: body([(*whole, False)]))
+            pl.when(jnp.logical_and(live, masked))(
+                lambda: body([(*whole, True)]))
+            return
+        # square blocks, the window a whole number of them: the diagonal
+        # tile keeps the pieces on and below its diagonal, the tile
+        # window / block to its left those on and above (a key there is
+        # seen when its place in the tile is past the query's), and the
+        # tiles between them are seen whole
+        step = self.bq // self.n_sub
+        cut = [slice(i * step, (i + 1) * step) for i in range(self.n_sub)]
+        edge = qi - self.window // self.bq
+
+        def pieces(cols, what):
+            return [(cut[a], cut[c], (True if self.k_padded else what)
+                     if c == a else self.k_padded)
+                    for a in range(self.n_sub) for c in cols(a)]
+
+        pl.when(jnp.logical_and(live, ki == qi))(lambda: body(
+            pieces(lambda a: range(a + 1), "causal")))
+        pl.when(jnp.logical_and(live, ki == edge))(lambda: body(
+            pieces(lambda a: range(a, self.n_sub), "window")))
+        pl.when(jnp.logical_and(live, jnp.logical_and(
+            ki != qi, ki != edge)))(lambda: body([(*whole, False)]))
 
 
 def _params(interpret):
@@ -279,9 +410,10 @@ def _transposed(x, dtype):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
                 acc_scr, *, t: _Tiles, scale, heads, hd, hdv):
-    qi, ki = pl.program_id(2), pl.program_id(3)
+    qi, jk = pl.program_id(2), pl.program_id(3)
+    ki = t.k_at(qi, jk)
 
-    @pl.when(ki == 0)
+    @pl.when(jk == 0)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, m_scr.dtype)
         l_scr[...] = jnp.zeros(l_scr.shape, l_scr.dtype)
@@ -294,7 +426,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
         v_t = _transposed(v_ref[0], v_ref.dtype)              # (W, block_k)
         for qs, ks, masked in pieces:
             k = k_ref[0, ks, :]
-            valid = t.valid(qi, ki, qs, ks) if masked else None
+            valid = t.valid(qi, ki, qs, ks, masked) if masked else None
             for g in range(heads):
                 rows = _head_rows(g, hdv)
                 s_t = _nt(k, qs_scr[g, qs, :])
@@ -305,8 +437,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
                 m_prev = m_scr[g, :, qs]
                 m_new = jnp.maximum(m_prev,
                                     jnp.max(s_t, axis=0, keepdims=True))
-                p_t = jnp.exp(s_t - m_new)
-                corr = jnp.exp(m_prev - m_new)
+                m_ref = m_new
+                if t.window is not None:
+                    # the first piece a query meets under a window may
+                    # hold none of its keys: the piece then adds nothing
+                    m_ref = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+                p_t = jnp.exp(s_t - m_ref)
+                corr = jnp.exp(m_prev - m_ref)
                 l_scr[g, :, qs] = l_scr[g, :, qs] * corr + jnp.sum(
                     p_t, axis=0, keepdims=True)
                 acc_scr[rows, qs] = acc_scr[rows, qs] * corr + _nn(
@@ -315,17 +452,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
 
     t.run(qi, ki, tile)
 
-    @pl.when(ki == t.n_k - 1)
+    @pl.when(jk == t.inner_k - 1)
     def _finish():
         for g in range(heads):
             rows = _head_rows(g, hdv)
-            acc_scr[rows, :] = acc_scr[rows, :] / l_scr[g]
-            lse_ref[0, g] = m_scr[g] + jnp.log(l_scr[g])
+            if t.window is None:
+                acc_scr[rows, :] = acc_scr[rows, :] / l_scr[g]
+                lse_ref[0, g] = m_scr[g] + jnp.log(l_scr[g])
+                continue
+            # a padded query row far past the last key sees none: its
+            # result is 0 and its lse finite
+            seen = l_scr[g] > 0.0
+            l = jnp.where(seen, l_scr[g], 1.0)
+            acc_scr[rows, :] = acc_scr[rows, :] / l
+            lse_ref[0, g] = jnp.where(seen, m_scr[g], 0.0) + jnp.log(l)
         o_ref[0] = acc_scr[...].T.astype(o_ref.dtype)
 
 
 def _fwd_call(q, k, v, *, t: _Tiles, heads, hd, hdv, scale, out_dtype,
-              interpret):
+              interpret, name="ff_flash_"):
     b, sq, width = q.shape
     w, wv = heads * hd, heads * hdv
     groups = width // w
@@ -333,13 +478,13 @@ def _fwd_call(q, k, v, *, t: _Tiles, heads, hd, hdv, scale, out_dtype,
 
     def kv_spec(width_):
         return pl.BlockSpec(
-            (1, bk, width_), lambda b_, j, qi, ki: (b_, jnp.minimum(
-                ki, t.last_live_k(qi)), j))
+            (1, bk, width_), lambda b_, j, qi, ki: (b_, t.k_block(qi, ki),
+                                                    j))
 
     return pl.pallas_call(
         functools.partial(_fwd_kernel, t=t, scale=scale, heads=heads, hd=hd,
                           hdv=hdv),
-        grid=(b, groups, t.n_q, t.n_k),
+        grid=(b, groups, t.n_q, t.inner_k),
         in_specs=[pl.BlockSpec((1, bq, w), lambda b_, j, qi, ki: (b_, qi, j)),
                   kv_spec(w), kv_spec(wv)],
         out_specs=[
@@ -358,7 +503,7 @@ def _fwd_call(q, k, v, *, t: _Tiles, heads, hd, hdv, scale, out_dtype,
             pltpu.VMEM((wv, bq), _F32),
         ],
         interpret=interpret,
-        name="ff_flash_fwd",
+        name=name + "fwd",
         **_params(interpret),
     )(q, k, v)
 
@@ -400,9 +545,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     else:
         dk_ref, dv_ref, ks_scr, vm_scr, dk_scr, dv_scr = rest
         kst_scr = dqt_scr = None
-    ki, qi = pl.program_id(2), pl.program_id(3)
+    ki, jq = pl.program_id(2), pl.program_id(3)
+    qi = t.q_at(ki, jq)
 
-    @pl.when(qi == 0)
+    @pl.when(jq == 0)
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
         dv_scr[...] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
@@ -417,7 +563,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     def tile(pieces):
         for qs, ks, masked in pieces:
             q, do = q_ref[0, qs, :], do_ref[0, qs, :]
-            valid = t.valid(qi, ki, qs, ks) if masked else None
+            valid = t.valid(qi, ki, qs, ks, masked) if masked else None
             dvs, dks = [], []
             for g in range(heads):
                 p_t, ds_t = _p_ds_t(ks_scr[g, ks, :], vm_scr[g, ks, :], q,
@@ -434,7 +580,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     t.run(qi, ki, tile)
 
-    @pl.when(qi == t.n_q - 1)
+    @pl.when(jq == t.inner_q - 1)
     def _finish():
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -450,9 +596,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    ks_scr, vm_scr, kst_scr, dqt_scr,
                    *, t: _Tiles, scale, heads, hd, hdv):
     """Q blocks outer, K blocks inner (the split form's second kernel)."""
-    qi, ki = pl.program_id(2), pl.program_id(3)
+    qi, jk = pl.program_id(2), pl.program_id(3)
+    ki = t.k_at(qi, jk)
 
-    @pl.when(ki == 0)
+    @pl.when(jk == 0)
     def _init():
         dqt_scr[...] = jnp.zeros(dqt_scr.shape, dqt_scr.dtype)
 
@@ -461,7 +608,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                   hdv)
         for qs, ks, masked in pieces:
             q, do = q_ref[0, qs, :], do_ref[0, qs, :]
-            valid = t.valid(qi, ki, qs, ks) if masked else None
+            valid = t.valid(qi, ki, qs, ks, masked) if masked else None
             for g in range(heads):
                 rows = _head_rows(g, hd)
                 _, ds_t = _p_ds_t(ks_scr[g, ks, :], vm_scr[g, ks, :], q, do,
@@ -472,13 +619,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     t.run(qi, ki, tile)
 
-    @pl.when(ki == t.n_k - 1)
+    @pl.when(jk == t.inner_k - 1)
     def _finish():
         dq_ref[0] = dqt_scr[...].T.astype(dq_ref.dtype)
 
 
 def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
-              fused, interpret):
+              fused, interpret, name="ff_flash_"):
     b, sq, width = q.shape
     w, wv = heads * hd, heads * hdv
     groups = width // w
@@ -488,12 +635,12 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
     # K blocks outer, Q blocks inner
     def q_spec(width_):
         return pl.BlockSpec(
-            (1, bq, width_), lambda b_, j, ki, qi: (b_, jnp.maximum(
-                qi, t.first_live_q(ki)), j))
+            (1, bq, width_), lambda b_, j, ki, qi: (b_, t.q_block(ki, qi),
+                                                    j))
 
     row_spec = pl.BlockSpec(
-        (1, heads, 1, bq), lambda b_, j, ki, qi: (b_, j, 0, jnp.maximum(
-            qi, t.first_live_q(ki))))
+        (1, heads, 1, bq), lambda b_, j, ki, qi: (b_, j, 0,
+                                                  t.q_block(ki, qi)))
 
     def kv_spec(width_):
         return pl.BlockSpec((1, bk, width_),
@@ -514,14 +661,14 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
         scratch += dq_scratch + [pltpu.VMEM((t.n_q, w, bq), _F32)]
     outs = pl.pallas_call(
         functools.partial(_bwd_kernel, with_dq=fused, **common),
-        grid=(b, groups, t.n_k, t.n_q),
+        grid=(b, groups, t.n_k, t.inner_q),
         in_specs=[q_spec(w), kv_spec(w), kv_spec(wv), q_spec(wv), row_spec,
                   row_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-        name="ff_flash_bwd" if fused else "ff_flash_bwd_dkv",
+        name=name + ("bwd" if fused else "bwd_dkv"),
         **_params(interpret),
     )(q, k, v, do, lse, delta)
     if fused:
@@ -537,19 +684,19 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
 
     def kv_spec(width_):
         return pl.BlockSpec(
-            (1, bk, width_), lambda b_, j, qi, ki: (b_, jnp.minimum(
-                ki, t.last_live_k(qi)), j))
+            (1, bk, width_), lambda b_, j, qi, ki: (b_, t.k_block(qi, ki),
+                                                    j))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
-        grid=(b, groups, t.n_q, t.n_k),
+        grid=(b, groups, t.n_q, t.inner_k),
         in_specs=[q_spec(w), kv_spec(w), kv_spec(wv), q_spec(wv), row_spec,
                   row_spec],
         out_specs=q_spec(w),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=kv_scratch + dq_scratch + [pltpu.VMEM((w, bq), _F32)],
         interpret=interpret,
-        name="ff_flash_bwd_dq",
+        name=name + "bwd_dq",
         **_params(interpret),
     )(q, k, v, do, lse, delta)
     return (dq, *outs)
@@ -567,7 +714,7 @@ def _should_interpret() -> bool:
 @functools.lru_cache(maxsize=None)
 def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
                 block_k, interpret, with_lse=False, packed=False,
-                scale=None):
+                scale=None, window=None):
     """Build a custom-VJP flash op specialized for one static configuration
     (shapes/dtypes/blocks are Python constants closed over by the kernels;
     the VJP residuals are pure arrays), and name its variant.  ``q_shape``
@@ -575,7 +722,10 @@ def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
     is the value's head width (v, the result and their gradients), which
     latent attention sets apart from the query/key width; ``scale`` is
     what the scores are multiplied by, ``1/sqrt(hd)`` unless a model
-    gives its own number.
+    gives its own number; under ``window`` (causal self-attention only)
+    query ``i`` sees the ``window`` keys ``i - window < t <= i`` and the
+    kernels are named ``ff_flash_win_*``.  A window no shorter than the
+    sequence leaves nothing out and is the causal call.
 
     With ``with_lse`` the op returns ``(out, lse)`` — the *partial*
     attention form used by ring/context parallelism, where per-chunk
@@ -587,26 +737,45 @@ def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
     sk = k_shape[2]
     scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     heads, hd, hdv, layout = _layout(h, d, dv)
-    bq = (_pick_block(sq, interpret) if block_q is None
+    if window is not None:
+        if not causal or sq != sk or with_lse or window < 1:
+            raise ValueError(
+                f"a window ({window}) is for causal self-attention of one "
+                f"piece: causal {causal}, {sq} queries on {sk} keys")
+        if window >= sq:
+            window = None
+    bq = (_pick_block(sq, interpret, window) if block_q is None
           else min(block_q, _round_up(sq, 8)))
-    bk = (_pick_block(sk, interpret) if block_k is None
+    bk = (_pick_block(sk, interpret, window) if block_k is None
           else min(block_k, _round_up(sk, 8)))
     sq_p, sk_p = _round_up(sq, bq), _round_up(sk, bk)
-    fwd_tiles, bwd_tiles = (
-        _Tiles(causal, sk, bq, bk, sq_p // bq, sk_p // bk, piece)
-        for piece in (_FWD_PIECE, _BWD_PIECE))
     fused = sq_p * heads * hd * 4 <= _FUSED_DQ_BYTES
     variant = f"{layout}.{'fused' if fused else 'split'}"
+    names = {}
+    if window is None:
+        fwd_tiles, bwd_tiles = (
+            _Tiles(causal, sk, bq, bk, sq_p // bq, sk_p // bk, piece)
+            for piece in (_FWD_PIECE, _BWD_PIECE))
+    else:
+        # the fused backward keeps dq^T of every Q block across the K
+        # blocks, so its inner axis stays whole; the others walk the band
+        fwd_tiles, bwd_tiles = (
+            _Tiles(causal, sk, bq, bk, sq_p // bq, sk_p // bk, piece,
+                   window, banded)
+            for piece, banded in ((_WINDOW_FWD_PIECE, True),
+                                  (_WINDOW_BWD_PIECE, not fused)))
+        variant += f".w{window}"
+        names = {"name": "ff_flash_win_"}
     out_dtype = jnp.float32 if with_lse else qdt
     # inline jits: a model's layers share one trace of each kernel body
     # (tracing them is most of what a call costs before it compiles),
     # while every call site keeps its own operator name in the program
     fwd_call = jax.jit(functools.partial(
         _fwd_call, t=fwd_tiles, heads=heads, hd=hd, hdv=hdv, scale=scale,
-        out_dtype=out_dtype, interpret=interpret), inline=True)
+        out_dtype=out_dtype, interpret=interpret, **names), inline=True)
     bwd_call = jax.jit(functools.partial(
         _bwd_call, t=bwd_tiles, heads=heads, hd=hd, hdv=hdv, scale=scale,
-        fused=fused, interpret=interpret), inline=True)
+        fused=fused, interpret=interpret, **names), inline=True)
 
     def prep(x, s_p, d=d, hd=hd):
         """-> (B, S_pad, H*hd_kernel); zero head columns do not change
@@ -705,27 +874,31 @@ def _call(q, k, v, q_shape, k_shape, dv, causal, block_q, block_k,
 
 
 def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
-                    interpret=None):
+                    interpret=None, window=None):
     """softmax(q kᵀ / sqrt(d) [+ causal mask]) v without materializing the
     score matrix.  q, k: (B, H, S, d) and v: (B, H, S, dv); returns
     (B, H, Sq, dv) in q's type.  Blocks default to what the shapes
-    select."""
+    select.  Under ``window`` a query sees itself and the ``window - 1``
+    keys before it."""
     return _call(q, k, v, tuple(q.shape), tuple(k.shape), v.shape[-1],
-                 causal, block_q, block_k, interpret)
+                 causal, block_q, block_k, interpret, window=window)
 
 
 def flash_attention_packed(q, k, v, num_heads, causal=False, block_q=None,
-                           block_k=None, interpret=None, scale=None):
+                           block_k=None, interpret=None, scale=None,
+                           window=None):
     """:func:`flash_attention` on the projections' own layout: q and k are
     (B, S, H*d) with head h in columns ``h*d:(h+1)*d``, v is (B, S, H*dv)
     and so is the result — no (B,S,H,d) <-> (B,H,S,d) transpose on either
-    side.  ``scale`` multiplies the scores in place of ``1/sqrt(d)``."""
+    side.  ``scale`` multiplies the scores in place of ``1/sqrt(d)``;
+    ``window`` as :func:`flash_attention`'s."""
     def bhsd(x):
         b, s, width = x.shape
         return (b, num_heads, s, width // num_heads)
 
     return _call(q, k, v, bhsd(q), bhsd(k), v.shape[-1] // num_heads, causal,
-                 block_q, block_k, interpret, packed=True, scale=scale)
+                 block_q, block_k, interpret, packed=True, scale=scale,
+                 window=window)
 
 
 def flash_attention_partial(q, k, v, causal=False, block_q=None,

@@ -43,14 +43,66 @@ def rope_angles(seq_length: int, dim: int, theta: float):
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def rotary_table(rule: Dict, seq_length: int):
+    """cos and sin, each (seq, rotated/2) float32, of one rotary rule: a
+    dict with ``dim`` (the leading dimensions of a head that turn; the
+    rest pass through), ``rope_theta`` and ``rope_type``:
+
+    * ``default``: :func:`rope_angles`;
+    * ``yarn`` (arXiv:2309.00071, as ``transformers``'
+      ``_compute_yarn_parameters`` computes it): pair ``i`` turns by the
+      blend ``(1 - r_i) / (factor theta^(2i/dim)) + r_i / theta^(2i/dim)``,
+      ``r_i`` 1 below the dimension that makes ``beta_fast`` turns over
+      ``original_max_position_embeddings`` positions, 0 above the one
+      that makes ``beta_slow``, linear between (both rounded outwards);
+      cos and sin are multiplied by ``attention_factor`` (``0.1 ln(factor)
+      + 1`` where the rule gives none), at every length."""
+    import math
+
+    import jax.numpy as jnp
+
+    dim, theta = int(rule["dim"]), float(rule["rope_theta"])
+    kind = rule.get("rope_type", "default")
+    if kind == "default":
+        return rope_angles(seq_length, dim, theta)
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: 'default' or 'yarn'")
+    factor = float(rule["factor"])
+    original = float(rule["original_max_position_embeddings"])
+
+    def turns_at(turns):    # the dimension that makes this many turns
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(float(rule.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(turns_at(float(rule.get("beta_slow", 1)))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    plain = theta ** (-2.0 * i / dim)
+    keep = 1.0 - jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    inv = plain / factor * (1.0 - keep) + plain * keep
+    ang = jnp.arange(seq_length, dtype=jnp.float32)[:, None] * inv[None, :]
+    grow = float(rule.get("attention_factor")
+                 or 0.1 * math.log(factor) + 1.0)
+    return grow * jnp.cos(ang), grow * jnp.sin(ang)
+
+
 def apply_rope(x, cos, sin, pairing: str = "split"):
     """Rotate the last axis of ``x`` (.., seq, [heads,] dim).  ``cos`` and
     ``sin`` are (seq, dim/2); a heads axis between seq and dim is
-    broadcast over."""
+    broadcast over.  Tables narrower than ``dim/2`` turn the leading
+    ``2 x`` their width of the axis and the rest passes through."""
     import jax.numpy as jnp
 
     if pairing not in ROPE_PAIRINGS:
         raise ValueError(f"rope pairing {pairing!r}: one of {ROPE_PAIRINGS}")
+    turned = 2 * cos.shape[-1]
+    if turned < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :turned], cos, sin, pairing),
+             x[..., turned:]], axis=-1)
     half = x.shape[-1] // 2
     if x.ndim == 4:                       # (B, S, H, dim)
         cos, sin = cos[:, None, :], sin[:, None, :]

@@ -1,8 +1,8 @@
-"""The fused head's kernels compiled by Mosaic for a described TPU v5e,
-at the widths the benchmark's cells run and at the widest the fusion
-takes: interpret mode says nothing about what the chip's compiler
-accepts (VMEM above all), and a compile here costs no chip time.  Nothing
-runs: a pass is not a measurement."""
+"""The fused head's kernels and the windowed flash kernels compiled by
+Mosaic for a described TPU v5e, at the widths the benchmark's cells run
+and at the widest the fusion takes: interpret mode says nothing about
+what the chip's compiler accepts (VMEM above all), and a compile here
+costs no chip time.  Nothing runs: a pass is not a measurement."""
 
 import re
 
@@ -51,3 +51,34 @@ def test_fused_head_compiles_at_the_tiles_the_shapes_pick(one_chip, d, v,
     logits = [m for m in re.findall(r"\w+\[(\d+),(\d+)\]", text)
               if int(m[0]) >= n and int(m[1]) >= v]
     assert not logits, "an array of tokens x vocabulary reached HBM"
+
+
+@pytest.mark.parametrize("heads,window,names", [
+    # laguna_s_2_1's sliding layers: blocks of 512, the band of two
+    (72, 512, ("ff_flash_win_fwd", "ff_flash_win_bwd_dkv",
+               "ff_flash_win_bwd_dq")),
+    # a window of several blocks (1024 x 1024 tiles, a band of five)
+    (8, 4096, ("ff_flash_win_fwd", "ff_flash_win_bwd_dkv",
+               "ff_flash_win_bwd_dq")),
+    # and its full layers: the causal kernels under their own names
+    (48, None, ("ff_flash_fwd", "ff_flash_bwd_dkv", "ff_flash_bwd_dq")),
+])
+def test_flash_compiles_at_the_laguna_cells_shapes(one_chip, heads, window,
+                                                   names):
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
+    x = jax.ShapeDtypeStruct((2, 8192, heads * 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def step(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: fa.flash_attention_packed(
+                q, k, v, heads, True, interpret=False, window=window
+            ).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    text = jax.jit(step).lower(x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in names:
+        assert re.search(rf"\b{name}\b", text), name
+    assert ("ff_flash_win_" in text) == (window is not None)

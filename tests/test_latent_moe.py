@@ -223,9 +223,9 @@ def test_latent_attention_against_the_reference():
 
 
 def _router(n_router=8, top_k=2, rate=1e-3, d=16, tokens=(2, 12)):
-    from flexflow_tpu.ops.expert_share import SigmoidRouter
+    from flexflow_tpu.ops.expert_share import TopKRouter
 
-    op = SigmoidRouter("r", _pc(2), Tensor(tokens + (d,)), n_router, top_k,
+    op = TopKRouter("r", _pc(2), Tensor(tokens + (d,)), n_router, top_k,
                        2.446, rate)
     return op, op.init_params(jax.random.PRNGKey(3)), op.init_state()
 
@@ -415,14 +415,14 @@ def test_nothing_is_dropped_until_rows_capacity_is_passed():
 
 @pytest.mark.parametrize("grid", [(2, 1), (1, 2)])
 def test_expert_grids_that_are_not_implemented_are_refused(grid):
-    from flexflow_tpu.ops.expert_share import HeldExperts, SigmoidRouter
+    from flexflow_tpu.ops.expert_share import HeldExperts, TopKRouter
 
     pc = ParallelConfig(grid, (0, 1))
     x, g = Tensor((2, 12, 16)), Tensor((2, 12, 8))
     with pytest.raises(ValueError, match="not implemented"):
         HeldExperts("e", pc, x, g, 24, (0, 4), 2).validate_partitioning()
     with pytest.raises(ValueError, match="not implemented"):
-        SigmoidRouter("r", pc, x, 8, 2, 1.0).validate_partitioning()
+        TopKRouter("r", pc, x, 8, 2, 1.0).validate_partitioning()
     with pytest.raises(ValueError, match="no range"):
         HeldExperts("e", _pc(2), x, g, 24, (4, 12), 2)
 

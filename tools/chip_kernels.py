@@ -3,6 +3,7 @@
     python tools/chip_kernels.py               # on the chip
     python tools/chip_kernels.py gmm           # the grouped products alone
     python tools/chip_kernels.py ce            # the fused head alone
+    python tools/chip_kernels.py win           # the windowed flash alone
 
 The CPU suite runs these kernels in interpret mode at test shapes, and the
 compiled branch picks other block shapes (flash_attention._make_flash,
@@ -26,7 +27,12 @@ uneven loads that fill half of it, 2048 x 1408, bfloat16): each ``ff_gmm``
 form against the ``jax.lax.ragged_dot`` call autodiff makes in its place,
 at the tiles the shapes pick and at the candidates of ``GMM_TILES``, 20
 pipelined calls each, and the whole gated feed-forward, forward and
-backward, through the kernels and through ``ragged_dot``.
+backward, through the kernels and through ``ragged_dot``; the windowed
+flash kernels at the Laguna cell's shapes (b2 s8192 d128, 72 heads under a
+window of 512, bfloat16) against XLA's masked attention walking the
+queries a window at a time, at the block and pieces the shapes pick and
+at the candidates of ``WIN_TILES``, beside the 48-head full layer's
+causal call (the check to run after a libtpu change).
 """
 
 import json
@@ -81,6 +87,59 @@ def case_flash(b, h, s, d, causal):
 
     kern = _grads(lambda q, k, v: flash_attention_packed(
         q, k, v, h, causal, interpret=False), 3)
+    return kern, _grads(xla, 3), args
+
+
+def case_flash_window(b, h, s, d, window, block=None, rows=512):
+    """The flash kernels against XLA's masked attention at shapes whose
+    score matrix does not fit: the queries ``rows`` at a time
+    (``lax.map``), each block against the ``rows + window`` keys it can
+    see (all of them without a window), the mask by position."""
+    from flexflow_tpu.ops.pallas.flash_attention import \
+        flash_attention_packed
+
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    args = [_rand(k, (b, s, h * d), jnp.bfloat16) for k in ks]
+    n = s // rows
+    back = s if window is None else window      # keys before a block's
+
+    def keys_of(x, first, span):
+        """The ``span`` key positions from ``first`` on (zeros before
+        position 0) under a window, every key without one."""
+        x = x.reshape(b, s, h, d)
+        if not window:
+            return x
+        return jax.lax.dynamic_slice_in_dim(
+            jnp.pad(x, ((0, 0), (back, 0), (0, 0), (0, 0))), first + back,
+            span, axis=1)
+
+    def xla(q, k, v):
+        def one(i, qb):         # qb (b, rows, h, d)
+            first = i * rows - back if window else 0    # of the keys
+            span = rows + back if window else s
+            kb, vb = keys_of(k, first, span), keys_of(v, first, span)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
+                                preferred_element_type=jnp.float32
+                                ) / d ** 0.5
+            qpos = i * rows + jnp.arange(rows)[:, None]
+            kpos = first + jnp.arange(span)[None, :]
+            seen = (kpos >= 0) & (kpos <= qpos) & (qpos - kpos < back)
+            prob = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+            return jnp.einsum("bhqk,bkhd->bqhd", prob.astype(vb.dtype), vb,
+                              preferred_element_type=jnp.float32)
+
+        blocks = q.reshape(b, n, rows, h, d).transpose(1, 0, 2, 3, 4)
+        out = jax.lax.map(jax.checkpoint(lambda c: one(*c)),
+                          (jnp.arange(n), blocks))
+        return out.transpose(1, 0, 2, 3, 4).reshape(b, s, h * d
+                                                    ).astype(q.dtype)
+
+    def forward(q, k, v):
+        return flash_attention_packed(q, k, v, h, True, block, block,
+                                      interpret=False, window=window)
+
+    kern = _grads(forward, 3)
+    kern.forward = forward
     return kern, _grads(xla, 3), args
 
 
@@ -149,6 +208,60 @@ CE_CASES = [
     ("fused_ce partial n16384 d2048 v25088 (vocab TP /4)", case_fused_ce,
      (16384, 2048, 25088, True, 2048)),
 ]
+
+
+# the Laguna cell's attention: a sliding layer (72 heads, window 512) and a
+# full layer (48 heads), and the (block, pieces) timed beside the rule's own
+WIN_SHAPE = (2, 72, 8192, 128, 512)
+WIN_TILES = ((512, 128, 128), (512, 256, 256), (512, 512, 512),
+             (256, 256, 256))
+
+
+def run_window():
+    """The rule's windowed call checked against XLA and timed, the
+    candidates timed, and the full layer's causal call beside them."""
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
+    b, h, s, d, window = WIN_SHAPE
+    failed = 0
+    cases = [(f"flash window b{b} h{h} s{s} d{d} w{window}",
+              case_flash_window, WIN_SHAPE),
+             (f"flash b{b} h48 s{s} d{d} causal", case_flash_window,
+              (b, 48, s, d, None))]
+    for name, make, shape in cases:
+        try:
+            rec = run_case(name, make, shape)
+            rec["ok"] = True
+        except Exception as e:
+            failed += 1
+            rec = {"case": name, "ok": False, "error": type(e).__name__,
+                   "message": str(e)[-3000:]}
+            traceback.print_exc(limit=3)
+        print(json.dumps(rec), flush=True)
+    pieces = fa._WINDOW_FWD_PIECE, fa._WINDOW_BWD_PIECE
+    rec = {"case": "flash window candidates (block/forward/backward "
+                   "pieces)", "forward_ms": {}, "forward_backward_ms": {}}
+    try:
+        for block, fwd, bwd in WIN_TILES:
+            fa._WINDOW_FWD_PIECE, fa._WINDOW_BWD_PIECE = fwd, bwd
+            fa._make_flash.cache_clear()
+            kern, _, args = case_flash_window(*WIN_SHAPE, block=block)
+            key = f"{block}/{fwd}/{bwd}"
+            rec["forward_ms"][key] = _pipelined(jax.jit(kern.forward),
+                                                args, 5)
+            rec["forward_backward_ms"][key] = _pipelined(jax.jit(kern),
+                                                         args, 5)
+        rec["ok"] = True
+    except Exception as e:
+        failed += 1
+        rec.update(ok=False, error=type(e).__name__, message=str(e)[-3000:])
+        traceback.print_exc(limit=3)
+    finally:
+        fa._WINDOW_FWD_PIECE, fa._WINDOW_BWD_PIECE = pieces
+        fa._make_flash.cache_clear()
+    print(json.dumps(rec), flush=True)
+    return failed
 
 
 # grouped products: (rows, d, d_ff, groups) of the Moonlight cell, and the
@@ -359,16 +472,18 @@ def run_case(name, make, shape, tol=3e-2):
 
 
 def main(argv):
-    if argv not in ([], ["gmm"], ["ce"]):
+    if argv not in ([], ["gmm"], ["ce"], ["win"]):
         raise SystemExit(f"chip_kernels: takes no argument, 'gmm' (the "
-                         f"grouped products alone) or 'ce' (the fused "
-                         f"head alone), got {argv}")
+                         f"grouped products alone), 'ce' (the fused "
+                         f"head alone) or 'win' (the windowed flash "
+                         f"alone), got {argv}")
     if jax.default_backend() != "tpu":
         raise SystemExit(
             f"chip_kernels: backend {jax.default_backend()!r} is not a "
             f"TPU; Mosaic compiles only there")
     failed = 0
-    cases = {"gmm": [], "ce": CE_CASES}.get("".join(argv), CASES + CE_CASES)
+    cases = {"gmm": [], "ce": CE_CASES, "win": []}.get(
+        "".join(argv), CASES + CE_CASES)
     for name, make, shape in cases:
         try:
             rec = run_case(name, make, shape)
@@ -379,7 +494,9 @@ def main(argv):
                    "message": str(e)[-3000:]}
             traceback.print_exc(limit=3)
         print(json.dumps(rec), flush=True)
-    if argv != ["ce"]:
+    if argv in ([], ["win"]):
+        failed += run_window()
+    if argv in ([], ["gmm"]):
         failed += run_gmm()
     return 1 if failed else 0
 
