@@ -20,7 +20,8 @@ beside the published ``num_hidden_layers``).
 Every block is recomputed in the backward pass (``recompute_blocks``), as
 ``models/latent_moe.py`` does and for its reason: the boundaries are kept,
 and with them the flash forward's named results; the scan's intermediates
-(a chunk's decay matrix is 64 heads x 256 x 256 a chunk) are made again.
+(a chunk's decay matrix is 64 heads x 256 x 256 a chunk: in VMEM where
+``ops/pallas/ssd_scan.py`` runs, in HBM elsewhere) are made again.
 """
 
 from __future__ import annotations

@@ -18,16 +18,20 @@ kernel for dX, dW and db and tiles picked from the width, won
 ``grouped_mm.py`` (``ff_gmm``, ``ff_gmm_t``, ``ff_gmm_dw``: the grouped
 products of the experts a chip holds) won the Moonlight cell, +11.3%
 (PR 31: a layer's product 0.49-0.58 ms where XLA's own grouped matmul
-under ``jax.lax.ragged_dot`` took 0.94-2.3, 85.8 -> 31.4 ms a step).  The
-max-pool, avg-pool and batch-norm kernels that lost their cells left in
-PR 30 (PERF.md section 6).
+under ``jax.lax.ragged_dot`` took 0.94-2.3, 85.8 -> 31.4 ms a step);
+``ssd_scan.py`` (``ff_ssd_fwd``, ``ff_ssd_bwd``: the state-space scan
+with a chunk's decay-weighted scores made in VMEM) won
+``granite_4_0_h_micro.train_1chip_b2_s8192_ref2``, +9.35% (PR 35: the
+nine scans 140.3 -> 50.7 ms a step, a layer's forward 3.6 -> 1.2 ms and
+its backward 8.3 -> 3.2).  The max-pool, avg-pool and batch-norm kernels
+that lost their cells left in PR 30 (PERF.md section 6).
 
 Which path an operator takes is decided from what the code observes and
 never by a switch: the backend (:func:`flash_enabled`, the one gate every
 caller shares) and the shapes and types, by the rules that live beside
 each kernel (``flash_attention._layout``, ``_pick_block``;
 ``grouped_mm._pick_tiles``; ``FFModel._fusion_ok`` and
-``fused_ce._pick_tiles``).  A new kernel joins
+``fused_ce._pick_tiles``; ``ssd_scan.fits``).  A new kernel joins
 the same way.
 
 Kernels run compiled (Mosaic) on TPU; interpreter mode is for the CPU test
@@ -42,7 +46,9 @@ from flexflow_tpu.ops.pallas.flash_attention import \
 # they are dearer to make again than to hold: a block that a model class
 # recomputes in the backward pass keeps these and nothing else
 # (FFModel._run_recomputed).  A kernel joins by naming its results in its
-# own module and adding them here.
+# own module and adding them here.  (The scan's ``y`` and entering states
+# are not named: kept, they cost 2.15 GB for +0.87%, PERF.md section 6,
+# PR 35.)
 KEPT_RESULTS = (*_FLASH_RESULTS,)
 
 
@@ -57,6 +63,24 @@ def flash_enabled() -> bool:
     import jax
 
     return jax.default_backend() == "tpu"
+
+
+def traced_once(fn, *avals):
+    """``fn`` traced now, for these shapes and types, to a function of
+    flat arrays that binds the traced equations wherever it is called: a
+    model's layers, the blocks it recomputes and their derivatives then
+    share one trace and one lowering of every kernel body, and each call
+    site's equations still carry its own operator's name.  (An inline
+    ``jit`` shares a trace only among callers in one tracing context, and
+    a step has four: 16 traces of the grouped products' kernels a
+    Moonlight step where 6 do, and ``setup_s`` outside its bound; PERF.md
+    section 6, PR 31.  ``grouped_mm.py`` and ``ssd_scan.py`` build their
+    custom-VJP passes through it.)"""
+    import jax
+    import jax.extend
+
+    closed = jax.make_jaxpr(fn)(*avals)
+    return jax.extend.core.jaxpr_as_fun(closed), closed.out_avals
 
 
 __all__ = ["KEPT_RESULTS", "flash_attention", "flash_enabled"]

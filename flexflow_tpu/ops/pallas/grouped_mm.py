@@ -58,10 +58,11 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.extend
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from flexflow_tpu.ops.pallas import traced_once
 
 LANES = 128
 _F32 = jnp.float32
@@ -427,19 +428,6 @@ def ff_gmm_dw(a, b, group_sizes, out_dtype=None, tiles=None, interpret=None):
     return _form("dw", a, b, group_sizes, out_dtype, tiles, interpret)
 
 
-def _traced_once(fn, *avals):
-    """``fn`` traced now, for these shapes and types, to a function of
-    flat arrays that binds the traced equations wherever it is called: a
-    model's layers, the blocks it recomputes and their derivatives then
-    share one trace and one lowering of every kernel body, and each call
-    site's equations still carry its own operator's name.  (An inline
-    ``jit`` shares a trace only among callers in one tracing context, and
-    a step has four: 16 traces of these kernels a Moonlight step where 6
-    do, and ``setup_s`` outside its bound; PERF.md section 6, PR 31.)"""
-    closed = jax.make_jaxpr(fn)(*avals)
-    return jax.extend.core.jaxpr_as_fun(closed), closed.out_avals
-
-
 @functools.lru_cache(maxsize=None)
 def _make_gated_ffn(m: int, d: int, f: int, groups: int, dtype: str,
                     interpret: bool):
@@ -500,8 +488,8 @@ def _make_gated_ffn(m: int, d: int, f: int, groups: int, dtype: str,
 
     operands = (aval(m, d), aval(groups, of="int32"), aval(groups, d, f),
                 aval(groups, d, f), aval(groups, f, d))
-    forward, (_, *kept) = _traced_once(forward, *operands)
-    backward, _ = _traced_once(backward, *operands, *kept, aval(m, d))
+    forward, (_, *kept) = traced_once(forward, *operands)
+    backward, _ = traced_once(backward, *operands, *kept, aval(m, d))
 
     @jax.custom_vjp
     def ffn(*operands):

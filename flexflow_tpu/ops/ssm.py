@@ -18,19 +18,33 @@ wide and shared by every head (one group, ``mamba_n_groups`` 1; more is
 refused).  The gate comes before the norm, and the norm runs over the
 whole inner width (one group).
 
-The scan runs in the chunked ("state-space dual") form, in plain
-``jax.numpy``: inside a chunk of ``chunk`` steps ``Y = (L * (C B^T))
-(delta x)`` with ``L[t, s] = exp(sum_{s<r<=t} delta_r A)`` for ``s <= t``;
-a chunk's own state ``sum_s exp(sum_{s<r<=end} delta_r A) delta_s x_s
-(outer) B_s``; the recurrence over a sequence's chunks as one small
-product with the decays between chunks (no loop in the program); and
-what the entering state adds, ``exp(cumsum) C_t H_in``.  Decays,
-cumulative sums, softplus and the carried state are float32; the products
-take the compute type with float32 accumulation.  A sequence that the
-chunk does not divide is padded with steps of ``delta = 0``, which leave
-the state as it is and add nothing.  Which form ran is counted once a
-trace in ``kernels.ssd.<form>.<chunk>x<heads>x<state>``; the only form is
-``xla_chunked`` until a kernel wins the cell (ops/pallas/__init__.py).
+The scan runs in the chunked ("state-space dual") form: inside a chunk
+of ``chunk`` steps ``Y = (L * (C B^T)) (delta x)`` with ``L[t, s] =
+exp(sum_{s<r<=t} delta_r A)`` for ``s <= t``; a chunk's own state ``sum_s
+exp(sum_{s<r<=end} delta_r A) delta_s x_s (outer) B_s``; the recurrence
+over a sequence's chunks; and what the entering state adds, ``exp(cumsum)
+C_t H_in``.  Decays, cumulative sums, softplus and the carried state are
+float32; the products take the compute type with float32 accumulation.  A
+sequence that the chunk does not divide is padded with steps of ``delta =
+0``, which leave the state as it is and add nothing.
+
+Two forms of it, one algorithm.  ``xla_chunked`` (:func:`ssd_chunked`) is
+plain ``jax.numpy``: every backend runs it, the tests hold the kernels to
+it, and it writes a chunk's ``(heads, chunk, chunk)`` decay matrix and
+``m`` to HBM (the recurrence over chunks as one small product with the
+decays between chunks, no loop in the program).  ``pallas``
+(``ops/pallas/ssd_scan.py``) makes the same matrices in VMEM a (sequence,
+chunk, group of heads) at a time, carries the state from chunk to chunk
+in VMEM, and has a backward of its own.  Which runs is decided where the
+operator is traced, from what the code observes and by no switch: the one
+kernel gate (``ops/pallas.flash_enabled()``: the backend is a TPU) and the
+shapes the kernels hold (``ssd_scan.fits``: a chunk of 128 or 256,
+heads in groups of eight that are 16, 32 or 64 wide, up to 4096 columns
+in all, a state of 128, bfloat16 or float32; the Granite cell's shape is
+the one timed on the chip); every other shape, the
+tests' chunks of 1, 3, 10 and 64 among them, keeps ``xla_chunked``.
+Which form ran is counted once a trace in
+``kernels.ssd.<form>.<chunk>x<heads>x<state>``.
 
 Grids: ('s', 'n') as the other sequence operators', of which only (1, 1)
 is implemented (a split sequence would hand states between shards).
@@ -229,8 +243,6 @@ class SSMScan(_SeqElementwise):
     """Everything between ``x, B, C, delta`` and ``y``: the state-space
     scan in its chunked form and the skip ``D x``."""
 
-    FORM = "xla_chunked"
-
     def __init__(self, name: str, pc: ParallelConfig, xbc: Tensor,
                  delta: Tensor, num_heads: int, head_dim: int, d_state: int,
                  chunk: int):
@@ -265,17 +277,28 @@ class SSMScan(_SeqElementwise):
     def forward(self, params, state, xs: List, train: bool):
         import jax.numpy as jnp
 
+        from flexflow_tpu.ops import pallas
+        from flexflow_tpu.ops.pallas import ssd_scan
+
         xbc, delta = xs
         b, s, _ = xbc.shape
         di, n, chunk = self.d_inner, self.d_state, self.chunk
-        obs.count(f"kernels.ssd.{self.FORM}.{chunk}x{self.num_heads}x{n}")
+        a = -jnp.exp(params["A_log"].astype(jnp.float32))
+        d = params["D"].astype(jnp.float32)
+        # the kernels where the backend is a TPU and the shapes are theirs
+        kernels = pallas.flash_enabled() and ssd_scan.fits(
+            chunk, self.num_heads, self.head_dim, n, xbc.dtype)
+        form = "pallas" if kernels else "xla_chunked"
+        obs.count(f"kernels.ssd.{form}.{chunk}x{self.num_heads}x{n}")
         obs.count("ssm.chunk", chunk, level=True)
         obs.count("ssm.chunks_per_sequence", -(-s // chunk), level=True)
+        if kernels:
+            return ssd_scan.ssd_scan(
+                xbc, delta, a, d, heads=self.num_heads,
+                head_dim=self.head_dim, state=n, chunk=chunk), state
         y = ssd_chunked(
             xbc[..., :di].reshape(b, s, self.num_heads, self.head_dim),
-            delta, -jnp.exp(params["A_log"].astype(jnp.float32)),
-            xbc[..., di:di + n], xbc[..., di + n:],
-            params["D"].astype(jnp.float32), chunk)
+            delta, a, xbc[..., di:di + n], xbc[..., di + n:], d, chunk)
         return y.reshape(b, s, di), state
 
     def cost_signature(self) -> tuple:

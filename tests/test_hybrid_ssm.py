@@ -100,19 +100,43 @@ def test_chunked_scan_equals_the_recurrence_over_time_steps(chunk):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
 
 
-def test_chunked_scan_survives_decays_that_underflow():
+@pytest.mark.parametrize("path", ["xla", "kernels"])
+def test_chunked_scan_survives_decays_that_underflow(path):
     """exp of a positive difference above the diagonal is never formed:
-    steps of delta A = -60 leave finite values and gradients."""
+    steps of delta A = -60 leave finite values and gradients, in
+    ``ssd_chunked`` and in the Pallas kernels (interpret mode, at shapes
+    that are theirs: 8 heads of 16, state 128, chunks of 128)."""
+    from flexflow_tpu.ops.pallas.ssd_scan import ssd_scan
     from flexflow_tpu.ops.ssm import ssd_chunked
 
-    x, dt, a, b, c, d = _scan_operands(8)
+    if path == "xla":
+        x, dt, a, b, c, d = _scan_operands(8)
+        scan = lambda x, dt: ssd_chunked(x, dt, a, b, c, d, 4)
+    else:
+        x, dt, a, d = (_rand(0, 1, 256, 8, 16), _rand(1, 1, 256, 8),
+                       _rand(2, 8), _rand(5, 8))
+        b, c = _rand(3, 1, 256, 128), _rand(4, 1, 256, 128)
+        scan = lambda x, dt: ssd_scan(
+            jnp.concatenate([x.reshape(1, 256, 128), b, c], -1), dt, a, d,
+            heads=8, head_dim=16, state=128, chunk=128,
+            interpret=True).reshape(x.shape)
     dt, a = dt * 0 + 3.0, a * 0 - 20.0
-    f = lambda x, dt: jnp.sum(ssd_chunked(x, dt, a, b, c, d, 4) ** 2)
+    f = lambda x, dt: jnp.sum(scan(x, dt) ** 2)
     value, grads = jax.value_and_grad(f, argnums=(0, 1))(x, dt)
     assert np.isfinite(value) and all(np.all(np.isfinite(g)) for g in grads)
-    np.testing.assert_allclose(ssd_chunked(x, dt, a, b, c, d, 4),
-                               _recurrence(x, dt, a, b, c, d), rtol=1e-5,
-                               atol=1e-6)
+    if path == "xla":
+        np.testing.assert_allclose(scan(x, dt),
+                                   _recurrence(x, dt, a, b, c, d),
+                                   rtol=1e-5, atol=1e-6)
+        return
+    with jax.default_matmul_precision("highest"):
+        want = _recurrence(x, dt, a, b, c, d)
+        # (the kernels' sums run over a state of 128 numbers and values
+        # of tens, where the case above has 5: the same limit in units
+        # of the largest value)
+        np.testing.assert_allclose(
+            scan(x, dt), want, rtol=1e-5,
+            atol=1e-6 * max(1.0, float(jnp.max(jnp.abs(want)))))
 
 
 def test_causal_convolution_against_a_loop():
@@ -346,13 +370,28 @@ def test_multipliers_scale_the_embedding_and_the_branches():
 # the whole model
 
 
-@pytest.mark.parametrize("head", ["plain", "fused"])
+def _kernel_model(b, s):
+    """A model whose scans are the Pallas kernels' shapes (8 heads of 32,
+    state 128, chunks of 128) and whose widths are whole lanes."""
+    from flexflow_tpu.models.hybrid_ssm import HybridSSMConfig, HybridSSMLM
+
+    cfg = _tiny_config(hidden_size=128, num_attention_heads=2,
+                       num_key_value_heads=1, mamba_n_heads=8,
+                       mamba_d_head=32, mamba_d_state=128,
+                       mamba_chunk_size=128, max_position_embeddings=512,
+                       vocab_size=256)
+    return cfg, HybridSSMLM(HybridSSMConfig.from_config(
+        cfg, batch_size=b, seq_length=s), MachineModel(jax.devices()[:1]))
+
+
+@pytest.mark.parametrize("head", ["plain", "fused", "scan_kernels"])
 def test_loss_and_every_operator_gradient_against_the_reference(
         head, tiny_model, pallas_kernels):
     """Seeded weights; ``fused``: a model of whole lanes with the kernel
     gate open, so that the tied head runs in the fused projection+CE
     kernel and the attention in the flash kernels, inside recomputed
-    blocks."""
+    blocks; ``scan_kernels``: the same with scans of the shapes
+    ``ff_ssd_fwd`` and ``ff_ssd_bwd`` take (the last chunk padded)."""
     import contextlib
 
     from benchmarks import harness
@@ -366,17 +405,23 @@ def test_loss_and_every_operator_gradient_against_the_reference(
         ff = HybridSSMLM(HybridSSMConfig.from_config(
             cfg, batch_size=b, seq_length=s),
             MachineModel(jax.devices()[:1]))
+    if head == "scan_kernels":
+        (cfg, ff), b, s = _kernel_model(2, 200), 2, 200
+    form = "kernels.ssd.pallas.128x8x128"
+    ran = _counted(form)
     params, state = ff.init(4)
     # every gain, D and bias away from its initial value
     params = jax.tree.map(
         lambda a: a + 0.1 * _rand(a.size % 97, *a.shape), params)
     toks = jax.random.randint(jax.random.PRNGKey(5), (b, s), 0,
                               cfg["vocab_size"])
-    with pallas_kernels() if head == "fused" else contextlib.nullcontext():
+    with pallas_kernels() if head != "plain" else contextlib.nullcontext():
         if head == "fused":
             assert ff._lm_head_fusion()
         (loss, _), grads = jax.value_and_grad(
             lambda p: ff.loss_fn(p, state, toks, toks), has_aux=True)(params)
+    # two scans, each traced forward and in its recomputed block
+    assert (_counted(form) > ran) == (head == "scan_kernels")
     plain = harness.op_params(ff, params)
     assert "lm_head" not in plain
     with jax.default_matmul_precision("highest"):
@@ -393,25 +438,42 @@ def test_loss_and_every_operator_gradient_against_the_reference(
                 err_msg=f"{op}.{leaf}")
 
 
-def test_recomputed_step_equals_the_plain_step(tiny_model):
-    model = tiny_model
-    toks = jax.random.randint(jax.random.PRNGKey(7), (2, 20), 0, 96)
+@pytest.mark.parametrize("path", ["xla", "kernels"])
+def test_recomputed_step_equals_the_plain_step(path, tiny_model,
+                                               pallas_kernels):
+    """``kernels``: a model whose scans run ``ff_ssd_fwd`` and
+    ``ff_ssd_bwd`` (and whose attention the flash kernels), so that a
+    recomputed block runs the forward kernel once more and hands its
+    backward the states it wrote."""
+    import contextlib
+
+    model, s, vocab = tiny_model, 20, 96
+    if path == "kernels":
+        (_, model), s, vocab = _kernel_model(2, 256), 256, 256
+    toks = jax.random.randint(jax.random.PRNGKey(7), (2, s), 0, vocab)
     params, state = model.init(3)
     before = jax.tree.map(np.asarray, params)
     blocks = model.recompute_blocks
-    out = model.make_train_step()(params, state, None, toks, toks)
-    try:
-        model.recompute_blocks = ()
-        model._recompute_cache = None
-        params2, state2 = model.init(3)
-        plain = model.make_train_step()(params2, state2, None, toks, toks)
-    finally:
-        model.recompute_blocks = blocks
-        model._recompute_cache = None
+    form = "kernels.ssd.pallas.128x8x128"
+    ran = _counted(form)
+    with pallas_kernels() if path == "kernels" else contextlib.nullcontext():
+        out = model.make_train_step()(params, state, None, toks, toks)
+        assert (_counted(form) > ran) == (path == "kernels")
+        try:
+            model.recompute_blocks = ()
+            model._recompute_cache = None
+            params2, state2 = model.init(3)
+            plain = model.make_train_step()(params2, state2, None, toks,
+                                            toks)
+        finally:
+            model.recompute_blocks = blocks
+            model._recompute_cache = None
     np.testing.assert_allclose(out[3], plain[3], rtol=1e-6)
     for a, b, p0 in zip(jax.tree.leaves(out[0]), jax.tree.leaves(plain[0]),
                         jax.tree.leaves(before)):
         np.testing.assert_allclose(a - p0, b - p0, rtol=1e-3, atol=1e-7)
+    if path == "kernels":
+        return
     # and on one fixed batch the loss falls
     step, (p, st), losses = model.make_train_step(), model.init(3), []
     for _ in range(8):

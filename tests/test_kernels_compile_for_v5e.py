@@ -1,6 +1,7 @@
-"""The fused head's kernels and the windowed flash kernels compiled by
-Mosaic for a described TPU v5e, at the widths the benchmark's cells run
-and at the widest the fusion takes: interpret mode says nothing about
+"""The fused head's kernels, the windowed flash kernels and the
+state-space scan's kernels compiled by Mosaic for a described TPU v5e, at
+the widths the benchmark's cells run, at the widest the fusion takes and
+at the corners of the scan's rule: interpret mode says nothing about
 what the chip's compiler accepts (VMEM above all), and a compile here
 costs no chip time.  Nothing runs: a pass is not a measurement."""
 
@@ -82,3 +83,40 @@ def test_flash_compiles_at_the_laguna_cells_shapes(one_chip, heads, window,
     for name in names:
         assert re.search(rf"\b{name}\b", text), name
     assert ("ff_flash_win_" in text) == (window is not None)
+
+
+@pytest.mark.parametrize("heads,head_dim,state,chunk,dtype", [
+    (64, 64, 128, 256, "bfloat16"),    # granite_4_0_h_micro
+    # the corners of ``ssd_scan.fits`` (compiled, not timed)
+    (64, 64, 128, 256, "float32"),     # the most VMEM the rule takes
+    (8, 32, 128, 128, "float32"),      # the shorter chunk, one group
+    (16, 16, 128, 128, "bfloat16"),    # the narrowest head, two groups
+    (256, 16, 128, 256, "bfloat16"),   # the most groups of heads
+])
+def test_scan_compiles_and_no_chunk_matrix_reaches_hbm(one_chip, heads,
+                                                       head_dim, state,
+                                                       chunk, dtype):
+    from flexflow_tpu.ops.pallas import ssd_scan as ss
+
+    b, s = 2, 8 * chunk
+    assert ss.fits(chunk, heads, head_dim, state, dtype)
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    def step(xbc, dt, a, d):
+        return jax.value_and_grad(
+            lambda *args: ss.ssd_scan(
+                *args, heads=heads, head_dim=head_dim, state=state,
+                chunk=chunk, interpret=False).astype(jnp.float32).sum(),
+            (0, 1, 2, 3))(xbc, dt, a, d)
+
+    text = jax.jit(step).lower(
+        shape((b, s, heads * head_dim + 2 * state), dtype),
+        shape((b, s, heads), jnp.float32), shape((heads,), jnp.float32),
+        shape((heads,), jnp.float32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("ff_ssd_fwd", "ff_ssd_bwd"):
+        assert re.search(rf"\b{name}\b", text), name
+    # a decay matrix or m would be (.., heads, chunk, chunk)
+    assert not re.search(rf"\[[\d,]*{chunk},{chunk}\]", text)

@@ -4,6 +4,7 @@
     python tools/chip_kernels.py gmm           # the grouped products alone
     python tools/chip_kernels.py ce            # the fused head alone
     python tools/chip_kernels.py win           # the windowed flash alone
+    python tools/chip_kernels.py scan          # the state-space scan alone
 
 The CPU suite runs these kernels in interpret mode at test shapes, and the
 compiled branch picks other block shapes (flash_attention._make_flash,
@@ -32,7 +33,13 @@ flash kernels at the Laguna cell's shapes (b2 s8192 d128, 72 heads under a
 window of 512, bfloat16) against XLA's masked attention walking the
 queries a window at a time, at the block and pieces the shapes pick and
 at the candidates of ``WIN_TILES``, beside the 48-head full layer's
-causal call (the check to run after a libtpu change).
+causal call (the check to run after a libtpu change); the state-space
+scan at the Granite cell's shape (2 x 8192 tokens, 64 heads of 64, state
+128, chunks of 256, bfloat16): ``y`` and the gradients of ``x``, ``B``,
+``C``, ``delta``, ``A_log`` and ``D`` through ``ff_ssd_fwd`` and
+``ff_ssd_bwd`` against ``ops/ssm.py: ssd_chunked``, both timed forward
+and forward + backward (the interpreter cannot see a write in flight, and
+the kernels carry a state from grid step to grid step).
 """
 
 import json
@@ -187,6 +194,43 @@ def case_fused_ce(n, d, v, partial, chunk=None):
     return kern, ref, [x, w, b, labels]
 
 
+def case_scan(b, s, h, p, n, chunk):
+    """Both sides on the mixer's own arrays: ``xBC`` as the projection
+    leaves it, float32 time steps within 0.001-0.1 (``SSMIn``'s), ``A``
+    from -1 to -h.  Returns y and the gradients of x, B, C, delta, A_log
+    and D under one cotangent."""
+    from flexflow_tpu.ops.pallas.ssd_scan import ssd_scan
+    from flexflow_tpu.ops.ssm import ssd_chunked
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    di = h * p
+    xbc = jax.nn.silu(_rand(ks[0], (b, s, di + 2 * n), jnp.float32)
+                      ).astype(jnp.bfloat16)
+    dt = jnp.exp(jax.random.uniform(ks[1], (b, s, h), jnp.float32,
+                                    np.log(0.001), np.log(0.1)))
+    a_log = jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32))
+    d = 1.0 + _rand(ks[2], (h,), jnp.float32, 0.1)
+
+    def kernel(xbc, dt, a_log, d):
+        return ssd_scan(xbc, dt, -jnp.exp(a_log), d, heads=h, head_dim=p,
+                        state=n, chunk=chunk, interpret=False)
+
+    def xla(xbc, dt, a_log, d):
+        return ssd_chunked(xbc[..., :di].reshape(b, s, h, p), dt,
+                           -jnp.exp(a_log), xbc[..., di:di + n],
+                           xbc[..., di + n:], d, chunk).reshape(b, s, di)
+
+    def leaves(forward):
+        def run(*args):
+            y, d_xbc, *rest = _grads(forward, 4)(*args)
+            return (y, d_xbc[..., :di], d_xbc[..., di:di + n],
+                    d_xbc[..., di + n:], *rest)
+        run.forward = forward   # run_case times it alone as well
+        return run
+
+    return leaves(kernel), leaves(xla), [xbc, dt, a_log, d]
+
+
 CASES = [
     ("flash b16 h12 s512 d64 causal", case_flash, (16, 12, 512, 64, True)),
     ("flash b16 h12 s512 d64 full", case_flash, (16, 12, 512, 64, False)),
@@ -207,6 +251,13 @@ CE_CASES = [
      (16384, 2048, 20480, False, 2048)),
     ("fused_ce partial n16384 d2048 v25088 (vocab TP /4)", case_fused_ce,
      (16384, 2048, 25088, True, 2048)),
+]
+
+
+# granite_4_0_h_micro's scan, a layer of the cell
+SCAN_CASES = [
+    ("ssd_scan b2 s8192 h64 p64 n128 chunk256", case_scan,
+     (2, 8192, 64, 64, 128, 256)),
 ]
 
 
@@ -465,6 +516,9 @@ def run_case(name, make, shape, tol=3e-2):
         rec["forward_backward_ms"] = _pipelined(compiled, args, 5)
         rec["backward_ms"] = round(
             rec["forward_backward_ms"] - rec["forward_ms"], 4)
+    if getattr(ref, "forward", None) is not None:
+        rec["xla_forward_ms"] = _pipelined(jax.jit(ref.forward), args, 5)
+        rec["xla_forward_backward_ms"] = _pipelined(jax.jit(ref), args, 5)
     if max(rec["rel_err"]) > tol:
         raise RuntimeError(f"kernel disagrees with its XLA reference: "
                            f"relative errors {rec['rel_err']} > {tol}")
@@ -472,18 +526,19 @@ def run_case(name, make, shape, tol=3e-2):
 
 
 def main(argv):
-    if argv not in ([], ["gmm"], ["ce"], ["win"]):
+    if argv not in ([], ["gmm"], ["ce"], ["win"], ["scan"]):
         raise SystemExit(f"chip_kernels: takes no argument, 'gmm' (the "
                          f"grouped products alone), 'ce' (the fused "
-                         f"head alone) or 'win' (the windowed flash "
-                         f"alone), got {argv}")
+                         f"head alone), 'win' (the windowed flash alone) "
+                         f"or 'scan' (the state-space scan alone), got "
+                         f"{argv}")
     if jax.default_backend() != "tpu":
         raise SystemExit(
             f"chip_kernels: backend {jax.default_backend()!r} is not a "
             f"TPU; Mosaic compiles only there")
     failed = 0
-    cases = {"gmm": [], "ce": CE_CASES, "win": []}.get(
-        "".join(argv), CASES + CE_CASES)
+    cases = {"gmm": [], "ce": CE_CASES, "win": [], "scan": SCAN_CASES}.get(
+        "".join(argv), CASES + CE_CASES + SCAN_CASES)
     for name, make, shape in cases:
         try:
             rec = run_case(name, make, shape)
