@@ -27,6 +27,7 @@ over the *global* batch (see ops/softmax.py for why this normalization).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional
 
@@ -134,27 +135,60 @@ def _fully_partitioned(op) -> bool:
 
 
 class _TrainStep:
-    """A jitted train step that remembers what it first ran on.
+    """A jitted train step that remembers what it first ran on, and the
+    host's side of every call.
 
-    Calls go straight through to the ``jax.jit`` object, and so does
-    every attribute (``lower``, ``trace``, ...).  Before its first call it
-    keeps the arguments' shapes, dtypes and shardings as
-    ``first_call`` and names itself its model's ``_ran_step``:
-    ``FFModel.operator_table()`` lowers this same object for them, so the
-    table it reads is of the program that ran and of no other."""
+    Every attribute (``lower``, ``trace``, ...) goes straight through to
+    the ``jax.jit`` object.  Before its first call it keeps the
+    arguments' shapes, dtypes and shardings as ``first_call`` and names
+    itself its model's ``_ran_step``: ``FFModel.operator_table()`` lowers
+    this same object for them, so the table it reads is of the program
+    that ran and of no other.  That first call (tracing, lowering, the
+    compilation or its fetch from the cache, the dispatch) runs under
+    ``ff:entry.step_build``, whose late ``args`` hold the wall seconds
+    each of JAX's stages took inside it; every later call under
+    ``ff:runtime.step`` (``n``: the call's number), the host's dispatch
+    of one step.  A call inside another trace is neither."""
+
+    STAGES = ("trace", "lower", "backend", "cache_fetch")
 
     def __init__(self, model, jitted):
+        import jax
+
         self._model = model
         self._jitted = jitted
+        self._tracer = jax.core.Tracer
         self.first_call = None
+        self.calls = 0
 
     def __call__(self, *args):
         if self.first_call is None:
-            self._remember(args)
-        return self._jitted(*args)
+            return self._build(args)
+        if args and isinstance(args[-1], self._tracer):
+            return self._jitted(*args)
+        self.calls += 1
+        with obs.span("ff:runtime.step", n=self.calls):
+            return self._jitted(*args)
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)
+
+    def _build(self, args):
+        self._remember(args)
+        if self.first_call is None:
+            return self._jitted(*args)
+        model = self._model
+        before = obs.summary()["counters"]
+        with obs.span("ff:entry.step_build", ops=len(model.layers),
+                      blocks=len(getattr(model, "recompute_blocks", ()))
+                      ) as build:
+            out = self._jitted(*args)
+            after = obs.summary()["counters"]
+            for stage in self.STAGES:
+                wall = f"compile.{stage}_wall_s"
+                build.args[stage + "_s"] = after.get(wall, 0.0) \
+                    - before.get(wall, 0.0)
+        return out
 
     def _remember(self, args):
         import jax
@@ -1206,6 +1240,29 @@ class FFModel:
         with self._honored_ctx():
             return self._apply(params, state, inputs, train)
 
+    @contextlib.contextmanager
+    def _op_scope(self, op, xs, block=None):
+        """``op``'s ``jax.named_scope`` (every device operation carries
+        its operator's name in its ``op_name`` metadata; obs/optrace.py
+        reads it back) and, where an input is a tracer, an
+        ``ff:entry.trace_op`` span: the Python of a jitted function runs
+        only while JAX traces it, so the span times the tracing of one
+        operator and a running step never meets it.  The raw records are
+        bounded a name, so the self seconds are also summed by the
+        operator's class (``entry.trace_op_s.<class>``)."""
+        import jax
+
+        with jax.named_scope(op.name):
+            if not any(isinstance(x, jax.core.Tracer) for x in xs):
+                yield
+                return
+            kind = type(op).__name__
+            where = {} if block is None else {"block": block}
+            with obs.span("ff:entry.trace_op", op=op.name, kind=kind,
+                          **where) as sp:
+                yield
+            obs.count("entry.trace_op_s." + kind, sp.self_s)
+
     def _apply(self, params, state, inputs: Dict[int, Any], train: bool):
         import jax
         from jax import lax
@@ -1317,11 +1374,12 @@ class FFModel:
                 lin = fusion[i]
                 if lin is None:
                     continue  # projection folded into its loss op
-                with jax.named_scope(lin.name), jax.named_scope(op.name):
+                x, labels = take(lin.inputs[0].tid), take(
+                    op.labels_tensor.tid)
+                with self._op_scope(lin, (x, labels)), \
+                        jax.named_scope(op.name):
                     values[op.output.tid] = self._run_fused_lm_head(
-                        lin, params.get(lin.param_key, {}),
-                        take(lin.inputs[0].tid),
-                        take(op.labels_tensor.tid))
+                        lin, params.get(lin.param_key, {}), x, labels)
                 continue
             xs = [take(t.tid) for t in op.inputs]
             if multi and plan is not None:
@@ -1329,9 +1387,7 @@ class FFModel:
                       for i, x in enumerate(xs)]
             elif multi:
                 xs = self._regrid_inputs(op, xs, specs)
-            # every device operation carries its operator's name in its
-            # ``op_name`` metadata (obs/optrace.py reads it back)
-            with jax.named_scope(op.name):
+            with self._op_scope(op, xs):
                 res, st = op.forward(self._member_params(params, op),
                                      self._member_state(state, op), xs,
                                      train)
@@ -1380,8 +1436,8 @@ class FFModel:
                      if i not in rng for t in op.inputs}
             outputs = [t.tid for op in ops for t in op.all_outputs()
                        if t.tid in later]
-            plan[idx[0]] = {"ops": idx, "inputs": inputs,
-                            "outputs": outputs}
+            plan[idx[0]] = {"block": len(plan), "ops": idx,
+                            "inputs": inputs, "outputs": outputs}
         self._recompute_cache = (key, plan)
         return plan
 
@@ -1417,11 +1473,11 @@ class FFModel:
             vals = dict(zip(blk["inputs"], xs))
             st_out = {}
             for op in ops:
-                with jax.named_scope(op.name):
+                op_xs = [vals[t.tid] for t in op.inputs]
+                with self._op_scope(op, op_xs, blk["block"]):
                     res, st = op.forward(
                         self._member_params(p, op),
-                        self._member_state(s, op),
-                        [vals[t.tid] for t in op.inputs], True)
+                        self._member_state(s, op), op_xs, True)
                     if st:
                         st = self._restack_state(op, st)
                 ys = res if isinstance(res, tuple) else (res,)
@@ -1431,11 +1487,18 @@ class FFModel:
                     st_out[op.name] = st
             return [vals[t] for t in blk["outputs"]], st_out
 
-        outs, st = jax.checkpoint(body, policy=keep)(
-            {op.param_key: params[op.param_key] for op in ops
-             if op.param_key in params},
-            {op.name: state[op.name] for op in ops if op.name in state},
-            [take(t) for t in blk["inputs"]])
+        # ``jax.checkpoint`` traces its body wherever it is called: what
+        # the span holds beyond its operators is JAX's own work on the
+        # block (the policy's questions, the partial evaluation)
+        with obs.span("ff:entry.trace_block", block=blk["block"],
+                      ops=len(ops)) as sp:
+            outs, st = jax.checkpoint(body, policy=keep)(
+                {op.param_key: params[op.param_key] for op in ops
+                 if op.param_key in params},
+                {op.name: state[op.name] for op in ops
+                 if op.name in state},
+                [take(t) for t in blk["inputs"]])
+        obs.count(f"entry.trace_block_s.{blk['block']}", sp.seconds)
         values.update(zip(blk["outputs"], outs))
         new_state.update(st)
 
@@ -1596,13 +1659,12 @@ class FFModel:
         return out
 
     def loss_fn(self, params, state, image, labels, train: bool = True):
-        import jax
-
         loss_op = self._loss_op()
         inputs = {self._inputs[0].tid: image}
         values, new_state = self.apply(params, state, inputs, train)
-        with jax.named_scope(loss_op.name):
-            loss = loss_op.loss(values[loss_op.output.tid], labels)
+        out = values[loss_op.output.tid]
+        with self._op_scope(loss_op, (out, labels)):
+            loss = loss_op.loss(out, labels)
         return loss, new_state
 
     def _publish_state_counters(self, state) -> None:
@@ -2316,17 +2378,15 @@ class FFModel:
                                 jax.block_until_ready(loss)
                         start = time.perf_counter()
                     try:
-                        with obs.span("ff:runtime.fit_step", step=it + 1):
-                            if sample_every \
-                                    and (it + 1) % sample_every == 0:
-                                params, state, opt_state, loss = \
-                                    self._sampled_step(
-                                        step, sections, op_samples, it,
-                                        loss, params, state, opt_state,
-                                        batch)
-                            else:
-                                params, state, opt_state, loss = step(
-                                    params, state, opt_state, *batch)
+                        if sample_every and (it + 1) % sample_every == 0:
+                            params, state, opt_state, loss = \
+                                self._sampled_step(
+                                    step, sections, op_samples, it,
+                                    loss, params, state, opt_state,
+                                    batch)
+                        else:
+                            params, state, opt_state, loss = step(
+                                params, state, opt_state, *batch)
                         if transient_retries:
                             healthy_streak += 1
                             if transient_reset \

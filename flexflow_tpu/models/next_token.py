@@ -30,7 +30,6 @@ class NextTokenLM(FFModel):
         """Mean next-token cross-entropy: position i predicts
         ``labels[i + 1]`` and the last position has no target, as
         ``TransformerLM.loss_fn`` shifts them.  No balance loss."""
-        import jax
         import jax.numpy as jnp
 
         labels = jnp.concatenate(
@@ -39,9 +38,9 @@ class NextTokenLM(FFModel):
         inputs = {self.tokens.tid: tokens, self.labels.tid: labels}
         values, new_state = self.apply(params, state, inputs, train)
         op = self.loss_op
-        with jax.named_scope(op.name):
-            total = op.loss(values[op.output.tid],
-                            values[op.labels_tensor.tid])
+        xs = (values[op.output.tid], values[op.labels_tensor.tid])
+        with self._op_scope(op, xs):
+            total = op.loss(*xs)
         return total / (self.t.batch_size * (self.t.seq_length - 1)), \
             new_state
 

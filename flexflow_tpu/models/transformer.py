@@ -172,7 +172,6 @@ class TransformerLM(FFModel):
     # ------------------------------------------------------------------
 
     def loss_fn(self, params, state, tokens, labels, train: bool = True):
-        import jax
         import jax.numpy as jnp
 
         if self.t.causal:
@@ -186,9 +185,9 @@ class TransformerLM(FFModel):
         inputs = {self.tokens.tid: tokens, self.labels.tid: labels}
         values, new_state = self.apply(params, state, inputs, train)
         op = self.loss_op
-        with jax.named_scope(op.name):
-            total = op.loss(values[op.output.tid],
-                            values[op.labels_tensor.tid])
+        xs = (values[op.output.tid], values[op.labels_tensor.tid])
+        with self._op_scope(op, xs):
+            total = op.loss(*xs)
         n_targets = self.t.batch_size * (self.t.seq_length - 1
                                          if self.t.causal
                                          else self.t.seq_length)

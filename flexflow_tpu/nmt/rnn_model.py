@@ -281,15 +281,13 @@ class RnnModel(FFModel):
 
     def loss_fn(self, params, state, src, dst, train: bool = True):
         """Mean NLL per target token over all decoder chunks."""
-        import jax
-
         inputs = {self.src_tokens.tid: src, self.dst_tokens.tid: dst}
         values, new_state = self.apply(params, state, inputs, train)
         total = 0.0
         for op in self.loss_ops:
-            with jax.named_scope(op.name):
-                total = total + op.loss(values[op.output.tid],
-                                        values[op.labels_tensor.tid])
+            xs = (values[op.output.tid], values[op.labels_tensor.tid])
+            with self._op_scope(op, xs):
+                total = total + op.loss(*xs)
         ntokens = self.rnn.batch_size * self.rnn.seq_length
         return total / ntokens, new_state
 

@@ -227,8 +227,8 @@ class RunLog:
 # the span and counter API (obs/spans.py) under ``obs.<name>``, imported
 # on first use: spans.py imports JAX, which the report tools that import
 # this package for its JSONL readers never need
-_SPANS_API = ("span", "count", "snapshot", "reset", "note_program",
-              "program", "counter_at")
+_SPANS_API = ("span", "count", "snapshot", "summary", "reset",
+              "note_program", "program", "counter_at")
 
 
 def __getattr__(name: str):

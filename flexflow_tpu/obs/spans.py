@@ -20,14 +20,23 @@ span costs two ``perf_counter`` calls, that TraceMe and a dict update.
 
 Importing this module registers, once, ``jax.monitoring`` listeners that
 add JAX's own tracing, lowering and compilation seconds and the compile
-cache's events to the ``compile.*`` counters.  ``flexflow_tpu.obs``
-re-exports the API lazily, so the jax-free report tools that import
-``obs`` for the JSONL readers still never import JAX.
+cache's events to the ``compile.*`` counters, and one ``gc.callbacks``
+entry that times the collector (``runtime.gc_s``,
+``runtime.gc_collections.gen<g>``, and a ``ff:runtime.gc`` span for a
+collection of the oldest generation).  ``flexflow_tpu.obs`` re-exports
+the API lazily, so the jax-free report tools that import ``obs`` for the
+JSONL readers still never import JAX.
+
+A ``compile.<stage>_s`` counter sums JAX's duration event at every level
+of nested ``jit``s, so it can exceed the wall clock; beside each stands
+``compile.<stage>_wall_s``, the measure of the union of the same events'
+intervals: the wall seconds in which that stage ran, no level twice.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import threading
 import time
 import weakref
@@ -49,6 +58,10 @@ _COMPILE_SECONDS = {
     "/jax/compilation_cache/cache_retrieval_time_sec":
         "compile.cache_fetch_s",
 }
+# the oldest disjoint intervals of a stage are folded into its sum once
+# this many are held (an enclosing event that came later could no longer
+# swallow them: it is clipped to where the folded ones end)
+_WALL_INTERVALS = 8192
 _COMPILE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "compile.cache_hits",
     "/jax/compilation_cache/cache_misses": "compile.cache_misses",
@@ -61,22 +74,30 @@ _records: Dict[str, collections.deque] = {}
 _counters: Dict[str, float] = {}
 _history: Dict[str, collections.deque] = {}   # name -> [t_first, t_last, v]
 _programs: Dict[str, weakref.ref] = {}
+# wall counter -> [measure, end of the folded intervals, [(a, b), ...]]
+_walls: Dict[str, list] = {}
+# collections timed but not yet published: (start, end, generation,
+# collected, the thread, the span it ran inside).  See ``_on_gc``.
+_gc_done: list = []
+_gc_open: list = []                   # [start, annotation or None]
+_OLDEST = len(gc.get_threshold()) - 1
 
 
 class span:
     """Context manager; see the module docstring.  ``seconds`` holds the
-    duration once the block has ended.  ``args`` may be added to inside
-    the block (a byte count known only at the end): late keys reach the
-    in-memory record, not the profiler's event, whose stats are fixed
-    when it opens."""
+    duration once the block has ended and ``self_s`` the part of it no
+    span opened inside took.  ``args`` may be added to inside the block
+    (a byte count known only at the end): late keys reach the in-memory
+    record, not the profiler's event, whose stats are fixed when it
+    opens."""
 
-    __slots__ = ("name", "args", "start", "seconds", "_children_s",
-                 "_parent", "_ann")
+    __slots__ = ("name", "args", "start", "seconds", "self_s",
+                 "_children_s", "_parent", "_ann")
 
     def __init__(self, name: str, **args):
         self.name = name
         self.args = args
-        self.seconds = 0.0
+        self.seconds = self.self_s = 0.0
         self._children_s = 0.0
 
     def __enter__(self):
@@ -98,22 +119,41 @@ class span:
         parent = self._parent
         if parent is not None:
             parent._children_s += dur
-        own = max(dur - self._children_s, 0.0)
+        self.self_s = own = max(dur - self._children_s, 0.0)
         rec = {"name": self.name, "start": self.start, "end": end,
                "parent": parent.name if parent is not None else None,
                "self_s": own, "thread": threading.get_ident(),
                "args": self.args}
         with _lock:
-            agg = _spans.get(self.name)
-            if agg is None:
-                agg = _spans[self.name] = [0, 0.0, 0.0]
-                _records[self.name] = collections.deque(
-                    maxlen=RECORDS_PER_NAME)
-            agg[0] += 1
-            agg[1] += dur
-            agg[2] += own
-            _records[self.name].append(rec)
+            _keep(rec)
         return False
+
+
+def _keep(rec: Dict) -> None:
+    """One ended span into the aggregate; ``_lock`` held."""
+    name = rec["name"]
+    agg = _spans.get(name)
+    if agg is None:
+        agg = _spans[name] = [0, 0.0, 0.0]
+        _records[name] = collections.deque(maxlen=RECORDS_PER_NAME)
+    agg[0] += 1
+    agg[1] += rec["end"] - rec["start"]
+    agg[2] += rec["self_s"]
+    _records[name].append(rec)
+
+
+def _count(name: str, value: float, level: bool, now: float) -> None:
+    """``count`` as of ``now``; ``_lock`` held."""
+    v = _counters[name] = value if level \
+        else _counters.get(name, 0) + value
+    hist = _history.get(name)
+    if hist is None:
+        hist = _history[name] = collections.deque(
+            maxlen=HISTORY_PER_COUNTER)
+    if hist and now - hist[-1][0] < _HISTORY_BUCKET_S:
+        hist[-1][1], hist[-1][2] = now, v
+    else:
+        hist.append([now, now, v])
 
 
 def count(name: str, value: float = 1, *, level: bool = False) -> None:
@@ -122,16 +162,7 @@ def count(name: str, value: float = 1, *, level: bool = False) -> None:
     what reads wrong summed over calls)."""
     now = time.perf_counter()
     with _lock:
-        v = _counters[name] = value if level \
-            else _counters.get(name, 0) + value
-        hist = _history.get(name)
-        if hist is None:
-            hist = _history[name] = collections.deque(
-                maxlen=HISTORY_PER_COUNTER)
-        if hist and now - hist[-1][0] < _HISTORY_BUCKET_S:
-            hist[-1][1], hist[-1][2] = now, v
-        else:
-            hist.append([now, now, v])
+        _count(name, value, level, now)
 
 
 def counter_at(snap: Dict, name: str, t: float) -> float:
@@ -147,6 +178,7 @@ def counter_at(snap: Dict, name: str, t: float) -> float:
 
 
 def _aggregate() -> Dict:
+    _publish_gc()
     return {"spans": {k: {"count": c, "total_s": t, "self_s": s}
                       for k, (c, t, s) in _spans.items()},
             "counters": dict(_counters)}
@@ -155,12 +187,16 @@ def _aggregate() -> Dict:
 def snapshot() -> Dict:
     """A copy of the aggregate: ``spans`` (per name ``count``,
     ``total_s``, ``self_s``), ``counters``, ``records`` (the kept raw
-    span records, by start) and ``counter_history``."""
+    span records, by start), ``dropped`` (per name, how many records the
+    bounded buffer has let go: a reader that needs every record of an
+    interval must find none) and ``counter_history``."""
     with _lock:
         return dict(
             _aggregate(),
             records=sorted((dict(r) for d in _records.values() for r in d),
                            key=lambda r: r["start"]),
+            dropped={k: _spans[k][0] - len(d) for k, d in _records.items()
+                     if _spans[k][0] > len(d)},
             counter_history={k: [tuple(h) for h in d]
                              for k, d in _history.items()})
 
@@ -175,10 +211,12 @@ def summary() -> Dict:
 
 def reset() -> None:
     with _lock:
+        del _gc_done[:]
         _spans.clear()
         _records.clear()
         _counters.clear()
         _history.clear()
+        _walls.clear()
 
 
 def note_program(name: str, model) -> None:
@@ -195,10 +233,38 @@ def program(name: str):
     return ref() if ref is not None else None
 
 
+def _wall(name: str, start: float, end: float) -> float:
+    """Add ``[start, end]`` to the union kept under ``name`` and return
+    the union's measure; ``_lock`` held.  JAX reports a stage when it
+    ends, so events come in the order they end and an enclosing one
+    after those it holds: a new interval can only reach back over the
+    newest ones kept."""
+    w = _walls.get(name)
+    if w is None:
+        w = _walls[name] = [0.0, float("-inf"), []]
+    start = max(start, w[1])
+    kept = w[2]
+    while kept and kept[-1][1] >= start:
+        a, b = kept.pop()
+        w[0] -= b - a
+        start, end = min(start, a), max(end, b)
+    if end > start:
+        kept.append((start, end))
+        w[0] += end - start
+    if len(kept) > _WALL_INTERVALS:
+        w[1] = kept[_WALL_INTERVALS // 2 - 1][1]
+        del kept[:_WALL_INTERVALS // 2]
+    return w[0]
+
+
 def _on_duration(event: str, secs: float, **kw) -> None:
     name = _COMPILE_SECONDS.get(event)
     if name is not None:
-        count(name, secs)
+        now = time.perf_counter()
+        wall = name[:-2] + "_wall_s"
+        with _lock:
+            _count(name, secs, False, now)
+            _count(wall, _wall(wall, now - secs, now), True, now)
 
 
 def _on_event(event: str, **kw) -> None:
@@ -207,5 +273,56 @@ def _on_event(event: str, **kw) -> None:
         count(name)
 
 
+def _on_gc(phase: str, info: Dict) -> None:
+    """Time one collection.  The interpreter runs a collection between
+    two bytecodes of whichever thread is due, possibly one that holds
+    ``_lock`` (any statement above that makes a container can be the
+    one): the callback therefore never waits for the lock.  It notes the
+    collection in ``_gc_done`` and publishes what is noted only if the
+    lock is free; otherwise the next collection, ``snapshot`` or
+    ``summary`` does.  Collections do not nest, so ``_gc_open`` holds at
+    most one."""
+    gen = info["generation"]
+    if phase == "start":
+        ann = None
+        if gen == _OLDEST:
+            ann = jax.profiler.TraceAnnotation("ff:runtime.gc",
+                                               generation=gen)
+            ann.__enter__()
+        _gc_open[:] = [time.perf_counter(), ann]
+        return
+    end = time.perf_counter()
+    if not _gc_open:
+        return              # registered while a collection was running
+    start, ann = _gc_open
+    del _gc_open[:]
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    stack = getattr(_tls, "stack", None)
+    _gc_done.append((start, end, gen, info["collected"],
+                     threading.get_ident(),
+                     stack[-1].name if stack else None))
+    if _lock.acquire(blocking=False):
+        try:
+            _publish_gc()
+        finally:
+            _lock.release()
+
+
+def _publish_gc() -> None:
+    """The collections noted so far into the counters (as of their own
+    end) and, for the oldest generation, the records; ``_lock`` held."""
+    while _gc_done:
+        start, end, gen, collected, thread, parent = _gc_done.pop(0)
+        _count("runtime.gc_s", end - start, False, end)
+        _count(f"runtime.gc_collections.gen{gen}", 1, False, end)
+        if gen == _OLDEST:
+            _keep({"name": "ff:runtime.gc", "start": start, "end": end,
+                   "parent": parent, "self_s": end - start,
+                   "thread": thread,
+                   "args": {"generation": gen, "collected": collected}})
+
+
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
 jax.monitoring.register_event_listener(_on_event)
+gc.callbacks.append(_on_gc)

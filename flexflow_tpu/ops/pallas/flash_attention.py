@@ -409,7 +409,11 @@ def _transposed(x, dtype):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
-                acc_scr, *, t: _Tiles, scale, heads, hd, hdv):
+                acc_scr, *, t: _Tiles, scale, heads, hd, hdv, name):
+    # a kernel's body is Python that runs once each time JAX traces it:
+    # ``kernels.traced.<kernel>`` beside the counters of traced calls
+    # says how many call sites share a trace
+    obs.count("kernels.traced." + name)
     qi, jk = pl.program_id(2), pl.program_id(3)
     ki = t.k_at(qi, jk)
 
@@ -483,7 +487,7 @@ def _fwd_call(q, k, v, *, t: _Tiles, heads, hd, hdv, scale, out_dtype,
 
     return pl.pallas_call(
         functools.partial(_fwd_kernel, t=t, scale=scale, heads=heads, hd=hd,
-                          hdv=hdv),
+                          hdv=hdv, name=name + "fwd"),
         grid=(b, groups, t.n_q, t.inner_k),
         in_specs=[pl.BlockSpec((1, bq, w), lambda b_, j, qi, ki: (b_, qi, j)),
                   kv_spec(w), kv_spec(wv)],
@@ -535,10 +539,11 @@ def _hoist_kv(k_ref, v_ref, ks_scr, vm_scr, kst_scr, scale, heads, hd, hdv):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                t: _Tiles, scale, heads, hd, hdv, with_dq):
+                t: _Tiles, scale, heads, hd, hdv, with_dq, name):
     """K blocks outer, Q blocks inner: dk, dv accumulate over the inner
     loop; with ``with_dq`` (the fused form) dq^T of the whole head group
     accumulates across both loops and leaves with the last K block."""
+    obs.count("kernels.traced." + name)
     if with_dq:
         (dq_ref, dk_ref, dv_ref,
          ks_scr, vm_scr, dk_scr, dv_scr, kst_scr, dqt_scr) = rest
@@ -594,8 +599,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    ks_scr, vm_scr, kst_scr, dqt_scr,
-                   *, t: _Tiles, scale, heads, hd, hdv):
+                   *, t: _Tiles, scale, heads, hd, hdv, name):
     """Q blocks outer, K blocks inner (the split form's second kernel)."""
+    obs.count("kernels.traced." + name)
     qi, jk = pl.program_id(2), pl.program_id(3)
     ki = t.k_at(qi, jk)
 
@@ -631,6 +637,7 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
     groups = width // w
     bq, bk = t.bq, t.bk
     common = dict(t=t, scale=scale, heads=heads, hd=hd, hdv=hdv)
+    first = name + ("bwd" if fused else "bwd_dkv")
 
     # K blocks outer, Q blocks inner
     def q_spec(width_):
@@ -660,7 +667,7 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
         out_shape.insert(0, jax.ShapeDtypeStruct(q.shape, q.dtype))
         scratch += dq_scratch + [pltpu.VMEM((t.n_q, w, bq), _F32)]
     outs = pl.pallas_call(
-        functools.partial(_bwd_kernel, with_dq=fused, **common),
+        functools.partial(_bwd_kernel, with_dq=fused, name=first, **common),
         grid=(b, groups, t.n_k, t.inner_q),
         in_specs=[q_spec(w), kv_spec(w), kv_spec(wv), q_spec(wv), row_spec,
                   row_spec],
@@ -668,7 +675,7 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-        name=name + ("bwd" if fused else "bwd_dkv"),
+        name=first,
         **_params(interpret),
     )(q, k, v, do, lse, delta)
     if fused:
@@ -688,7 +695,7 @@ def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
                                                     j))
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
+        functools.partial(_bwd_dq_kernel, name=name + "bwd_dq", **common),
         grid=(b, groups, t.n_q, t.inner_k),
         in_specs=[q_spec(w), kv_spec(w), kv_spec(wv), q_spec(wv), row_spec,
                   row_spec],

@@ -114,6 +114,7 @@ def _params(interpret, semantics):
 
 def _fwd_kernel(x_ref, w_ref, b_ref, lab_ref, nll_ref, lse_ref,
                 m_scr, l_scr, corr_scr, *, vocab, block_v):
+    obs.count("kernels.traced.ff_ce_fwd")     # once a trace of the body
     vi = pl.program_id(1)
     nv = pl.num_programs(1)
     v_off = vi * block_v
@@ -206,6 +207,7 @@ def _tile_dlogits(x_ref, w_ref, b_ref, lab_ref, lse_ref, gp_ref, goh_ref,
 def _bwd_kernel(x_ref, w_ref, b_ref, lab_ref, lse_ref, gp_ref, goh_ref,
                 dw_in, db_in, dx_ref, dw_ref, db_ref, dx_scr, *, vocab,
                 block_v, reread):
+    obs.count("kernels.traced.ff_ce_bwd")
     ni, vi = pl.program_id(0), pl.program_id(1)
     nv = pl.num_programs(1)
     t = _tile_dlogits(x_ref, w_ref, b_ref, lab_ref, lse_ref, gp_ref,

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from flexflow_tpu import obs
 from flexflow_tpu.ops.pallas import traced_once
 
 LANES = 128
@@ -214,6 +215,9 @@ def _rows_of(offsets, gids, tiles, i, tm: int):
 
 def _gmm_kernel(offsets, gids, tiles, counts, a_ref, w_ref, o_ref, *acc,
                 tm, sub, depth_steps, transposed):
+    # once a trace of the body
+    obs.count("kernels.traced.ff_gmm_t" if transposed
+              else "kernels.traced.ff_gmm")
     i, ci = pl.program_id(1), pl.program_id(2)
     _, mine, touches = _rows_of(offsets, gids, tiles, i, tm)
     fresh = jnp.logical_or(i == 0, tiles[i] != tiles[jnp.maximum(i - 1, 0)])
@@ -312,6 +316,7 @@ def _gmm_call(a, w, visits, *, tiles, transposed, out_dtype, interpret):
 
 def _dw_kernel(offsets, gids, tiles, counts, a_ref, b_ref, o_ref, acc_ref,
                *, tm, n_visits):
+    obs.count("kernels.traced.ff_gmm_dw")
     i = pl.program_id(2)
     g = gids[i]
     live = i < counts[0]

@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from flexflow_tpu import obs
 from flexflow_tpu.ops.pallas import traced_once
 
 LANES = 128
@@ -198,6 +199,7 @@ def _row_blocks(l):
 
 def _fwd_kernel(rows_ref, skip_ref, x_ref, b_ref, c_ref, y_ref, hin_ref,
                 state, cb, dtx, y1, *, p):
+    obs.count("kernels.traced.ff_ssd_fwd")    # once a trace of the body
     ci, g = pl.program_id(1), pl.program_id(2)
     cdt = x_ref.dtype
 
@@ -253,6 +255,7 @@ def _head_sums(v, p):
 def _bwd_kernel(rows_ref, skip_ref, x_ref, b_ref, c_ref, dy_ref, hin_ref,
                 dx_ref, dbc_ref, drows_ref, dskip_ref,
                 dstate, cb, dcb, db, dc, m, dtx, y1, d_dtx, *, p, n):
+    obs.count("kernels.traced.ff_ssd_bwd")
     ci, g = pl.program_id(1), pl.program_id(2)
     groups = pl.num_programs(2)
     l, cdt = x_ref.shape[0], x_ref.dtype
