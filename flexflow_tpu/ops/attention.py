@@ -235,7 +235,15 @@ class GroupedQueryAttention(Op):
 
     * ``rope``: a rotary rule (``ops/seq_gated.rotary_table``: the
       dimensions of a head that turn, theta, default or YaRN) applied to q
-      and k here, where alone they exist;
+      and k here, where alone they exist.  On the TPU, for heads of whole
+      lane tiles (``ops/pallas/rope.fits``), by ``ff_rope`` on the
+      ``(B, S, heads * head_dim)`` arrays as the products write them and
+      the flash kernels read them; the de-interleave of ``apply_rope``'s
+      pairs lives inside that kernel, as a product with a 0/1 matrix, so
+      ``wq`` and ``wk`` are multiplied, updated and compared in their
+      published column order whichever path runs
+      (``kernels.rope.pallas.<heads>x<head_dim>r<turned>`` or
+      ``kernels.rope.xla.<..>`` counts a traced call);
     * ``window``: a query sees itself and the ``window - 1`` keys before
       it (the flash kernels skip the tiles left of the window as they skip
       those above the diagonal; ``attn.window`` reads it);
@@ -334,13 +342,7 @@ class GroupedQueryAttention(Op):
 
         q, k, v = (proj(x, params[w]) for w in ("wq", "wk", "wv"))
         if self.rope:
-            from flexflow_tpu.ops.seq_gated import apply_rope, rotary_table
-
-            cos, sin = rotary_table(self.rope, s)
-            q = apply_rope(q.reshape(b, s, h, hd), cos, sin
-                           ).reshape(b, s, h * hd)
-            k = apply_rope(k.reshape(b, s, kv, hd), cos, sin
-                           ).reshape(b, s, kv * hd)
+            q, k = self._turned(q, k)
         # the level is what a one-group model's cell reads; the count by
         # group size tells a model's layers apart
         obs.count("attn.kv_groups", h // kv, level=True)
@@ -367,6 +369,30 @@ class GroupedQueryAttention(Op):
             out = (out.reshape(b, s, h, hd) * g[..., None]
                    ).astype(x.dtype).reshape(b, s, h * hd)
         return proj(out, params["wo"]), state
+
+    def _turned(self, q, k):
+        """q and k (B, S, heads * head_dim) with their rotary positions.
+        Where ``rope.fits``, ``ff_rope`` turns them in the layout they
+        are made and read in, one pass each; else ``apply_rope`` on the
+        4-D view, as on every other backend."""
+        from flexflow_tpu import obs
+        from flexflow_tpu.ops.pallas import rope
+        from flexflow_tpu.ops.seq_gated import apply_rope, rotary_table
+
+        b, s, _ = q.shape
+        hd, rotated = self.head_dim, int(self.rope["dim"])
+        cos, sin = rotary_table(self.rope, s)
+        kernel = rope.fits(hd, rotated, q.dtype)
+        turned = []
+        for y, heads in ((q, self.num_heads), (k, self.num_kv_heads)):
+            obs.count(f"kernels.rope.{'pallas' if kernel else 'xla'}."
+                      f"{heads}x{hd}r{rotated}")
+            if kernel:
+                turned.append(rope.rope_packed(y, cos, sin, heads))
+            else:
+                turned.append(apply_rope(y.reshape(b, s, heads, hd), cos,
+                                         sin).reshape(b, s, heads * hd))
+        return turned
 
     def cost_signature(self) -> tuple:
         sig = (self.num_heads, self.num_kv_heads, self.head_dim, self.scale)

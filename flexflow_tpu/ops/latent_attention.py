@@ -120,6 +120,7 @@ class LatentAttention(Op):
     def forward(self, params, state, xs: List, train: bool):
         import jax.numpy as jnp
 
+        from flexflow_tpu import obs
         from flexflow_tpu.ops.pallas.flash_attention import \
             flash_attention_packed
 
@@ -138,6 +139,10 @@ class LatentAttention(Op):
         c = rms_norm(ckv[..., :self.kv_rank], params["kv_norm"], self.eps)
         kv = proj(c, params["wkvb"]).reshape(b, s, h, nope + vd)
         cos, sin = rope_angles(s, rope, self.rope_theta)
+        # the turned dimensions lie behind ``nope`` of a ``nope + rope``
+        # wide head, on no lane tile: apply_rope (``pallas/rope.fits``)
+        obs.count(f"kernels.rope.xla.{h}x{nope + rope}r{rope}")
+        obs.count(f"kernels.rope.xla.1x{rope}r{rope}")
         q_pe = apply_rope(q[..., nope:], cos, sin)
         k_pe = apply_rope(ckv[..., self.kv_rank:], cos, sin)
         q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)

@@ -23,16 +23,21 @@ under ``jax.lax.ragged_dot`` took 0.94-2.3, 85.8 -> 31.4 ms a step);
 with a chunk's decay-weighted scores made in VMEM) won
 ``granite_4_0_h_micro.train_1chip_b2_s8192_ref2``, +9.35% (PR 35: the
 nine scans 140.3 -> 50.7 ms a step, a layer's forward 3.6 -> 1.2 ms and
-its backward 8.3 -> 3.2).  The max-pool, avg-pool and batch-norm kernels
-that lost their cells left in PR 30 (PERF.md section 6).
+its backward 8.3 -> 3.2); ``rope.py`` (``ff_rope``, ``ff_rope_t``: rotary
+positions in the layout the projections write and the flash kernels
+read) won ``laguna_s_2_1.train_1chip_b2_s8192_ref2``, +10.8% (PR 37: a
+sliding layer's rotary chain 21 -> 3.0 ms a step and a full layer's 12 ->
+2.2, the step 841.3 -> 758.8 ms).  The
+max-pool, avg-pool and batch-norm kernels that lost their cells left in
+PR 30 (PERF.md section 6).
 
 Which path an operator takes is decided from what the code observes and
 never by a switch: the backend (:func:`flash_enabled`, the one gate every
 caller shares) and the shapes and types, by the rules that live beside
 each kernel (``flash_attention._layout``, ``_pick_block``;
 ``grouped_mm._pick_tiles``; ``FFModel._fusion_ok`` and
-``fused_ce._pick_tiles``; ``ssd_scan.fits``).  A new kernel joins
-the same way.
+``fused_ce._pick_tiles``; ``ssd_scan.fits``; ``rope.fits``).  A new
+kernel joins the same way.
 
 Kernels run compiled (Mosaic) on TPU; interpreter mode is for the CPU test
 suite, whose ``pallas_kernels`` fixture (tests/conftest.py) patches the
@@ -74,8 +79,8 @@ def traced_once(fn, *avals):
     ``jit`` shares a trace only among callers in one tracing context, and
     a step has four: 16 traces of the grouped products' kernels a
     Moonlight step where 6 do, and ``setup_s`` outside its bound; PERF.md
-    section 6, PR 31.  ``grouped_mm.py`` and ``ssd_scan.py`` build their
-    custom-VJP passes through it.)"""
+    section 6, PR 31.  ``grouped_mm.py``, ``ssd_scan.py`` and ``rope.py``
+    build their custom-VJP passes through it.)"""
     import jax
     import jax.extend
 

@@ -93,7 +93,15 @@ def apply_rope(x, cos, sin, pairing: str = "split"):
     """Rotate the last axis of ``x`` (.., seq, [heads,] dim).  ``cos`` and
     ``sin`` are (seq, dim/2); a heads axis between seq and dim is
     broadcast over.  Tables narrower than ``dim/2`` turn the leading
-    ``2 x`` their width of the axis and the rest passes through."""
+    ``2 x`` their width of the axis and the rest passes through.
+
+    The statement of the mathematics, and the path of every caller but
+    one: ``LatentAttention`` on every backend, ``GroupedQueryAttention``
+    off the TPU and wherever ``ops/pallas/rope.fits`` refuses its head.
+    On the TPU a head of whole lane tiles is turned by ``ff_rope``
+    (``ops/pallas/rope.py``), which the tests hold to this function: on
+    the 4-D view XLA pays for the de-interleave below with relayouts of
+    the whole array (PERF.md section 6, PR 37)."""
     import jax.numpy as jnp
 
     if pairing not in ROPE_PAIRINGS:

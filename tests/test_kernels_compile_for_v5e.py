@@ -1,7 +1,8 @@
-"""The fused head's kernels, the windowed flash kernels and the
-state-space scan's kernels compiled by Mosaic for a described TPU v5e, at
+"""The fused head's kernels, the windowed flash kernels, the state-space
+scan's kernels and the rotary kernels (alone and inside
+``GroupedQueryAttention``) compiled by Mosaic for a described TPU v5e, at
 the widths the benchmark's cells run, at the widest the fusion takes and
-at the corners of the scan's rule: interpret mode says nothing about
+at the corners of the scan's and the rotary rule: interpret mode says nothing about
 what the chip's compiler accepts (VMEM above all), and a compile here
 costs no chip time.  Nothing runs: a pass is not a measurement."""
 
@@ -120,3 +121,125 @@ def test_scan_compiles_and_no_chunk_matrix_reaches_hbm(one_chip, heads,
         assert re.search(rf"\b{name}\b", text), name
     # a decay matrix or m would be (.., heads, chunk, chunk)
     assert not re.search(rf"\[[\d,]*{chunk},{chunk}\]", text)
+
+
+_YARN_64 = {"dim": 64, "rope_theta": 500000.0, "rope_type": "yarn",
+            "factor": 32.0, "original_max_position_embeddings": 4096,
+            "beta_fast": 32.0, "beta_slow": 1.0}
+
+
+def _entry(text):
+    """name -> (opcode, operand names, line) of the entry computation's
+    instructions, in schedule order."""
+    body = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", text, re.S).group(1)
+    table = {}
+    for line in body.split("\n"):
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = .*? ([\w\-]+)\((.*)$", line)
+        if m:
+            table[m.group(1)] = (m.group(2), re.findall(
+                r"%[\w.\-]+", m.group(3).split("),")[0]), line)
+    return table
+
+
+@pytest.mark.parametrize("heads,window,rule,flash", [
+    # laguna_s_2_1's sliding layers and its full layers
+    (72, 512, {"dim": 128, "rope_theta": 10000.0}, "ff_flash_win_fwd"),
+    (48, None, _YARN_64, "ff_flash_fwd"),
+])
+def test_rotary_positions_reach_the_flash_kernels_without_a_relayout(
+        one_chip, monkeypatch, heads, window, rule, flash):
+    """``GroupedQueryAttention``'s forward and gradient at the cell's two
+    shapes: the q product writes bfloat16 in the layout ``ff_rope`` reads
+    and ``ff_rope`` the one the flash forward reads, with nothing between
+    (the parent had six 302-604 MB relayouts there, in float32); the same
+    on the way back, from the flash backward's dq through ``ff_rope_t``
+    into the products that make dW and dx; and no ``T(2,128)``-tiled activation
+    (what the lane de-interleave of ``apply_rope`` forced, PERF.md section
+    6, PR 37).  The gate is left out: its float32 4-D view is another
+    family, as is the float32 ``delta`` of the flash backward."""
+    import importlib
+
+    from flexflow_tpu.ops import pallas
+    from flexflow_tpu.ops.attention import GroupedQueryAttention
+    from flexflow_tpu.ops.base import Tensor
+    from flexflow_tpu.ops.pallas import rope
+    from flexflow_tpu.strategy import ParallelConfig
+
+    fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(pallas, "flash_enabled", lambda: True)
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    monkeypatch.setattr(rope, "_should_interpret", lambda: False)
+    b, s, d, hd = 2, 8192, 3072, 128
+    op = GroupedQueryAttention(
+        "attn", ParallelConfig((1, 1, 1), (0,)), Tensor((b, s, d),
+                                                        "bfloat16"),
+        heads, 8, hd, hd ** -0.5, rope=rule, window=window)
+    params = {k: jax.ShapeDtypeStruct(v, jnp.float32, sharding=one_chip)
+              for k, v in op._shapes().items()}
+    x = jax.ShapeDtypeStruct((b, s, d), jnp.bfloat16, sharding=one_chip)
+
+    def step(params, x):
+        return jax.value_and_grad(
+            lambda p, x: op.forward(p, {}, [x], True)[0].astype(
+                jnp.float32).sum(), (0, 1))(params, x)
+
+    text = jax.jit(step).lower(params, x).compile().as_text()
+    entry = _entry(text)
+    def calls(kernel):
+        return [k for k, (opc, _, line) in entry.items()
+                if opc == "custom-call" and re.search(rf"\b{kernel}\b", line)]
+
+    def users(name):
+        return [k for k, (_, ops, _) in entry.items() if name in ops]
+
+    ropes, back_ropes = calls("ff_rope"), calls("ff_rope_t")
+    assert len(ropes) == len(back_ropes) == 2, "q and k, each way"
+    q = f"bf16[{b},{s},{heads * hd}]"
+    assert not [line for line in text.split("\n")
+                if "T(2,128)" in line and f"[{b},{s}," in line]
+    # forward: product -> ff_rope -> flash
+    (fwd,) = calls(flash)
+    turned = entry[fwd][1][0]
+    assert turned in ropes, entry[turned][2][:200]
+    opcode, _, line = entry[entry[turned][1][0]]
+    assert opcode == "fusion" and "dot_general" in line \
+        and line.split(" = ")[1].startswith(q), line[:300]
+    # backward: flash's dq -> ff_rope -> the products that make dW and dx
+    (dq,) = calls(flash.replace("fwd", "bwd_dq"))
+    (back,) = [r for r in users(dq) if r in back_ropes]
+    assert entry[back][1][0] == dq
+    for user in users(back):
+        opcode, _, line = entry[user]
+        assert opcode == "fusion" and "dot_general" in line, line[:300]
+
+
+@pytest.mark.parametrize("heads,head_dim,rotated,dtype", [
+    (72, 128, 128, "bfloat16"),     # laguna_s_2_1's sliding layers' q
+    (48, 128, 64, "bfloat16"),      # and its full layers'
+    # the corners of ``rope.fits`` (compiled, not timed)
+    (8, 256, 256, "float32"),       # the widest head, the most VMEM
+    (6, 256, 64, "bfloat16"),       # blocks of three heads
+    (7, 128, 2, "float32"),         # the narrowest turn, a roll by one
+])
+def test_rope_compiles_at_the_cells_shapes_and_the_rules_corners(
+        one_chip, monkeypatch, heads, head_dim, rotated, dtype):
+    from flexflow_tpu.ops import pallas
+    from flexflow_tpu.ops.pallas import rope
+    from flexflow_tpu.ops.seq_gated import rope_angles
+
+    monkeypatch.setattr(pallas, "flash_enabled", lambda: True)
+    assert rope.fits(head_dim, rotated, dtype)
+    b, s = 2, 2048
+
+    def step(x):
+        cos, sin = rope_angles(s, rotated, 10000.0)
+        return jax.value_and_grad(
+            lambda x: rope.rope_packed(x, cos, sin, heads, interpret=False
+                                       ).astype(jnp.float32).sum())(x)
+
+    text = jax.jit(step).lower(jax.ShapeDtypeStruct(
+        (b, s, heads * head_dim), dtype, sharding=one_chip)
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("ff_rope", "ff_rope_t"):
+        assert re.search(rf"\b{name}\b", text), name

@@ -5,6 +5,7 @@
     python tools/chip_kernels.py ce            # the fused head alone
     python tools/chip_kernels.py win           # the windowed flash alone
     python tools/chip_kernels.py scan          # the state-space scan alone
+    python tools/chip_kernels.py rope          # the rotary positions alone
 
 The CPU suite runs these kernels in interpret mode at test shapes, and the
 compiled branch picks other block shapes (flash_attention._make_flash,
@@ -39,7 +40,13 @@ scan at the Granite cell's shape (2 x 8192 tokens, 64 heads of 64, state
 ``C``, ``delta``, ``A_log`` and ``D`` through ``ff_ssd_fwd`` and
 ``ff_ssd_bwd`` against ``ops/ssm.py: ssd_chunked``, both timed forward
 and forward + backward (the interpreter cannot see a write in flight, and
-the kernels carry a state from grid step to grid step).
+the kernels carry a state from grid step to grid step); the rotary
+positions of the Laguna cell's q and k (2 x 8192 tokens, 72, 48 and 8
+heads of 128, all or 64 of them turned, bfloat16): ``ff_rope`` and
+``ff_rope_t`` against ``ops/seq_gated.py: apply_rope`` on the 4-D view,
+each side's gradient beside the float32 computation of it, both timed
+alone and behind a sliding layer's q projection, and the kernel at the
+candidates of ``ROPE_BLOCKS``.
 """
 
 import json
@@ -475,6 +482,121 @@ def run_gmm():
     return failed
 
 
+# laguna_s_2_1's rotary positions: a sliding layer's queries (72 heads, all
+# 128 dimensions turned, theta 10 000), a full layer's (48 heads, 64 of 128
+# turned, YaRN) and the keys of both (8 heads); and the (rows a grid step,
+# rows a product with the pairing matrix) timed beside the kernel's own
+ROPE_YARN = {"dim": 64, "rope_theta": 500000.0, "rope_type": "yarn",
+             "factor": 32.0, "original_max_position_embeddings": 4096,
+             "beta_fast": 32.0, "beta_slow": 1.0}
+ROPE_SHAPES = ((72, {"dim": 128, "rope_theta": 10000.0}), (48, ROPE_YARN),
+               (8, {"dim": 128, "rope_theta": 10000.0}), (8, ROPE_YARN))
+ROPE_BLOCKS = ((512, 128), (512, 512), (256, 256), (1024, 256))
+ROPE_SHAPE = (2, 8192, 3072, 128)   # batch, positions, hidden, a head
+
+
+def run_rope():
+    """``ff_rope`` against ``apply_rope`` on the 4-D view of the same
+    array: the turned values, the gradient of the input, how far each
+    side's gradient lies from the float32 computation of it, and each
+    side's forward and forward + backward ms, alone and behind the q
+    projection of a sliding layer."""
+    from flexflow_tpu.ops.pallas import rope
+    from flexflow_tpu.ops.seq_gated import apply_rope, rotary_table
+
+    b, s, d, hd = ROPE_SHAPE
+    failed = 0
+
+    def sides(heads, rule):
+        cos, sin = rotary_table(rule, s)
+        return (lambda q: rope.rope_packed(q, cos, sin, heads),
+                lambda q: apply_rope(q.reshape(b, s, heads, hd), cos, sin
+                                     ).reshape(b, s, heads * hd))
+
+    def times(rec, side, fn, args, n):
+        rec[side + "_forward_ms"] = _pipelined(jax.jit(fn), args, 10)
+        rec[side + "_forward_backward_ms"] = _pipelined(
+            jax.jit(_grads(fn, n)), args, 10)
+
+    for heads, rule in ROPE_SHAPES:
+        rec = {"case": f"rope b{b} s{s} h{heads} d{hd} r{rule['dim']} "
+                       f"{rule.get('rope_type', 'default')}"}
+        try:
+            kernel, xla = sides(heads, rule)
+            q = _rand(jax.random.PRNGKey(heads), (b, s, heads * hd),
+                      jnp.bfloat16)
+            got = jax.jit(_grads(kernel, 1))(q)
+            want = jax.jit(_grads(xla, 1))(q)
+            exact = jax.jit(_grads(xla, 1))(q.astype(jnp.float32))
+            rec["rel_err"] = [round(_rel_err(g, w), 6)
+                              for g, w in zip(got, want)]
+            rec["differing"] = [int(jnp.sum(g != w))
+                                for g, w in zip(got, want)]
+            rec["gradient_from_float32"] = {
+                "kernel": round(_rel_err(got[1], exact[1]), 6),
+                "xla": round(_rel_err(want[1], exact[1]), 6)}
+            times(rec, "kernel", kernel, [q], 1)
+            times(rec, "xla", xla, [q], 1)
+            rec["ok"] = max(rec["rel_err"]) <= 1e-2
+            failed += not rec["ok"]
+        except Exception as e:
+            failed += 1
+            rec.update(ok=False, error=type(e).__name__,
+                       message=str(e)[-3000:])
+            traceback.print_exc(limit=3)
+        print(json.dumps(rec), flush=True)
+
+    heads, rule = ROPE_SHAPES[0]
+    kernel, xla = sides(heads, rule)
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    x = _rand(ks[0], (b, s, d), jnp.bfloat16)
+    w = _rand(ks[1], (d, heads * hd), jnp.float32, d ** -0.5)
+
+    def proj(x, w):
+        return jnp.einsum("bsd,de->bse", x, w.astype(x.dtype),
+                          preferred_element_type=jnp.float32
+                          ).astype(x.dtype)
+
+    rec = {"case": f"q projection + rope b{b} s{s} h{heads} d{hd}"}
+    try:
+        behind = {"kernel": lambda x, w: kernel(proj(x, w)),
+                  "xla": lambda x, w: xla(proj(x, w)), "product": proj}
+        got, want = (jax.jit(_grads(behind[k], 2))(x, w)
+                     for k in ("kernel", "xla"))
+        rec["rel_err"] = [round(_rel_err(g, w_), 6)
+                          for g, w_ in zip(got, want)]
+        for side, fn in behind.items():
+            times(rec, side, fn, [x, w], 2)
+        rec["ok"] = max(rec["rel_err"]) <= 1e-2
+        failed += not rec["ok"]
+    except Exception as e:
+        failed += 1
+        rec.update(ok=False, error=type(e).__name__, message=str(e)[-3000:])
+        traceback.print_exc(limit=3)
+    print(json.dumps(rec), flush=True)
+
+    blocks = rope._ROWS, rope._SUB_ROWS
+    rec = {"case": "rope candidates (rows a step/rows a product)",
+           "forward_ms": {}}
+    try:
+        q = _rand(jax.random.PRNGKey(1), (b, s, heads * hd), jnp.bfloat16)
+        for rows, sub in (blocks, *ROPE_BLOCKS):
+            rope._ROWS, rope._SUB_ROWS = rows, sub
+            rope._make_rope.cache_clear()
+            rec["forward_ms"][f"{rows}/{sub}"] = _pipelined(
+                jax.jit(sides(heads, rule)[0]), [q], 10)
+        rec["ok"] = True
+    except Exception as e:
+        failed += 1
+        rec.update(ok=False, error=type(e).__name__, message=str(e)[-3000:])
+        traceback.print_exc(limit=3)
+    finally:
+        rope._ROWS, rope._SUB_ROWS = blocks
+        rope._make_rope.cache_clear()
+    print(json.dumps(rec), flush=True)
+    return failed
+
+
 def _rel_err(a, b):
     a = np.asarray(a, np.float32)
     b = np.asarray(b, np.float32)
@@ -526,18 +648,19 @@ def run_case(name, make, shape, tol=3e-2):
 
 
 def main(argv):
-    if argv not in ([], ["gmm"], ["ce"], ["win"], ["scan"]):
+    if argv not in ([], ["gmm"], ["ce"], ["win"], ["scan"], ["rope"]):
         raise SystemExit(f"chip_kernels: takes no argument, 'gmm' (the "
                          f"grouped products alone), 'ce' (the fused "
-                         f"head alone), 'win' (the windowed flash alone) "
-                         f"or 'scan' (the state-space scan alone), got "
-                         f"{argv}")
+                         f"head alone), 'win' (the windowed flash alone), "
+                         f"'scan' (the state-space scan alone) or 'rope' "
+                         f"(the rotary positions alone), got {argv}")
     if jax.default_backend() != "tpu":
         raise SystemExit(
             f"chip_kernels: backend {jax.default_backend()!r} is not a "
             f"TPU; Mosaic compiles only there")
     failed = 0
-    cases = {"gmm": [], "ce": CE_CASES, "win": [], "scan": SCAN_CASES}.get(
+    cases = {"gmm": [], "ce": CE_CASES, "win": [], "scan": SCAN_CASES,
+             "rope": []}.get(
         "".join(argv), CASES + CE_CASES + SCAN_CASES)
     for name, make, shape in cases:
         try:
@@ -553,6 +676,8 @@ def main(argv):
         failed += run_window()
     if argv in ([], ["gmm"]):
         failed += run_gmm()
+    if argv in ([], ["rope"]):
+        failed += run_rope()
     return 1 if failed else 0
 
 
