@@ -14,17 +14,21 @@ transformers); flags follow the house style of the reference parsers
         benchmarks/configs/granite_4_0_h_micro.json --preset rehearsal -b 2
     python -m flexflow_tpu.apps.lm --model-config \
         benchmarks/configs/laguna_s_2_1.json --preset rehearsal -b 2 -s 32
+    python -m flexflow_tpu.apps.lm --model-config \
+        benchmarks/configs/lfm2_8b_a1b.json --preset rehearsal -b 2 -s 32
 
 ``--model-config`` names a file of a public ``config.json``'s keys
 (``model_type`` ``deepseek_v3``: latent attention and expert layers,
 ``models/latent_moe.py``; ``granitemoehybrid``: Mamba-2 and grouped-query
 attention layers, ``models/hybrid_ssm.py``; ``laguna``: sliding-window and
 full attention layers over a softmax top-k expert layer,
-``models/laguna.py``; ``--preset`` lays one of the
-file's own named groups of keys over it); without it the flags describe a
-``TransformerLM``.  Data is synthetic random tokens; labels are the tokens themselves (causal
-models learn next-token prediction via the internal shift; see
-TransformerLM).
+``models/laguna.py``; ``lfm2_moe``: gated short convolutions and
+grouped-query attention under q/k norms over a sigmoid top-k expert layer
+with a selection bias, a tied head, ``models/lfm2.py``; ``--preset`` lays
+one of the file's own named groups of keys over it); without it the flags
+describe a ``TransformerLM``.  Data is synthetic random tokens; labels are
+the tokens themselves (causal models learn next-token prediction via the
+internal shift; see TransformerLM).
 """
 
 from __future__ import annotations
@@ -267,9 +271,24 @@ def _laguna(config, over):
         f"{t.router_outputs} held")
 
 
+def _lfm2(config, over):
+    from flexflow_tpu.models.lfm2 import Lfm2Config, Lfm2LM
+
+    t = Lfm2Config.from_config(config, **over)
+    kinds = t.layer_types[:t.num_layers]
+    return t, Lfm2LM, (
+        f"{t.num_layers} blocks ({kinds.count('conv')} conv of "
+        f"{t.conv_L_cache} taps, {kinds.count('full_attention')} "
+        f"attention), hidden {t.hidden_size}, {t.num_attention_heads} "
+        f"query heads on {t.num_key_value_heads}, "
+        f"{min(t.num_dense_layers, t.num_layers)} dense, experts "
+        f"[{t.experts_held[0]}, {t.experts_held[1]}) of "
+        f"{t.router_outputs} held")
+
+
 #: ``model_type`` of a configuration file -> the class that builds it
 MODEL_TYPES = {"deepseek_v3": _latent_moe, "granitemoehybrid": _hybrid_ssm,
-               "laguna": _laguna}
+               "laguna": _laguna, "lfm2_moe": _lfm2}
 
 
 def _main_model_config(cfg, argv, machine, log) -> dict:

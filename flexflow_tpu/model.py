@@ -427,12 +427,22 @@ class FFModel:
 
     def grouped_query_attention(self, name, input, num_heads, num_kv_heads,
                                 head_dim, scale, rope=None, window=None,
-                                gate: bool = False) -> Tensor:
+                                gate: bool = False,
+                                qk_norm: float = None) -> Tensor:
         from flexflow_tpu.ops.attention import GroupedQueryAttention
 
         return self._add(GroupedQueryAttention(
             name, self._pc(name, 3), input, num_heads, num_kv_heads,
-            head_dim, scale, rope, window, gate))
+            head_dim, scale, rope, window, gate, qk_norm))
+
+    def gated_short_conv(self, name, input, taps) -> Tensor:
+        """A gated short convolution (ops/short_conv.py): one product in,
+        a depthwise causal convolution of ``taps`` taps between two
+        gates, one product out."""
+        from flexflow_tpu.ops.short_conv import GatedShortConv
+
+        return self._add(GatedShortConv(name, self._pc(name, 2), input,
+                                        taps))
 
     def ssm_mixer(self, name, input, num_heads, head_dim, d_state, d_conv,
                   chunk, conv_bias: bool = True,
@@ -452,12 +462,14 @@ class FFModel:
 
     def top_k_router(self, name, input, n_router, top_k, scale,
                      bias_update_rate: float = 1e-3,
-                     score: str = "sigmoid") -> Tensor:
+                     score: str = "sigmoid",
+                     denominator_eps: float = 0.0) -> Tensor:
         from flexflow_tpu.ops.expert_share import TopKRouter
 
         return self._add(TopKRouter(name, self._pc(name, 2), input,
                                     n_router, top_k, scale,
-                                    bias_update_rate, score))
+                                    bias_update_rate, score,
+                                    denominator_eps))
 
     def held_experts(self, name, input, gates, d_ff, experts_held, top_k,
                      capacity_factor: float = 2.0) -> Tensor:

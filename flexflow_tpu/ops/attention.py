@@ -18,8 +18,9 @@ Execution paths:
 
 :class:`GroupedQueryAttention` is the causal layer of the 2023-on decoder
 blocks: more query heads than key-value heads, no bias, a softmax scale
-the model gives and, each where the model asks for it, rotary positions
-on q and k, a sliding window and a per-head gate on the result.
+the model gives and, each where the model asks for it, RMSNorms on each
+head of q and k, rotary positions on q and k, a sliding window and a
+per-head gate on the result.
 
 New capability relative to the reference (which has no attention ops,
 SURVEY.md §2.6); cited rows: CP/ring-attention, SP."""
@@ -230,9 +231,17 @@ class GroupedQueryAttention(Op):
     """Causal attention with ``num_heads`` query heads on ``num_kv_heads``
     key-value heads (query heads ``g j .. g j + g - 1`` read key-value head
     ``j``), ``softmax(scale q k^T + causal mask) v`` with ``scale`` a
-    given number and no bias.  Three things a model may add, and without
-    them the operator is what it was, parameter for parameter:
+    given number and no bias.  Four things a model may add, and without
+    them the operator is what it was, parameter for parameter
+    (``granitemoehybrid`` takes none of them, ``laguna`` the rotary rule,
+    the window and the gate, ``lfm2_moe`` the norms and the rotary rule):
 
+    * ``qk_norm``: the eps of an RMSNorm over the ``head_dim`` values of
+      each head of q and of k, with one learned gain vector for q
+      (``q_norm``) and one for k (``k_norm``) shared by their heads,
+      before the rotary turn; float32 statistics, rounded to the
+      operands' type where the rotary reads them (``attn.qk_norm`` counts
+      a traced layer);
     * ``rope``: a rotary rule (``ops/seq_gated.rotary_table``: the
       dimensions of a head that turn, theta, default or YaRN) applied to q
       and k here, where alone they exist.  On the TPU, for heads of whole
@@ -243,7 +252,10 @@ class GroupedQueryAttention(Op):
       ``wq`` and ``wk`` are multiplied, updated and compared in their
       published column order whichever path runs
       (``kernels.rope.pallas.<heads>x<head_dim>r<turned>`` or
-      ``kernels.rope.xla.<..>`` counts a traced call);
+      ``kernels.rope.xla.<..>`` counts a traced call).  On the TPU at a
+      head the kernel refuses (64: half a lane tile) the same rotation
+      runs as two products with 0/1 matrices in that layout
+      (``ops/seq_gated.rope_by_products``), not on a 4-D view;
     * ``window``: a query sees itself and the ``window - 1`` keys before
       it (the flash kernels skip the tiles left of the window as they skip
       those above the diagonal; ``attn.window`` reads it);
@@ -263,7 +275,7 @@ class GroupedQueryAttention(Op):
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  num_heads: int, num_kv_heads: int, head_dim: int,
                  scale: float, rope: Dict = None, window: int = None,
-                 gate: bool = False):
+                 gate: bool = False, qk_norm: float = None):
         super().__init__(name, pc, [input])
         assert input.ndim == 3
         if num_heads % num_kv_heads:
@@ -279,6 +291,7 @@ class GroupedQueryAttention(Op):
                              f"dimensions in a head of {self.head_dim}")
         self.window = None if window is None else int(window)
         self.gate = bool(gate)
+        self.qk_norm = None if qk_norm is None else float(qk_norm)
         self.output = Tensor(input.shape, input.dtype, self, name)
 
     def _shapes(self) -> Dict:
@@ -297,13 +310,22 @@ class GroupedQueryAttention(Op):
         shapes = self._shapes()
         keys = jax.random.split(rng, len(shapes))
         init = jax.nn.initializers.glorot_uniform()
-        return {k: init(key, shape, "float32")
-                for key, (k, shape) in zip(keys, shapes.items())}
+        params = {k: init(key, shape, "float32")
+                  for key, (k, shape) in zip(keys, shapes.items())}
+        for k in self._gains():
+            params[k] = jax.numpy.ones((self.head_dim,), "float32")
+        return params
+
+    def _gains(self) -> tuple:
+        """The names of the head norms' gain vectors, each (head_dim,)."""
+        return ("q_norm", "k_norm") if self.qk_norm is not None else ()
 
     def param_specs(self):
         from jax.sharding import PartitionSpec as P
 
-        return {k: P(None, None) for k in self._shapes()}
+        specs = {k: P(None, None) for k in self._shapes()}
+        specs.update({k: P(None) for k in self._gains()})
+        return specs
 
     def output_spec(self):
         from jax.sharding import PartitionSpec as P
@@ -341,6 +363,14 @@ class GroupedQueryAttention(Op):
                               ).astype(a.dtype)
 
         q, k, v = (proj(x, params[w]) for w in ("wq", "wk", "wv"))
+        if self.qk_norm is not None:
+            from flexflow_tpu.ops.seq_gated import rms_norm
+
+            obs.count("attn.qk_norm")
+            q, k = (rms_norm(y.reshape(b, s, heads, hd), params[gain],
+                             self.qk_norm).reshape(b, s, heads * hd)
+                    for y, heads, gain in ((q, h, "q_norm"),
+                                           (k, kv, "k_norm")))
         if self.rope:
             q, k = self._turned(q, k)
         # the level is what a one-group model's cell reads; the count by
@@ -373,11 +403,13 @@ class GroupedQueryAttention(Op):
     def _turned(self, q, k):
         """q and k (B, S, heads * head_dim) with their rotary positions.
         Where ``rope.fits``, ``ff_rope`` turns them in the layout they
-        are made and read in, one pass each; else ``apply_rope`` on the
-        4-D view, as on every other backend."""
+        are made and read in, one pass each; on a TPU at a head it
+        refuses, ``rope_by_products`` in the same layout; else
+        ``apply_rope`` on the 4-D view, as on every other backend."""
         from flexflow_tpu import obs
         from flexflow_tpu.ops.pallas import rope
-        from flexflow_tpu.ops.seq_gated import apply_rope, rotary_table
+        from flexflow_tpu.ops.seq_gated import (apply_rope, rope_by_products,
+                                                rotary_table)
 
         b, s, _ = q.shape
         hd, rotated = self.head_dim, int(self.rope["dim"])
@@ -389,6 +421,9 @@ class GroupedQueryAttention(Op):
                       f"{heads}x{hd}r{rotated}")
             if kernel:
                 turned.append(rope.rope_packed(y, cos, sin, heads))
+            elif pallas.flash_enabled():
+                # a TPU and no kernel for this head: no 4-D view there
+                turned.append(rope_by_products(y, cos, sin, heads))
             else:
                 turned.append(apply_rope(y.reshape(b, s, heads, hd), cos,
                                          sin).reshape(b, s, heads * hd))
@@ -399,6 +434,8 @@ class GroupedQueryAttention(Op):
         if self.rope or self.window is not None or self.gate:
             sig += (tuple(sorted(self.rope.items())) if self.rope else None,
                     self.window, self.gate)
+        if self.qk_norm is not None:
+            sig += (("qk_norm", self.qk_norm),)
         return sig
 
     def flops_per_sample(self) -> float:
@@ -410,7 +447,10 @@ class GroupedQueryAttention(Op):
             w = self.window
             met = (w * (w + 1) / 2 + (s - w) * w) / s
         attn = 4.0 * self.num_heads * self.head_dim * met
-        return s * (proj + attn)
+        norms = 4.0 * self.head_dim * (self.num_heads + self.num_kv_heads) \
+            if self.qk_norm is not None else 0.0
+        return s * (proj + attn + norms)
 
     def param_bytes(self) -> int:
-        return 4 * sum(a * b_ for a, b_ in self._shapes().values())
+        return 4 * (sum(a * b_ for a, b_ in self._shapes().values())
+                    + len(self._gains()) * self.head_dim)

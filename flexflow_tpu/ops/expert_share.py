@@ -1,12 +1,15 @@
 """One chip's share of an expert layer whose experts are spread over
-several chips (DeepSeek-V3's layer, as Moonlight-16B-A3B has it, and the
-Qwen2-MoE family's, as Laguna-S-2.1 has it).
+several chips (DeepSeek-V3's layer, as Moonlight-16B-A3B has it, the
+Qwen2-MoE family's, as Laguna-S-2.1 has it, and ``lfm2_moe``'s, as
+LFM2-8B-A1B has it: Moonlight's rule without a shared expert beside it).
 
 :class:`TopKRouter` scores every token against ALL ``n_router`` experts
 of the layer in float32, picks the ``top_k`` largest and hands on a dense
 ``(batch, seq, n_router)`` array of combine weights: ``scale * score /
-sum of the selected scores`` for the selected experts, 0 elsewhere.  The
-score rule (``SCORE_RULES``) is the model's:
+(sum of the selected scores + denominator_eps)`` for the selected experts,
+0 elsewhere (``denominator_eps`` is 0 and absent from the program unless
+the model's published code adds one: ``lfm2_moe`` 1e-6).  The score rule
+(``SCORE_RULES``) is the model's:
 
 * ``sigmoid``: each logit's sigmoid, the selection by ``score + bias``.
   The bias only selects; it is state, moved after every training step by
@@ -76,7 +79,8 @@ class TopKRouter(Op):
 
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  n_router: int, top_k: int, scale: float,
-                 bias_update_rate: float = 1e-3, score: str = "sigmoid"):
+                 bias_update_rate: float = 1e-3, score: str = "sigmoid",
+                 denominator_eps: float = 0.0):
         super().__init__(name, pc, [input])
         assert input.ndim == 3
         if score not in SCORE_RULES:
@@ -87,6 +91,7 @@ class TopKRouter(Op):
         self.scale = float(scale)
         self.score = score
         self.bias_update_rate = float(bias_update_rate)
+        self.denominator_eps = float(denominator_eps)
         self.output = Tensor(input.shape[:2] + (self.n_router,), "float32",
                              self, name)
 
@@ -138,7 +143,11 @@ class TopKRouter(Op):
         mask = jnp.sum(jax.nn.one_hot(chosen, self.n_router,
                                       dtype=jnp.float32), axis=-2)
         picked = score * mask
-        gates = self.scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+        scaled = self.scale * picked
+        total = jnp.sum(picked, axis=-1, keepdims=True)
+        if self.denominator_eps:
+            total = total + self.denominator_eps
+        gates = scaled / total
         if not train or self.score != "sigmoid":
             return gates, state
         load = jnp.sum(mask, axis=(0, 1))

@@ -125,6 +125,52 @@ def apply_rope(x, cos, sin, pairing: str = "split"):
     return out.astype(x.dtype)
 
 
+def rope_by_products(x, cos, sin, heads: int):
+    """:func:`apply_rope` (the ``split`` pairing) on the row-major ``(B, S,
+    heads * head_dim)`` array without a 4-D view: the de-interleave and
+    the swap of the halves are two products with constant matrices of 0,
+    1 and -1 (``x P`` = ``(x0, x2, .. | x1, x3, .. | the rest)``, ``x Q`` =
+    ``(-x1, -x3, .. | x0, x2, .. | 0)``, block-diagonal over the heads:
+    exact, one term a column, float32 out), then ``x P * C + x Q * S`` with
+    ``C`` = cos on the turned lanes and 1 on the rest, ``S`` = sin and 0,
+    rounded once.  The same numbers as ``apply_rope`` to the last bit.
+
+    For the TPU at a head ``ops/pallas/rope.fits`` refuses: there XLA's
+    code for ``apply_rope``'s stride-2 lane slices on a ``(B, S, heads,
+    64)`` view did not finish a step of the LFM2 cell in ten minutes
+    (PERF.md section 6, PR 38), and two plain products of the width
+    squared (1.5 ms a pass at 32 heads of 64) cost the idle MXU little."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, s, width = x.shape
+    d, half = width // heads, cos.shape[-1]
+    turned = 2 * half
+    p, q = np.zeros((d, d), np.float32), np.zeros((d, d), np.float32)
+    i = np.arange(half)
+    p[2 * i, i] = p[2 * i + 1, half + i] = 1.0
+    p[np.arange(turned, d), np.arange(turned, d)] = 1.0
+    q[2 * i + 1, i], q[2 * i, half + i] = -1.0, 1.0
+    eye = jnp.eye(heads, dtype=x.dtype)
+
+    def over_heads(m):      # (d, d) -> block-diagonal (width, width)
+        m = jnp.asarray(m, x.dtype)
+        return (eye[:, None, :, None] * m[None, :, None, :]).reshape(
+            width, width)
+
+    f32 = jnp.float32
+    exact = jax.lax.Precision.HIGHEST if x.dtype == f32 else None
+    xp, xq = (jnp.einsum("bsw,wv->bsv", x, over_heads(m), precision=exact,
+                         preferred_element_type=f32) for m in (p, q))
+    rest = ((0, 0), (0, d - turned))
+    c = jnp.tile(jnp.pad(jnp.concatenate([cos, cos], -1), rest,
+                         constant_values=1.0), (1, heads))
+    sn = jnp.tile(jnp.pad(jnp.concatenate([sin, sin], -1), rest),
+                  (1, heads))
+    return (xp * c + xq * sn).astype(x.dtype)
+
+
 class RMSNormSeq(_SeqElementwise):
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  eps: float = 1e-5):

@@ -64,16 +64,19 @@ from flexflow_tpu.strategy import ParallelConfig
 
 def causal_conv1d(x, w, b):
     """Depthwise causal convolution along a sequence: x (B, S, C), w
-    (C, K), b (C,) -> ``y_t[c] = b[c] + sum_j w[c, j] x_{t-K+1+j}[c]``
-    with zeros before the sequence starts; float32, whatever x's type."""
+    (C, K), b (C,) or None -> ``y_t[c] = b[c] + sum_j w[c, j]
+    x_{t-K+1+j}[c]`` with zeros before the sequence starts; float32,
+    whatever x's type.  (The gated short convolution of
+    ``ops/short_conv.py`` is the caller without a bias.)"""
     import jax.numpy as jnp
 
     k = w.shape[1]
     s = x.shape[1]
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    y = b.astype(jnp.float32)
+    y = None if b is None else b.astype(jnp.float32)
     for j in range(k):
-        y = y + w[:, j].astype(jnp.float32) * xp[:, j:j + s]
+        tap = w[:, j].astype(jnp.float32) * xp[:, j:j + s]
+        y = tap if y is None else y + tap
     return y
 
 

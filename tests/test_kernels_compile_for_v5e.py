@@ -1,6 +1,7 @@
 """The fused head's kernels, the windowed flash kernels, the state-space
-scan's kernels and the rotary kernels (alone and inside
-``GroupedQueryAttention``) compiled by Mosaic for a described TPU v5e, at
+scan's kernels, the rotary kernels (alone and inside
+``GroupedQueryAttention``), the held experts' grouped products and one
+cell's whole train step compiled for a described TPU v5e, at
 the widths the benchmark's cells run, at the widest the fusion takes and
 at the corners of the scan's and the rotary rule: interpret mode says nothing about
 what the chip's compiler accepts (VMEM above all), and a compile here
@@ -243,3 +244,95 @@ def test_rope_compiles_at_the_cells_shapes_and_the_rules_corners(
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     for name in ("ff_rope", "ff_rope_t"):
         assert re.search(rf"\b{name}\b", text), name
+
+
+def test_grouped_products_compile_at_the_lfm2_cells_shapes(one_chip):
+    """``ff_gmm`` at the held experts' shapes of the LFM2 cell (a buffer
+    of 32 768 rows, 8 experts of 2048 x 1792, bfloat16): the nine products
+    of a layer, at the tiles ``_pick_tiles`` gives them."""
+    from flexflow_tpu.ops.pallas import grouped_mm as gm
+
+    m, d, f, g = 32768, 2048, 1792, 8
+    assert gm._pick_tiles("gmm", m, d, f, 2, 2) == (512, 2048, 1792)
+    assert gm._pick_tiles("gmm_t", m, f, d, 2, 2) == (512, 1792, 2048)
+    assert gm._pick_tiles("dw", m, d, f, 2, 2) == (128, 2048, 1792)
+
+    def shape(s, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def step(rows, sizes, w_gate, w_up, w_down):
+        def loss(rows, *w):
+            y, tiles = gm.gated_ffn(rows, sizes, *w, interpret=False)
+            assert tiles == (512, 2048, 1792)
+            return y.astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, (0, 1, 2, 3))(rows, w_gate, w_up,
+                                                      w_down)
+
+    text = jax.jit(step).lower(
+        shape((m, d)), shape((g,), jnp.int32), shape((g, d, f)),
+        shape((g, d, f)), shape((g, f, d))).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    for name in ("ff_gmm", "ff_gmm_t", "ff_gmm_dw"):
+        assert re.search(rf"\b{name}\b", text), name
+
+
+def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
+    """The whole recomputed train step of ``lfm2_8b_a1b`` at published
+    widths, 2 x 8192 tokens, for the described chip: the kernels the
+    shapes pick are in it, the counters read what the shapes say, and
+    arguments plus temporaries leave room beside the comparison's trees
+    (PERF.md section 6, PR 38 quotes the bytes)."""
+    import importlib
+    import json
+    import os
+
+    from flexflow_tpu import obs
+    from flexflow_tpu.machine import MachineModel
+    from flexflow_tpu.models.lfm2 import Lfm2Config, Lfm2LM
+    from flexflow_tpu.ops import pallas
+
+    monkeypatch.setattr(pallas, "flash_enabled", lambda: True)
+    for name in ("flash_attention", "grouped_mm", "fused_ce"):
+        mod = importlib.import_module(f"flexflow_tpu.ops.pallas.{name}")
+        monkeypatch.setattr(mod, "_should_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "lfm2_8b_a1b.json")) as f:
+        config = json.load(f)
+    ff = Lfm2LM(Lfm2Config.from_config(config, batch_size=2,
+                                       seq_length=8192),
+                MachineModel(list(one_chip.device_set)))
+    before = dict(obs.snapshot()["counters"])
+    params, state, opt = ff.abstract_train_state()
+    toks = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+    compiled = ff.make_train_step().lower(params, state, opt, toks,
+                                          toks).compile()
+    counters = obs.snapshot()["counters"]
+
+    def counted(name):
+        return counters.get(name, 0) - before.get(name, 0)
+
+    for name, traced in (("kernels.short_conv.xla.2048x3", 6),
+                         ("attn.qk_norm", 2), ("attn.kv_groups.4", 2),
+                         ("kernels.rope.xla.32x64r64", 2),
+                         ("kernels.rope.xla.8x64r64", 2),
+                         ("kernels.flash.pack2.split", 2),
+                         ("kernels.gmm.ff_gmm.512x2048x1792", 6),
+                         ("runtime.recomputed_blocks", 8)):
+        assert counted(name) == traced, name
+    assert not [k for k in counters if k.startswith("kernels.rope.pallas.")
+                and counted(k) and "x64r" in k]
+    assert counters["moe.rows_capacity"] == 32768
+    assert counters["conv.taps"] == 3
+    text = compiled.as_text()
+    for name in ("ff_flash_fwd", "ff_flash_bwd_dkv", "ff_flash_bwd_dq",
+                 "ff_gmm", "ff_gmm_t", "ff_gmm_dw", "ff_ce_fwd",
+                 "ff_ce_bwd"):
+        assert re.search(rf"\b{name}\b", text), name
+    assert not re.search(r"\bff_rope\b", text)
+    memory = compiled.memory_analysis()
+    # 772.2 M float32 parameters in and out, donated
+    assert memory.argument_size_in_bytes == pytest.approx(3.089e9, rel=1e-3)
+    assert memory.temp_size_in_bytes < 4.0e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 7.0e9
