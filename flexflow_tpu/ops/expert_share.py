@@ -231,6 +231,38 @@ def _row_moves():
     return dispatch, combine
 
 
+def route_block(pairs: int) -> int:
+    """The block :func:`first_reaching` cuts ``pairs`` running counts
+    into: the largest power of two whose square is at most ``pairs``, at
+    least 128.  256 at the expert cells' 131 072: on the chip 128 to 512
+    took 0.19-0.22 ms a search at their 10 240 to 32 768 rows, 1024 0.44
+    at 32 768, whose gathered blocks leave VMEM (PERF.md section 6,
+    PR 39)."""
+    return max(128, 1 << (pairs.bit_length() - 1) // 2)
+
+
+def first_reaching(upto, rows: int, block: int):
+    """For r < ``rows``: the first index whose value in the non-decreasing
+    int32 ``upto`` reaches r + 1, ``len(upto)`` where none does
+    (``searchsorted(upto, r + 1)``), as two counts and no sequential
+    search.  ``upto`` is cut into blocks of ``block`` (the last padded
+    with int32's largest): a row's block is the number of blocks whose
+    last count is under r + 1, its place in the block the number of that
+    block's counts under r + 1."""
+    import jax.numpy as jnp
+
+    p = upto.shape[0]
+    nb = -(-p // block)
+    blocks = jnp.pad(upto, (0, nb * block - p),
+                     constant_values=jnp.iinfo(jnp.int32).max
+                     ).reshape(nb, block)
+    want = jnp.arange(1, rows + 1, dtype=jnp.int32)[:, None]
+    blk = jnp.minimum(jnp.sum(blocks[None, :, -1] < want, axis=1,
+                              dtype=jnp.int32), nb - 1)
+    within = jnp.sum(blocks[blk] < want, axis=1, dtype=jnp.int32)
+    return blk * block + within
+
+
 def route_rows(held_gates, rows_capacity: int):
     """Where each (token, held expert) pair with a weight above 0 goes in
     a buffer of ``rows_capacity`` rows filled expert by expert, tokens in
@@ -239,7 +271,7 @@ def route_rows(held_gates, rows_capacity: int):
     -1 for an empty row), ``slot_rows`` (tokens, experts held; the row of
     each pair, ``rows_capacity`` where there is none or it did not fit),
     ``group_sizes`` (experts held,) and the number of pairs that did not
-    fit."""
+    fit.  A traced call counts ``moe.route.blocked.<blocks>x<block>``."""
     import jax.numpy as jnp
 
     t, e = held_gates.shape
@@ -251,8 +283,9 @@ def route_rows(held_gates, rows_capacity: int):
     ends = jnp.minimum(upto.reshape(e, t)[:, -1], rows_capacity)
     group_sizes = jnp.diff(ends, prepend=0)
     # the pair that fills row r is the first whose running count is r + 1
-    pair = jnp.searchsorted(upto, jnp.arange(1, rows_capacity + 1,
-                                             dtype=jnp.int32), side="left")
+    block = route_block(t * e)
+    obs.count(f"moe.route.blocked.{-(-t * e // block)}x{block}")
+    pair = first_reaching(upto, rows_capacity, block)
     used = jnp.arange(rows_capacity) < jnp.minimum(total, rows_capacity)
     pair = jnp.where(used, pair, 0)
     row_token, row_expert = pair % t, pair // t

@@ -318,6 +318,7 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
                          ("kernels.rope.xla.8x64r64", 2),
                          ("kernels.flash.pack2.split", 2),
                          ("kernels.gmm.ff_gmm.512x2048x1792", 6),
+                         ("moe.route.blocked.512x256", 6),
                          ("runtime.recomputed_blocks", 8)):
         assert counted(name) == traced, name
     assert not [k for k in counters if k.startswith("kernels.rope.pallas.")
@@ -330,6 +331,8 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
                  "ff_ce_bwd"):
         assert re.search(rf"\b{name}\b", text), name
     assert not re.search(r"\bff_rope\b", text)
+    # route_rows' search of the rows' pairs is two counts, not a loop
+    assert " while(" not in text
     memory = compiled.memory_analysis()
     # 772.2 M float32 parameters in and out, donated
     assert memory.argument_size_in_bytes == pytest.approx(3.089e9, rel=1e-3)

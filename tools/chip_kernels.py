@@ -6,6 +6,7 @@
     python tools/chip_kernels.py win           # the windowed flash alone
     python tools/chip_kernels.py scan          # the state-space scan alone
     python tools/chip_kernels.py rope          # the rotary positions alone
+    python tools/chip_kernels.py route         # the held experts' row search
 
 The CPU suite runs these kernels in interpret mode at test shapes, and the
 compiled branch picks other block shapes (flash_attention._make_flash,
@@ -46,9 +47,15 @@ heads of 128, all or 64 of them turned, bfloat16): ``ff_rope`` and
 ``ff_rope_t`` against ``ops/seq_gated.py: apply_rope`` on the 4-D view,
 each side's gradient beside the float32 computation of it, both timed
 alone and behind a sliding layer's q projection, and the kernel at the
-candidates of ``ROPE_BLOCKS``.
+candidates of ``ROPE_BLOCKS``; the held experts' row search at the three
+expert cells' shapes (``ROUTE_SHAPES``: 131 072 running counts, 10 240 to
+32 768 buffer rows): each method of ``jnp.searchsorted`` and
+``expert_share.first_reaching`` at each of ``ROUTE_BLOCKS``, checked
+against ``np.searchsorted`` on every row, and ``route_rows`` whole with
+the parent's search and with the blocked count (no kernel: XLA alone).
 """
 
+import functools
 import json
 import os
 import sys
@@ -482,6 +489,70 @@ def run_gmm():
     return failed
 
 
+# route_rows at the three expert cells' shapes: (cell, tokens, experts held,
+# rows_capacity, top_k, router outputs); each pair is picked at the
+# balanced load, top_k / router outputs, which fills half the buffer.  The
+# search of the parent's route_rows in each of jnp.searchsorted's methods
+# and the blocked count at each of ROUTE_BLOCKS, all against np.searchsorted
+ROUTE_SHAPES = (("moonlight_16b_a3b", 16384, 8, 24576, 6, 64),
+                ("lfm2_8b_a1b", 16384, 8, 32768, 4, 32),
+                ("laguna_s_2_1", 16384, 8, 10240, 10, 256))
+ROUTE_BLOCKS = (128, 256, 512, 1024, 2048)
+ROUTE_METHODS = ("scan", "scan_unrolled", "sort", "compare_all")
+
+
+def run_route():
+    """One record a cell: ms a call of each search of the rows' pairs
+    (pipelined), whether it equals ``np.searchsorted`` on every row, and
+    the whole ``route_rows`` with the parent's search and with the
+    blocked count at the rule's block."""
+    from flexflow_tpu.ops import expert_share as es
+
+    failed = 0
+    for cell, t, e, rows, top_k, n_router in ROUTE_SHAPES:
+        rng = np.random.RandomState(39)
+        gates = (rng.rand(t, e) < top_k / n_router).astype(np.float32)
+        upto = np.cumsum(gates.T.reshape(-1) > 0, dtype=np.int32)
+        want = np.searchsorted(upto, np.arange(1, rows + 1), side="left")
+        rec = {"case": f"route {cell}", "pairs": t * e, "rows": rows,
+               "picked": int(upto[-1]), "rule_block": es.route_block(t * e),
+               "search_ms": {}, "exact": {}}
+        searches = {f"searchsorted.{m}": functools.partial(
+            jnp.searchsorted, side="left", method=m) for m in ROUTE_METHODS}
+        searches.update({f"blocked.{b}": functools.partial(
+            lambda u, q, b: es.first_reaching(u, q.shape[0], b), b=b)
+            for b in ROUTE_BLOCKS})
+        args = (jnp.asarray(upto), jnp.arange(1, rows + 1, dtype=jnp.int32))
+        for key, search in searches.items():
+            try:
+                fn = jax.jit(search)
+                got = np.asarray(jax.block_until_ready(fn(*args)))
+                rec["exact"][key] = bool((got == want).all())
+                rec["search_ms"][key] = _pipelined(fn, args)
+                failed += not rec["exact"][key]
+            except Exception as e_:
+                failed += 1
+                rec["search_ms"][key] = f"{type(e_).__name__}: {e_}"[:300]
+                traceback.print_exc(limit=3)
+        rec["route_rows_ms"] = {}
+        blocked = es.first_reaching
+        try:
+            for side, search in (
+                    ("parent", lambda u, r, b: jnp.searchsorted(
+                        u, jnp.arange(1, r + 1, dtype=jnp.int32))),
+                    ("blocked", blocked)):
+                es.first_reaching = search
+                fn = jax.jit(lambda g: es.route_rows(g, rows))
+                rec["route_rows_ms"][side] = _pipelined(
+                    fn, (jnp.asarray(gates),))
+        finally:
+            es.first_reaching = blocked
+        rec["ok"] = all(rec["exact"].values()) and len(rec["exact"]) == len(
+            searches)
+        print(json.dumps(rec), flush=True)
+    return failed
+
+
 # laguna_s_2_1's rotary positions: a sliding layer's queries (72 heads, all
 # 128 dimensions turned, theta 10 000), a full layer's (48 heads, 64 of 128
 # turned, YaRN) and the keys of both (8 heads); and the (rows a grid step,
@@ -648,19 +719,21 @@ def run_case(name, make, shape, tol=3e-2):
 
 
 def main(argv):
-    if argv not in ([], ["gmm"], ["ce"], ["win"], ["scan"], ["rope"]):
+    if argv not in ([], ["gmm"], ["ce"], ["win"], ["scan"], ["rope"],
+                    ["route"]):
         raise SystemExit(f"chip_kernels: takes no argument, 'gmm' (the "
                          f"grouped products alone), 'ce' (the fused "
                          f"head alone), 'win' (the windowed flash alone), "
-                         f"'scan' (the state-space scan alone) or 'rope' "
-                         f"(the rotary positions alone), got {argv}")
+                         f"'scan' (the state-space scan alone), 'rope' "
+                         f"(the rotary positions alone) or 'route' (the "
+                         f"held experts' row search alone), got {argv}")
     if jax.default_backend() != "tpu":
         raise SystemExit(
             f"chip_kernels: backend {jax.default_backend()!r} is not a "
             f"TPU; Mosaic compiles only there")
     failed = 0
     cases = {"gmm": [], "ce": CE_CASES, "win": [], "scan": SCAN_CASES,
-             "rope": []}.get(
+             "rope": [], "route": []}.get(
         "".join(argv), CASES + CE_CASES + SCAN_CASES)
     for name, make, shape in cases:
         try:
@@ -678,6 +751,8 @@ def main(argv):
         failed += run_gmm()
     if argv in ([], ["rope"]):
         failed += run_rope()
+    if argv in ([], ["route"]):
+        failed += run_route()
     return 1 if failed else 0
 
 
