@@ -16,6 +16,9 @@ transformers); flags follow the house style of the reference parsers
         benchmarks/configs/laguna_s_2_1.json --preset rehearsal -b 2 -s 32
     python -m flexflow_tpu.apps.lm --model-config \
         benchmarks/configs/lfm2_8b_a1b.json --preset rehearsal -b 2 -s 32
+    python -m flexflow_tpu.apps.lm --model-config \
+        benchmarks/configs/smallthinker_21b_a3b.json --preset rehearsal \
+        -b 2 -s 32
 
 ``--model-config`` names a file of a public ``config.json``'s keys
 (``model_type`` ``deepseek_v3``: latent attention and expert layers,
@@ -24,8 +27,13 @@ attention layers, ``models/hybrid_ssm.py``; ``laguna``: sliding-window and
 full attention layers over a softmax top-k expert layer,
 ``models/laguna.py``; ``lfm2_moe``: gated short convolutions and
 grouped-query attention under q/k norms over a sigmoid top-k expert layer
-with a selection bias, a tied head, ``models/lfm2.py``; ``--preset`` lays
-one of the file's own named groups of keys over it); without it the flags
+with a selection bias, a tied head, ``models/lfm2.py``; a file without a
+``model_type`` by its ``model_name``: ``smallthinker_21b_instruct``,
+NoPE full and rotary windowed attention over ReGLU experts routed before
+attention, ``models/smallthinker.py``; ``benchmarks/builders/
+model_config_lm.py`` builds any of them for the benchmark through
+``model_of_config``; ``--preset`` lays one of the file's own named groups
+of keys over it); without it the flags
 describe a ``TransformerLM``.  Data is synthetic random tokens; labels are
 the tokens themselves (causal models learn next-token prediction via the
 internal shift; see TransformerLM).
@@ -286,9 +294,43 @@ def _lfm2(config, over):
         f"{t.router_outputs} held")
 
 
+def _smallthinker(config, over):
+    from flexflow_tpu.models.smallthinker import (SmallThinkerConfig,
+                                                  SmallThinkerLM)
+
+    t = SmallThinkerConfig.from_config(config, **over)
+    windowed = sum(t.sliding_window_layout[:t.num_layers])
+    return t, SmallThinkerLM, (
+        f"{t.num_layers} blocks ({windowed} of window "
+        f"{t.sliding_window_size}, {t.num_layers - windowed} full, "
+        f"{t.num_layers - sum(t.rope_layout[:t.num_layers])} without "
+        f"rotary), hidden {t.hidden_size}, {t.num_attention_heads} query "
+        f"heads on {t.num_key_value_heads}, experts "
+        f"[{t.experts_held[0]}, {t.experts_held[1]}) of "
+        f"{t.router_outputs} held")
+
+
 #: ``model_type`` of a configuration file -> the class that builds it
 MODEL_TYPES = {"deepseek_v3": _latent_moe, "granitemoehybrid": _hybrid_ssm,
                "laguna": _laguna, "lfm2_moe": _lfm2}
+#: ``model_name`` of a file that has no ``model_type`` (SmallThinker's
+#: ``config.json``) -> the class that builds it
+MODEL_NAMES = {"smallthinker_21b_instruct": _smallthinker}
+
+
+def model_of_config(config: dict):
+    """The key a configuration file is dispatched on and its builder:
+    ``model_type`` in ``MODEL_TYPES``, or ``model_name`` in
+    ``MODEL_NAMES`` where the file has no ``model_type``.  A builder
+    takes the file and fields laid over it and gives the model's config,
+    its class and a line saying what it builds."""
+    by, table = ("model_type", MODEL_TYPES) if "model_type" in config \
+        else ("model_name", MODEL_NAMES)
+    build = table.get(config.get(by))
+    if build is None:
+        raise SystemExit(f"--model-config: {by} {config.get(by)!r}; this "
+                         f"driver builds {sorted(table)} files only")
+    return by, build
 
 
 def _main_model_config(cfg, argv, machine, log) -> dict:
@@ -304,11 +346,7 @@ def _main_model_config(cfg, argv, machine, log) -> dict:
     preset = getattr(cfg, "_preset", "")
     if preset:
         config.update(config[preset])
-    build = MODEL_TYPES.get(config.get("model_type"))
-    if build is None:
-        raise SystemExit(f"--model-config: model_type "
-                         f"{config.get('model_type')!r}; this driver builds "
-                         f"{sorted(MODEL_TYPES)} files only")
+    by, build = model_of_config(config)
     if getattr(cfg, "_strategy_file", "") \
             or getattr(cfg, "_pipeline_stages", 0):
         raise SystemExit("--model-config takes no --strategy or "
@@ -339,7 +377,8 @@ def _main_model_config(cfg, argv, machine, log) -> dict:
         raise SystemExit(f"{t.seq_length} positions, the configuration has "
                          f"{config['max_position_embeddings']}")
     model = model_class(t, machine)
-    log(f"LM: {config.get('name', cfg._model_config)}, {what}, seq "
+    log(f"LM: {config.get('name', cfg._model_config)} (by {by} "
+        f"{config[by]}), {what}, seq "
         f"{t.seq_length}, vocab {t.vocab_size}, batch {t.batch_size}, "
         f"{machine.num_devices} devices")
     data = synthetic_lm_batches(machine, t.batch_size, t.seq_length,

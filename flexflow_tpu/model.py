@@ -472,12 +472,13 @@ class FFModel:
                                     denominator_eps))
 
     def held_experts(self, name, input, gates, d_ff, experts_held, top_k,
-                     capacity_factor: float = 2.0) -> Tensor:
+                     capacity_factor: float = 2.0,
+                     act: str = "silu") -> Tensor:
         from flexflow_tpu.ops.expert_share import HeldExperts
 
         return self._add(HeldExperts(name, self._pc(name, 2), input, gates,
                                      d_ff, experts_held, top_k,
-                                     capacity_factor))
+                                     capacity_factor, act))
 
     def softmax_seq(self, name, logits: Tensor, labels: Tensor) -> Tensor:
         from flexflow_tpu.ops.softmax_dp import SoftmaxDP

@@ -255,7 +255,9 @@ class GroupedQueryAttention(Op):
       ``kernels.rope.xla.<..>`` counts a traced call).  On the TPU at a
       head the kernel refuses (64: half a lane tile) the same rotation
       runs as two products with 0/1 matrices in that layout
-      (``ops/seq_gated.rope_by_products``), not on a 4-D view;
+      (``ops/seq_gated.rope_by_products``), not on a 4-D view.  Without a
+      rule no position enters (NoPE: Granite's layer, SmallThinker's full
+      ones), and ``attn.rope.none`` counts the traced layer;
     * ``window``: a query sees itself and the ``window - 1`` keys before
       it (the flash kernels skip the tiles left of the window as they skip
       those above the diagonal; ``attn.window`` reads it);
@@ -373,6 +375,8 @@ class GroupedQueryAttention(Op):
                                            (k, kv, "k_norm")))
         if self.rope:
             q, k = self._turned(q, k)
+        else:
+            obs.count("attn.rope.none")
         # the level is what a one-group model's cell reads; the count by
         # group size tells a model's layers apart
         obs.count("attn.kv_groups", h // kv, level=True)

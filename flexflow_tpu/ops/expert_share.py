@@ -1,7 +1,9 @@
 """One chip's share of an expert layer whose experts are spread over
 several chips (DeepSeek-V3's layer, as Moonlight-16B-A3B has it, the
 Qwen2-MoE family's, as Laguna-S-2.1 has it, and ``lfm2_moe``'s, as
-LFM2-8B-A1B has it: Moonlight's rule without a shared expert beside it).
+LFM2-8B-A1B has it: Moonlight's rule without a shared expert beside it,
+and SmallThinker's: a softmax router read before attention, ReGLU
+experts).
 
 :class:`TopKRouter` scores every token against ALL ``n_router`` experts
 of the layer in float32, picks the ``top_k`` largest and hands on a dense
@@ -30,6 +32,14 @@ and the rows go back to their tokens weighted.  Pairs beyond the buffer
 are counted in ``state["dropped"]``, which must read 0.  Rows move by
 gathers in both passes (a token reads the row of each of its held
 experts, or a row of zeros): the TPU serialises scatters.
+
+An expert is a gated feed-forward of three matrices whose gate passes
+SiLU (``act`` ``silu``) or ReLU (``relu``: ReGLU, as SmallThinker's
+experts); a traced layer counts ``moe.expert_act.<act>``, and what its
+router read as the graph shows it: ``moe.router_input.ffn`` where the
+router read the experts' own input, ``moe.router_input.block`` where it
+read another tensor (SmallThinker's router reads the block's normed
+input before attention, and its gates then cross the attention).
 
 Which path the grouped products take (:func:`grouped_gated_ffn`) is
 observed, never set.  On a TPU (``ops/pallas.flash_enabled``), at widths
@@ -294,20 +304,23 @@ def route_rows(held_gates, rows_capacity: int):
             jnp.maximum(total - rows_capacity, 0))
 
 
-def grouped_gated_ffn(rows, group_sizes, w_gate, w_up, w_down):
-    """The gated SiLU feed-forward of each group's expert on its rows:
-    three grouped products with float32 accumulation (nine with their
+def grouped_gated_ffn(rows, group_sizes, w_gate, w_up, w_down,
+                      act: str = "silu"):
+    """The gated feed-forward of each group's expert on its rows, its gate
+    through ``act`` (``grouped_mm.ACTIVATIONS``: SiLU or ReLU): three
+    grouped products with float32 accumulation (nine with their
     backward), through the ``ff_gmm`` kernels where the backend is a TPU
     and the shapes are theirs, else ``jax.lax.ragged_dot``."""
     import jax
     import jax.numpy as jnp
 
+    from flexflow_tpu.ops.pallas import grouped_mm
+
     w_gate, w_up, w_down = (w.astype(rows.dtype)
                             for w in (w_gate, w_up, w_down))
     if pallas.flash_enabled():
-        from flexflow_tpu.ops.pallas import grouped_mm
-
-        made = grouped_mm.gated_ffn(rows, group_sizes, w_gate, w_up, w_down)
+        made = grouped_mm.gated_ffn(rows, group_sizes, w_gate, w_up, w_down,
+                                    act=act)
         if made is not None:
             y, tiles = made
             obs.count("kernels.gmm.ff_gmm." + "x".join(map(str, tiles)))
@@ -318,7 +331,8 @@ def grouped_gated_ffn(rows, group_sizes, w_gate, w_up, w_down):
         return jax.lax.ragged_dot(a, w, group_sizes,
                                   preferred_element_type=jnp.float32)
 
-    h = (jax.nn.silu(gmm(rows, w_gate)) * gmm(rows, w_up)).astype(rows.dtype)
+    gating = grouped_mm.ACTIVATIONS[act]
+    h = (gating(gmm(rows, w_gate)) * gmm(rows, w_up)).astype(rows.dtype)
     return gmm(h, w_down).astype(rows.dtype)
 
 
@@ -327,8 +341,15 @@ class HeldExperts(Op):
 
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  gates: Tensor, d_ff: int, experts_held: Tuple[int, int],
-                 top_k: int, capacity_factor: float = 2.0):
+                 top_k: int, capacity_factor: float = 2.0,
+                 act: str = "silu"):
+        from flexflow_tpu.ops.pallas.grouped_mm import ACTIVATIONS
+
         super().__init__(name, pc, [input, gates])
+        if act not in ACTIVATIONS:
+            raise ValueError(f"op {name!r}: expert activation {act!r}, one "
+                             f"of {sorted(ACTIVATIONS)}")
+        self.act = act
         assert input.ndim == 3 and gates.shape[:2] == input.shape[:2]
         self.d, self.d_ff = input.shape[2], int(d_ff)
         self.n_router = gates.shape[2]
@@ -392,13 +413,18 @@ class HeldExperts(Op):
         lo, hi = self.experts_held
         obs.count("moe.experts_held", self.num_held, level=True)
         obs.count("moe.rows_capacity", self.rows_capacity, level=True)
+        obs.count(f"moe.expert_act.{self.act}")
+        router = self.inputs[1].producer
+        if router is not None:
+            obs.count("moe.router_input." + (
+                "ffn" if router.inputs[0] is self.inputs[0] else "block"))
         g = jax.lax.stop_gradient(gates).reshape(b * s, self.n_router)
         row_token, row_w, slot_rows, group_sizes, dropped = route_rows(
             g[:, lo:hi], self.rows_capacity)
         dispatch, combine = _row_moves()
         rows = dispatch(x.reshape(b * s, d), row_token, slot_rows)
         y = grouped_gated_ffn(rows, group_sizes, params["w_gate"],
-                              params["w_up"], params["w_down"])
+                              params["w_up"], params["w_down"], self.act)
         out = combine(y, gates.reshape(b * s, self.n_router)[:, lo:hi],
                       row_token, row_w, slot_rows)
         if train:
@@ -422,7 +448,7 @@ class HeldExperts(Op):
 
     def cost_signature(self) -> tuple:
         return (self.experts_held, self.n_router, self.top_k, self.d_ff,
-                self.rows_capacity)
+                self.rows_capacity, self.act)
 
     def flops_per_sample(self) -> float:
         # the balanced load: top_k * held / n_router experts a token

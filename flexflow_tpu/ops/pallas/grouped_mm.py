@@ -40,9 +40,9 @@ matrix once; an empty group is visited once and reads zeros; on a tile
 that a boundary cuts both of its operands are masked (a row of another
 group, or of none, may hold anything).
 
-:func:`gated_ffn` puts the nine products of a gated SiLU feed-forward
-under one ``custom_vjp``; every rounding is where autodiff of the
-``ragged_dot`` form has it: float32 ``gate``, ``up`` and SiLU, one
+:func:`gated_ffn` puts the nine products of a gated feed-forward (a SiLU
+or ReLU gate) under one ``custom_vjp``; every rounding is where autodiff
+of ``ragged_dot`` has it: float32 ``gate``, ``up`` and activation, one
 rounding of ``h`` and of ``y``, row gradients rounded once a product,
 weight gradients accumulated in float32 and rounded once.
 
@@ -433,14 +433,22 @@ def ff_gmm_dw(a, b, group_sizes, out_dtype=None, tiles=None, interpret=None):
     return _form("dw", a, b, group_sizes, out_dtype, tiles, interpret)
 
 
+#: the gate's activation by name: SiLU (SwiGLU, every model's but one)
+#: or ReLU (ReGLU, whose backward passes the gradient where the gate's
+#: product is above 0)
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 @functools.lru_cache(maxsize=None)
 def _make_gated_ffn(m: int, d: int, f: int, groups: int, dtype: str,
-                    interpret: bool):
-    """(the gated SiLU feed-forward of each group's expert on its rows as
-    one custom-VJP function of (rows, group_sizes, w_gate, w_up, w_down),
-    all operands of one type; the tiles of its first product), or None
-    where the shapes are not the kernels'.  Built once a configuration:
-    the forward pass and the backward pass are each traced once, here."""
+                    interpret: bool, act: str = "silu"):
+    """(the gated feed-forward of each group's expert on its rows, its
+    gate through ``ACTIVATIONS[act]``, as one custom-VJP function of
+    (rows, group_sizes, w_gate, w_up, w_down), all operands of one type;
+    the tiles of its first product), or None where the shapes are not the
+    kernels'.  Built once a configuration: the forward pass and the
+    backward pass are each traced once, here."""
+    gating = ACTIVATIONS[act]
     size = jnp.dtype(dtype).itemsize
     picked = {
         "up": _pick_tiles("gmm", m, d, f, size, 4),
@@ -475,14 +483,14 @@ def _make_gated_ffn(m: int, d: int, f: int, groups: int, dtype: str,
     def forward(rows, group_sizes, w_gate, w_up, w_down):
         walk = _visits(group_sizes, m, tm, False)
         gate, up_ = up(rows, w_gate, walk), up(rows, w_up, walk)
-        h = (jax.nn.silu(gate) * up_).astype(rows.dtype)
+        h = (gating(gate) * up_).astype(rows.dtype)
         return (down(h, w_down, walk), *walk, gate, up_, h)
 
     def backward(rows, group_sizes, w_gate, w_up, w_down, *rest):
         *walk, gate, up_, h, d_y = rest
         by_group = _visits(group_sizes, m, tm_dw, True)
-        _, gating = jax.vjp(lambda g, u: jax.nn.silu(g) * u, gate, up_)
-        d_gate, d_up = (v.astype(rows.dtype) for v in gating(
+        _, pull = jax.vjp(lambda g, u: gating(g) * u, gate, up_)
+        d_gate, d_up = (v.astype(rows.dtype) for v in pull(
             d_h(d_y, w_down, walk).astype(_F32)))
         d_x = d_rows(d_gate, w_gate, walk) + d_rows(d_up, w_up, walk)
         return (d_x, dw_up(rows, d_gate, by_group),
@@ -512,18 +520,20 @@ def _make_gated_ffn(m: int, d: int, f: int, groups: int, dtype: str,
     return ffn, picked["up"]
 
 
-def gated_ffn(rows, group_sizes, w_gate, w_up, w_down, interpret=None):
-    """``(y, tiles)`` with y the gated SiLU feed-forward
-    ``(silu(rows @ w_gate[g]) * (rows @ w_up[g])) @ w_down[g]`` of each
-    group's rows through the kernels, or None where the shapes or types
-    are not theirs (the caller keeps ``jax.lax.ragged_dot``).  rows
-    (m, d); w_gate, w_up (G, d, f) and w_down (G, f, d) in rows' type."""
+def gated_ffn(rows, group_sizes, w_gate, w_up, w_down, interpret=None,
+              act: str = "silu"):
+    """``(y, tiles)`` with y the gated feed-forward
+    ``(act(rows @ w_gate[g]) * (rows @ w_up[g])) @ w_down[g]`` of each
+    group's rows through the kernels (``act`` one of ``ACTIVATIONS``),
+    or None where the shapes or types are not theirs (the caller keeps
+    ``jax.lax.ragged_dot``).  rows (m, d); w_gate, w_up (G, d, f) and
+    w_down (G, f, d) in rows' type."""
     (m, d), f = rows.shape, w_gate.shape[2]
     if not all(w.dtype == rows.dtype for w in (w_gate, w_up, w_down)):
         return None
     interpret = _should_interpret() if interpret is None else interpret
     made = _make_gated_ffn(m, d, f, w_gate.shape[0], rows.dtype.name,
-                           interpret)
+                           interpret, act)
     if made is None:
         return None
     ffn, tiles = made

@@ -188,22 +188,24 @@ def small_rows(monkeypatch):
     yield
 
 
-def _experts(d, f, tokens=(2, 12)):
+def _experts(d, f, tokens=(2, 12), act="silu"):
     from flexflow_tpu.ops.expert_share import HeldExperts
 
     return HeldExperts("e", ParallelConfig((1, 1), (0,)),
                        Tensor(tokens + (d,)), Tensor(tokens + (8,)), f,
-                       (2, 6), 2)
+                       (2, 6), 2, act=act)
 
 
+@pytest.mark.parametrize("act", ["silu", "relu"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_held_experts_with_the_gate_open_against_the_gate_shut(
-        dtype, small_rows, pallas_kernels):
+        dtype, act, small_rows, pallas_kernels):
     """Values and all gradients (rows, router weights, the three expert
-    matrices), and the counter that says which path a layer took."""
+    matrices), and the counter that says which path a layer took, under
+    either gate of the experts (SwiGLU, ReGLU)."""
     from flexflow_tpu import obs
 
-    op = _experts(128, 256)
+    op = _experts(128, 256, act=act)
     assert op.rows_capacity == 48
     p = op.init_params(jax.random.PRNGKey(5))
     rng = np.random.RandomState(3)

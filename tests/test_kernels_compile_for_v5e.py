@@ -339,3 +339,129 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
     assert memory.temp_size_in_bytes < 4.0e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 7.0e9
+
+
+@pytest.mark.parametrize("window,names", [
+    # smallthinker_21b_a3b's windowed layers: blocks of 1024, a band of
+    # five a block over 16 384 positions
+    (4096, ("ff_flash_win_fwd", "ff_flash_win_bwd_dkv",
+            "ff_flash_win_bwd_dq")),
+    # and its NoPE full layer, causal over the whole 16 384
+    (None, ("ff_flash_fwd", "ff_flash_bwd_dkv", "ff_flash_bwd_dq")),
+])
+def test_flash_compiles_at_the_smallthinker_cells_shapes(one_chip, window,
+                                                         names):
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
+    x = jax.ShapeDtypeStruct((1, 16384, 28 * 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def step(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: fa.flash_attention_packed(
+                q, k, v, 28, True, interpret=False, window=window
+            ).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    text = jax.jit(step).lower(x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in names:
+        assert re.search(rf"\b{name}\b", text), name
+
+
+def test_reglu_grouped_products_compile_at_the_smallthinker_cells_shapes(
+        one_chip):
+    """``ff_gmm`` under a ReLU gate at the held experts' shapes of the
+    SmallThinker cell (a buffer of 49 152 rows, 16 experts of 2560 x 768,
+    bfloat16): the nine products of a layer at the tiles ``_pick_tiles``
+    gives them, the ReLU and its mask in XLA between them."""
+    from flexflow_tpu.ops.pallas import grouped_mm as gm
+
+    m, d, f, g = 49152, 2560, 768, 16
+    assert gm._pick_tiles("gmm", m, d, f, 2, 4) == (512, 2560, 768)
+
+    def shape(s, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def step(rows, sizes, w_gate, w_up, w_down):
+        def loss(rows, *w):
+            y, tiles = gm.gated_ffn(rows, sizes, *w, interpret=False,
+                                    act="relu")
+            assert tiles == (512, 2560, 768)
+            return y.astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, (0, 1, 2, 3))(rows, w_gate, w_up,
+                                                      w_down)
+
+    text = jax.jit(step).lower(
+        shape((m, d)), shape((g,), jnp.int32), shape((g, d, f)),
+        shape((g, d, f)), shape((g, f, d))).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    for name in ("ff_gmm", "ff_gmm_t", "ff_gmm_dw"):
+        assert re.search(rf"\b{name}\b", text), name
+
+
+def test_the_smallthinker_cells_step_compiles_and_fits(one_chip,
+                                                       monkeypatch):
+    """The whole recomputed train step of ``smallthinker_21b_a3b`` at
+    published widths, one sequence of 16 384 tokens, for the described
+    chip: the kernels the shapes pick are in it, the counters read what
+    the shapes say, and arguments plus temporaries leave room beside the
+    comparison's trees (PERF.md section 6, PR 40 quotes the bytes)."""
+    import importlib
+    import json
+    import os
+
+    from flexflow_tpu import obs
+    from flexflow_tpu.machine import MachineModel
+    from flexflow_tpu.models.smallthinker import (SmallThinkerConfig,
+                                                  SmallThinkerLM)
+    from flexflow_tpu.ops import pallas
+
+    monkeypatch.setattr(pallas, "flash_enabled", lambda: True)
+    for name in ("flash_attention", "grouped_mm", "fused_ce", "rope"):
+        mod = importlib.import_module(f"flexflow_tpu.ops.pallas.{name}")
+        monkeypatch.setattr(mod, "_should_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "smallthinker_21b_a3b.json")) as f:
+        config = json.load(f)
+    ff = SmallThinkerLM(SmallThinkerConfig.from_config(
+        config, batch_size=1, seq_length=16384),
+        MachineModel(list(one_chip.device_set)))
+    before = dict(obs.snapshot()["counters"])
+    params, state, opt = ff.abstract_train_state()
+    toks = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    compiled = ff.make_train_step().lower(params, state, opt, toks,
+                                          toks).compile()
+    counters = obs.snapshot()["counters"]
+
+    def counted(name):
+        return counters.get(name, 0) - before.get(name, 0)
+
+    for name, traced in (("attn.kv_groups.7", 4), ("attn.rope.none", 1),
+                         ("kernels.flash.pack1.split", 1),
+                         ("kernels.flash.pack1.split.w4096", 3),
+                         ("kernels.rope.pallas.28x128r128", 3),
+                         ("kernels.rope.pallas.4x128r128", 3),
+                         ("moe.router_input.block", 4),
+                         ("moe.expert_act.relu", 4),
+                         ("kernels.gmm.ff_gmm.512x2560x768", 4),
+                         ("kernels.ce.fwd.512x2048", 1),
+                         ("kernels.ce.fused_bwd.1024x256", 1),
+                         ("runtime.recomputed_blocks", 4)):
+        assert counted(name) == traced, name
+    assert counters["moe.rows_capacity"] == 49152
+    assert counters["attn.window"] == 4096
+    text = compiled.as_text()
+    for name in ("ff_flash_fwd", "ff_flash_bwd_dkv", "ff_flash_bwd_dq",
+                 "ff_flash_win_fwd", "ff_flash_win_bwd_dkv",
+                 "ff_flash_win_bwd_dq", "ff_rope", "ff_rope_t", "ff_gmm",
+                 "ff_gmm_t", "ff_gmm_dw", "ff_ce_fwd", "ff_ce_bwd"):
+        assert re.search(rf"\b{name}\b", text), name
+    assert " while(" not in text
+    memory = compiled.memory_analysis()
+    # 656.5 M float32 parameters in and out, donated
+    assert memory.argument_size_in_bytes == pytest.approx(2.626e9, rel=1e-3)
+    assert memory.temp_size_in_bytes < 4.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 7.0e9

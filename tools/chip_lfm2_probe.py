@@ -1,6 +1,8 @@
-"""What the tolerances and the assumed embedding spread of
-``benchmarks/configs/lfm2_8b_a1b.json`` rest on, read on the chip at
-published widths in one process:
+"""What the tolerances and the assumed embedding spread of an expert
+configuration (``benchmarks/configs/lfm2_8b_a1b.json`` by default,
+another by ``--config`` and ``--mix``: ``smallthinker_21b_a3b`` and
+``train_1chip_b1_s16384``) rest on, read on the chip at published widths
+in one process:
 
 1. for each candidate spread of the embedding (``--embedding-stds``; the
    head is tied, so the spread is the logits' too): the starting loss, the
@@ -9,9 +11,10 @@ published widths in one process:
    (pairs beyond it would be dropped, and must read 0), from the program's
    own forward pass; then ``--steps`` training steps of the program from
    those weights: the loss of each, and the load and the dropped pairs of
-   the last (the table is one draw scaled, which is what a spread is);
+   the last (the table is one draw scaled, which is what a spread is; an
+   untied head keeps its own matrix);
 2. at the configuration's own spread, the share of (token, expert layer)
-   top-4 selections on which the program (bfloat16 activations, a float32
+   top-k selections on which the program (bfloat16 activations, a float32
    router) and the float32 reference disagree;
 3. what the comparison would read for a run in a coarser format than the
    configuration states: the reference's loss and gradient with every
@@ -24,8 +27,9 @@ published widths in one process:
     chiprun -- python3 tools/chip_lfm2_probe.py [--seed N]
         [--embedding-stds 0.05,0.3,1.0] [--steps 6] [--at-std 0.3]
         [--selections-only] [--cpu-rehearsal]
+        [--config lfm2_8b_a1b --mix train_1chip_b2_s8192_ref2]
 
-Prints one JSON line and writes it to ``chiprun_out/lfm2_probe.json``.
+Prints one JSON line and writes it to ``chiprun_out/<config>_probe.json``.
 """
 
 import argparse
@@ -51,6 +55,8 @@ def main() -> int:
                     help="training steps from each candidate spread")
     ap.add_argument("--selections-only", action="store_true",
                     help="parts 1 and 2: no coarser-format readings")
+    ap.add_argument("--config", default="lfm2_8b_a1b")
+    ap.add_argument("--mix", default="train_1chip_b2_s8192_ref2")
     args = ap.parse_args()
 
     import jax
@@ -58,7 +64,6 @@ def main() -> int:
     import numpy as np
 
     from benchmarks import compare, harness
-    from benchmarks.reference import lfm2_8b_a1b as ref
     from benchmarks.traffic_gen import fold_seed, load_mix
     from flexflow_tpu.utils.chip import enable_compile_cache, require_tpu
 
@@ -73,9 +78,11 @@ def main() -> int:
         enable_compile_cache()
     jax.config.update("jax_default_prng_impl", "rbg")
     with open(os.path.join(ROOT, "benchmarks", "configs",
-                           "lfm2_8b_a1b.json")) as f:
+                           args.config + ".json")) as f:
         config = json.load(f)
-    mix = load_mix("train_1chip_b2_s8192_ref2")
+    mix = load_mix(args.mix)
+    ref = harness.load_by_name(os.path.join(ROOT, "benchmarks",
+                                            "reference"), args.config)
     if args.cpu_rehearsal:
         config.update(config["rehearsal"])
         mix.update(mix["rehearsal"])
@@ -168,7 +175,7 @@ def main() -> int:
                selections_that_differ=int(differ.sum()),
                share_that_differs=float(differ.mean()),
                share_by_layer=[float(x) for x in differ.mean(axis=(1, 2))],
-               # of the four experts a token takes, how many are others
+               # of the experts a token takes, how many are others
                experts_that_differ=float(
                    (ours & ~theirs).sum() / max(ours.sum(), 1)))
 
@@ -210,8 +217,8 @@ def main() -> int:
         del g
     line = json.dumps(out)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "lfm2_probe.json"),
-              "w") as f:
+    with open(os.path.join(ROOT, "chiprun_out",
+                           f"{args.config}_probe.json"), "w") as f:
         f.write(line + "\n")
     print(line, flush=True)
     return 3 if args.cpu_rehearsal else 0
