@@ -30,8 +30,9 @@ load by default) whichever experts they fall on, the three products of
 each gated feed-forward run as grouped products over the ragged groups,
 and the rows go back to their tokens weighted.  Pairs beyond the buffer
 are counted in ``state["dropped"]``, which must read 0.  Rows move by
-gathers in both passes (a token reads the row of each of its held
-experts, or a row of zeros): the TPU serialises scatters.
+gathers in both passes (a token reads the rows of its own pairs, of
+which it has at most ``min(top_k, experts held)``, and a row of zeros
+for each it lacks): the TPU serialises scatters.
 
 An expert is a gated feed-forward of three matrices whose gate passes
 SiLU (``act`` ``silu``) or ReLU (``relu``: ReGLU, as SmallThinker's
@@ -176,17 +177,17 @@ class TopKRouter(Op):
 # rows to and from the buffer, by gathers in both passes
 
 
-def _gather_sum(rows, slot_rows, weights=None):
-    """out[t] = sum_j weights[t, j] * rows_[slot_rows[t, j]] in float32,
-    where ``rows_`` is ``rows`` with a row of zeros appended: the row a
-    slot without a pair names."""
+def _gather_sum(rows, slots, weights=None):
+    """out[t] = sum_j weights[t, j] * rows_[slots[t, j]] in float32, the
+    terms in column order, where ``rows_`` is ``rows`` with a row of zeros
+    appended: the row a slot without a pair names."""
     import jax.numpy as jnp
 
     rows_ = jnp.concatenate(
         [rows, jnp.zeros((1,) + rows.shape[1:], rows.dtype)])
     out = None
-    for j in range(slot_rows.shape[1]):
-        part = jnp.take(rows_, slot_rows[:, j], axis=0).astype(jnp.float32)
+    for j in range(slots.shape[1]):
+        part = jnp.take(rows_, slots[:, j], axis=0).astype(jnp.float32)
         if weights is not None:
             part = part * weights[:, j:j + 1]
         out = part if out is None else out + part
@@ -201,26 +202,34 @@ def _row_moves():
     import jax.numpy as jnp
 
     @jax.custom_vjp
-    def dispatch(x, row_token, slot_rows):
+    def dispatch(x, row_token, slots):
         return jnp.take(x, row_token, axis=0)
 
-    def dispatch_fwd(x, row_token, slot_rows):
-        return dispatch(x, row_token, slot_rows), slot_rows
+    def dispatch_fwd(x, row_token, slots):
+        return dispatch(x, row_token, slots), slots
 
-    def dispatch_bwd(slot_rows, d_rows):
-        return (_gather_sum(d_rows, slot_rows).astype(d_rows.dtype),
+    def dispatch_bwd(slots, d_rows):
+        return (_gather_sum(d_rows, slots).astype(d_rows.dtype),
                 None, None)
 
     dispatch.defvjp(dispatch_fwd, dispatch_bwd)
 
     @jax.custom_vjp
-    def combine(y, w, row_token, row_w, slot_rows):
+    def combine(y, w, row_token, row_w, slot_rows, slot_live=None):
         """out[t] = sum_j w[t, j] * y[slot_rows[t, j]]; ``row_w`` is the
-        flat index into ``w`` of each buffer row's weight (-1: no pair)."""
-        return _gather_sum(y, slot_rows, w).astype(y.dtype)
+        flat index into ``w`` of each buffer row's weight (-1: no pair).
+        Given ``slot_live`` the sum runs over a token's live slots alone,
+        each weighted by the one held column whose row it names: the same
+        terms in the same order, without the zeros."""
+        if slot_live is None:
+            return _gather_sum(y, slot_rows, w).astype(y.dtype)
+        mine = ((slot_rows[:, :, None] == slot_live[:, None, :])
+                & (slot_rows < y.shape[0])[:, :, None])
+        w_live = jnp.sum(jnp.where(mine, w[:, :, None], 0.0), axis=1)
+        return _gather_sum(y, slot_live, w_live).astype(y.dtype)
 
-    def combine_fwd(y, w, row_token, row_w, slot_rows):
-        return (combine(y, w, row_token, row_w, slot_rows),
+    def combine_fwd(y, w, row_token, row_w, slot_rows, slot_live=None):
+        return (combine(y, w, row_token, row_w, slot_rows, slot_live),
                 (y, w, row_token, row_w, slot_rows))
 
     def combine_bwd(res, d_out):
@@ -235,7 +244,7 @@ def _row_moves():
         d_w_rows = jnp.where(row_w >= 0, d_w_rows, 0.0)
         d_w = jnp.take(jnp.concatenate([d_w_rows, jnp.zeros((1,))]),
                        slot_rows, axis=0).astype(w.dtype)
-        return d_y, d_w, None, None, None
+        return d_y, d_w, None, None, None, None
 
     combine.defvjp(combine_fwd, combine_bwd)
     return dispatch, combine
@@ -273,15 +282,35 @@ def first_reaching(upto, rows: int, block: int):
     return blk * block + within
 
 
-def route_rows(held_gates, rows_capacity: int):
+def live_slots(slot_rows, rows_capacity: int, k: int):
+    """(tokens, k): each token's rows in the buffer in expert order, then
+    ``rows_capacity`` (the row of zeros), for tokens with at most ``k``
+    pairs.  No sort: a running count along the held axis ranks each pair
+    among its token's, and column ``i`` takes the one slot of rank ``i``
+    by a compare-and-min over (tokens, experts held, k)."""
+    import jax.numpy as jnp
+
+    live = slot_rows < rows_capacity
+    rank = jnp.cumsum(live, axis=1, dtype=jnp.int32) - 1
+    mine = live[:, :, None] & (rank[:, :, None]
+                               == jnp.arange(k, dtype=jnp.int32))
+    return jnp.min(jnp.where(mine, slot_rows[:, :, None], rows_capacity),
+                   axis=1)
+
+
+def route_rows(held_gates, rows_capacity: int, per_token: int):
     """Where each (token, held expert) pair with a weight above 0 goes in
     a buffer of ``rows_capacity`` rows filled expert by expert, tokens in
-    order.  ``held_gates``: (tokens, experts held).  Returns
-    ``row_token`` (rows,), ``row_w`` (rows,; flat index into held_gates,
-    -1 for an empty row), ``slot_rows`` (tokens, experts held; the row of
-    each pair, ``rows_capacity`` where there is none or it did not fit),
-    ``group_sizes`` (experts held,) and the number of pairs that did not
-    fit.  A traced call counts ``moe.route.blocked.<blocks>x<block>``."""
+    order.  ``held_gates``: (tokens, experts held), at most ``per_token``
+    pairs a token.  Returns ``row_token`` (rows,), ``row_w`` (rows,; flat
+    index into held_gates, -1 for an empty row), ``slot_rows`` (tokens,
+    experts held; the row of each pair, ``rows_capacity`` where there is
+    none or it did not fit), ``slot_live`` (tokens, K = min(per_token,
+    experts held); :func:`live_slots`, or ``slot_rows`` itself where K is
+    every held expert), ``group_sizes`` (experts held,) and the number of
+    pairs that did not fit.  A traced call counts
+    ``moe.route.blocked.<blocks>x<block>`` and
+    ``moe.route.slots.<K>of<experts held>``."""
     import jax.numpy as jnp
 
     t, e = held_gates.shape
@@ -300,7 +329,11 @@ def route_rows(held_gates, rows_capacity: int):
     pair = jnp.where(used, pair, 0)
     row_token, row_expert = pair % t, pair // t
     row_w = jnp.where(used, row_token * e + row_expert, -1)
-    return (row_token, row_w, slot_rows, group_sizes,
+    k = min(per_token, e)
+    obs.count(f"moe.route.slots.{k}of{e}")
+    slot_live = (slot_rows if k == e
+                 else live_slots(slot_rows, rows_capacity, k))
+    return (row_token, row_w, slot_rows, slot_live, group_sizes,
             jnp.maximum(total - rows_capacity, 0))
 
 
@@ -419,14 +452,15 @@ class HeldExperts(Op):
             obs.count("moe.router_input." + (
                 "ffn" if router.inputs[0] is self.inputs[0] else "block"))
         g = jax.lax.stop_gradient(gates).reshape(b * s, self.n_router)
-        row_token, row_w, slot_rows, group_sizes, dropped = route_rows(
-            g[:, lo:hi], self.rows_capacity)
+        row_token, row_w, slot_rows, slot_live, group_sizes, dropped = \
+            route_rows(g[:, lo:hi], self.rows_capacity, self.top_k)
         dispatch, combine = _row_moves()
-        rows = dispatch(x.reshape(b * s, d), row_token, slot_rows)
+        rows = dispatch(x.reshape(b * s, d), row_token, slot_live)
         y = grouped_gated_ffn(rows, group_sizes, params["w_gate"],
                               params["w_up"], params["w_down"], self.act)
         out = combine(y, gates.reshape(b * s, self.n_router)[:, lo:hi],
-                      row_token, row_w, slot_rows)
+                      row_token, row_w, slot_rows,
+                      None if slot_live is slot_rows else slot_live)
         if train:
             state = {"counts": jnp.sum((g > 0).astype(jnp.float32), axis=0),
                      "dropped": dropped.astype(jnp.float32)}

@@ -319,6 +319,7 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
                          ("kernels.flash.pack2.split", 2),
                          ("kernels.gmm.ff_gmm.512x2048x1792", 6),
                          ("moe.route.blocked.512x256", 6),
+                         ("moe.route.slots.4of8", 6),
                          ("runtime.recomputed_blocks", 8)):
         assert counted(name) == traced, name
     assert not [k for k in counters if k.startswith("kernels.rope.pallas.")
@@ -446,6 +447,7 @@ def test_the_smallthinker_cells_step_compiles_and_fits(one_chip,
                          ("moe.router_input.block", 4),
                          ("moe.expert_act.relu", 4),
                          ("kernels.gmm.ff_gmm.512x2560x768", 4),
+                         ("moe.route.slots.6of16", 4),
                          ("kernels.ce.fwd.512x2048", 1),
                          ("kernels.ce.fused_bwd.1024x256", 1),
                          ("runtime.recomputed_blocks", 4)):

@@ -469,10 +469,13 @@ def _write_other():
 
 #: sha256 (first 16) of each cell's whole train step as the parent commit
 #: traced it at the cell's shape, the kernel gate open, addresses of
-#: Python objects left out
-_PARENTS = {"moonlight_16b_a3b": "7fed2ab9cbf18ea7",
+#: Python objects left out.  Moonlight's and LFM2's renewed when combine
+#: and dispatch's gradient came to gather a token's 6 and 4 live slots of
+#: 8 held; Laguna's (top-10 over 8 held: every slot may be live) and
+#: Granite's (no expert layer) stayed the parent's
+_PARENTS = {"moonlight_16b_a3b": "00625ad2439f6b30",
             "laguna_s_2_1": "d870f8c241d84db4",
-            "lfm2_8b_a1b": "34463073238cd514",
+            "lfm2_8b_a1b": "ab6383a4e4a0a1b1",
             "granite_4_0_h_micro": "f13cab76c5e4f896"}
 
 

@@ -7,6 +7,7 @@
     python tools/chip_kernels.py scan          # the state-space scan alone
     python tools/chip_kernels.py rope          # the rotary positions alone
     python tools/chip_kernels.py route         # the held experts' row search
+    python tools/chip_kernels.py moves         # their dispatch and combine
 
 The CPU suite runs these kernels in interpret mode at test shapes, and the
 compiled branch picks other block shapes (flash_attention._make_flash,
@@ -52,7 +53,12 @@ expert cells' shapes (``ROUTE_SHAPES``: 131 072 running counts, 10 240 to
 32 768 buffer rows): each method of ``jnp.searchsorted`` and
 ``expert_share.first_reaching`` at each of ``ROUTE_BLOCKS``, checked
 against ``np.searchsorted`` on every row, and ``route_rows`` whole with
-the parent's search and with the blocked count (no kernel: XLA alone).
+the parent's search and with the blocked count (no kernel: XLA alone);
+the held experts' dispatch and combine at the four expert cells' shapes
+(``MOVE_SHAPES``: 16 384 tokens, 8 or 16 held experts, 10 240 to 49 152
+buffer rows), forward and backward, gathering every held slot of a token
+(the parent's form) and its ``min(top_k, held)`` live slots, each output
+of the two compared value by value (XLA alone).
 """
 
 import functools
@@ -542,13 +548,86 @@ def run_route():
                         u, jnp.arange(1, r + 1, dtype=jnp.int32))),
                     ("blocked", blocked)):
                 es.first_reaching = search
-                fn = jax.jit(lambda g: es.route_rows(g, rows))
+                fn = jax.jit(lambda g: es.route_rows(g, rows, top_k))
                 rec["route_rows_ms"][side] = _pipelined(
                     fn, (jnp.asarray(gates),))
         finally:
             es.first_reaching = blocked
         rec["ok"] = all(rec["exact"].values()) and len(rec["exact"]) == len(
             searches)
+        print(json.dumps(rec), flush=True)
+    return failed
+
+
+# dispatch and combine at the four expert cells' shapes: (cell, tokens,
+# width, experts held, rows_capacity, top_k, router outputs); each token
+# picks top_k of the router's outputs at random, the held ones are the first
+MOVE_SHAPES = (("smallthinker_21b_a3b", 16384, 2560, 16, 49152, 6, 64),
+               ("lfm2_8b_a1b", 16384, 2048, 8, 32768, 4, 32),
+               ("moonlight_16b_a3b", 16384, 2048, 8, 24576, 6, 64),
+               ("laguna_s_2_1", 16384, 3072, 8, 10240, 10, 256))
+
+
+def run_moves():
+    """One record a cell: ms a call (pipelined) of combine's forward and
+    of dispatch and combine forward and backward under one cotangent, in
+    the parent's form (every held slot of a token) and over each token's
+    live slots, and how many values of each output differ between the
+    two forms."""
+    from flexflow_tpu.ops import expert_share as es
+
+    dispatch, combine = es._row_moves()
+    failed = 0
+    for cell, t, d, e, rows, top_k, n_router in MOVE_SHAPES:
+        rng = np.random.RandomState(41)
+        top = np.argsort(rng.rand(t, n_router), -1)[:, :top_k]
+        gates = np.zeros((t, n_router), np.float32)
+        np.put_along_axis(gates, top, rng.rand(t, top_k), -1)
+        w = jnp.asarray(gates[:, :e])
+        row_token, row_w, slot_rows, slot_live, _, dropped = jax.jit(
+            lambda g: es.route_rows(g, rows, top_k))(w)
+        ks = jax.random.split(jax.random.PRNGKey(41), 4)
+        x = _rand(ks[0], (t, d), jnp.bfloat16)
+        y = _rand(ks[1], (rows, d), jnp.bfloat16)
+        cot = (_rand(ks[2], (rows, d), jnp.bfloat16),
+               _rand(ks[3], (t, d), jnp.bfloat16))
+        live = slot_live if slot_live.shape[1] < e else None
+        rec = {"case": f"moves {cell}", "slots": f"{slot_live.shape[1]}of{e}",
+               "pairs": int(jnp.sum(w > 0)), "dropped": int(dropped),
+               "combine_fwd_ms": {}, "fwd_bwd_ms": {}}
+        outs = {}
+        try:
+            for form, slots in (("parent", None), ("live", live)):
+                def moves(x, y, w, slots=slots):
+                    return (dispatch(x, row_token,
+                                     slot_rows if slots is None else slots),
+                            combine(y, w, row_token, row_w, slot_rows, slots))
+
+                def fwd_bwd(x, y, w, moves=moves):
+                    out, vjp = jax.vjp(moves, x, y, w)
+                    return out, vjp(cot)
+
+                fn = jax.jit(fwd_bwd)
+                outs[form] = jax.block_until_ready(fn(x, y, w))
+                rec["fwd_bwd_ms"][form] = _pipelined(fn, (x, y, w), 5)
+                fwd = jax.jit(lambda y, w, slots=slots: combine(
+                    y, w, row_token, row_w, slot_rows, slots))
+                rec["combine_fwd_ms"][form] = _pipelined(fwd, (y, w), 5)
+            rec["differing"] = {
+                name: int(jnp.sum(a != b)) for name, a, b in zip(
+                    ("rows", "out", "d_x", "d_y", "d_w"),
+                    jax.tree.leaves(outs["live"]),
+                    jax.tree.leaves(outs["parent"]))}
+            rec["out_rel_err"] = _rel_err(outs["live"][0][1],
+                                          outs["parent"][0][1])
+            rec["ok"] = rec["out_rel_err"] <= 1e-3 and not any(
+                n for k, n in rec["differing"].items() if k != "out")
+            failed += not rec["ok"]
+        except Exception as e_:
+            failed += 1
+            rec.update(ok=False, error=type(e_).__name__,
+                       message=str(e_)[-3000:])
+            traceback.print_exc(limit=3)
         print(json.dumps(rec), flush=True)
     return failed
 
@@ -720,20 +799,21 @@ def run_case(name, make, shape, tol=3e-2):
 
 def main(argv):
     if argv not in ([], ["gmm"], ["ce"], ["win"], ["scan"], ["rope"],
-                    ["route"]):
+                    ["route"], ["moves"]):
         raise SystemExit(f"chip_kernels: takes no argument, 'gmm' (the "
                          f"grouped products alone), 'ce' (the fused "
                          f"head alone), 'win' (the windowed flash alone), "
                          f"'scan' (the state-space scan alone), 'rope' "
-                         f"(the rotary positions alone) or 'route' (the "
-                         f"held experts' row search alone), got {argv}")
+                         f"(the rotary positions alone), 'route' (the "
+                         f"held experts' row search alone) or 'moves' "
+                         f"(their dispatch and combine alone), got {argv}")
     if jax.default_backend() != "tpu":
         raise SystemExit(
             f"chip_kernels: backend {jax.default_backend()!r} is not a "
             f"TPU; Mosaic compiles only there")
     failed = 0
     cases = {"gmm": [], "ce": CE_CASES, "win": [], "scan": SCAN_CASES,
-             "rope": [], "route": []}.get(
+             "rope": [], "route": [], "moves": []}.get(
         "".join(argv), CASES + CE_CASES + SCAN_CASES)
     for name, make, shape in cases:
         try:
@@ -753,6 +833,8 @@ def main(argv):
         failed += run_rope()
     if argv in ([], ["route"]):
         failed += run_route()
+    if argv in ([], ["moves"]):
+        failed += run_moves()
     return 1 if failed else 0
 
 
