@@ -45,7 +45,8 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(ROOT, "chiprun_out", "chip_smoke")
 
 # at the smoke's S=512 the backward is the one fused kernel (the split
-# ff_flash_bwd_dkv + ff_flash_bwd_dq pair serves S*W*4 > 2 MB of dq)
+# ff_flash_bwd_dkv + ff_flash_bwd_dq pair serves a fused backward whose
+# VMEM count passes flash_attention._FUSED_BWD_BYTES)
 FLASH_KERNELS = ("ff_flash_fwd", "ff_flash_bwd")
 CE_KERNELS = ("ff_ce_fwd", "ff_ce_bwd")
 

@@ -16,7 +16,7 @@ No bias, no query compression (``q_lora_rank`` null).  Training computes
 the keys and values in full; the absorbed form that serves from the
 compressed cache is not built (ROADMAP queue 2).  On the TPU the scores
 run in the flash kernels at query/key width 192 and value width 128
-(``kernels.flash.pad256v128.split`` at 8192 positions); elsewhere in
+(``kernels.flash.pad256v128.fused`` at 8192 positions); elsewhere in
 plain XLA.  Grid ('s', 'h', 'n') as the attention operator's, of which
 only (1, 1, 1) is implemented and anything else is refused.
 """

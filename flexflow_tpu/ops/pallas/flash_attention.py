@@ -44,11 +44,14 @@ square pieces (512 forward, 256 backward) of which those above the
 diagonal are dropped and only those on it build a mask; the last K block
 of a padded length builds one too, and no other tile does; ``1/sqrt(hd)``
 is folded into the hoisted copy of q (forward) or k (backward).  The
-backward is one kernel when ``S*W*4`` bytes of dq fit the VMEM budget
-(``fused``: K blocks outer, Q inner, one recomputation of the
-probabilities a tile, dq^T accumulated in scratch and written once a head
-group) and two beyond it (``split``: ``ff_flash_bwd_dkv`` and
-``ff_flash_bwd_dq``).
+backward is one kernel while what it keeps in VMEM fits
+``_FUSED_BWD_BYTES`` (:func:`_fused_bwd_bytes`; ``fused``: K blocks
+outer, Q inner, one recomputation of the probabilities a tile, dq^T of
+the whole head group accumulated in float32 scratch, a Q block's written
+into the resident dq block once no later K block adds to it) and two
+beyond it (``split``: ``ff_flash_bwd_dkv`` and ``ff_flash_bwd_dq``, each
+rebuilding the tile): S 16 384 at W 128 and S 8192 at W 256 are fused,
+S 65 536 at W 256 is split.
 
 Window.  Under ``window`` (causal self-attention) query ``i`` sees the
 keys ``i - window < t <= i``.  The blocks are the largest of 1024, 512, 256,
@@ -56,7 +59,7 @@ keys ``i - window < t <= i``.  The blocks are the largest of 1024, 512, 256,
 of every query's window in it is skipped exactly as one above the diagonal
 is, and the inner grid axis walks only the band of blocks a block can meet
 (two steps a block at a window of one block, where the causal call walks
-sixteen at S 8192), the fused backward excepted; the tile the window's
+sixteen at S 8192), the fused backward's too; the tile the window's
 left edge cuts is walked in pieces like the one on the diagonal (128
 forward, 256 backward), those below ITS diagonal dropped.  The kernels of
 such a call are named ``ff_flash_win_*``.
@@ -79,7 +82,8 @@ transposed tiles 0.71 / 1.26 at 512 x 512, 1.33 / 1.50 at 256 x 256, 0.59 /
 1.38 at 1024 x 1024; with the diagonal walked in pieces 0.51 / 1.08 (pieces
 of 512) and 0.81 / 0.93 (256), which is why the passes take different
 pieces; inside the GPT-2 step 0.50 / 0.78.  The split backward costs 0.5-1.0
-ms more than the fused one.  The kernels beat XLA's blockwise attention
+ms more than the fused one there (PERF.md section 6 has the two forms at
+8192 and 16 384 positions).  The kernels beat XLA's blockwise attention
 at every shape tried (S 512 to 8192, hd 64 and 128, bf16 and float32).
 
 This is the framework's hand-written-kernel layer — the role the CUDA leaf
@@ -103,9 +107,10 @@ from flexflow_tpu import obs
 
 LANES = 128
 _NEG_INF = float("-inf")
-# dq^T of one head group stays in VMEM across the fused backward
-_FUSED_DQ_BYTES = 2 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# what the fused backward may count (:func:`_fused_bwd_bytes`), a quarter
+# of the limit left for what Mosaic holds beyond the count
+_FUSED_BWD_BYTES = _VMEM_LIMIT_BYTES * 3 // 4
 # score tiles, and the pieces a tile on the diagonal is walked in: sizes
 # picked on the v5e (PERF.md section 6, PR 27)
 _BLOCK = 1024
@@ -211,14 +216,14 @@ class _Tiles:
     Under a window (query ``i`` sees the keys ``i - window < t <= i``;
     self-attention only) the tile the left edge cuts, ``window / block``
     blocks left of the diagonal one, splits the same way with the pieces
-    below ITS diagonal dropped, and with ``banded`` the inner grid axis
+    below ITS diagonal dropped, and the inner grid axis of every kernel
     walks only the blocks some query of the outer block can see
     (``inner_q``, ``inner_k`` steps; :meth:`q_at`, :meth:`k_at` give the
     block of a step), so the tiles the window leaves out cost no grid
     step.  Without a window the inner axis is every block, as it was."""
 
     def __init__(self, causal, sk, block_q, block_k, n_q, n_k, piece,
-                 window=None, banded=False):
+                 window=None):
         self.causal, self.sk = causal, sk
         self.bq, self.bk, self.n_q, self.n_k = block_q, block_k, n_q, n_k
         self.k_padded = sk % block_k != 0
@@ -227,9 +232,8 @@ class _Tiles:
         if window is not None:
             square = square and window % block_q == 0
         self.n_sub = block_q // piece if square else 1
-        self.banded = banded and window is not None
         self.inner_q, self.inner_k = n_q, n_k
-        if self.banded:
+        if window is not None:
             self.inner_k = max(
                 self._last_k(q, min) - self._first_k(q, max) + 1
                 for q in range(n_q))
@@ -255,10 +259,12 @@ class _Tiles:
     def q_at(self, ki, j):
         """The Q block of inner step ``j`` under K block ``ki``; past the
         last one the window lets ``ki`` meet it names no live tile."""
-        return self._first_q(ki, jnp.minimum) + j if self.banded else j
+        return self._first_q(ki, jnp.minimum) + j \
+            if self.window is not None else j
 
     def k_at(self, qi, j):
-        return self._first_k(qi, jnp.maximum) + j if self.banded else j
+        return self._first_k(qi, jnp.maximum) + j \
+            if self.window is not None else j
 
     def live(self, qi, ki):
         if not self.causal:
@@ -542,7 +548,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 t: _Tiles, scale, heads, hd, hdv, with_dq, name):
     """K blocks outer, Q blocks inner: dk, dv accumulate over the inner
     loop; with ``with_dq`` (the fused form) dq^T of the whole head group
-    accumulates across both loops and leaves with the last K block."""
+    accumulates across both loops, a Q block's leaving with the last K
+    block that sees it."""
     obs.count("kernels.traced." + name)
     if with_dq:
         (dq_ref, dk_ref, dv_ref,
@@ -561,7 +568,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                   hdv)
 
     if with_dq:
-        @pl.when(ki == 0)
+        # dq^T of Q block qi sums over the K blocks that see it: zeroed at
+        # the first, written at the last.  Under a window the inner axis
+        # walks the band, so those are the window's left edge and the
+        # diagonal, and a step past the last Q block names none
+        first = ki == 0
+        if t.window is not None:
+            first = jnp.logical_and(qi < t.n_q,
+                                    ki == t._first_k(qi, jnp.maximum))
+
+        @pl.when(first)
         def _init_dq():
             dqt_scr[qi] = jnp.zeros(dqt_scr.shape[1:], dqt_scr.dtype)
 
@@ -591,7 +607,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
     if with_dq:
-        @pl.when(ki == t.n_k - 1)
+        last = ki == t.n_k - 1
+        if t.window is not None:
+            last = jnp.logical_and(qi < t.n_q, ki == t.last_live_k(qi))
+
+        @pl.when(last)
         def _finish_dq():
             rows = pl.ds(pl.multiple_of(qi * t.bq, t.bq), t.bq)
             dq_ref[0, rows, :] = dqt_scr[qi].T.astype(dq_ref.dtype)
@@ -628,6 +648,24 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     @pl.when(jk == t.inner_k - 1)
     def _finish():
         dq_ref[0] = dqt_scr[...].T.astype(dq_ref.dtype)
+
+
+def _fused_bwd_bytes(bq, bk, n_q, heads, hd, hdv, itemsize):
+    """VMEM the fused backward asks for: the pipeline's two copies of the
+    q, do, k and v blocks, of the lse and delta rows (a row a head at
+    eight sublanes) and of the dk, dv and whole-sequence dq blocks; the
+    hoisted k and v per head and (k scaled)^T; dk, dv and dq^T of every Q
+    block in float32; two float32 score tiles alive at once.  Compiles
+    for a described v5e took this or up to 8.25 MiB less (29.5 of 31.4
+    MiB at 2 x 8192 x 16 heads of 192 / 128, 26.75 of 28.9 at 16 384 x 28
+    of 128)."""
+    w, wv = heads * hd, heads * hdv
+    blocks = 2 * (bq * (w + wv) + 2 * bk * (w + wv) + n_q * bq * w) \
+        * itemsize
+    rows = 2 * 2 * heads * 8 * bq * 4
+    scratch = (heads * bk * (w + wv) + w * bk) * itemsize \
+        + (bk * (w + wv) + n_q * w * bq) * 4
+    return blocks + rows + scratch + 2 * bk * bq * 4
 
 
 def _bwd_call(q, k, v, do, lse, delta, *, t: _Tiles, heads, hd, hdv, scale,
@@ -756,7 +794,9 @@ def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
     bk = (_pick_block(sk, interpret, window) if block_k is None
           else min(block_k, _round_up(sk, 8)))
     sq_p, sk_p = _round_up(sq, bq), _round_up(sk, bk)
-    fused = sq_p * heads * hd * 4 <= _FUSED_DQ_BYTES
+    itemsize = max(jnp.dtype(x).itemsize for x in (qdt, kdt, vdt))
+    fused = _fused_bwd_bytes(bq, bk, sq_p // bq, heads, hd, hdv,
+                             itemsize) <= _FUSED_BWD_BYTES
     variant = f"{layout}.{'fused' if fused else 'split'}"
     names = {}
     if window is None:
@@ -764,13 +804,10 @@ def _make_flash(q_shape, k_shape, dv, qdt, kdt, vdt, causal, block_q,
             _Tiles(causal, sk, bq, bk, sq_p // bq, sk_p // bk, piece)
             for piece in (_FWD_PIECE, _BWD_PIECE))
     else:
-        # the fused backward keeps dq^T of every Q block across the K
-        # blocks, so its inner axis stays whole; the others walk the band
         fwd_tiles, bwd_tiles = (
             _Tiles(causal, sk, bq, bk, sq_p // bq, sk_p // bk, piece,
-                   window, banded)
-            for piece, banded in ((_WINDOW_FWD_PIECE, True),
-                                  (_WINDOW_BWD_PIECE, not fused)))
+                   window)
+            for piece in (_WINDOW_FWD_PIECE, _WINDOW_BWD_PIECE))
         variant += f".w{window}"
         names = {"name": "ff_flash_win_"}
     out_dtype = jnp.float32 if with_lse else qdt

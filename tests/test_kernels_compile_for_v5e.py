@@ -58,13 +58,11 @@ def test_fused_head_compiles_at_the_tiles_the_shapes_pick(one_chip, d, v,
 
 @pytest.mark.parametrize("heads,window,names", [
     # laguna_s_2_1's sliding layers: blocks of 512, the band of two
-    (72, 512, ("ff_flash_win_fwd", "ff_flash_win_bwd_dkv",
-               "ff_flash_win_bwd_dq")),
+    (72, 512, ("ff_flash_win_fwd", "ff_flash_win_bwd")),
     # a window of several blocks (1024 x 1024 tiles, a band of five)
-    (8, 4096, ("ff_flash_win_fwd", "ff_flash_win_bwd_dkv",
-               "ff_flash_win_bwd_dq")),
+    (8, 4096, ("ff_flash_win_fwd", "ff_flash_win_bwd")),
     # and its full layers: the causal kernels under their own names
-    (48, None, ("ff_flash_fwd", "ff_flash_bwd_dkv", "ff_flash_bwd_dq")),
+    (48, None, ("ff_flash_fwd", "ff_flash_bwd")),
 ])
 def test_flash_compiles_at_the_laguna_cells_shapes(one_chip, heads, window,
                                                    names):
@@ -81,7 +79,7 @@ def test_flash_compiles_at_the_laguna_cells_shapes(one_chip, heads, window,
             ).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
 
     text = jax.jit(step).lower(x, x, x).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     for name in names:
         assert re.search(rf"\b{name}\b", text), name
     assert ("ff_flash_win_" in text) == (window is not None)
@@ -205,8 +203,11 @@ def test_rotary_positions_reach_the_flash_kernels_without_a_relayout(
     opcode, _, line = entry[entry[turned][1][0]]
     assert opcode == "fusion" and "dot_general" in line \
         and line.split(" = ")[1].startswith(q), line[:300]
-    # backward: flash's dq -> ff_rope -> the products that make dW and dx
-    (dq,) = calls(flash.replace("fwd", "bwd_dq"))
+    # backward: flash's dq (the first result of the fused backward) ->
+    # ff_rope_t -> the products that make dW and dx
+    (bwd,) = calls(flash.replace("fwd", "bwd"))
+    (dq,) = [k for k in users(bwd) if entry[k][0] == "get-tuple-element"
+             and "index=0" in entry[k][2]]
     (back,) = [r for r in users(dq) if r in back_ropes]
     assert entry[back][1][0] == dq
     for user in users(back):
@@ -316,7 +317,7 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
                          ("attn.qk_norm", 2), ("attn.kv_groups.4", 2),
                          ("kernels.rope.xla.32x64r64", 2),
                          ("kernels.rope.xla.8x64r64", 2),
-                         ("kernels.flash.pack2.split", 2),
+                         ("kernels.flash.pack2.fused", 2),
                          ("kernels.gmm.ff_gmm.512x2048x1792", 6),
                          ("moe.route.blocked.512x256", 6),
                          ("moe.route.slots.4of8", 6),
@@ -327,9 +328,8 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
     assert counters["moe.rows_capacity"] == 32768
     assert counters["conv.taps"] == 3
     text = compiled.as_text()
-    for name in ("ff_flash_fwd", "ff_flash_bwd_dkv", "ff_flash_bwd_dq",
-                 "ff_gmm", "ff_gmm_t", "ff_gmm_dw", "ff_ce_fwd",
-                 "ff_ce_bwd"):
+    for name in ("ff_flash_fwd", "ff_flash_bwd", "ff_gmm", "ff_gmm_t",
+                 "ff_gmm_dw", "ff_ce_fwd", "ff_ce_bwd"):
         assert re.search(rf"\b{name}\b", text), name
     assert not re.search(r"\bff_rope\b", text)
     # route_rows' search of the rows' pairs is two counts, not a loop
@@ -345,10 +345,9 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
 @pytest.mark.parametrize("window,names", [
     # smallthinker_21b_a3b's windowed layers: blocks of 1024, a band of
     # five a block over 16 384 positions
-    (4096, ("ff_flash_win_fwd", "ff_flash_win_bwd_dkv",
-            "ff_flash_win_bwd_dq")),
+    (4096, ("ff_flash_win_fwd", "ff_flash_win_bwd")),
     # and its NoPE full layer, causal over the whole 16 384
-    (None, ("ff_flash_fwd", "ff_flash_bwd_dkv", "ff_flash_bwd_dq")),
+    (None, ("ff_flash_fwd", "ff_flash_bwd")),
 ])
 def test_flash_compiles_at_the_smallthinker_cells_shapes(one_chip, window,
                                                          names):
@@ -365,8 +364,49 @@ def test_flash_compiles_at_the_smallthinker_cells_shapes(one_chip, window,
             ).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
 
     text = jax.jit(step).lower(x, x, x).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     for name in names:
+        assert re.search(rf"\b{name}\b", text), name
+
+
+@pytest.mark.parametrize("b,s,heads,d,dv", [
+    # moonlight_16b_a3b: pad256v128, the widest dq^T of the cells (8 MB)
+    (2, 8192, 16, 192, 128),
+    # smallthinker_21b_a3b's full layer: the longest (16 384 x 128)
+    (1, 16384, 28, 128, 128),
+])
+def test_fused_backward_compiles_within_the_vmem_it_counts(
+        one_chip, monkeypatch, b, s, heads, d, dv):
+    """The flash kernels at a cell's shape compile for the described chip
+    with the VMEM limit cut to what ``_fused_bwd_bytes`` counts for the
+    fused backward: the count is at least Mosaic's own figure, and it is
+    within the budget, so the shapes pick the fused form."""
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
+    g, hd, hdv, _ = fa._layout(heads, d, dv)
+    block = fa._pick_block(s, False)
+    count = fa._fused_bwd_bytes(block, block, s // block, g, hd, hdv, 2)
+    assert count <= fa._FUSED_BWD_BYTES
+    monkeypatch.setattr(fa, "_VMEM_LIMIT_BYTES", count)
+    fa._make_flash.cache_clear()
+    try:
+        qk = jax.ShapeDtypeStruct((b, s, heads * d), jnp.bfloat16,
+                                  sharding=one_chip)
+        v = jax.ShapeDtypeStruct((b, s, heads * dv), jnp.bfloat16,
+                                 sharding=one_chip)
+
+        def step(q, k, v):
+            return jax.value_and_grad(
+                lambda q, k, v: fa.flash_attention_packed(
+                    q, k, v, heads, True, interpret=False
+                ).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+        text = jax.jit(step).lower(qk, qk, v).compile().as_text()
+    finally:
+        fa._make_flash.cache_clear()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("ff_flash_fwd", "ff_flash_bwd"):
         assert re.search(rf"\b{name}\b", text), name
 
 
@@ -440,8 +480,8 @@ def test_the_smallthinker_cells_step_compiles_and_fits(one_chip,
         return counters.get(name, 0) - before.get(name, 0)
 
     for name, traced in (("attn.kv_groups.7", 4), ("attn.rope.none", 1),
-                         ("kernels.flash.pack1.split", 1),
-                         ("kernels.flash.pack1.split.w4096", 3),
+                         ("kernels.flash.pack1.fused", 1),
+                         ("kernels.flash.pack1.fused.w4096", 3),
                          ("kernels.rope.pallas.28x128r128", 3),
                          ("kernels.rope.pallas.4x128r128", 3),
                          ("moe.router_input.block", 4),
@@ -455,9 +495,8 @@ def test_the_smallthinker_cells_step_compiles_and_fits(one_chip,
     assert counters["moe.rows_capacity"] == 49152
     assert counters["attn.window"] == 4096
     text = compiled.as_text()
-    for name in ("ff_flash_fwd", "ff_flash_bwd_dkv", "ff_flash_bwd_dq",
-                 "ff_flash_win_fwd", "ff_flash_win_bwd_dkv",
-                 "ff_flash_win_bwd_dq", "ff_rope", "ff_rope_t", "ff_gmm",
+    for name in ("ff_flash_fwd", "ff_flash_bwd", "ff_flash_win_fwd",
+                 "ff_flash_win_bwd", "ff_rope", "ff_rope_t", "ff_gmm",
                  "ff_gmm_t", "ff_gmm_dw", "ff_ce_fwd", "ff_ce_bwd"):
         assert re.search(rf"\b{name}\b", text), name
     assert " while(" not in text

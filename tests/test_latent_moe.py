@@ -126,7 +126,7 @@ def test_flash_at_a_value_width_of_its_own(case, monkeypatch):
     monkeypatch.setattr(fa, "_FWD_PIECE", 16)
     monkeypatch.setattr(fa, "_BWD_PIECE", 8)
     if split:
-        monkeypatch.setattr(fa, "_FUSED_DQ_BYTES", 0)
+        monkeypatch.setattr(fa, "_FUSED_BWD_BYTES", 0)
     fa._make_flash.cache_clear()
     b, h, s = 1, 2, 40
     q, k = (_rand(i, b, h, s, 192).astype(dtype) for i in (6, 7))
@@ -182,7 +182,7 @@ def test_flash_layouts_by_widths():
     _, mla = fa._make_flash((2, 16, 8192, 192), (2, 16, 8192, 192), 128,
                             "bfloat16", "bfloat16", "bfloat16", True, None,
                             None, False, packed=True)
-    assert (gpt2, mla) == ("pack2.fused", "pad256v128.split")
+    assert (gpt2, mla) == ("pack2.fused", "pad256v128.fused")
 
 
 # ---------------------------------------------------------------------------

@@ -16,14 +16,19 @@ def _rand(rng, *shape):
     return jnp.asarray(rng.randn(*shape).astype("float32"))
 
 
-def _dense_attention(q, k, v, causal):
-    """(out, lse) of plain softmax attention in float32, (B, H, S, d)."""
+def _dense_attention(q, k, v, causal, window=None):
+    """(out, lse) of plain softmax attention in float32, (B, H, S, d);
+    under ``window`` query i sees the keys i - window < t <= i."""
     q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest"
                    ) / np.sqrt(q.shape[-1])
     if causal:
         qi = jnp.arange(q.shape[2])[:, None]
-        s = jnp.where(qi >= jnp.arange(k.shape[2])[None, :], s, -jnp.inf)
+        ki = jnp.arange(k.shape[2])[None, :]
+        seen = qi >= ki
+        if window is not None:
+            seen = seen & (qi - ki < window)
+        s = jnp.where(seen, s, -jnp.inf)
     lse = jax.nn.logsumexp(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v,
                      precision="highest")
@@ -82,6 +87,9 @@ _FLASH_CASES = {
     "wide_key_blocks":
         ((1, 2, 64, 64, 64), "float32", True, (16, 32), "bhsd",
          "pack2.fused"),
+    "five_causal_blocks":
+        ((1, 2, 80, 80, 64), "float32", True, (16, 16), "bhsd",
+         "pack2.fused"),
     "split_backward":
         ((1, 2, 48, 48, 64), "float32", True, (16, 16), "bhsd",
          "pack2.split"),
@@ -122,7 +130,7 @@ def test_flash_parity(case, small_flash, monkeypatch):
     (b, h, sq, sk, d), dtype, causal, (bq, bk), form, variant = \
         _FLASH_CASES[case]
     if variant.endswith("split"):
-        monkeypatch.setattr(fa, "_FUSED_DQ_BYTES", 0)
+        monkeypatch.setattr(fa, "_FUSED_BWD_BYTES", 0)
     rng = np.random.RandomState(2)
     q = _rand(rng, b, h, sq, d).astype(dtype)
     k, v = (_rand(rng, b, h, sk, d).astype(dtype) for _ in range(2))
@@ -170,6 +178,49 @@ def test_flash_parity(case, small_flash, monkeypatch):
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=gtol, atol=gtol)
+
+
+# s, window, block: the fused backward under a window, whose inner axis
+# walks the band, so a Q block's dq^T is zeroed at the window's left edge
+# and written at the diagonal, both mid-axis
+_FUSED_WALKS = {
+    "window_of_one_block": (64, 16, 16),
+    "window_of_two_blocks": (96, 32, 16),
+    "padded_length_under_a_window": (72, 32, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FUSED_WALKS))
+def test_fused_backward_against_plain_attention(case, small_flash,
+                                                monkeypatch):
+    """The fused backward under a window (one kernel, 2 x 2 pieces a
+    diagonal and a left edge tile) at four to six Q blocks: out and all
+    three gradients against plain attention, and the variant counted is
+    the fused one."""
+    from flexflow_tpu import obs
+
+    fa = small_flash
+    monkeypatch.setattr(fa, "_WINDOW_FWD_PIECE", 8)
+    monkeypatch.setattr(fa, "_WINDOW_BWD_PIECE", 8)
+    fa._make_flash.cache_clear()
+    s, window, block = _FUSED_WALKS[case]
+    rng = np.random.RandomState(5)
+    q, k, v, w = (_rand(rng, 1, 2, s, 64) for _ in range(4))
+
+    def loss(attn):
+        return jax.value_and_grad(
+            lambda q, k, v: (attn(q, k, v) * w).sum(), argnums=(0, 1, 2))
+
+    name = f"kernels.flash.pack2.fused.w{window}"
+    before = obs.snapshot()["counters"].get(name, 0)
+    out, grads = loss(lambda q, k, v: fa.flash_attention(
+        q, k, v, True, block, block, window=window))(q, k, v)
+    assert obs.snapshot()["counters"].get(name, 0) == before + 1
+    want, want_grads = loss(lambda q, k, v: _dense_attention(
+        q, k, v, True, window)[0])(q, k, v)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    for got, ref in zip(grads, want_grads):
+        np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
 
 
 def _top_level_eqns(jaxpr):
@@ -234,7 +285,7 @@ def test_a_checkpoint_that_keeps_the_named_results_runs_the_forward_once(
     fa = small_flash
     assert KEPT_RESULTS == ("ff_flash_out", "ff_flash_lse")
     if backward == "split":
-        monkeypatch.setattr(fa, "_FUSED_DQ_BYTES", 0)
+        monkeypatch.setattr(fa, "_FUSED_BWD_BYTES", 0)
     b, h, s, d = 1, 2, 32, 64
     rng = np.random.RandomState(11)
     kw = dict(causal=True, block_q=16, block_k=16)

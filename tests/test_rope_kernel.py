@@ -251,9 +251,10 @@ def test_wrong_shapes_are_refused_by_the_call_itself():
 # granite_4_0_h_micro's attention layer (32 query heads of 64 on 8, no
 # positions, window or gate) at the cell's shape: the jaxpr of its forward
 # and gradient with the kernel gate open, as the parent commit (PR 36)
-# traced it
+# traced it, renewed when the VMEM count gave it the fused flash backward
+# (the split form still traces to 4cb7f2428fd568f5)
 _GRANITE = ((2, 8192, 2048), 32, 8, 64, 0.015625)
-_GRANITE_DIGEST = "4cb7f2428fd568f5"
+_GRANITE_DIGEST = "b2dd7dcf59eda730"
 
 
 def test_operator_without_positions_traces_to_the_parents_program(

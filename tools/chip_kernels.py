@@ -8,6 +8,7 @@
     python tools/chip_kernels.py rope          # the rotary positions alone
     python tools/chip_kernels.py route         # the held experts' row search
     python tools/chip_kernels.py moves         # their dispatch and combine
+    python tools/chip_kernels.py bwd           # flash's backward, both forms
 
 The CPU suite runs these kernels in interpret mode at test shapes, and the
 compiled branch picks other block shapes (flash_attention._make_flash,
@@ -58,7 +59,12 @@ the held experts' dispatch and combine at the four expert cells' shapes
 (``MOVE_SHAPES``: 16 384 tokens, 8 or 16 held experts, 10 240 to 49 152
 buffer rows), forward and backward, gathering every held slot of a token
 (the parent's form) and its ``min(top_k, held)`` live slots, each output
-of the two compared value by value (XLA alone).
+of the two compared value by value (XLA alone); the flash backward at
+the token cells' attention calls (``BWD_SHAPES``: 8192 and 16 384
+positions, W 128 and 256, causal and under windows of 512 and 4096), in
+the form the shapes pick and in the split pair, the three gradients of
+the two compared and each timed forward and forward + backward, 5 calls
+in flight.
 """
 
 import functools
@@ -372,6 +378,74 @@ def _pipelined(fn, args, calls=20):
         jax.block_until_ready(outs)
         best = min(best, (time.perf_counter() - t0) / calls)
     return round(best * 1e3, 4)
+
+
+# the token cells' flash calls: (b, s, heads, d, dv, window)
+BWD_SHAPES = {
+    "smallthinker_full": (1, 16384, 28, 128, 128, None),
+    "smallthinker_w4096": (1, 16384, 28, 128, 128, 4096),
+    "moonlight": (2, 8192, 16, 192, 128, None),
+    "laguna_full": (2, 8192, 48, 128, 128, None),
+    "laguna_w512": (2, 8192, 72, 128, 128, 512),
+    "lfm2_granite": (2, 8192, 32, 64, 64, None),
+}
+
+
+def run_backward():
+    """One record a shape: the rule's form and the split pair (the
+    budget cut to 0), the kernels each compiled, the largest relative
+    difference of their gradients, and ms a call of each."""
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.ops.pallas.flash_attention")
+    from flexflow_tpu.utils.profiling import pallas_kernel_calls
+
+    budget = fa._FUSED_BWD_BYTES
+    failed = 0
+    for name, (b, s, h, d, dv, window) in BWD_SHAPES.items():
+        rec = {"case": f"flash backward {name}",
+               "shape": [b, s, h, d, dv, window]}
+        try:
+            ks = jax.random.split(jax.random.PRNGKey(5), 3)
+            args = [_rand(ks[0], (b, s, h * d), jnp.bfloat16),
+                    _rand(ks[1], (b, s, h * d), jnp.bfloat16),
+                    _rand(ks[2], (b, s, h * dv), jnp.bfloat16)]
+
+            def forward(q, k, v):
+                return fa.flash_attention_packed(q, k, v, h, True,
+                                                 interpret=False,
+                                                 window=window)
+
+            grads = {}
+            for form, bytes_ in (("rule", budget), ("split", 0)):
+                fa._FUSED_BWD_BYTES = bytes_
+                fa._make_flash.cache_clear()
+                compiled = jax.jit(_grads(forward, 3)).lower(
+                    *args).compile()
+                rec[f"{form}_kernels"] = pallas_kernel_calls(
+                    compiled.as_text())
+                grads[form] = jax.block_until_ready(compiled(*args))[1:]
+                rec[f"{form}_forward_backward_ms"] = _pipelined(
+                    compiled, args, 5)
+            rec["forward_ms"] = _pipelined(jax.jit(forward), args, 5)
+            for form in ("rule", "split"):
+                rec[f"{form}_backward_ms"] = round(
+                    rec[f"{form}_forward_backward_ms"] - rec["forward_ms"],
+                    4)
+            rec["rel_diff"] = [round(_rel_err(a, b_), 5) for a, b_ in
+                               zip(grads["rule"], grads["split"])]
+            rec["ok"] = max(rec["rel_diff"]) < 1e-2
+            failed += not rec["ok"]
+        except Exception as e:
+            failed += 1
+            rec.update(ok=False, error=type(e).__name__,
+                       message=str(e)[-3000:])
+            traceback.print_exc(limit=3)
+        finally:
+            fa._FUSED_BWD_BYTES = budget
+            fa._make_flash.cache_clear()
+        print(json.dumps(rec), flush=True)
+    return failed
 
 
 def run_gmm():
@@ -799,21 +873,22 @@ def run_case(name, make, shape, tol=3e-2):
 
 def main(argv):
     if argv not in ([], ["gmm"], ["ce"], ["win"], ["scan"], ["rope"],
-                    ["route"], ["moves"]):
+                    ["route"], ["moves"], ["bwd"]):
         raise SystemExit(f"chip_kernels: takes no argument, 'gmm' (the "
                          f"grouped products alone), 'ce' (the fused "
                          f"head alone), 'win' (the windowed flash alone), "
                          f"'scan' (the state-space scan alone), 'rope' "
                          f"(the rotary positions alone), 'route' (the "
-                         f"held experts' row search alone) or 'moves' "
-                         f"(their dispatch and combine alone), got {argv}")
+                         f"held experts' row search alone), 'moves' "
+                         f"(their dispatch and combine alone) or 'bwd' "
+                         f"(flash's backward, both forms), got {argv}")
     if jax.default_backend() != "tpu":
         raise SystemExit(
             f"chip_kernels: backend {jax.default_backend()!r} is not a "
             f"TPU; Mosaic compiles only there")
     failed = 0
     cases = {"gmm": [], "ce": CE_CASES, "win": [], "scan": SCAN_CASES,
-             "rope": [], "route": [], "moves": []}.get(
+             "rope": [], "route": [], "moves": [], "bwd": []}.get(
         "".join(argv), CASES + CE_CASES + SCAN_CASES)
     for name, make, shape in cases:
         try:
@@ -835,6 +910,8 @@ def main(argv):
         failed += run_route()
     if argv in ([], ["moves"]):
         failed += run_moves()
+    if argv in ([], ["bwd"]):
+        failed += run_backward()
     return 1 if failed else 0
 
 
