@@ -55,6 +55,16 @@ def _opt_leaf_base(k: str) -> str:
     return k[:-len(_MASTER_SUFFIX)] if k.endswith(_MASTER_SUFFIX) else k
 
 
+def _cast_floats(tree, dtype):
+    """``tree`` with its floating leaves cast to ``dtype`` (a step's
+    compute dtype) and the others as they are."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree.map(lambda v: v.astype(dtype) if jnp.issubdtype(
+        v.dtype, jnp.floating) else v, tree)
+
+
 def _point_shape(shape, spec, sizes):
     """Shape of one grid point's slice of a ``shape``-d leaf under a
     single-axis PartitionSpec (the set-family residency layout)."""
@@ -1709,7 +1719,7 @@ class FFModel:
             else ()
 
     def _jit_step(self, train_step):
-        """The donated, jitted train step every step factory returns,
+        """The donated, jitted train step ``_make_step`` returns,
         noted in ``obs`` as the program a device trace of training shows
         (:class:`_TrainStep`: it remembers what it first ran on, which
         ``operator_table()`` lowers it for again)."""
@@ -1725,135 +1735,49 @@ class FFModel:
             train_step, donate_argnums=self._donate((0, 1, 2))))
 
     def make_train_step(self):
-        """Jitted full training iteration (forward+backward+update).  A
-        new step factory wraps its optimizer update in
-        ``jax.named_scope(UPDATE_SCOPE)`` and returns ``_jit_step(..)``,
-        as the four here do."""
-        import jax
-
+        """Jitted full training iteration (forward+backward+update) under
+        SGD with momentum and weight decay: ``v = mu*v + g + wd*p;
+        p -= lr*v``."""
         cfg = self.config
         lr, wd, mu = cfg.learning_rate, cfg.weight_decay, cfg.momentum
-        cdtype = cfg.compute_dtype
-        if self._mixed_precision():
-            return self._make_mixed_train_step(lr, wd, mu, cdtype)
 
-        def train_step(params, state, opt_state, image, labels):
-            image = image.astype(cdtype)
+        def momentum(p, g, v):
+            v = mu * v + g + wd * p
+            return p - lr * v, v
 
-            def lf(p):
-                return self.loss_fn(p, state, image, labels, train=True)
-
-            (loss, new_state), grads = jax.value_and_grad(lf, has_aux=True)(
-                params)
-
-            def upd(p, g, v):
-                v = mu * v + g + wd * p
-                return p - lr * v, v
-
-            with jax.named_scope(UPDATE_SCOPE):
-                new_params_and_v = jax.tree.map(upd, params, grads,
-                                                opt_state)
-                new_params = jax.tree.map(
-                    lambda t: t[0], new_params_and_v,
-                    is_leaf=lambda t: isinstance(t, tuple))
-                new_v = jax.tree.map(
-                    lambda t: t[1], new_params_and_v,
-                    is_leaf=lambda t: isinstance(t, tuple))
-                psh = self._param_shardings(new_params)
-                new_params = self._constrain_params(new_params, psh)
-                new_v = self._constrain_params(new_v, psh)
-            return new_params, self._constrain_state(new_state), new_v, loss
-
-        return self._jit_step(train_step)
-
-    def _make_mixed_train_step(self, lr, wd, mu, cdtype):
-        """Master-weight variant of make_train_step (param_dtype !=
-        float32): the forward/backward runs on compute-dtype casts of
-        the low-precision stored params, the momentum update runs in
-        float32 against the masters in the optimizer state, and the
-        stored params are re-cast from the updated masters — update math
-        never accumulates in the storage dtype."""
-        import jax
-        import jax.numpy as jnp
-
-        def train_step(params, state, opt_state, image, labels):
-            image = image.astype(cdtype)
-
-            def lf(p):
-                pc = jax.tree.map(
-                    lambda v: v.astype(cdtype)
-                    if jnp.issubdtype(v.dtype, jnp.floating) else v, p)
-                return self.loss_fn(pc, state, image, labels, train=True)
-
-            (loss, new_state), grads = jax.value_and_grad(
-                lf, has_aux=True)(params)
-            new_params, new_opt = {}, {}
-            with jax.named_scope(UPDATE_SCOPE):
-                for key, sub in params.items():
-                    np_, no_, osub = {}, {}, opt_state[key]
-                    for k, p in sub.items():
-                        mk = k + _MASTER_SUFFIX
-                        if mk in osub:
-                            g = grads[key][k].astype(jnp.float32)
-                            m, v = osub[mk], osub[k]
-                            v = mu * v + g + wd * m
-                            m = m - lr * v
-                            np_[k] = m.astype(p.dtype)
-                            no_[k], no_[mk] = v, m
-                        else:  # non-float leaf: in-dtype legacy update
-                            v = mu * osub[k] + grads[key][k] + wd * p
-                            np_[k], no_[k] = p - lr * v, v
-                    new_params[key], new_opt[key] = np_, no_
-                psh = self._param_shardings(new_params)
-                new_params = self._constrain_params(new_params, psh)
-                new_opt = self._constrain_params(
-                    new_opt, self._opt_shardings(new_opt, psh))
-            return new_params, self._constrain_state(new_state), new_opt, \
-                loss
-
-        return self._jit_step(train_step)
+        return self._make_step(momentum)
 
     def make_sgd_step(self, lr: float):
         """Plain-SGD train step over ``self.loss_fn(params, state, *batch)``
         — shared by the RNN and transformer subclasses (their reference
-        counterparts apply bare rate*grad updates, nmt/rnn.cu:684-702).
-        In mixed-precision mode the opt_state carries the float32 masters
-        (master_opt_state); the rate*grad update runs against them."""
-        import jax
+        counterparts apply bare rate*grad updates, nmt/rnn.cu:684-702)."""
+        return self._make_step(lambda p, g: (p - lr * g,))
 
-        if self._mixed_precision():
-            return self._make_mixed_sgd_step(lr)
-
-        def train_step(params, state, opt_state, *batch):
-            def lf(p):
-                return self.loss_fn(p, state, *batch, train=True)
-
-            (loss, new_state), grads = jax.value_and_grad(
-                lf, has_aux=True)(params)
-            with jax.named_scope(UPDATE_SCOPE):
-                new_params = jax.tree.map(lambda p, g: p - lr * g, params,
-                                          grads)
-                new_params = self._constrain_params(
-                    new_params, self._param_shardings(new_params))
-            return new_params, self._constrain_state(new_state), \
-                opt_state, loss
-
-        return self._jit_step(train_step)
-
-    def _make_mixed_sgd_step(self, lr: float):
-        """Master-weight variant of make_sgd_step: float32 rate*grad
-        update against the masters, stored params re-cast from them."""
+    def _make_step(self, rule):
+        """The one train step: the floating batch inputs cast to the
+        compute dtype, the loss's value and gradient, and ``rule(p, g,
+        *carried) -> (p', *carried')`` applied leaf by leaf inside
+        ``jax.named_scope(UPDATE_SCOPE)``, jitted by ``_jit_step``.  What
+        the rule carries for a leaf (momentum's velocity) rides in the
+        optimizer state under the leaf's name.  In mixed precision the
+        loss reads compute-dtype casts of the stored params and a leaf
+        with a float32 master (``<leaf>__master``) is updated there: the
+        rule runs on the master against the float32 gradient and the
+        stored leaf is re-cast from the new master, so update math never
+        accumulates in the storage dtype; a leaf without one (not
+        floating) is updated in its own dtype."""
         import jax
         import jax.numpy as jnp
 
         cdtype = self.config.compute_dtype
+        mixed = self._mixed_precision()
 
         def train_step(params, state, opt_state, *batch):
+            batch = _cast_floats(batch, cdtype)
+
             def lf(p):
-                pc = jax.tree.map(
-                    lambda v: v.astype(cdtype)
-                    if jnp.issubdtype(v.dtype, jnp.floating) else v, p)
-                return self.loss_fn(pc, state, *batch, train=True)
+                return self.loss_fn(_cast_floats(p, cdtype) if mixed else p,
+                                    state, *batch, train=True)
 
             (loss, new_state), grads = jax.value_and_grad(
                 lf, has_aux=True)(params)
@@ -1863,13 +1787,17 @@ class FFModel:
                     np_, no_ = {}, {}
                     osub = (opt_state or {}).get(key, {})
                     for k, p in sub.items():
-                        mk = k + _MASTER_SUFFIX
+                        g, mk = grads[key][k], k + _MASTER_SUFFIX
+                        carried = (osub[k],) if k in osub else ()
                         if mk in osub:
-                            m = osub[mk] - lr * grads[key][k].astype(
-                                jnp.float32)
+                            m, *carried = rule(osub[mk],
+                                               g.astype(jnp.float32),
+                                               *carried)
                             np_[k], no_[mk] = m.astype(p.dtype), m
                         else:
-                            np_[k] = p - lr * grads[key][k]
+                            np_[k], *carried = rule(p, g, *carried)
+                        if carried:
+                            (no_[k],) = carried
                     new_params[key] = np_
                     if no_:
                         new_opt[key] = no_
@@ -1980,11 +1908,7 @@ class FFModel:
         def eval_step(params, state, image, labels):
             image = image.astype(self.config.compute_dtype)
             if self._mixed_precision():
-                cdtype = self.config.compute_dtype
-                params = jax.tree.map(
-                    lambda v: v.astype(cdtype)
-                    if jnp.issubdtype(v.dtype, jnp.floating) else v,
-                    params)
+                params = _cast_floats(params, self.config.compute_dtype)
             inputs = {self._inputs[0].tid: image}
             values, _ = self.apply(params, state, inputs, train=False)
             log_probs = values[loss_op.output.tid]
@@ -2016,7 +1940,6 @@ class FFModel:
         fed zeros by the engine — the softmax op reads it but only
         ``loss()`` consumes it, and serving never calls ``loss()``)."""
         import jax
-        import jax.numpy as jnp
 
         tids = tuple(output_tids) if output_tids is not None \
             else (self._loss_op().output.tid,)
@@ -2024,15 +1947,9 @@ class FFModel:
 
         def predict_step(params, state, *batch):
             if self._mixed_precision():
-                params = jax.tree.map(
-                    lambda v: v.astype(cdtype)
-                    if jnp.issubdtype(v.dtype, jnp.floating) else v,
-                    params)
-            inputs = {}
-            for t, b in zip(self._inputs, batch):
-                if jnp.issubdtype(b.dtype, jnp.floating):
-                    b = b.astype(cdtype)
-                inputs[t.tid] = b
+                params = _cast_floats(params, cdtype)
+            inputs = {t.tid: b for t, b in zip(
+                self._inputs, _cast_floats(batch, cdtype))}
             values, _ = self.apply(params, state, inputs, train=False)
             return tuple(values[tid] for tid in tids)
 

@@ -224,7 +224,8 @@ def test_held_experts_with_the_gate_open_against_the_gate_shut(
 
     def run():
         before = dict(obs.snapshot()["counters"])
-        out = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(p, x, gates)
+        out = jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(
+            p, x, gates)
         after = obs.snapshot()["counters"]
         return out, {k: v - before.get(k, 0) for k, v in after.items()
                      if k.startswith("kernels.gmm.")

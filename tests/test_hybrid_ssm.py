@@ -5,6 +5,7 @@ trains from a ``granitemoehybrid`` configuration: each against plain
 ``jax.numpy``, the chunked scan against the recurrence over time steps,
 and the whole model against ``benchmarks/reference/granite_4_0_h_micro.py``."""
 
+import contextlib
 import json
 import os
 
@@ -13,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lm_check
 from flexflow_tpu.machine import MachineModel
 from flexflow_tpu.ops.base import Tensor
 from flexflow_tpu.strategy import ParallelConfig
@@ -92,10 +94,13 @@ def test_chunked_scan_equals_the_recurrence_over_time_steps(chunk):
         return jnp.sum(fn(*args) * weight)
 
     chunked = lambda *args: ssd_chunked(*args, chunk)
-    np.testing.assert_allclose(chunked(*ops), _recurrence(*ops),
+    np.testing.assert_allclose(jax.jit(chunked)(*ops),
+                               jax.jit(_recurrence)(*ops),
                                rtol=2e-5, atol=2e-6)
-    got = jax.grad(lambda *a: loss(chunked, *a), argnums=range(6))(*ops)
-    want = jax.grad(lambda *a: loss(_recurrence, *a), argnums=range(6))(*ops)
+    got = jax.jit(jax.grad(lambda *a: loss(chunked, *a),
+                           argnums=range(6)))(*ops)
+    want = jax.jit(jax.grad(lambda *a: loss(_recurrence, *a),
+                            argnums=range(6)))(*ops)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
 
@@ -122,11 +127,12 @@ def test_chunked_scan_survives_decays_that_underflow(path):
             interpret=True).reshape(x.shape)
     dt, a = dt * 0 + 3.0, a * 0 - 20.0
     f = lambda x, dt: jnp.sum(scan(x, dt) ** 2)
-    value, grads = jax.value_and_grad(f, argnums=(0, 1))(x, dt)
+    value, grads = jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(x, dt)
     assert np.isfinite(value) and all(np.all(np.isfinite(g)) for g in grads)
+    scanned = jax.jit(scan)(x, dt)
     if path == "xla":
-        np.testing.assert_allclose(scan(x, dt),
-                                   _recurrence(x, dt, a, b, c, d),
+        np.testing.assert_allclose(scanned,
+                                   jax.jit(_recurrence)(x, dt, a, b, c, d),
                                    rtol=1e-5, atol=1e-6)
         return
     with jax.default_matmul_precision("highest"):
@@ -135,7 +141,7 @@ def test_chunked_scan_survives_decays_that_underflow(path):
         # of tens, where the case above has 5: the same limit in units
         # of the largest value)
         np.testing.assert_allclose(
-            scan(x, dt), want, rtol=1e-5,
+            scanned, want, rtol=1e-5,
             atol=1e-6 * max(1.0, float(jnp.max(jnp.abs(want)))))
 
 
@@ -175,13 +181,19 @@ def test_mixer_operators_against_the_reference():
                            zip((first, scan, out), keys))
     p_scan["D"] = _rand(5, h)
     x = _rand(1, 2, 20, 64)
-    (z, xbc, delta), _ = first.forward(p_in, {}, [x], True)
+
+    @jax.jit
+    def chained(x):
+        (z, xbc, delta), _ = first.forward(p_in, {}, [x], True)
+        y, _ = scan.forward(p_scan, {}, [xbc, delta], True)
+        return out.forward(p_out, {}, [y, z], True)[0], delta
+
+    got, delta = chained(x)
     assert delta.dtype == jnp.float32 and delta.shape == (2, 20, h)
-    y, _ = scan.forward(p_scan, {}, [xbc, delta], True)
-    got, _ = out.forward(p_out, {}, [y, z], True)
     ref = _reference()
     with jax.default_matmul_precision("highest"):
-        want = jax.vmap(lambda r: ref._mamba(p_in, p_scan, p_out, r, cfg))(x)
+        want = jax.jit(jax.vmap(
+            lambda r: ref._mamba(p_in, p_scan, p_out, r, cfg)))(x)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     # dt_bias gives time steps within 0.001-0.1 at a zero projection
     dt0 = jax.nn.softplus(p_in["dt_bias"])
@@ -225,8 +237,6 @@ def test_grouped_query_attention_against_repeated_heads(path,
     that is not 1/sqrt(64): the operator against attention with k and v
     repeated, values and weight gradients; with the kernel gate open the
     scores run in the flash kernels."""
-    import contextlib
-
     from flexflow_tpu.ops.attention import GroupedQueryAttention
 
     h, kv, hd, scale = 8, 2, 64, 0.03
@@ -244,12 +254,13 @@ def test_grouped_query_attention_against_repeated_heads(path,
     flash = "kernels.flash.pack2.fused"
     before = _counted(flash)
     with pallas_kernels() if path == "kernels" else contextlib.nullcontext():
-        got, grads = jax.value_and_grad(
-            lambda p: jnp.sum(op.forward(p, {}, [x], True)[0] ** 2))(params)
+        got, grads = jax.jit(jax.value_and_grad(
+            lambda p: jnp.sum(op.forward(p, {}, [x], True)[0] ** 2)))(
+                params)
     assert _counted(flash) == before + (path == "kernels")
     assert _counted("attn.kv_groups") == 4
-    want, want_grads = jax.value_and_grad(
-        lambda p: jnp.sum(plain(p) ** 2))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: jnp.sum(plain(p) ** 2)))(params)
     np.testing.assert_allclose(got, want, rtol=1e-4)
     for key in params:
         np.testing.assert_allclose(grads[key], want_grads[key], rtol=2e-3,
@@ -320,7 +331,8 @@ def test_tied_head_is_one_parameter_with_the_gradient_of_both_uses(
     toks = jax.random.randint(jax.random.PRNGKey(3), (2, 20), 0, 96)
     cfg = _tiny_config()
 
-    grad = jax.grad(lambda p: ff.loss_fn(p, state, toks, toks)[0])(params)
+    grad = jax.jit(jax.grad(
+        lambda p: ff.loss_fn(p, state, toks, toks)[0]))(params)
 
     # the same loss with the two uses under two names
     ref = _reference()
@@ -337,8 +349,8 @@ def test_tied_head_is_one_parameter_with_the_gradient_of_both_uses(
 
     with jax.default_matmul_precision("highest"):
         table = plain["embed"]["table"]
-        by_lookup, by_head = jax.grad(two_tables, argnums=(0, 1))(table,
-                                                                  table)
+        by_lookup, by_head = jax.jit(jax.grad(two_tables, argnums=(0, 1)))(
+            table, table)
     assert float(jnp.linalg.norm(by_lookup)) > 0
     assert float(jnp.linalg.norm(by_head)) > 0
     np.testing.assert_allclose(grad["embed"]["table"], by_lookup + by_head,
@@ -392,9 +404,6 @@ def test_loss_and_every_operator_gradient_against_the_reference(
     kernel and the attention in the flash kernels, inside recomputed
     blocks; ``scan_kernels``: the same with scans of the shapes
     ``ff_ssd_fwd`` and ``ff_ssd_bwd`` take (the last chunk padded)."""
-    import contextlib
-
-    from benchmarks import harness
     from flexflow_tpu.models.hybrid_ssm import HybridSSMConfig, HybridSSMLM
 
     ff, cfg, b, s = tiny_model, _tiny_config(), 2, 20
@@ -407,35 +416,17 @@ def test_loss_and_every_operator_gradient_against_the_reference(
             MachineModel(jax.devices()[:1]))
     if head == "scan_kernels":
         (cfg, ff), b, s = _kernel_model(2, 200), 2, 200
+    kernels = pallas_kernels if head != "plain" else contextlib.nullcontext
+    if head == "fused":
+        with kernels():
+            assert ff._lm_head_fusion()
     form = "kernels.ssd.pallas.128x8x128"
     ran = _counted(form)
-    params, state = ff.init(4)
-    # every gain, D and bias away from its initial value
-    params = jax.tree.map(
-        lambda a: a + 0.1 * _rand(a.size % 97, *a.shape), params)
-    toks = jax.random.randint(jax.random.PRNGKey(5), (b, s), 0,
-                              cfg["vocab_size"])
-    with pallas_kernels() if head != "plain" else contextlib.nullcontext():
-        if head == "fused":
-            assert ff._lm_head_fusion()
-        (loss, _), grads = jax.value_and_grad(
-            lambda p: ff.loss_fn(p, state, toks, toks), has_aux=True)(params)
+    plain = lm_check.check_against_reference(ff, cfg, _reference(), b, s,
+                                             kernels)
+    assert "lm_head" not in plain
     # two scans, each traced forward and in its recomputed block
     assert (_counted(form) > ran) == (head == "scan_kernels")
-    plain = harness.op_params(ff, params)
-    assert "lm_head" not in plain
-    with jax.default_matmul_precision("highest"):
-        total, want, n = _reference().sum_loss_and_grads(plain, (toks, toks),
-                                                         cfg)
-    assert n == b * (s - 1)
-    np.testing.assert_allclose(loss, total / n, rtol=1e-5)
-    assert set(want) == set(grads)
-    for op, leaves in want.items():
-        for leaf, g in leaves.items():
-            scale = float(jnp.max(jnp.abs(g))) / n
-            np.testing.assert_allclose(
-                grads[op][leaf], g / n, rtol=2e-3, atol=2e-4 * scale + 1e-9,
-                err_msg=f"{op}.{leaf}")
 
 
 @pytest.mark.parametrize("path", ["xla", "kernels"])
@@ -445,8 +436,6 @@ def test_recomputed_step_equals_the_plain_step(path, tiny_model,
     ``ff_ssd_bwd`` (and whose attention the flash kernels), so that a
     recomputed block runs the forward kernel once more and hands its
     backward the states it wrote."""
-    import contextlib
-
     model, s, vocab = tiny_model, 20, 96
     if path == "kernels":
         (_, model), s, vocab = _kernel_model(2, 256), 256, 256

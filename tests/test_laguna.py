@@ -7,6 +7,8 @@ over positions, the whole model against
 ``benchmarks/reference/laguna_s_2_1.py``, and what Granite's, Moonlight's
 and GPT-2's cells run held to what it was."""
 
+import contextlib
+import functools
 import hashlib
 import importlib
 import json
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lm_check
 from flexflow_tpu.machine import MachineModel
 from flexflow_tpu.ops.base import Tensor
 from flexflow_tpu.strategy import ParallelConfig
@@ -93,6 +96,25 @@ _WINDOWS = [(64, 16, 16, 8), (64, 12, 16, 8), (64, 1, 16, 8),
             (40, 16, 16, 4), (96, 32, 16, 8), (48, 16, None, 8)]
 
 
+def _operands(s):
+    return tuple(_rand(i + s, 2, 2, s, 16) for i in range(4))
+
+
+def _outputs_and_grads(attend, q, k, v, w):
+    # the gradients of sum(attend * w): its vjp of w
+    out, vjp = jax.vjp(attend, q, k, v)
+    return out, jnp.sum(out * w), vjp(w)
+
+
+@functools.lru_cache(maxsize=None)
+def _by_loop(s, window):
+    """The loop's result, its weighted sum and gradients: one reading
+    for both backward forms."""
+    return jax.jit(_outputs_and_grads, static_argnums=0)(
+        lambda q, k, v: _attention_by_loop(q, k, v, window, 0.25),
+        *_operands(s))
+
+
 @pytest.mark.parametrize("backward", ["fused", "split"])
 @pytest.mark.parametrize("s,window,block,piece", _WINDOWS)
 def test_windowed_flash_against_a_loop_over_positions(s, window, block,
@@ -106,7 +128,6 @@ def test_windowed_flash_against_a_loop_over_positions(s, window, block,
                         2 ** 21 if backward == "fused" else 64)
     fa._make_flash.cache_clear()
     try:
-        q, k, v, w = (_rand(i + s, 2, 2, s, 16) for i in range(4))
         name = f"kernels.flash.pad128.{backward}" \
             + (f".w{window}" if window < s else "")
         before = _counted(name)
@@ -115,15 +136,12 @@ def test_windowed_flash_against_a_loop_over_positions(s, window, block,
             return fa.flash_attention(q, k, v, True, block, block,
                                       window=window)
 
-        got, grads = jax.value_and_grad(
-            lambda *a: jnp.sum(kernels(*a) * w), (0, 1, 2))(q, k, v)
+        out, got, grads = jax.jit(_outputs_and_grads, static_argnums=0)(
+            kernels, *_operands(s))
         assert _counted(name) == before + 1
-        want, want_grads = jax.value_and_grad(
-            lambda *a: jnp.sum(_attention_by_loop(*a, window, 0.25) * w),
-            (0, 1, 2))(q, k, v)
+        want_out, want, want_grads = _by_loop(s, window)
         np.testing.assert_allclose(got, want, rtol=1e-5)
-        np.testing.assert_allclose(kernels(q, k, v), _attention_by_loop(
-            q, k, v, window, 0.25), rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(out, want_out, rtol=1e-4, atol=1e-5)
         for g, wg in zip(grads, want_grads):
             np.testing.assert_allclose(g, wg, rtol=1e-3, atol=1e-5)
     finally:
@@ -179,10 +197,9 @@ def test_plain_path_window_against_a_loop_over_positions(window):
     def heads(x, n):
         return x.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
 
-    want = _attention_by_loop(heads(q, h),
-                              jnp.repeat(heads(k, kv), h // kv, axis=1),
-                              jnp.repeat(heads(v, kv), h // kv, axis=1),
-                              window, 0.3)
+    want = jax.jit(_attention_by_loop, static_argnums=(3, 4))(
+        heads(q, h), jnp.repeat(heads(k, kv), h // kv, axis=1),
+        jnp.repeat(heads(v, kv), h // kv, axis=1), window, 0.3)
     np.testing.assert_allclose(
         got, want.transpose(0, 2, 1, 3).reshape(b, s, h * hd), rtol=1e-5,
         atol=1e-6)
@@ -276,8 +293,6 @@ def test_attention_operator_against_the_reference(window, path,
     window and per-head gate together, values and every weight's gradient,
     the gate's among them; with the kernel gate open the scores run in
     the flash kernels."""
-    import contextlib
-
     ref = _reference()
     kind = "full_attention" if window is None else "sliding_attention"
     c = _tiny_config(hidden_size=32, sliding_window=8,
@@ -292,8 +307,8 @@ def test_attention_operator_against_the_reference(window, path,
     x, w = _rand(1, 2, 24, 32), _rand(2, 2, 24, 32)
     groups = _counted("attn.kv_groups.3")
     with pallas_kernels() if path == "kernels" else contextlib.nullcontext():
-        got, grads = jax.value_and_grad(
-            lambda p: jnp.sum(op.forward(p, {}, [x], True)[0] * w))(params)
+        got, grads = jax.jit(jax.value_and_grad(
+            lambda p: jnp.sum(op.forward(p, {}, [x], True)[0] * w)))(params)
     assert _counted("attn.kv_groups.3") == groups + 1
     assert _counted("attn.kv_groups") == 3
     if window:
@@ -303,8 +318,8 @@ def test_attention_operator_against_the_reference(window, path,
         return jnp.stack([ref._attention(p, row, c, 0) for row in x])
 
     with jax.default_matmul_precision("highest"):
-        want, want_grads = jax.value_and_grad(
-            lambda p: jnp.sum(plain(p) * w))(params)
+        want, want_grads = jax.jit(jax.value_and_grad(
+            lambda p: jnp.sum(plain(p) * w)))(params)
     np.testing.assert_allclose(got, want, rtol=1e-4)
     for key in params:
         np.testing.assert_allclose(grads[key], want_grads[key], rtol=2e-3,
@@ -412,29 +427,37 @@ def test_the_shares_add_up_to_the_uncut_layer():
     pw = experts((0, 16)).init_params(jax.random.PRNGKey(4))
     router, pr, st = _router(n_router, top_k, d, tokens,
                              c["moe_routed_scaling_factor"])
-    gates, _ = router.forward(pr, st, [x], True)
+    gates = jax.jit(lambda x: router.forward(pr, st, [x], True)[0])(x)
     shared = {k: _rand(15 + i, *shape, scale=0.1) for i, (k, shape) in
               enumerate([("w_gate", (d, f)), ("w_up", (d, f)),
                          ("w_down", (f, d))])}
     flat = x.reshape(-1, d)
     params = {"blk1_moe_router": pr, "blk1_moe_shared": shared,
               "blk1_moe_experts": pw}
-    with jax.default_matmul_precision("highest"):
-        want = ref.feed_forward(params, 1, flat, c)
-        total = ref._glu(flat, **shared)
+
+    @jax.jit
+    def layer(x, gates):
+        """The uncut reference, its shared expert, and each share beside
+        what the reference gives for the same experts."""
+        shares = []
         for lo in range(0, n_router, 4):
             share = experts((lo, lo + 4))
             ps = {k: v[lo:lo + 4] for k, v in pw.items()}
             y, state = share.forward(ps, share.init_state(), [x, gates],
                                      True)
-            assert float(state["dropped"]) == 0
-            # a share is what the reference gives for the same experts
-            np.testing.assert_allclose(
-                y.reshape(-1, d), ref.routed_part(
-                    ps, gates.reshape(-1, n_router), flat,
-                    dict(c, experts_held=[lo, lo + 4])), rtol=1e-4,
-                atol=1e-5)
-            total = total + y.reshape(-1, d)
+            shares.append((y.reshape(-1, d), state["dropped"],
+                           ref.routed_part(
+                               ps, gates.reshape(-1, n_router), flat,
+                               dict(c, experts_held=[lo, lo + 4]))))
+        return ref.feed_forward(params, 1, flat, c), \
+            ref._glu(flat, **shared), shares
+
+    with jax.default_matmul_precision("highest"):
+        want, total, shares = layer(x, gates)
+    for y, dropped, y_ref in shares:
+        assert float(dropped) == 0
+        np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-5)
+        total = total + y
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
 
 
@@ -479,12 +502,10 @@ def test_loss_and_every_operator_gradient_against_the_reference(
     gate open, so that the head runs in the fused projection+CE kernel
     and the attention in the flash kernels, windowed and full, inside
     recomputed blocks."""
-    import contextlib
-
-    from benchmarks import harness
     from flexflow_tpu.models.laguna import LagunaConfig, LagunaLM
 
     ff, cfg, b, s = tiny_model, _tiny_config(), 2, 20
+    kernels = contextlib.nullcontext
     if head == "fused":
         fa = _flash()
         monkeypatch.setattr(fa, "_WINDOW_BLOCKS", (64, 32))
@@ -497,33 +518,13 @@ def test_loss_and_every_operator_gradient_against_the_reference(
         ff = LagunaLM(LagunaConfig.from_config(
             cfg, batch_size=b, seq_length=s),
             MachineModel(jax.devices()[:1]))
-    params, state = ff.init(4)
-    # every gain away from its initial value
-    params = jax.tree.map(
-        lambda a: a + 0.1 * _rand(a.size % 97, *a.shape), params)
-    toks = jax.random.randint(jax.random.PRNGKey(5), (b, s), 0,
-                              cfg["vocab_size"])
-    windowed = _counted("kernels.flash.pack1.fused.w64")
-    with pallas_kernels() if head == "fused" else contextlib.nullcontext():
-        if head == "fused":
+        kernels = pallas_kernels
+        with kernels():
             assert ff._lm_head_fusion()
-        (loss, _), grads = jax.value_and_grad(
-            lambda p: ff.loss_fn(p, state, toks, toks), has_aux=True)(params)
+    windowed = _counted("kernels.flash.pack1.fused.w64")
+    lm_check.check_against_reference(ff, cfg, _reference(), b, s, kernels)
     if head == "fused":
         assert _counted("kernels.flash.pack1.fused.w64") == windowed + 2
-    plain = harness.op_params(ff, params)
-    with jax.default_matmul_precision("highest"):
-        total, want, n = _reference().sum_loss_and_grads(plain, (toks, toks),
-                                                         cfg)
-    assert n == b * (s - 1)
-    np.testing.assert_allclose(loss, total / n, rtol=1e-5)
-    assert set(want) == set(grads)
-    for op, leaves in want.items():
-        for leaf, g in leaves.items():
-            scale = float(jnp.max(jnp.abs(g))) / n
-            np.testing.assert_allclose(
-                grads[op][leaf], g / n, rtol=2e-3, atol=2e-4 * scale + 1e-9,
-                err_msg=f"{op}.{leaf}")
 
 
 def test_recomputed_step_trains_and_counts_the_mechanisms(tiny_model):

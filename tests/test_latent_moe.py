@@ -294,28 +294,38 @@ def test_the_shares_add_up_to_the_uncut_layer():
     pw = whole.init_params(jax.random.PRNGKey(4))
     router, pr, st = _router(n_router, top_k, d=d, tokens=tokens)
     router.scale = c["routed_scaling_factor"]
-    gates, _ = router.forward(pr, st, [x], True)
+    gates = jax.jit(lambda x: router.forward(pr, st, [x], True)[0])(x)
     shared = {k: _rand(15 + i, *shape, scale=0.1) for i, (k, shape) in
               enumerate([("w_gate", (d, 2 * f)), ("w_up", (d, 2 * f)),
                          ("w_down", (2 * f, d))])}
     flat = x.reshape(-1, d)
-    want = ref._glu(flat, **shared) + ref.routed_part(
-        pw, ref.router_weights(pr["kernel"], flat, c), flat, c)
 
-    total = ref._glu(flat, **shared)
-    for lo in range(0, n_router, 2):            # eight shares of two
-        share = _experts((lo, lo + 2), n_router, top_k, d, f, tokens)
-        ps = {k: v[lo:lo + 2] for k, v in pw.items()}
-        y, state = share.forward(ps, share.init_state(), [x, gates], True)
+    @jax.jit
+    def layer(x, gates):
+        """The uncut reference, its shared experts, and each of eight
+        shares of two beside what the reference gives for the same
+        experts_held."""
+        shares = []
+        for lo in range(0, n_router, 2):
+            share = _experts((lo, lo + 2), n_router, top_k, d, f, tokens)
+            ps = {k: v[lo:lo + 2] for k, v in pw.items()}
+            y, state = share.forward(ps, share.init_state(), [x, gates],
+                                     True)
+            shares.append((y.reshape(-1, d), state, ref.routed_part(
+                ps, gates.reshape(-1, n_router), flat,
+                dict(c, experts_held=[lo, lo + 2]))))
+        return ref.routed_part(pw, ref.router_weights(pr["kernel"], flat,
+                                                      c), flat, c), \
+            ref._glu(flat, **shared), shares
+
+    routed, total, shares = layer(x, gates)
+    want = total + routed
+    for y, state, y_ref in shares:
         assert float(state["dropped"]) == 0
         np.testing.assert_array_equal(state["counts"],
                                       (gates > 0).sum((0, 1)))
-        # a share is what the reference gives for the same experts_held
-        np.testing.assert_allclose(
-            y.reshape(-1, d), ref.routed_part(
-                ps, gates.reshape(-1, n_router), flat,
-                dict(c, experts_held=[lo, lo + 2])), rtol=1e-4, atol=1e-5)
-        total = total + y.reshape(-1, d)
+        np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-5)
+        total = total + y
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
 
 
@@ -370,9 +380,9 @@ def test_share_gradients_against_the_reference(products, pallas_kernels,
     before = _counted("kernels.gmm." + products)
     with (_gmm_kernels(pallas_kernels, monkeypatch)
           if products == "ff_gmm" else contextlib.nullcontext()):
-        got = jax.grad(ours, (0, 1, 2))(p, x, pr["kernel"])
+        got = jax.jit(jax.grad(ours, (0, 1, 2)))(p, x, pr["kernel"])
     assert _counted("kernels.gmm." + products) == before + 1
-    want = jax.grad(theirs, (0, 1, 2))(p, x, pr["kernel"])
+    want = jax.jit(jax.grad(theirs, (0, 1, 2)))(p, x, pr["kernel"])
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
 

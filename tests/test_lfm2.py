@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lm_check
 from flexflow_tpu.machine import MachineModel
 from flexflow_tpu.ops.base import Tensor
 from flexflow_tpu.strategy import ParallelConfig
@@ -359,27 +360,34 @@ def test_the_shares_add_up_to_the_uncut_layer():
     pw = experts((0, 32)).init_params(jax.random.PRNGKey(4))
     router, pr, st = _router(32, 4, d, tokens, c["routed_scaling_factor"],
                              denominator_eps=1e-6)
-    gates, _ = router.forward(pr, st, [x], True)
+    gates = jax.jit(lambda x: router.forward(pr, st, [x], True)[0])(x)
     assert int(jnp.sum(gates > 0)) == 4 * 48
     flat = x.reshape(-1, d)
     params = {"blk1_moe_router": pr, "blk1_moe_experts": pw}
-    total, pairs = jnp.zeros_like(flat), 0
-    with jax.default_matmul_precision("highest"):
-        want = ref.feed_forward(params, 1, flat, c)
+
+    @jax.jit
+    def layer(x, gates):
+        """The uncut reference, and each share beside what the reference
+        gives for the same experts."""
+        shares = []
         for lo in range(0, 32, 8):
             share = experts((lo, lo + 8))
             ps = {k: v[lo:lo + 8] for k, v in pw.items()}
             y, state = share.forward(ps, share.init_state(), [x, gates],
                                      True)
-            assert float(state["dropped"]) == 0
-            pairs += int(jnp.sum(state["counts"][lo:lo + 8]))
-            # a share is what the reference gives for the same experts
-            np.testing.assert_allclose(
-                y.reshape(-1, d), ref.routed_part(
-                    ps, gates.reshape(-1, 32), flat,
-                    dict(c, experts_held=[lo, lo + 8])), rtol=1e-4,
-                atol=1e-5)
-            total = total + y.reshape(-1, d)
+            shares.append((y.reshape(-1, d), state, ref.routed_part(
+                ps, gates.reshape(-1, 32), flat,
+                dict(c, experts_held=[lo, lo + 8]))))
+        return ref.feed_forward(params, 1, flat, c), shares
+
+    with jax.default_matmul_precision("highest"):
+        want, shares = layer(x, gates)
+    total, pairs = jnp.zeros_like(flat), 0
+    for lo, (y, state, y_ref) in zip(range(0, 32, 8), shares):
+        assert float(state["dropped"]) == 0
+        pairs += int(jnp.sum(state["counts"][lo:lo + 8]))
+        np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-5)
+        total = total + y
     assert pairs == 4 * 48, "every selection falls in exactly one share"
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
 
@@ -447,10 +455,10 @@ def test_loss_and_every_operator_gradient_against_the_reference(
     the attention in the ``pack2`` flash kernels behind its norms and
     rotary, and the held experts in ``ff_gmm``, inside recomputed
     blocks."""
-    from benchmarks import harness
     from flexflow_tpu.models.lfm2 import Lfm2Config, Lfm2LM
 
     ff, cfg, b, s = tiny_model, _tiny_config(), 2, 20
+    kernels = contextlib.nullcontext
     if head == "fused":
         cfg, b, s = _tiny_config(
             hidden_size=128, num_attention_heads=2, num_key_value_heads=1,
@@ -459,18 +467,13 @@ def test_loss_and_every_operator_gradient_against_the_reference(
         ff = Lfm2LM(Lfm2Config.from_config(
             cfg, batch_size=b, seq_length=s),
             MachineModel(jax.devices()[:1]))
-    params, state = ff.init(4)
-    # every gain away from its initial value, the bias off zero
-    params = jax.tree.map(
-        lambda a: a + 0.1 * _rand(a.size % 97, *a.shape), params)
-    toks = jax.random.randint(jax.random.PRNGKey(5), (b, s), 0,
-                              cfg["vocab_size"])
-    packed = _counted("kernels.flash.pack2.fused")
-    with pallas_kernels() if head == "fused" else contextlib.nullcontext():
-        if head == "fused":
+        kernels = pallas_kernels
+        with kernels():
             assert ff._lm_head_fusion()
-        (loss, _), grads = jax.value_and_grad(
-            lambda p: ff.loss_fn(p, state, toks, toks), has_aux=True)(params)
+    packed = _counted("kernels.flash.pack2.fused")
+    plain = lm_check.check_against_reference(ff, cfg, _reference(), b, s,
+                                             kernels)
+    assert "lm_head" not in plain
     if head == "fused":
         assert _counted("kernels.flash.pack2.fused") == packed + 1
         assert _counted("kernels.rope.xla.2x64r64") >= 1
@@ -478,20 +481,6 @@ def test_loss_and_every_operator_gradient_against_the_reference(
 
         assert not any(k.startswith("kernels.rope.pallas.") and "x64r" in k
                        for k in obs.snapshot()["counters"])
-    plain = harness.op_params(ff, params)
-    assert "lm_head" not in plain
-    with jax.default_matmul_precision("highest"):
-        total, want, n = _reference().sum_loss_and_grads(plain, (toks, toks),
-                                                         cfg)
-    assert n == b * (s - 1)
-    np.testing.assert_allclose(loss, total / n, rtol=1e-5)
-    assert set(want) == set(grads)
-    for op, leaves in want.items():
-        for leaf, g in leaves.items():
-            scale = float(jnp.max(jnp.abs(g))) / n
-            np.testing.assert_allclose(
-                grads[op][leaf], g / n, rtol=2e-3, atol=2e-4 * scale + 1e-9,
-                err_msg=f"{op}.{leaf}")
 
 
 def test_recomputed_step_trains_and_counts_the_mechanisms(tiny_model):

@@ -141,11 +141,12 @@ def test_compute_dtype_reaches_token_models(machine8):
                              compute_dtype="bfloat16")
     tlm = TransformerLM(tcfg, machine8)
     params, state = tlm.init(seed=0)
+    import jax
     import jax.numpy as jnp
     toks = jnp.zeros((8, 16), "int32")
-    values, _ = tlm.apply(params, state,
-                          {tlm.tokens.tid: toks, tlm.labels.tid: toks},
-                          train=True)
+    values, _ = jax.jit(lambda p: tlm.apply(
+        p, state, {tlm.tokens.tid: toks, tlm.labels.tid: toks},
+        train=True))(params)
     embed_out = values[tlm.layers[0].output.tid]
     assert embed_out.dtype == jnp.bfloat16
     # master params stay f32 (bf16 is compute-only)

@@ -59,12 +59,14 @@ def test_moe_index_dispatch_matches_dense_spec():
         params = op.init_params(jax.random.PRNGKey(0))
         x = jnp.asarray(np.random.RandomState(1).randn(4, 16, 8),
                         jnp.float32)
-        (y, aux), _ = op.forward(params, {}, [x], train=True)
+        (y, aux), _ = jax.jit(lambda p, x: op.forward(p, {}, [x], True))(
+            params, x)
         probs = jax.nn.softmax(
             jnp.einsum("bsd,de->bse", x, params["wg"]), -1)
-        dispatch, combine, aux_d = _dense_route_oracle(op, probs)
+        dispatch, combine, aux_d = jax.jit(
+            lambda probs: _dense_route_oracle(op, probs))(probs)
         # the op's dense reconstruction must equal the independent oracle
-        d2, c2, aux2 = op._route(probs)
+        d2, c2, aux2 = jax.jit(op._route)(probs)
         np.testing.assert_allclose(np.asarray(d2), np.asarray(dispatch),
                                    atol=1e-6)
         np.testing.assert_allclose(np.asarray(c2), np.asarray(combine),
@@ -142,13 +144,26 @@ def test_moe_top1_router_gets_task_gradient():
 
 
 def test_moe_eval_loss_excludes_aux(machine8):
-    """loss_fn(train=False) must be plain CE — no aux regularizer."""
+    """loss_fn(train=False) must be plain CE — no aux regularizer: the
+    training loss is the eval loss plus the weighted auxiliary loss of
+    each expert layer, as the layers compute it."""
     m = _moe_lm(machine8)
     params, state = m.init()
     toks = _tokens(machine8)
-    train_loss, _ = m.loss_fn(params, state, toks, toks, train=True)
-    eval_loss, _ = m.loss_fn(params, state, toks, toks, train=False)
-    assert float(train_loss) > float(eval_loss)  # aux > 0 always
+
+    @jax.jit
+    def losses(params):
+        values, _ = m.apply(params, state, {m.tokens.tid: toks,
+                                            m.labels.tid: toks}, True)
+        return (m.loss_fn(params, state, toks, toks, train=True)[0],
+                m.loss_fn(params, state, toks, toks, train=False)[0],
+                [values[tid] for tid in m._moe_aux_tids])
+
+    train_loss, eval_loss, aux = losses(params)
+    assert len(aux) == 2 and all(float(a) > 0 for a in aux)
+    np.testing.assert_allclose(
+        train_loss, eval_loss + sum(m.t.moe_aux_weight * a for a in aux),
+        rtol=1e-6)
 
 
 def test_moe_shard_flops_not_uniform():

@@ -188,7 +188,8 @@ def test_rnn_weight_sharing_grads(machine8):
                                    cfg.vocab_size, seed=2)
     src, dst = next(data)
 
-    g = jax.grad(lambda p: m.loss_fn(p, state, src, dst)[0])(params)
+    g = jax.jit(jax.grad(lambda p: m.loss_fn(p, state, src, dst)[0]))(
+        params)
     assert float(jnp.abs(g["encoder0"]["w_ih"]).max()) > 0
     assert float(jnp.abs(g["srcEmbed"]["table"]).max()) > 0
     assert float(jnp.abs(g["linear"]["kernel"]).max()) > 0

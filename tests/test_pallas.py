@@ -156,7 +156,8 @@ def test_flash_parity(case, small_flash, monkeypatch):
             if form == "partial":  # a non-zero lse cotangent
                 total = total + (lse * u).sum()
             return total, (out, lse)
-        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))
 
     name = f"kernels.flash.{variant}"
     before = obs.snapshot()["counters"].get(name, 0)
@@ -208,8 +209,8 @@ def test_fused_backward_against_plain_attention(case, small_flash,
     q, k, v, w = (_rand(rng, 1, 2, s, 64) for _ in range(4))
 
     def loss(attn):
-        return jax.value_and_grad(
-            lambda q, k, v: (attn(q, k, v) * w).sum(), argnums=(0, 1, 2))
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: (attn(q, k, v) * w).sum(), argnums=(0, 1, 2)))
 
     name = f"kernels.flash.pack2.fused.w{window}"
     before = obs.snapshot()["counters"].get(name, 0)
@@ -317,8 +318,8 @@ def test_a_checkpoint_that_keeps_the_named_results_runs_the_forward_once(
     assert kernels(f) == bwd + ["ff_flash_fwd"]
     assert kernels(jax.checkpoint(f)) == bwd + ["ff_flash_fwd"] * 2
     assert kernels(keeping) == bwd + ["ff_flash_fwd"]
-    want = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    got = jax.grad(keeping, argnums=(0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
+    got = jax.jit(jax.grad(keeping, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
 
@@ -342,9 +343,9 @@ def test_partial_combine_matches_full():
         o2, l2 = flash_attention_partial(q, k[:, :, 16:], v[:, :, 16:])
         return (combine_partials(o1, l1, o2, l2)[0] ** 2).sum()
 
-    g1 = jax.grad(loss_pc, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(lambda q, k, v: (blockwise_attention(q, k, v, False) ** 2)
-                  .sum(), argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(loss_pc, argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(lambda q, k, v: (blockwise_attention(
+        q, k, v, False) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
@@ -361,14 +362,17 @@ def test_ring_attention_flash_path(machine8, causal, pallas_kernels):
     rng = np.random.RandomState(6)
     q, k, v = (_rand(rng, 2, 2, 32, 8) for _ in range(3))
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(4,), ("s",))
-    ref = blockwise_attention(q, k, v, causal)
-    gref = jax.grad(lambda q, k, v: (blockwise_attention(q, k, v, causal)
-                                     ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    def value_and_grads(attend):
+        return jax.jit(lambda q, k, v: (attend(q, k, v), jax.grad(
+            lambda q, k, v: (attend(q, k, v) ** 2).sum(),
+            argnums=(0, 1, 2))(q, k, v)))(q, k, v)
+
+    ref, gref = value_and_grads(
+        lambda q, k, v: blockwise_attention(q, k, v, causal))
     with pallas_kernels():
-        got = ring_attention(q, k, v, mesh, "s", causal)
-        gfl = jax.grad(lambda q, k, v: (ring_attention(q, k, v, mesh, "s",
-                                                       causal) ** 2).sum(),
-                       argnums=(0, 1, 2))(q, k, v)
+        got, gfl = value_and_grads(
+            lambda q, k, v: ring_attention(q, k, v, mesh, "s", causal))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     for a, b in zip(gfl, gref):
@@ -391,7 +395,8 @@ def test_transformer_forward_matches_with_flash_forced(machine8, pallas_kernels)
     def run():
         tlm = TransformerLM(tcfg, machine8)
         params, state = tlm.init(seed=0)
-        loss, _ = tlm.loss_fn(params, state, toks, toks, train=True)
+        loss, _ = jax.jit(lambda p: tlm.loss_fn(p, state, toks, toks,
+                                                train=True))(params)
         return float(loss)
 
     base = run()
@@ -464,11 +469,12 @@ def test_fused_linear_ce_parity(n, v, block_n, block_v, form, interpreter):
                 ((lse * wgt_lse).sum() if partial else 0.0)
         return loss
 
-    for got, want in zip(kernel(x, w, b)[:1 + partial], ref(x, w, b)):
+    for got, want in zip(jax.jit(kernel)(x, w, b)[:1 + partial],
+                         jax.jit(ref)(x, w, b)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
-    g1 = jax.grad(weighted(kernel), argnums=(0, 1, 2))(x, w, b)
-    g2 = jax.grad(weighted(ref), argnums=(0, 1, 2))(x, w, b)
+    g1 = jax.jit(jax.grad(weighted(kernel), argnums=(0, 1, 2)))(x, w, b)
+    g2 = jax.jit(jax.grad(weighted(ref), argnums=(0, 1, 2)))(x, w, b)
     for a, c in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                    rtol=1e-4, atol=1e-4)
@@ -597,7 +603,8 @@ def test_lm_head_fusion_matches_unfused(machine8, pallas_kernels):
     def run():
         tlm = TransformerLM(tcfg, machine8)
         params, state = tlm.init(seed=0)
-        loss, _ = tlm.loss_fn(params, state, toks, toks, train=True)
+        loss, _ = jax.jit(lambda p: tlm.loss_fn(p, state, toks, toks,
+                                                train=True))(params)
         return float(loss)
 
     base = run()
@@ -620,8 +627,8 @@ def test_lm_head_fusion_grads_match(machine8, pallas_kernels):
     def grads():
         tlm = TransformerLM(tcfg, machine8)
         params, state = tlm.init(seed=0)
-        g = jax.grad(lambda p: tlm.loss_fn(p, state, toks, toks,
-                                           train=True)[0])(params)
+        g = jax.jit(jax.grad(lambda p: tlm.loss_fn(p, state, toks, toks,
+                                                   train=True)[0]))(params)
         return jax.tree.leaves(g)
 
     base = grads()
@@ -652,9 +659,9 @@ def test_lm_head_fusion_vocab_tp(machine8, pallas_kernels):
         with ctx:
             tlm = TransformerLM(tcfg, machine8, s)
             params, state = tlm.init(seed=0)
-            loss, _ = tlm.loss_fn(params, state, toks, toks, train=True)
-            g = jax.grad(lambda p: tlm.loss_fn(p, state, toks, toks,
-                                               train=True)[0])(params)
+            (loss, _), g = jax.jit(jax.value_and_grad(
+                lambda p: tlm.loss_fn(p, state, toks, toks, train=True),
+                has_aux=True))(params)
             return float(loss), jax.tree.leaves(g)
 
     base_loss, base_g = run(False)

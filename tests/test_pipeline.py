@@ -43,9 +43,10 @@ def test_pipeline_matches_sequential_forward():
     x = jax.random.normal(jax.random.PRNGKey(1), (M * mb, d))
     xs = microbatch(x, M)
 
-    out = spmd_pipeline(_simple_stage, params, xs, mesh,
-                        batch_spec=P("n"))
-    ref = sequential_reference(_simple_stage, jax.device_get(params), xs)
+    out = jax.jit(lambda p, xs: spmd_pipeline(_simple_stage, p, xs, mesh,
+                                              batch_spec=P("n")))(params, xs)
+    ref = jax.jit(lambda p, xs: sequential_reference(_simple_stage, p, xs))(
+        jax.device_get(params), xs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 
@@ -64,8 +65,8 @@ def test_pipeline_gradients_match_sequential():
     def loss_seq(p):
         return (sequential_reference(_simple_stage, p, xs) ** 2).sum()
 
-    g_pipe = jax.grad(loss_pipe)(place_stage_params(params, mesh))
-    g_seq = jax.grad(loss_seq)(params)
+    g_pipe = jax.jit(jax.grad(loss_pipe))(place_stage_params(params, mesh))
+    g_seq = jax.jit(jax.grad(loss_seq))(params)
     for k in params:
         np.testing.assert_allclose(np.asarray(g_pipe[k]),
                                    np.asarray(g_seq[k]),
@@ -85,9 +86,11 @@ def test_transformer_block_pipeline_matches_sequential():
     x = jax.random.normal(jax.random.PRNGKey(5), (B, L, D))
     xs = microbatch(x, 2)
 
-    out = spmd_pipeline(block, place_stage_params(params, mesh), xs, mesh,
-                        batch_spec=P("n"))
-    ref = sequential_reference(block, params, xs)
+    out = jax.jit(lambda p, xs: spmd_pipeline(block, p, xs, mesh,
+                                              batch_spec=P("n")))(
+        place_stage_params(params, mesh), xs)
+    ref = jax.jit(lambda p, xs: sequential_reference(block, p, xs))(params,
+                                                                     xs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
 
@@ -146,8 +149,8 @@ def test_pipelined_lm_matches_sequential(machine8):
     params = model.init(0)
     toks = jnp.asarray(np.random.RandomState(0).randint(0, 64, (8, 16)),
                        "int32")
-    a = float(model.loss_fn(params, toks, toks))
-    b = float(model.loss_reference(params, toks, toks))
+    a = float(jax.jit(model.loss_fn)(params, toks, toks))
+    b = float(jax.jit(model.loss_reference)(params, toks, toks))
     assert abs(a - b) < 1e-4, (a, b)
     # and it trains
     step = model.make_train_step()
@@ -246,8 +249,8 @@ def test_pipelined_lm_tp_matches_sequential(machine8):
     params = model.init(0)
     toks = jnp.asarray(np.random.RandomState(0).randint(0, 64, (8, 16)),
                        "int32")
-    a = float(model.loss_fn(params, toks, toks))
-    b = float(model.loss_reference(params, toks, toks))
+    a = float(jax.jit(model.loss_fn)(params, toks, toks))
+    b = float(jax.jit(model.loss_reference)(params, toks, toks))
     assert abs(a - b) < 1e-4, (a, b)
     # TP weights are physically sharded: a tp-split leaf has per-device
     # shards smaller than the leaf

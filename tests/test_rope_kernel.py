@@ -182,9 +182,9 @@ def test_operator_is_the_same_function_of_its_parameters_on_both_paths(
     name = f"x{HD}r{rule['dim']}"
 
     def run():
-        return jax.value_and_grad(
+        return jax.jit(jax.value_and_grad(
             lambda p, x: jnp.sum(op.forward(p, {}, [x], True)[0] * ct),
-            (0, 1))(params, x)
+            (0, 1)))(params, x)
 
     with pallas_kernels():
         before = {k: _counted(f"kernels.rope.{k}{name}")

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lm_check
 from flexflow_tpu.machine import MachineModel
 from flexflow_tpu.ops.base import Tensor
 from flexflow_tpu.strategy import ParallelConfig
@@ -101,20 +102,26 @@ def test_reglu_grouped_products_against_plain_jnp(small_tiles):
             out = out.at[lo:hi].set(h @ w_down[g])
         return out
 
+    def value_and_pullback(f):
+        def run(*args):
+            out, pull = jax.vjp(f, *args)
+            return out, pull(d_y)
+        return jax.jit(run)(rows, *ws)
+
     with jax.default_matmul_precision("highest"):
-        want, pull_want = jax.vjp(plain, rows, *ws)
-        want_grads = pull_want(d_y)
+        want, want_grads = value_and_pullback(plain)
     group_sizes = jnp.asarray(sizes, jnp.int32)
     y, tiles = gm.gated_ffn(rows, group_sizes, *ws, act="relu")
     assert tiles == (16, 128, 128)
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
     assert not np.asarray(y[32:]).any()
-    _, pull = jax.vjp(lambda r, *w: gm.gated_ffn(
-        r, group_sizes, *w, act="relu")[0], rows, *ws)
-    for got, ref in zip(pull(d_y), want_grads):
+    _, grads = value_and_pullback(lambda r, *w: gm.gated_ffn(
+        r, group_sizes, *w, act="relu")[0])
+    for got, ref in zip(grads, want_grads):
         np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-5)
     # a ReLU gate is not a SiLU one: the default gives other numbers
-    silu, _ = gm.gated_ffn(rows, group_sizes, *ws)
+    silu = jax.jit(lambda r, *w: gm.gated_ffn(r, group_sizes, *w)[0])(
+        rows, *ws)
     assert float(jnp.max(jnp.abs(silu - y))) > 1e-3
     # the ragged_dot path off the kernels computes the same
     from flexflow_tpu.ops.expert_share import grouped_gated_ffn
@@ -235,6 +242,7 @@ def test_the_routers_gates_do_not_see_the_attention(tiny_model):
     router = next(op for op in ff.layers if op.name == "blk1_moe_router")
     norm2 = next(op for op in ff.layers if op.name == "blk1_norm2")
 
+    @jax.jit
     def read(params):
         values, _ = ff.apply(params, state, {ff.tokens.tid: toks,
                                              ff.labels.tid: toks}, False)
@@ -251,7 +259,8 @@ def test_the_routers_gates_do_not_see_the_attention(tiny_model):
     assert float(jnp.max(jnp.abs(read(gained)[0] - gates))) > 1e-3
     # and the router's gradient returns through norm1, the experts'
     # through norm2
-    grads = jax.grad(lambda p: ff.loss_fn(p, state, toks, toks)[0])(params)
+    grads = jax.jit(jax.grad(
+        lambda p: ff.loss_fn(p, state, toks, toks)[0]))(params)
     for name in ("blk1_moe_router", "blk1_norm1", "blk1_norm2"):
         assert float(jnp.max(jnp.abs(jax.tree.leaves(grads[name])[0]))) > 0
 
@@ -286,21 +295,28 @@ def test_the_shares_add_up_to_the_uncut_layer():
          "blk1_attn_window": attn.init_params(jax.random.PRNGKey(4)),
          "blk1_moe_experts": share(0, 16).init_params(
              jax.random.PRNGKey(5))}
-    n, _ = norm1.forward(p["blk1_norm1"], {}, [x], True)
-    gates, _ = router.forward(p["blk1_moe_router"], {}, [n], True)
-    h = x + attn.forward(p["blk1_attn_window"], {}, [n], True)[0]
-    m, _ = norm2.forward(p["blk1_norm2"], {}, [h], True)
-    total, pairs = h, 0
-    for lo in range(0, 16, 4):
-        op = share(lo)
-        ps = {k: v[lo:lo + 4] for k, v in p["blk1_moe_experts"].items()}
-        y, st = op.forward(ps, op.init_state(), [m, gates], True)
+    @jax.jit
+    def shares(x):
+        n, _ = norm1.forward(p["blk1_norm1"], {}, [x], True)
+        gates, _ = router.forward(p["blk1_moe_router"], {}, [n], True)
+        h = x + attn.forward(p["blk1_attn_window"], {}, [n], True)[0]
+        m, _ = norm2.forward(p["blk1_norm2"], {}, [h], True)
+        held = []
+        for lo in range(0, 16, 4):
+            op = share(lo)
+            ps = {k: v[lo:lo + 4] for k, v in p["blk1_moe_experts"].items()}
+            held.append(op.forward(ps, op.init_state(), [m, gates], True))
+        return h, held
+
+    total, held = shares(x)
+    pairs = 0
+    for lo, (y, st) in zip(range(0, 16, 4), held):
         assert float(st["dropped"]) == 0
         pairs += int(jnp.sum(st["counts"][lo:lo + 4]))
         total = total + y
     assert pairs == 3 * 48, "every selection falls in exactly one share"
     with jax.default_matmul_precision("highest"):
-        want = ref._block(p, layer, x, c)
+        want = jax.jit(lambda x: ref._block(p, layer, x, c))(x)
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-4)
 
 
@@ -312,11 +328,11 @@ def test_loss_and_every_operator_gradient_against_the_reference(
     attention in the flash kernels (windowed and full) behind ``ff_rope``
     on the windowed layers, and the ReGLU experts in ``ff_gmm``, inside
     recomputed blocks."""
-    from benchmarks import harness
     from flexflow_tpu.models.smallthinker import (SmallThinkerConfig,
                                                   SmallThinkerLM)
 
     ff, cfg, b, s = tiny_model, _tiny_config(), 2, 20
+    kernels = contextlib.nullcontext
     if path == "kernels":
         fa = importlib.import_module(
             "flexflow_tpu.ops.pallas.flash_attention")
@@ -331,40 +347,19 @@ def test_loss_and_every_operator_gradient_against_the_reference(
         ff = SmallThinkerLM(SmallThinkerConfig.from_config(
             cfg, batch_size=b, seq_length=s),
             MachineModel(jax.devices()[:1]))
-    params, state = ff.init(4)
-    # every gain away from its initial value
-    params = jax.tree.map(
-        lambda a: a + 0.1 * _rand(a.size % 97, *a.shape), params)
-    toks = jax.random.randint(jax.random.PRNGKey(5), (b, s), 0,
-                              cfg["vocab_size"])
+        kernels = pallas_kernels
+        with kernels():
+            assert ff._lm_head_fusion()
     names = ("kernels.flash.pack1.fused.w64", "kernels.flash.pack1.fused",
              "kernels.rope.pallas.2x128r128", "attn.rope.none",
              "kernels.gmm.ff_gmm.512x128x128")
     before = {k: _counted(k) for k in names}
-    with pallas_kernels() if path == "kernels" else \
-            contextlib.nullcontext():
-        if path == "kernels":
-            assert ff._lm_head_fusion()
-        (loss, _), grads = jax.value_and_grad(
-            lambda p: ff.loss_fn(p, state, toks, toks), has_aux=True)(params)
+    lm_check.check_against_reference(ff, cfg, _reference(), b, s, kernels)
     if path == "kernels":
         # two windowed layers, one full without rotary, three expert
         # layers through ff_gmm
         assert {k: _counted(k) - v for k, v in before.items()} == dict(
             zip(names, (2, 1, 2, 1, 3)))
-    plain = harness.op_params(ff, params)
-    with jax.default_matmul_precision("highest"):
-        total, want, n = _reference().sum_loss_and_grads(plain, (toks, toks),
-                                                         cfg)
-    assert n == b * (s - 1)
-    np.testing.assert_allclose(loss, total / n, rtol=1e-5)
-    assert set(want) == set(grads)
-    for op, leaves in want.items():
-        for leaf, g in leaves.items():
-            scale = float(jnp.max(jnp.abs(g))) / n
-            np.testing.assert_allclose(
-                grads[op][leaf], g / n, rtol=2e-3, atol=2e-4 * scale + 1e-9,
-                err_msg=f"{op}.{leaf}")
 
 
 def test_recomputed_step_trains_and_counts_the_mechanisms(tiny_model):

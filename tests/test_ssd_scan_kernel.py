@@ -80,10 +80,14 @@ def _leaves(case, form):
     fn = {"kernels": functools.partial(_kernels, chunk=chunk),
           "chunked": functools.partial(_chunked, chunk=chunk),
           "recurrence": _recurrence}[form]
+    value_and_grads = jax.value_and_grad(
+        lambda *a: (lambda y: (jnp.sum(y * weight), y))(fn(*a)),
+        argnums=range(6), has_aux=True)
+    if form != "recurrence":
+        # (the recurrence, a step at a time, is slow to compile unrolled)
+        value_and_grads = jax.jit(value_and_grads)
     with jax.default_matmul_precision("highest"):
-        y, grads = jax.value_and_grad(
-            lambda *a: (lambda y: (jnp.sum(y * weight), y))(fn(*a)),
-            argnums=range(6), has_aux=True)(*ops)
+        y, grads = value_and_grads(*ops)
     return dict(zip(LEAVES, (y[1], *grads)))
 
 
@@ -222,8 +226,8 @@ def test_operator_through_the_kernels_equals_the_operator_without(
           jax.nn.softplus(_rand(2, 2, 256, 8) - 1.0)]
     f = lambda p, xs: jnp.sum(op.forward(p, {}, xs, True)[0] ** 2)
     with pallas_kernels():
-        got = jax.value_and_grad(f, argnums=(0, 1))(params, xs)
-    want = jax.value_and_grad(f, argnums=(0, 1))(params, xs)
+        got = jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(params, xs)
+    want = jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(params, xs)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(
             a, b, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(b))))

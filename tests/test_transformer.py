@@ -46,9 +46,9 @@ def test_ring_matches_dense(machine8, causal):
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     # gradient parity
-    g_ref = jax.grad(lambda q: dense_attn(q, k, v, causal).sum())(q)
-    g_ring = jax.grad(
-        lambda q: ring_attention(q, k, v, mesh, "s", causal).sum())(q)
+    g_ref = jax.jit(jax.grad(lambda q: dense_attn(q, k, v, causal).sum()))(q)
+    g_ring = jax.jit(jax.grad(
+        lambda q: ring_attention(q, k, v, mesh, "s", causal).sum()))(q)
     np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_ref),
                                rtol=1e-4, atol=1e-5)
 
