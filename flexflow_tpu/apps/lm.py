@@ -19,6 +19,8 @@ transformers); flags follow the house style of the reference parsers
     python -m flexflow_tpu.apps.lm --model-config \
         benchmarks/configs/smallthinker_21b_a3b.json --preset rehearsal \
         -b 2 -s 32
+    python -m flexflow_tpu.apps.lm --model-config \
+        benchmarks/configs/ling_3_0_flash.json --preset rehearsal -b 2 -s 32
 
 ``--model-config`` names a file of a public ``config.json``'s keys
 (``model_type`` ``deepseek_v3``: latent attention and expert layers,
@@ -30,7 +32,9 @@ grouped-query attention under q/k norms over a sigmoid top-k expert layer
 with a selection bias, a tied head, ``models/lfm2.py``; a file without a
 ``model_type`` by its ``model_name``: ``smallthinker_21b_instruct``,
 NoPE full and rotary windowed attention over ReGLU experts routed before
-attention, ``models/smallthinker.py``; ``benchmarks/builders/
+attention, ``models/smallthinker.py``; ``bailing_hybrid``: Kimi delta
+attention and latent attention layers over a sigmoid router limited to
+groups, ``models/ling_hybrid.py``; ``benchmarks/builders/
 model_config_lm.py`` builds any of them for the benchmark through
 ``model_of_config``; ``--preset`` lays one of the file's own named groups
 of keys over it); without it the flags
@@ -310,9 +314,24 @@ def _smallthinker(config, over):
         f"{t.router_outputs} held")
 
 
+def _ling(config, over):
+    from flexflow_tpu.models.ling_hybrid import LingHybridConfig, LingHybridLM
+
+    t = LingHybridConfig.from_config(config, **over)
+    mla = sum(t.is_mla(i) for i in range(t.num_layers))
+    return t, LingHybridLM, (
+        f"{t.num_layers} blocks ({t.num_layers - mla} KDA, {mla} MLA; "
+        f"{min(t.first_k_dense_replace, t.num_layers)} dense), hidden "
+        f"{t.hidden_size}, {t.num_attention_heads} heads, experts "
+        f"[{t.experts_held[0]}, {t.experts_held[1]}) of "
+        f"{t.router_outputs} held, top {t.num_experts_per_tok} in "
+        f"{t.topk_group} of {t.n_group} groups")
+
+
 #: ``model_type`` of a configuration file -> the class that builds it
 MODEL_TYPES = {"deepseek_v3": _latent_moe, "granitemoehybrid": _hybrid_ssm,
-               "laguna": _laguna, "lfm2_moe": _lfm2}
+               "laguna": _laguna, "lfm2_moe": _lfm2,
+               "bailing_hybrid": _ling}
 #: ``model_name`` of a file that has no ``model_type`` (SmallThinker's
 #: ``config.json``) -> the class that builds it
 MODEL_NAMES = {"smallthinker_21b_instruct": _smallthinker}

@@ -427,13 +427,14 @@ class FFModel:
         return self._add(GatedFFNSeq(name, self._pc(name, 2), input, d_ff))
 
     def latent_attention(self, name, input, num_heads, kv_rank, nope_dim,
-                         rope_dim, v_dim, rope_theta,
-                         eps: float = 1e-5) -> Tensor:
+                         rope_dim, v_dim, rope_theta, eps: float = 1e-5,
+                         qk_norm: float = None,
+                         head_gate: bool = False) -> Tensor:
         from flexflow_tpu.ops.latent_attention import LatentAttention
 
         return self._add(LatentAttention(
             name, self._pc(name, 3), input, num_heads, kv_rank, nope_dim,
-            rope_dim, v_dim, rope_theta, eps))
+            rope_dim, v_dim, rope_theta, eps, qk_norm, head_gate))
 
     def grouped_query_attention(self, name, input, num_heads, num_kv_heads,
                                 head_dim, scale, rope=None, window=None,
@@ -470,16 +471,32 @@ class FFModel:
         return self._add(SSMOut(name + "_out", self._pc(name + "_out", 2),
                                 y, first.z, input.shape[2], eps))
 
+    def kda_mixer(self, name, input, num_heads, head_dim, taps,
+                  lower_bound, eps: float = 1e-6) -> Tensor:
+        """A Kimi-delta-attention mixer as its three operators
+        (ops/kda.py): ``<name>_in``, ``<name>_scan`` and ``<name>_out``."""
+        from flexflow_tpu.ops.kda import KDAIn, KDAOut, KDAScan
+
+        first = KDAIn(name + "_in", self._pc(name + "_in", 2), input,
+                      num_heads, head_dim, taps, lower_bound)
+        self._add(first)
+        o = self._add(KDAScan(name + "_scan", self._pc(name + "_scan", 2),
+                              first.q, first.k, first.v, first.g, first.beta,
+                              num_heads, head_dim, lower_bound))
+        return self._add(KDAOut(name + "_out", self._pc(name + "_out", 2),
+                                o, first.z, head_dim, input.shape[2], eps))
+
     def top_k_router(self, name, input, n_router, top_k, scale,
                      bias_update_rate: float = 1e-3,
                      score: str = "sigmoid",
-                     denominator_eps: float = 0.0) -> Tensor:
+                     denominator_eps: float = 0.0, n_group: int = 1,
+                     topk_group: int = 1) -> Tensor:
         from flexflow_tpu.ops.expert_share import TopKRouter
 
         return self._add(TopKRouter(name, self._pc(name, 2), input,
                                     n_router, top_k, scale,
                                     bias_update_rate, score,
-                                    denominator_eps))
+                                    denominator_eps, n_group, topk_group))
 
     def held_experts(self, name, input, gates, d_ff, experts_held, top_k,
                      capacity_factor: float = 2.0,

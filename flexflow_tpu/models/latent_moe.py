@@ -2,7 +2,9 @@
 ``deepseek_v3``; Moonlight-16B-A3B is one): pre-norm blocks of latent
 attention and a gated feed-forward, the first ``first_k_dense_replace``
 of them dense and the rest expert layers (a sigmoid router over all the
-layer's experts, the experts this chip holds, the shared experts), RMSNorm
+layer's experts, its choice limited to the ``topk_group`` best of
+``n_group`` groups where there are groups, the experts this chip holds,
+the shared experts), RMSNorm
 throughout, rotary positions inside the attention, an untied head without
 bias.  The field names are the public ``config.json``'s.
 
@@ -51,6 +53,8 @@ class LatentMoEConfig:
     router_outputs: int = 8             # experts of a layer, on all chips
     experts_held: Tuple[int, int] = (0, 8)
     num_experts_per_tok: int = 2
+    n_group: int = 1                    # the top k within the topk_group
+    topk_group: int = 1                 # best of n_group groups
     n_shared_experts: int = 2
     routed_scaling_factor: float = 1.0
     rope_theta: float = 10000.0
@@ -73,7 +77,7 @@ class LatentMoEConfig:
         mechanisms this class builds are accepted."""
         want = {"scoring_func": "sigmoid", "topk_method": "noaux_tc",
                 "norm_topk_prob": True, "hidden_act": "silu",
-                "n_group": 1, "topk_group": 1, "moe_layer_freq": 1,
+                "moe_layer_freq": 1,
                 "q_lora_rank": None, "attention_bias": False,
                 "tie_word_embeddings": False,
                 "num_nextn_predict_layers": 0}
@@ -133,7 +137,8 @@ class LatentMoELM(NextTokenLM):
                 gates = self.top_k_router(
                     f"blk{i}_moe_router", h, t.router_outputs,
                     t.num_experts_per_tok, t.routed_scaling_factor,
-                    t.bias_update_rate, score="sigmoid")
+                    t.bias_update_rate, score="sigmoid", n_group=t.n_group,
+                    topk_group=t.topk_group)
                 routed = self.held_experts(
                     f"blk{i}_moe_experts", h, gates,
                     t.moe_intermediate_size, t.experts_held,

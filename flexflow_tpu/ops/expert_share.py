@@ -20,6 +20,15 @@ the model's published code adds one: ``lfm2_moe`` 1e-6).  The score rule
 * ``softmax``: the softmax over all the layer's logits, the selection by
   the score itself; no bias and no state.
 
+Under ``n_group`` > 1 (DeepSeek-V3's ``noaux_tc`` with groups, as
+``bailing_hybrid`` has it) the selection is limited to groups first: the
+router's outputs fall into ``n_group`` groups of consecutive experts, a
+group is ranked by the sum of its two largest ranking values, the
+``topk_group`` best groups are kept, and the ``top_k`` are chosen among
+their experts alone; a traced router counts
+``moe.router_groups.<topk_group>of<n_group>``.  At one group the program
+is the ungrouped one.
+
 :class:`HeldExperts` is told which experts it holds (``experts_held``, a
 range), and computes their part of ``sum_e weight_e * E_e(x)`` for the
 tokens routed to them; what the other chips' experts would add is left
@@ -91,18 +100,25 @@ class TopKRouter(Op):
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  n_router: int, top_k: int, scale: float,
                  bias_update_rate: float = 1e-3, score: str = "sigmoid",
-                 denominator_eps: float = 0.0):
+                 denominator_eps: float = 0.0, n_group: int = 1,
+                 topk_group: int = 1):
         super().__init__(name, pc, [input])
         assert input.ndim == 3
         if score not in SCORE_RULES:
             raise ValueError(f"op {name!r}: score rule {score!r}, one of "
                              f"{SCORE_RULES}")
+        if n_router % n_group or not 1 <= topk_group <= n_group \
+                or top_k > topk_group * (n_router // n_group) \
+                or (n_group > 1 and n_router // n_group < 2):
+            raise ValueError(f"op {name!r}: top {top_k} of {topk_group} "
+                             f"of {n_group} groups over {n_router} experts")
         self.d = input.shape[2]
         self.n_router, self.top_k = int(n_router), int(top_k)
         self.scale = float(scale)
         self.score = score
         self.bias_update_rate = float(bias_update_rate)
         self.denominator_eps = float(denominator_eps)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         self.output = Tensor(input.shape[:2] + (self.n_router,), "float32",
                              self, name)
 
@@ -150,6 +166,8 @@ class TopKRouter(Op):
         else:
             score = jax.nn.softmax(logits, axis=-1)
             ranked = jax.lax.stop_gradient(score)
+        if self.n_group > 1:
+            ranked = self._in_kept_groups(ranked)
         _, chosen = jax.lax.top_k(ranked, self.top_k)
         mask = jnp.sum(jax.nn.one_hot(chosen, self.n_router,
                                       dtype=jnp.float32), axis=-2)
@@ -165,6 +183,21 @@ class TopKRouter(Op):
         bias = state["bias"] + self.bias_update_rate * jnp.sign(
             jnp.mean(load) - load)
         return gates, {"bias": jax.lax.stop_gradient(bias)}
+
+    def _in_kept_groups(self, ranked):
+        """``ranked`` with -inf outside the ``topk_group`` groups whose
+        two largest values sum highest."""
+        import jax
+        import jax.numpy as jnp
+
+        obs.count(f"moe.router_groups.{self.topk_group}of{self.n_group}")
+        grouped = ranked.reshape(ranked.shape[:-1] + (self.n_group, -1))
+        best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(best, self.topk_group)
+        keep = jnp.sum(jax.nn.one_hot(kept, self.n_group, dtype=jnp.int32),
+                       axis=-2) > 0
+        return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
+            ranked.shape)
 
     def flops_per_sample(self) -> float:
         return 2.0 * self.output.shape[1] * self.d * self.n_router

@@ -12,9 +12,24 @@ its value is ``v_dim`` wide (128):
     out          = softmax(RoPE'd q k^T / sqrt(nope + rope), causal) v
     y            = out Wo
 
-No bias, no query compression (``q_lora_rank`` null).  Training computes
-the keys and values in full; the absorbed form that serves from the
-compressed cache is not built (ROADMAP queue 2).  On the TPU the scores
+Two things a model may add, each off by default, and without them the
+operator is what it was, parameter for parameter (``deepseek_v3`` takes
+neither, ``bailing_hybrid`` both):
+
+* ``qk_norm``: the eps of an RMSNorm over each head's ``nope + rope``
+  values of q and of k (the shared rotary key broadcast to every head
+  first), one learned gain for q (``q_norm``) and one for k (``k_norm``)
+  shared by their heads, before the rotary turn (``attn.qk_norm``);
+* ``head_gate``: one more matrix ``wg`` (hidden x heads) and ``sigmoid(x
+  wg)``, one number a head and token, on that head's result before ``wo``
+  (``attn.head_gate``).
+
+The rotary pairing is the published ``rope_interleave`` one: a head's
+turned dimensions de-interleaved and the halves turned against each other
+(``apply_rope``'s ``split``; ``attn.rope.interleave`` counts a traced
+layer).  No bias, no query compression (``q_lora_rank`` null).
+Training computes the keys and values in full; the absorbed form that
+serves from the compressed cache is not built (ROADMAP queue 2).  On the TPU the scores
 run in the flash kernels at query/key width 192 and value width 128
 (``kernels.flash.pad256v128.fused`` at 8192 positions); elsewhere in
 plain XLA.  Grid ('s', 'h', 'n') as the attention operator's, of which
@@ -57,7 +72,8 @@ class LatentAttention(Op):
 
     def __init__(self, name: str, pc: ParallelConfig, input: Tensor,
                  num_heads: int, kv_rank: int, nope_dim: int, rope_dim: int,
-                 v_dim: int, rope_theta: float, eps: float = 1e-5):
+                 v_dim: int, rope_theta: float, eps: float = 1e-5,
+                 qk_norm: float = None, head_gate: bool = False):
         super().__init__(name, pc, [input])
         assert input.ndim == 3
         self.d_model = input.shape[2]
@@ -67,6 +83,8 @@ class LatentAttention(Op):
         self.v_dim = int(v_dim)
         self.rope_theta = float(rope_theta)
         self.eps = float(eps)
+        self.qk_norm = None if qk_norm is None else float(qk_norm)
+        self.head_gate = bool(head_gate)
         self.output = Tensor(input.shape, input.dtype, self, name)
 
     @property
@@ -75,10 +93,20 @@ class LatentAttention(Op):
 
     def _shapes(self) -> Dict:
         d, h = self.d_model, self.num_heads
-        return {"wq": (d, h * self.qk_dim),
-                "wkva": (d, self.kv_rank + self.rope_dim),
-                "wkvb": (self.kv_rank, h * (self.nope_dim + self.v_dim)),
-                "wo": (h * self.v_dim, d)}
+        shapes = {"wq": (d, h * self.qk_dim),
+                  "wkva": (d, self.kv_rank + self.rope_dim),
+                  "wkvb": (self.kv_rank, h * (self.nope_dim + self.v_dim)),
+                  "wo": (h * self.v_dim, d)}
+        if self.head_gate:
+            shapes["wg"] = (d, h)
+        return shapes
+
+    def _gains(self) -> Dict:
+        """The norms' gain vectors by name and width."""
+        gains = {"kv_norm": self.kv_rank}
+        if self.qk_norm is not None:
+            gains.update(q_norm=self.qk_dim, k_norm=self.qk_dim)
+        return gains
 
     def init_params(self, rng) -> Dict:
         import jax
@@ -89,15 +117,16 @@ class LatentAttention(Op):
         init = jax.nn.initializers.glorot_uniform()
         p = {k: init(key, shape, "float32")
              for key, (k, shape) in zip(keys, shapes.items())}
-        p["kv_norm"] = jnp.ones((self.kv_rank,), "float32")
+        for k, width in self._gains().items():
+            p[k] = jnp.ones((width,), "float32")
         return p
 
     def param_specs(self):
         from jax.sharding import PartitionSpec as P
 
-        return {"wq": P(None, None), "wkva": P(None, None),
-                "wkvb": P(None, None), "wo": P(None, None),
-                "kv_norm": P(None)}
+        specs = {k: P(None, None) for k in self._shapes()}
+        specs.update({k: P(None) for k in self._gains()})
+        return specs
 
     def output_spec(self):
         from jax.sharding import PartitionSpec as P
@@ -118,6 +147,7 @@ class LatentAttention(Op):
                 f"parts) is not implemented")
 
     def forward(self, params, state, xs: List, train: bool):
+        import jax
         import jax.numpy as jnp
 
         from flexflow_tpu import obs
@@ -139,28 +169,56 @@ class LatentAttention(Op):
         c = rms_norm(ckv[..., :self.kv_rank], params["kv_norm"], self.eps)
         kv = proj(c, params["wkvb"]).reshape(b, s, h, nope + vd)
         cos, sin = rope_angles(s, rope, self.rope_theta)
-        # the turned dimensions lie behind ``nope`` of a ``nope + rope``
-        # wide head, on no lane tile: apply_rope (``pallas/rope.fits``)
-        obs.count(f"kernels.rope.xla.{h}x{nope + rope}r{rope}")
-        obs.count(f"kernels.rope.xla.1x{rope}r{rope}")
-        q_pe = apply_rope(q[..., nope:], cos, sin)
-        k_pe = apply_rope(ckv[..., self.kv_rank:], cos, sin)
-        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_pe[:, :, None, :], (b, s, h, rope))],
-            axis=-1)
+        obs.count("attn.rope.interleave")
+        if self.qk_norm is None:
+            # the turned dimensions lie behind ``nope`` of a ``nope +
+            # rope`` wide head, on no lane tile: apply_rope
+            # (``pallas/rope.fits``)
+            obs.count(f"kernels.rope.xla.{h}x{nope + rope}r{rope}")
+            obs.count(f"kernels.rope.xla.1x{rope}r{rope}")
+            q_pe = apply_rope(q[..., nope:], cos, sin)
+            k_pe = apply_rope(ckv[..., self.kv_rank:], cos, sin)
+            q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_pe[:, :, None, :], (b, s, h, rope))],
+                axis=-1)
+        else:
+            # normed a head, the shared rotary key with each head's own
+            # statistics, then turned
+            obs.count("attn.qk_norm")
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(
+                    ckv[:, :, None, self.kv_rank:], (b, s, h, rope))],
+                axis=-1)
+            q, k = (rms_norm(y, params[gain], self.qk_norm)
+                    for y, gain in ((q, "q_norm"), (k, "k_norm")))
+            obs.count(f"kernels.rope.xla.{h}x{nope + rope}r{rope}", 2)
+            q, k = (jnp.concatenate([y[..., :nope],
+                                     apply_rope(y[..., nope:], cos, sin)],
+                                    axis=-1) for y in (q, k))
         q, k = q.reshape(b, s, -1), k.reshape(b, s, -1)
         v = kv[..., nope:].reshape(b, s, h * vd)
         if pallas.flash_enabled():
             out = flash_attention_packed(q, k, v, h, causal=True)
         else:
             out = causal_attention(q, k, v, h)
-        return proj(out.astype(x.dtype), params["wo"]), state
+        out = out.astype(x.dtype)
+        if self.head_gate:
+            obs.count("attn.head_gate")
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", x, params["wg"].astype(x.dtype),
+                preferred_element_type=jnp.float32))
+            out = (out.reshape(b, s, h, vd) * gate[..., None]
+                   ).astype(x.dtype).reshape(b, s, h * vd)
+        return proj(out, params["wo"]), state
 
     def cost_signature(self) -> tuple:
-        return (self.num_heads, self.kv_rank, self.nope_dim, self.rope_dim,
-                self.v_dim)
+        sig = (self.num_heads, self.kv_rank, self.nope_dim, self.rope_dim,
+               self.v_dim)
+        if self.qk_norm is not None or self.head_gate:
+            sig += (self.qk_norm, self.head_gate)
+        return sig
 
     def flops_per_sample(self) -> float:
         s = self.output.shape[1]
@@ -171,4 +229,4 @@ class LatentAttention(Op):
 
     def param_bytes(self) -> int:
         return 4 * (sum(a * b_ for a, b_ in self._shapes().values())
-                    + self.kv_rank)
+                    + sum(self._gains().values()))

@@ -161,7 +161,10 @@ def main() -> int:
     params, state, loss, ours = read(own_std)
     out.update(embedding_std=own_std, starting_loss=loss, **load_of(ours))
     say("the program's selections read")
-    plain = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32),
+    # on the host: each reference gradient below takes its own device copy
+    # and gives it up to the gradient (donated), so that a reference that
+    # fits beside one float32 tree of the weights fits here
+    plain = jax.tree.map(lambda a: np.asarray(a, np.float32),
                          built["op_params"](params))
     del params
     with jax.default_matmul_precision("highest"):
@@ -187,7 +190,8 @@ def main() -> int:
         try:
             with jax.default_matmul_precision("highest"):
                 loss, g, n = jax.jit(lambda p, b: ref.sum_loss_and_grads(
-                    p, b, config))(plain, batch)
+                    p, b, config), donate_argnums=0)(
+                        jax.device_put(plain), batch)
             n = int(n)
             say(f"reference gradient, operands {operands}, router {router}")
             return float(loss) / n, jax.tree.map(
@@ -214,6 +218,7 @@ def main() -> int:
                      "median_op_grad_rel_l2": float(np.median(
                          [v["raw"] for v in per.values()])),
                      "seconds": seconds}
+        say(f"{name}: {json.dumps(out[name])}")
         del g
     line = json.dumps(out)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
